@@ -95,18 +95,6 @@ _KNOBS: List[Knob] = [
        "daft_tpu/device/backend.py", "device",
        "seconds to wait for device-backend initialization before falling "
        "back to host"),
-    _k("DAFT_TPU_COMPILATION_CACHE", "str", None,
-       "daft_tpu/device/backend.py", "device",
-       "persistent XLA compilation-cache directory (amortizes remote "
-       "compiles across processes)"),
-    _k("DAFT_TPU_COMPILE_CACHE", "str", None, "daft_tpu/device/backend.py",
-       "device", "legacy alias of `DAFT_TPU_COMPILATION_CACHE`"),
-    _k("DAFT_TPU_COMPILE_CACHE_DIR", "str", None,
-       "daft_tpu/device/backend.py", "device",
-       "explicit persistent XLA compilation-cache directory for ANY "
-       "backend (CPU included — same-machine opt-in, bypassing the "
-       "TPU-only default): AOT warm-up compiles survive process "
-       "restarts"),
     _k("DAFT_TPU_SIZE_CLASSES", "str", "pow2", "daft_tpu/device/column.py",
        "device", "size-class ladder batches pad to: `pow2` (default), "
        "`pow4` (coarser: 4x steps, fewer distinct programs, more "
@@ -117,8 +105,9 @@ _KNOBS: List[Knob] = [
        "device", "`1` AOT-compiles (`jit(...).lower().compile()`) the "
        "device kernel library — and any already-compiled fused "
        "fragments — over the size-class x strategy grid at serving "
-       "startup, so first queries re-enter warm programs; pairs with "
-       "`DAFT_TPU_COMPILE_CACHE_DIR` to survive restarts",
+       "startup, so first queries re-enter warm programs; the "
+       "persistent compile cache (`JAX_COMPILATION_CACHE_DIR`, else "
+       "`<repo>/.cache/jax` off-CPU) makes them survive restarts",
        config_field="tpu_aot_warmup"),
     _k("DAFT_TPU_FUSION", "str", "auto", "daft_tpu/physical/fusion.py",
        "device", "whole-query fusion regions (round 21): `auto` lets the "
@@ -151,13 +140,6 @@ _KNOBS: List[Knob] = [
        "daft_tpu/device/costmodel.py", "device",
        "path of the persisted link profile (default: under the user cache "
        "dir)", default_str="auto"),
-    _k("DAFT_TPU_PEAK_FLOPS", "float", 197e12,
-       "daft_tpu/device/costmodel.py", "device",
-       "chip peak FLOP/s the MFU ledger normalizes against (default: "
-       "v5e bf16)", default_str="197e12"),
-    _k("DAFT_TPU_HBM_BPS", "float", 819e9, "daft_tpu/device/costmodel.py",
-       "device", "chip HBM bandwidth the roofline normalizes against",
-       default_str="819e9"),
     _k("DAFT_TPU_DISPATCH_LOG", "str", None, "daft_tpu/device/costmodel.py",
        "device", "JSONL path appending one record per real device dispatch"),
     _k("DAFT_TPU_CACHE_INVEST", "bool", True,

@@ -1,13 +1,17 @@
 """Hash-based relational kernels as Pallas programs (round 12).
 
-The r6 sort-based kernels close the dispatch-count gap but leave ~400x of
-the roofline on the table (BENCH_r05 ``mfu`` block: grouped-agg at 0.012%
-MFU / 0.067% of the memory roofline, join at 0.004%): every radix pass
-re-streams every packed key plane through HBM, and the segment reductions
-re-stream the value planes once more. The kernels here are the one-pass
-hash formulation the reference engine uses host-side (probe tables,
-``src/daft-recordbatch/src/probeable/probe_table.rs``), rebuilt as
-TPU Pallas programs:
+STATUS (PR 23): INTERPRETER-ONLY. These kernels have never compiled for a
+TPU. Asked for a described ``v5e:2x2`` (JAX 0.9.0 / libtpu 0.0.34,
+``interpret=False``), the TPU kernel compiler refuses both entry points
+with ``NotImplementedError: Unimplemented primitive in Pallas TPU lowering
+for KernelType.TC: dynamic_slice`` — the bodies index vectors with traced
+scalars, scatter with ``.at[j].set/add/min/max`` inside
+``fori_loop``/``while_loop`` and carry ``uint64`` words. So the cost model
+(``costmodel._hash_capable_backend``) never picks them in ``auto``; a
+forced ``DAFT_TPU_KERNEL_GROUPBY=hash`` / ``DAFT_TPU_KERNEL_JOIN=hash`` on
+an accelerator raises the compiler's error. Rewrite-for-the-TPU or delete
+is ROADMAP A2(b). What follows describes the design, which the Pallas
+interpreter executes on the CPU (parity tests only):
 
 - ``hash_grouped_agg_impl``: an open-addressing hash table (linear
   probing over the r6 packed u64 key codes — ``kernels._sort_codes`` /
@@ -23,20 +27,14 @@ TPU Pallas programs:
   re-dispatch contract as ``kernels.join_fused_impl``, same pair order
   (left-major, ascending right row), so it is a drop-in strategy swap.
 
-Kernel shape (why the table rides VMEM values, not per-element refs):
-each grid step streams one row block HBM→VMEM, loads the table planes
-into loop-carried VALUES, runs the probe/insert loop as pure JAX
-(``lax.while_loop`` probing, ``.at[].set/add/min/max`` updates — XLA
-keeps loop-carried buffers in place), and writes the planes back once.
-Grid steps execute sequentially on TPU, so the single-writer table needs
-no atomics, and the only HBM traffic is ONE pass over the rows plus the
-table spill/fill per block — the one-pass story the MFU ledger prices.
-Tables above ``DAFT_TPU_KERNEL_MAX_TABLE`` slots do not fit VMEM and the
-cost model keeps those dispatches on the sort path.
+Intended kernel shape: each grid step streams one row block, loads the
+table planes into loop-carried VALUES, runs the probe/insert loop as pure
+JAX (``lax.while_loop`` probing, ``.at[].set/add/min/max`` updates), and
+writes the planes back once. Tables above ``DAFT_TPU_KERNEL_MAX_TABLE``
+slots stay on the sort path.
 
-CPU backends (the tier-1 dev box) run the identical kernels under the
-Pallas interpreter (``interpret=True``) so parity is provable without
-silicon; ``DAFT_TPU_KERNEL_INTERPRET`` overrides the auto-detection.
+``interpret_default()``: the CPU backend interprets; an accelerator NEVER
+interprets unless the user set ``DAFT_TPU_KERNEL_INTERPRET=1`` themselves.
 """
 
 from __future__ import annotations
@@ -71,9 +69,12 @@ class HashKeyWidthError(ValueError):
 # ------------------------------------------------------------ configuration
 
 def interpret_default() -> bool:
-    """Pallas interpreter mode unless a real accelerator is attached.
-    Stable per process (the backend cannot change under us), so reading it
-    at trace time cannot mask a retrace."""
+    """Pallas interpreter mode on the CPU backend only. On an accelerator
+    this is False unless the user set ``DAFT_TPU_KERNEL_INTERPRET=1``
+    themselves — a kernel the chip's compiler refuses must fail loudly,
+    not crawl through the emulator. Stable per process (the backend
+    cannot change under us), so reading it at trace time cannot mask a
+    retrace."""
     from ..analysis import knobs
     v = knobs.env_raw("DAFT_TPU_KERNEL_INTERPRET")
     if v is not None:

@@ -3,9 +3,11 @@ first query needs it (``DAFT_TPU_AOT_WARMUP=1``).
 
 ROADMAP item 1's warm-up tax (55s of first-query traces + compiles in
 r12) is paid once per (program, size class) — so pay it at session
-start, off the query path, and PERSIST it: with
-``DAFT_TPU_COMPILE_CACHE_DIR`` set, every ``jit(...).lower().compile()``
-here lands in the XLA compilation cache, and the next process re-loads
+start, off the query path, and PERSIST it: with the persistent compile
+cache on (``JAX_COMPILATION_CACHE_DIR`` if set, else ``<repo>/.cache/jax``
+on a non-CPU backend — ``backend.configure_compile_cache``), every
+``jit(...).lower().compile()`` here lands in it, and the next process
+re-loads
 the executable from disk instead of re-compiling (tracing still runs,
 but tracing is milliseconds; compiling was the seconds).  This is the
 piece the r11 serving plane's single-flight compile cache needed to

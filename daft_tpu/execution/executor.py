@@ -629,13 +629,17 @@ class LocalExecutor:
                 return fragment.submit_fused_agg(
                     prog, rb, node.group_by, agg_cols, node.schema(),
                     groups=getattr(node, "group_ndv", None))
-            except Exception:  # device OOM / lowering failure → host tier
+            except Exception as exc:
+                # lowering/compile errors propagate; only device resource
+                # exhaustion degrades to the host, counted
+                drt.device_failed("executor.fused_agg.submit", exc)
                 return None
 
         def drain_device_agg(tok) -> Optional[MicroPartition]:
             try:
                 out = fragment.drain_fused_agg_table(tok)
-            except Exception:  # device failure mid-flight → host tier
+            except Exception as exc:
+                drt.device_failed("executor.fused_agg.drain", exc)
                 return None
             if out is None:
                 return None
@@ -992,13 +996,15 @@ class LocalExecutor:
             try:
                 return fragment.submit_region(prog, rb, node.exprs,
                                               node.schema())
-            except Exception:
+            except Exception as exc:
+                drt.device_failed("executor.region.submit", exc)
                 return None
 
         def device_drain(tok) -> Optional[MicroPartition]:
             try:
                 out = fragment.drain_region(tok)
-            except Exception:
+            except Exception as exc:
+                drt.device_failed("executor.region.drain", exc)
                 return None
             if out is None:
                 return None
@@ -1139,13 +1145,15 @@ class LocalExecutor:
                 return fragment.submit_join_agg(
                     prog, rb, build, node.group_by, agg_cols,
                     node.schema(), start_out_cap=g_hint[0])
-            except Exception:
+            except Exception as exc:
+                drt.device_failed("executor.join_agg.submit", exc)
                 return None
 
         def device_drain(tok) -> Optional[MicroPartition]:
             try:
                 res = fragment.drain_join_agg(tok)
-            except Exception:
+            except Exception as exc:
+                drt.device_failed("executor.join_agg.drain", exc)
                 return None
             if res is None:
                 return None
@@ -1278,7 +1286,8 @@ class LocalExecutor:
                 tuple(sb(v) for v in vals),
                 tuple(sb(v) for v in vvalids), sb(mask), ops)
             host = jax.device_get((fk, fkv, fv, fvv, gmask))
-        except Exception:
+        except Exception as exc:
+            drt.device_failed("executor.mesh.grouped_agg", exc)
             return None
         _count_ici_exchange(total, list(keys) + list(vals),
                             list(kvalids) + list(vvalids))
@@ -1358,7 +1367,8 @@ class LocalExecutor:
                 mesh, tuple(sb(p) for p in planes),
                 tuple(sb(v) for v in valids), sb(mask), sb(pid))
             host = jax.device_get((op, ov, om))
-        except Exception:
+        except Exception as exc:
+            drt.device_failed("executor.mesh.hash_repartition", exc)
             return None
         _count_ici_exchange(total, planes, valids)
         op, ov, om = [[np.asarray(a) for a in grp]

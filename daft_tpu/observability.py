@@ -118,6 +118,16 @@ def _ledger_raw() -> Dict[str, dict]:
         return {}
 
 
+def _device_failures_raw() -> Dict[str, dict]:
+    """Snapshot of device work that failed and ran on the host instead
+    (``runtime.device_failed``): ``{site: {"count", "first_error"}}``."""
+    try:
+        from .device import costmodel
+        return costmodel.failures_snapshot()
+    except Exception:
+        return {}
+
+
 def _recovery_raw() -> Dict[str, int]:
     """Raw snapshot of the distributed resilience counters (retries,
     quarantines, recomputed map tasks, speculative wins/losses …) —
@@ -328,6 +338,9 @@ class RuntimeStatsContext:
         # process-wide ledger now, diff at finish() → this query's share
         self._ledger0 = _ledger_raw()
         self.device_kernels: Dict[str, dict] = {}
+        # …device work that failed and was replaced by a host run
+        self._device_failures0 = _device_failures_raw()
+        self.device_failures: Dict[str, dict] = {}
         # same pattern for the resilience plane's recovery events
         self._recovery0 = _recovery_raw()
         self.recovery: Dict[str, int] = {}
@@ -436,6 +449,15 @@ class RuntimeStatsContext:
                     self._ledger0, _ledger_raw())
         except Exception:
             self.device_kernels = {}
+        fails = _device_failures_raw()
+        counts = self._plane("device_failures") if self._attributed else {
+            site: d["count"]
+            - self._device_failures0.get(site, {}).get("count", 0)
+            for site, d in fails.items()}
+        self.device_failures = {
+            site: {"count": int(n),
+                   "first_error": fails.get(site, {}).get("first_error", "")}
+            for site, n in counts.items() if n}
         if self._attributed:
             self.recovery = {k: int(v)
                              for k, v in self._plane("recovery").items()}
@@ -588,8 +610,9 @@ class RuntimeStatsContext:
             for kind, d in sorted(self.device_kernels.items()):
                 extra = ""
                 if "achieved_gbps" in d:
-                    extra = (f" {d['achieved_gbps']} GB/s"
-                             f" ({d.get('roofline_pct', 0)}% roofline)")
+                    extra = f" {d['achieved_gbps']} GB/s"
+                    if "roofline_pct" in d:  # a known chip is attached
+                        extra += f" ({d['roofline_pct']}% roofline)"
                 if "mfu_pct" in d:
                     extra += f" {d['mfu_pct']}% MFU"
                 if "strategy" in d:
@@ -610,6 +633,11 @@ class RuntimeStatsContext:
                 lines.append(
                     f"  {kind}: dispatches={d['dispatches']} "
                     f"rows={d['rows']} time={d['seconds']:.3f}s{extra}")
+        if self.device_failures:
+            lines.append("device failures (ran on the HOST instead):")
+            for site, d in sorted(self.device_failures.items()):
+                lines.append(f"  {site}: {d['count']} — first error: "
+                             f"{d['first_error']}")
         if self.recovery:
             lines.append("resilience (recovery events):")
             for k, v in sorted(self.recovery.items()):
@@ -1132,7 +1160,8 @@ def flight_entry(ctx: RuntimeStatsContext) -> dict:
         "operators": ctx.as_dict(),
     }
     for block in ("recovery", "shuffle", "exchange", "io", "spill",
-                  "governor", "adaptive", "device_kernels", "serving",
+                  "governor", "adaptive", "device_kernels",
+                  "device_failures", "serving",
                   "sanitizer", "retrace", "plansan"):
         v = getattr(ctx, block, None)
         if v:

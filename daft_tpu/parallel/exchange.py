@@ -66,16 +66,13 @@ def _program_key(f, mesh, in_specs, out_specs, check_vma):
 
 
 def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` across jax versions: new jax exports it top-level
-    with ``check_vma``; older releases ship ``jax.experimental.shard_map``
-    whose equivalent knob is ``check_rep``.
-
-    The program is returned JITTED and MEMOIZED on (fn identity, mesh,
-    in/out specs): un-jitted shard_map executes eagerly (per-op dispatch
-    over every mesh shard — measured ~70 s for one tiny mesh-exchanged
-    Q1 on the 8-device CPU mesh, vs milliseconds compiled), and a fresh
-    ``jax.jit`` wrapper per call could never hit jax's trace cache, so
-    every exchange re-traced the same collective."""
+    """``jax.shard_map`` (JAX 0.9: top-level, ``check_vma``), returned
+    JITTED and MEMOIZED on (fn identity, mesh, in/out specs): un-jitted
+    shard_map executes eagerly (per-op dispatch over every mesh shard —
+    ~70 s for one tiny mesh-exchanged Q1 on the 8-device CPU mesh, vs
+    milliseconds compiled), and a fresh ``jax.jit`` wrapper per call could
+    never hit jax's trace cache, so every exchange re-traced the same
+    collective."""
     keyed = _program_key(f, mesh, in_specs, out_specs, check_vma)
     if keyed is not None:
         hit = _program_cache.get(keyed[1])
@@ -85,17 +82,8 @@ def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=False):
         _program_counters["misses"] += 1
     else:
         _program_counters["uncacheable"] += 1
-    try:
-        from jax import shard_map as sm
-        mapped = sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    check_vma=check_vma)
-    except (ImportError, TypeError):
-        # TypeError: intermediate jax versions export top-level shard_map
-        # but still spell the knob check_rep — fall through to the
-        # experimental path, which takes it under that name
-        from jax.experimental.shard_map import shard_map as sm
-        mapped = sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    check_rep=check_vma)
+    mapped = jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=check_vma)
     from ..analysis import retrace_sanitizer
     program = jax.jit(mapped)
     # uncacheable programs (unhashable closure cell) each get a UNIQUE

@@ -384,8 +384,9 @@ class QueryScheduler:
         self._threads.append(t)
         # AOT warm-up (DAFT_TPU_AOT_WARMUP=1): compile the device
         # program library over the size-class grid BEFORE traffic
-        # arrives, so first queries re-enter warm programs; with
-        # DAFT_TPU_COMPILE_CACHE_DIR the executables persist across
+        # arrives, so first queries re-enter warm programs; with the
+        # persistent compile cache (JAX_COMPILATION_CACHE_DIR, else
+        # <repo>/.cache/jax off-CPU) the executables persist across
         # restarts and amortize across replicas.  Never raises; the
         # stats land in the counters for the serve bench to report.
         try:

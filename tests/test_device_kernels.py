@@ -196,7 +196,7 @@ def test_fused_join_overflow_redispatches_once(monkeypatch):
 
 # ------------------------------------------------------------- MFU ledger
 
-def test_ledger_records_and_derives():
+def test_ledger_records_and_derives(monkeypatch):
     costmodel.ledger_reset()
     costmodel.ledger_record("argsort", rows=100, nbytes=1e9, seconds=0.5)
     costmodel.ledger_record("argsort", rows=50, nbytes=1e9, seconds=0.5)
@@ -204,13 +204,21 @@ def test_ledger_records_and_derives():
     d = snap["argsort"]
     assert d["dispatches"] == 2 and d["rows"] == 150
     assert d["achieved_gbps"] == 2.0
-    assert d["roofline_pct"] == pytest.approx(
-        100.0 * 2e9 / costmodel.hbm_bps(), rel=1e-6)
+    # the CPU has no published peaks: no share of a chip that isn't there
+    assert costmodel.device_peaks() is None
+    assert "roofline_pct" not in d and "mfu_pct" not in d
+    # a chip in the DEVICE_PEAKS table gets its shares
+    monkeypatch.setattr(costmodel, "device_peaks",
+                        lambda: costmodel.DEVICE_PEAKS["TPU v5 lite"])
+    d = costmodel.ledger_snapshot()["argsort"]
+    assert d["roofline_pct"] == pytest.approx(100.0 * 2e9 / 819e9, rel=1e-4)
     costmodel.ledger_reset()
     assert costmodel.ledger_snapshot() == {}
 
 
-def test_ledger_delta_isolates_a_query():
+def test_ledger_delta_isolates_a_query(monkeypatch):
+    monkeypatch.setattr(costmodel, "device_peaks",
+                        lambda: costmodel.DEVICE_PEAKS["TPU v5 lite"])
     costmodel.ledger_reset()
     costmodel.ledger_record("join", rows=10, nbytes=100.0, seconds=0.1)
     before = costmodel.ledger_snapshot(raw=True)

@@ -725,7 +725,7 @@ def test_attributed_device_kernels_isolated():
     assert set(c2.device_kernels) == {"serve_test_join"}
     assert c1.device_kernels["serve_test_argsort"]["rows"] == 10
     assert c2.device_kernels["serve_test_join"]["dispatches"] == 1
-    assert "mfu_pct" in c2.device_kernels["serve_test_join"]
+    assert "achieved_tflops" in c2.device_kernels["serve_test_join"]
 
 
 def test_cancel_unwinds_noncacheable_runner_drain(monkeypatch):

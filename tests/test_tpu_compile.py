@@ -1,0 +1,229 @@
+"""The query path's device programs COMPILE for the TPU — asked of the chip's
+own compiler, for a described (not attached) ``v5e:2x2``, at no chip time
+(``/opt/skills/guides/on-chip-measurement`` section 2).
+
+Interpret-mode and CPU-backend tests cannot see what the TPU compiler
+refuses (PR 23 found the Pallas hash kernels refused outright), so the
+programs TPC-H Q1/Q6/Q3 dispatch are lowered and compiled here: the sort
+grouped-agg in Q1's key/agg layout, the fused join, the packed-key argsort,
+one fused scan->filter->agg fragment, and the mesh grouped-agg collective on
+a 4-device ``Mesh`` of the described devices. Capacities are moderate on
+purpose (16384-row sorts, ~25 s each): ``lax.sort`` compile time on this
+compiler grows with the bucket (ROADMAP A8), and the whole file must stay
+under ~3 minutes in one worker.
+
+Rules this file keeps (the guide's, because pytest-xdist imports every test
+file in every worker and only ONE process may load libtpu): the topology is
+described inside a module-scoped fixture — never at import, not autouse, not
+in conftest.py; everything built from it (shardings, meshes, shapes) is
+built in fixtures/tests; compiles run in this process; the persistent
+compilation cache is off around them (a described-device compile is written
+to it but can never be read back).
+"""
+
+from __future__ import annotations
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from daft_tpu.device import costmodel, kernels
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _q1_sort_grouped_agg(S):
+    """kernels.grouped_agg_impl in TPC-H Q1's layout: two int32 dictionary
+    -code keys (l_returnflag, l_linestatus), seven f32 sums (f64 rides f32
+    on the TPU) — the sort strategy every TPU group-by resolves to."""
+    C = 16384
+    ops = ("sum",) * 7
+    keys = (S((C,), jnp.int32),) * 2
+    vals = (S((C,), jnp.float32),) * 7
+    bools = lambda n: (S((C,), jnp.bool_),) * n  # noqa: E731
+    fn = jax.jit(kernels.grouped_agg_impl, static_argnames=("ops",))
+    return fn.lower(keys, bools(2), vals, bools(7), S((C,), jnp.bool_),
+                    ops=ops)
+
+
+def _join_fused(S):
+    """kernels.join_fused_impl on int64 group ids (joins.match_indices):
+    4096-row probe side, 16384-row build side (the sorted one), FK-shaped
+    output bucket — chip_smoke's Q3 customer-orders join at 64 parts."""
+    cl, cr = 4096, 16384
+    fn = jax.jit(kernels.join_fused_impl, static_argnames=("out_capacity",))
+    return fn.lower(S((cl,), jnp.int64), S((cl,), jnp.bool_),
+                    S((cl,), jnp.bool_), S((cr,), jnp.int64),
+                    S((cr,), jnp.bool_), S((cr,), jnp.bool_),
+                    out_capacity=cr)
+
+
+def _packed_argsort(S):
+    """kernels.argsort_kernel: Q3's (revenue desc f32, o_orderdate asc
+    int32) top-k key layout through the packed-u64 radix words."""
+    C = 16384
+    return kernels.argsort_kernel.lower(
+        (S((C,), jnp.float32), S((C,), jnp.int32)),
+        (S((C,), jnp.bool_),) * 2, S((C,), jnp.bool_),
+        descending=(True, False), nulls_first=(False, False))
+
+
+def _fused_scan_filter_agg(S):
+    """One fused scan->filter->project->agg fragment (fragment.get_fused_agg):
+    TPC-H Q6's shape — predicate over date/float columns, a product, one
+    global sum — as the single jit program the executor dispatches."""
+    import datetime
+
+    from daft_tpu import DataType, col, lit
+    from daft_tpu.device import fragment
+    from daft_tpu.schema import Field, Schema
+    schema = Schema([Field("l_shipdate", DataType.date()),
+                     Field("l_discount", DataType.float32()),
+                     Field("l_quantity", DataType.float32()),
+                     Field("l_extendedprice", DataType.float32())])
+    pred = ((col("l_shipdate") >= lit(datetime.date(1994, 1, 1)))
+            & (col("l_shipdate") < lit(datetime.date(1995, 1, 1)))
+            & (col("l_quantity") < 24))
+    child = [(col("l_extendedprice") * col("l_discount")).alias("__v0__")]
+    prog = fragment.get_fused_agg([], child, ("sum",), pred, schema)
+    assert prog is not None, "Q6-shaped fragment must be device-compilable"
+    C = 524288   # chip_smoke's lineitem bucket (SF1 in 16 parts)
+    arrays = {n: S((C,), prog.in_np_dtypes[n])
+              for n in prog.compiled.needs_cols}
+    valids = {n: S((C,), jnp.bool_) for n in prog.compiled.needs_cols}
+    assert not prog.compiled.scalar_specs
+    return prog.packed_fn.lower(arrays, valids, S((C,), jnp.bool_), (),
+                                out_cap=fragment._OUT_CAP0, strategy="sort",
+                                dims=())
+
+
+ONE_CHIP_PROGRAMS = {
+    "sort_grouped_agg_q1_layout": _q1_sort_grouped_agg,
+    "join_fused": _join_fused,
+    "packed_key_argsort": _packed_argsort,
+    "fused_scan_filter_agg_q6": _fused_scan_filter_agg,
+}
+
+
+@pytest.mark.parametrize("name", sorted(ONE_CHIP_PROGRAMS))
+def test_program_compiles_for_one_v5e_chip(name, one_chip):
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = ONE_CHIP_PROGRAMS[name](S).compile()
+    assert compiled.memory_analysis() is not None
+
+
+def test_sharded_grouped_agg_compiles_for_four_chip_mesh(topo):
+    """exchange.sharded_grouped_agg (DeviceExchangeAgg's collective) on a
+    Mesh of the four described devices: the compiler must place an
+    all-to-all across them."""
+    from daft_tpu.parallel import exchange
+    assert len(topo.devices) == 4
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    sh = NamedSharding(mesh, P("data"))
+    n, C = 4, 4096
+
+    def S(dtype):
+        return jax.ShapeDtypeStruct((n * C,), dtype, sharding=sh)
+
+    def step(k0, k1, kv0, kv1, v0, v1, vv0, vv1, m):
+        return exchange.sharded_grouped_agg(
+            mesh, (k0, k1), (kv0, kv1), (v0, v1), (vv0, vv1), m,
+            ("sum", "sum"))
+
+    b = S(jnp.bool_)
+    lowered = jax.jit(step).lower(S(jnp.int32), S(jnp.int32), b, b,
+                                  S(jnp.float32), S(jnp.float32), b, b, b)
+    text = lowered.compile().as_text()
+    assert "all-to-all" in text
+
+
+def _hash_agg(S):
+    from daft_tpu.device import pallas_kernels as pk
+    C = 1024
+    fn = jax.jit(lambda k, kv, v, vv, m: pk.hash_grouped_agg_impl(
+        (k,), (kv,), (v,), (vv,), m, ("sum",), C, interpret=False))
+    return fn.lower(S((C,), jnp.int32), S((C,), jnp.bool_),
+                    S((C,), jnp.float32), S((C,), jnp.bool_),
+                    S((C,), jnp.bool_))
+
+
+def _hash_join(S):
+    from daft_tpu.device import pallas_kernels as pk
+    C = 1024
+    fn = jax.jit(lambda a, b, c, d, e, f: pk.hash_join_impl(
+        a, b, c, d, e, f, out_capacity=C, interpret=False))
+    return fn.lower(S((C,), jnp.int64), S((C,), jnp.bool_),
+                    S((C,), jnp.bool_), S((C,), jnp.int64),
+                    S((C,), jnp.bool_), S((C,), jnp.bool_))
+
+
+@pytest.mark.parametrize("build", [_hash_agg, _hash_join],
+                         ids=["hash_grouped_agg", "hash_join"])
+def test_pallas_hash_kernels_are_refused_by_the_tpu_compiler(build,
+                                                             one_chip):
+    """Pins the verdict ``costmodel._HASH_KERNELS_COMPILE_ON`` records: with
+    ``interpret=False`` the TPU kernel compiler refuses both Pallas hash
+    kernels, so a forced ``DAFT_TPU_KERNEL_GROUPBY/JOIN=hash`` on the chip
+    raises. A PR that makes them lower flips this test and the gate
+    together."""
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    with pytest.raises(Exception, match="Pallas TPU lowering|Mosaic|"
+                                        "[Uu]nimplemented|not implemented"):
+        build(S).compile()
+
+
+def test_auto_never_selects_hash_kernels_on_tpu(monkeypatch):
+    """The honest kernel gate: with the backend reported as ``tpu``,
+    ``auto`` resolves both strategy models to ``sort`` at every size (the
+    Pallas hash kernels do not compile for the TPU), the interpreter is
+    never chosen for an accelerator, and a FORCED hash is honoured so the
+    compiler's refusal reaches the user instead of a silent sort run."""
+    from daft_tpu.device import backend, pallas_kernels as pk
+    monkeypatch.setattr(backend, "backend_name", lambda wait=True: "tpu")
+    monkeypatch.delenv("DAFT_TPU_KERNEL_GROUPBY", raising=False)
+    monkeypatch.delenv("DAFT_TPU_KERNEL_JOIN", raising=False)
+    monkeypatch.delenv("DAFT_TPU_KERNEL_INTERPRET", raising=False)
+    assert backend.is_accelerator()
+    assert not costmodel._hash_capable_backend()
+    assert pk.interpret_default() is False
+    for rows in (1 << 10, 1 << 14, 1 << 17, 1 << 20):
+        for groups in (None, 4.0, rows / 2):
+            s, _ = costmodel.groupby_strategy(
+                rows, groups, [np.dtype("int32")] * 2, 1024, log=False)
+            assert s == "sort", (rows, groups)
+        for n_right in (1 << 8, 1 << 12, rows):
+            assert costmodel._join_strategy(rows, n_right) == "sort"
+    monkeypatch.setenv("DAFT_TPU_KERNEL_GROUPBY", "hash")
+    monkeypatch.setenv("DAFT_TPU_KERNEL_JOIN", "hash")
+    assert costmodel.groupby_strategy(
+        1 << 14, 4.0, [np.dtype("int32")], 1024, log=False)[0] == "hash"
+    assert costmodel._join_strategy(1 << 14, 1 << 10) == "hash"
+    assert pk.interpret_default() is False   # forced hash still compiles
