@@ -127,10 +127,16 @@ def agg_recordbatch(batch, to_agg: List[Expression], group_by: List[Expression])
     if out is not None:
         return out
 
-    specs = [split_agg_expr(e) for e in to_agg]
-    if not group_by:
-        return _agg_global(batch, specs)
-    return _agg_groupby(batch, specs, group_by)
+    # the host's aggregate kernels (the final merge of device partials
+    # comes through here too)
+    from . import tracing
+    with tracing.span("agg:host", lane="pipeline",
+                      attrs={"rows_in": len(batch)}) as sp:
+        specs = [split_agg_expr(e) for e in to_agg]
+        out = _agg_groupby(batch, specs, group_by) if group_by \
+            else _agg_global(batch, specs)
+        sp.set("groups", len(out))
+    return out
 
 
 def _eval_child(batch, child: Optional[Expression], i: int) -> Series:

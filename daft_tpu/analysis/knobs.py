@@ -453,10 +453,8 @@ _KNOBS: List[Knob] = [
     # ------------------------------------------------- observability
     _k("DAFT_TPU_XPLANE_DIR", "str", None, "daft_tpu/observability.py",
        "observability", "directory capturing a jax profiler "
-       "(xplane/TensorBoard) trace per query"),
-    _k("DAFT_TPU_CHROME_TRACE", "str", None, "daft_tpu/observability.py",
-       "observability", "`1` or a path; writes a chrome://tracing JSON for "
-       "the last execution"),
+       "(xplane/TensorBoard) trace per query; the query is traced and its "
+       "spans lie in the profile as `daft:<span>`"),
     _k("DAFT_TPU_PROGRESS", "bool", False, "daft_tpu/observability.py",
        "observability", "`1` enables a tqdm partition-progress bar"),
     _k("DAFT_TPU_OTLP_ENDPOINT", "str", None, "daft_tpu/observability.py",

@@ -470,7 +470,15 @@ class DataFrame:
 
     # ---- conversions -----------------------------------------------------
     def to_pydict(self) -> Dict[str, list]:
-        return self._materialize().to_recordbatch().to_pydict()
+        from . import observability as obs, tracing
+        # the conversion belongs to the query's trace (``result:collect``)
+        with obs.query_scope() as traced:
+            parts = self._materialize()
+            with tracing.attach(traced()), \
+                    tracing.span("result:collect", lane="driver") as sp:
+                rb = parts.to_recordbatch()
+                sp.set("rows", len(rb))
+                return rb.to_pydict()
 
     def to_pylist(self) -> List[Dict[str, Any]]:
         return list(self.iter_rows())

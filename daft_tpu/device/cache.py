@@ -77,11 +77,19 @@ class DeviceColumnCache:
         self._cols: "OrderedDict[Tuple, _Entry]" = OrderedDict()
         self._masks: "OrderedDict[Tuple, Tuple]" = OrderedDict()  # fp -> (mask, rows, cap)
         self._bytes = 0
+        # since the process started (``clear()`` empties the cache, not
+        # these): whole-table lookups served / not, bytes put, bytes
+        # pushed out by the budget
+        self._hits = self._misses = 0
+        self._put_bytes = self._evicted_bytes = 0
 
     # ------------------------------------------------------------------
     def stats(self) -> Dict[str, int]:
         with self._lock:
-            return {"entries": len(self._cols), "bytes": self._bytes}
+            return {"entries": len(self._cols), "bytes": self._bytes,
+                    "hits": self._hits, "misses": self._misses,
+                    "put_bytes": self._put_bytes,
+                    "evicted_bytes": self._evicted_bytes}
 
     def clear(self) -> None:
         with self._lock:
@@ -95,20 +103,22 @@ class DeviceColumnCache:
         """All requested columns cached → assembled DeviceTable, else None."""
         with self._lock:
             mask = self._masks.get(fp)
-            if mask is None:
+            entries = [self._cols.get((fp, c)) for c in cols] \
+                if mask is not None else [None]
+            if any(e is None for e in entries):
+                self._misses += 1
                 return None
-            out = {}
+            self._hits += 1
             for c in cols:
-                e = self._cols.get((fp, c))
-                if e is None:
-                    return None
                 self._cols.move_to_end((fp, c))
-                out[c] = e.col
             self._masks.move_to_end(fp)
             row_mask, rows, cap = mask
-            return dcol.DeviceTable(out, row_mask, rows, cap, resident=True)
+            return dcol.DeviceTable(
+                {c: e.col for c, e in zip(cols, entries)}, row_mask, rows,
+                cap, resident=True)
 
     def put_table(self, fp: Tuple, dt: dcol.DeviceTable) -> None:
+        from .. import tracing
         add = 0
         sized = []
         for name, col in dt.columns.items():
@@ -117,25 +127,31 @@ class DeviceColumnCache:
             add += nbytes
         if add > _budget():
             return
-        # the caller's table now SHARES buffers with the cache — it must
-        # never be donated to a fused program from here on
-        dt.resident = True
-        with self._lock:
-            self._masks[fp] = (dt.row_mask, dt.row_count, dt.capacity)
-            for name, col, nbytes in sized:
-                key = (fp, name)
-                old = self._cols.pop(key, None)
-                if old is not None:
-                    self._bytes -= old.nbytes
-                self._cols[key] = _Entry(col, nbytes)
-                self._bytes += nbytes
-            self._evict_locked()
+        # bookkeeping only: the planes were put by ``encode_batch``,
+        # whose ``device:put`` spans carry their bytes
+        with tracing.span("device:put", lane="device",
+                          attrs={"cached": 1, "cached_bytes": add}):
+            # the caller's table now SHARES buffers with the cache — it
+            # must never be donated to a fused program from here on
+            dt.resident = True
+            with self._lock:
+                self._masks[fp] = (dt.row_mask, dt.row_count, dt.capacity)
+                for name, col, nbytes in sized:
+                    key = (fp, name)
+                    old = self._cols.pop(key, None)
+                    if old is not None:
+                        self._bytes -= old.nbytes
+                    self._cols[key] = _Entry(col, nbytes)
+                    self._bytes += nbytes
+                self._put_bytes += add
+                self._evict_locked()
 
     def _evict_locked(self) -> None:
         budget = _budget()
         while self._bytes > budget and self._cols:
             _, e = self._cols.popitem(last=False)
             self._bytes -= e.nbytes
+            self._evicted_bytes += e.nbytes
         live_fps = {k[0] for k in self._cols}
         for fp in [f for f in self._masks if f not in live_fps]:
             del self._masks[fp]

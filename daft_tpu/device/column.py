@@ -261,15 +261,26 @@ def encode_series(s: Series, capacity: int,
     res = _resident_column(s, capacity) if allow_resident else None
     if res is not None:
         return res
-    vals, validity, dictionary = _np_encode(s)
-    n = len(vals)
-    if n < capacity:
-        vals = np.concatenate(
-            [vals, np.zeros(capacity - n, dtype=vals.dtype)])
-        validity = np.concatenate(
-            [validity, np.zeros(capacity - n, dtype=np.bool_)])
-    return DeviceColumn(jnp.asarray(vals), jnp.asarray(validity),
-                        s.datatype(), dictionary)
+    from .. import tracing
+    # the numpy side: planes, validity, dictionary ranks, padding
+    with tracing.span("device:encode", lane="device") as sp:
+        vals, validity, dictionary = _np_encode(s)
+        n = len(vals)
+        if n < capacity:
+            vals = np.concatenate(
+                [vals, np.zeros(capacity - n, dtype=vals.dtype)])
+            validity = np.concatenate(
+                [validity, np.zeros(capacity - n, dtype=np.bool_)])
+        nbytes = int(vals.nbytes) + int(validity.nbytes)
+        sp.set("rows", n)
+        sp.set("cols", 1)
+        sp.set("bytes", nbytes)
+    # the host's time in the two puts; the copy's device side is the
+    # profile's transfer events. Bytes as the HBM cache counts them.
+    with tracing.span("device:put", lane="device",
+                      attrs={"bytes": nbytes, "cached": 0}):
+        return DeviceColumn(jnp.asarray(vals), jnp.asarray(validity),
+                            s.datatype(), dictionary)
 
 
 def _resident_column(s: Series, capacity: int) -> Optional[DeviceColumn]:
@@ -324,7 +335,12 @@ def encode_batch(batch, columns: Optional[List[str]] = None) -> DeviceTable:
     cols = {nm: encode_series(batch.get_column(nm), cap) for nm in names}
     mask = np.zeros(cap, dtype=np.bool_)
     mask[:n] = True
-    return DeviceTable(cols, jnp.asarray(mask), n, cap)
+    from .. import tracing
+    # the live-row mask is a put the HBM cache's byte count leaves out
+    with tracing.span("device:put", lane="device",
+                      attrs={"bytes": 0, "mask_bytes": cap, "cached": 0}):
+        row_mask = jnp.asarray(mask)
+    return DeviceTable(cols, row_mask, n, cap)
 
 
 def _resident_batch(batch, names, n: int, cap: int
@@ -371,7 +387,10 @@ def decode_columns(named: "List[tuple]", count: int) -> "List[Series]":
     planes for residency hand-off when the async pipeline is enabled —
     a downstream device op then re-enters without a host round trip."""
     from . import pipeline
-    fetched = pipeline.fetch_host([(c.data, c.validity) for _, c in named])
+    fetched = [(c.data, c.validity) for _, c in named]
+    if any(_is_device_array(c.data) for _, c in named):
+        fetched = pipeline.fetch_host(fetched)  # else: planes of a
+        # packed result, fetched with it and already on the host
     register = pipeline.inflight_window() > 0
     out = []
     for (name, col), (vals, validity) in zip(named, fetched):

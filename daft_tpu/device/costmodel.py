@@ -37,6 +37,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from .. import tracing as _tracing
+
 # host-side kernel bandwidths (bytes/s) for the Arrow compute tier these
 # decisions compare against; coarse on purpose (see module docstring)
 HOST_VECTOR_BPS = 2.0e9     # elementwise eval / filter, per byte touched
@@ -294,6 +296,8 @@ def reset_for_tests() -> None:
     with _ici_lock:
         _ici = None
     decision_counts.clear()
+    for k in scan_table_counts:
+        scan_table_counts[k] = 0
     ledger_reset()
     _peaks_memo.clear()
     from . import calibration
@@ -600,6 +604,21 @@ def _forced() -> Optional[bool]:
 #: by explain_analyze; reset_for_tests clears them
 decision_counts: dict = {}
 _counts_lock = threading.Lock()
+
+#: where each scan task's table came from on the device tier's scan path
+#: (``executor._fragment_scan_tasks``), since the process started: served
+#: from the HBM column cache, encoded and uploaded now, or left to the
+#: host. A table from the cache passes no gate, so ``decision_counts``
+#: never sees it; reset_for_tests zeroes these
+scan_table_counts: dict = dict.fromkeys(_tracing.TABLE_SOURCES, 0)
+
+
+def count_scan_table(source: str) -> None:
+    """Tally one scan task's table, process-wide and on the current
+    query's trace (its root span and ``summary()["tables"]``)."""
+    with _counts_lock:
+        scan_table_counts[source] += 1
+    _tracing.tally(source)
 
 
 def _log(kind: str, device: bool, host_s: float, dev_s: float,

@@ -165,13 +165,19 @@ def get_fused_agg(group_exprs: List[Expression], child_exprs: List[Expression],
 
     def run_packed(arrays, valids, row_mask, scalars, out_cap: int,
                    strategy: str = "sort", dims: Tuple[int, ...] = ()):
-        keys, kvalids, vals, vvalids, row_mask = eval_inputs(
-            arrays, valids, row_mask, scalars)
+        # named scopes land in every HLO op's metadata: the device
+        # trace's operations name the stage they belong to
+        with jax.named_scope("eval-inputs"):
+            keys, kvalids, vals, vvalids, row_mask = eval_inputs(
+                arrays, valids, row_mask, scalars)
         if nk == 0:
-            results = kernels.global_agg_impl(vals, vvalids, row_mask, ops)
+            with jax.named_scope("global/reduce"):
+                results = kernels.global_agg_impl(vals, vvalids, row_mask,
+                                                  ops)
             flat = [v for v, _ in results] + [m for _, m in results]
             meta["global_dtypes"] = [x.dtype for x in flat]
-            return jnp.stack([_pack_i64(x.reshape(())) for x in flat])
+            with jax.named_scope("pack-i64"):
+                return jnp.stack([_pack_i64(x.reshape(())) for x in flat])
         # round 12: the whole scan→filter→project→agg chain stays ONE jit
         # program either way — `strategy` only swaps the reduction's inner
         # loop (dense direct slot indexing vs one-pass Pallas hash table
@@ -186,10 +192,11 @@ def get_fused_agg(group_exprs: List[Expression], child_exprs: List[Expression],
                 keys, kvalids, vals, vvalids, row_mask, ops, out_cap)
         flat = list(ok) + list(okv) + list(ov) + list(ovv)
         meta["grouped_dtypes"] = [x.dtype for x in flat]
-        rows = [jnp.full((out_cap,), 0, jnp.int64).at[0]
-                .set(g.astype(jnp.int64))]
-        rows += [_pack_i64(x) for x in flat]  # already [out_cap]-wide
-        return jnp.stack(rows)
+        with jax.named_scope("pack-i64"):
+            rows = [jnp.full((out_cap,), 0, jnp.int64).at[0]
+                    .set(g.astype(jnp.int64))]
+            rows += [_pack_i64(x) for x in flat]  # already [out_cap]-wide
+            return jnp.stack(rows)
 
     prog = FusedAggProgram(
         jax.jit(run_packed, static_argnames=("out_cap", "strategy", "dims")),
@@ -255,21 +262,27 @@ def submit_fused_agg(prog: FusedAggProgram, batch, group_exprs, agg_exprs,
 def _dispatch_packed(prog: FusedAggProgram, dt: dcol.DeviceTable,
                      out_cap: int, strategy: str = "sort",
                      donate: bool = False, dims: Tuple[int, ...] = ()):
+    from .. import tracing
     from ..analysis import retrace_sanitizer
-    arrays = {n: col.data for n, col in dt.columns.items()}
-    valids = {n: col.validity for n, col in dt.columns.items()}
-    scalars = runtime._prep_scalars(prog.compiled, dt)
-    fn = prog.donate_fn() if donate else prog.packed_fn
-    # the declared trace signature (dispatch_registry: fragment.packed /
-    # fragment.donate) — everything the jit cache key may depend on; a
-    # second trace for the SAME key is the retrace tax and a sanitizer
-    # budget violation
-    with retrace_sanitizer.dispatch_scope(
-            "fragment.donate" if donate else "fragment.packed",
-            (id(prog), dt.capacity, out_cap, strategy, dims,
-             tuple(s.shape for s in scalars))):
-        return fn(arrays, valids, dt.row_mask, scalars, out_cap=out_cap,
-                  strategy=strategy, dims=dims)
+    # the host's time to enqueue one program (the device runs it later)
+    with tracing.span("device:dispatch", lane="device",
+                      attrs={"program": "fused_agg",
+                             "capacity": dt.capacity,
+                             "strategy": strategy}):
+        arrays = {n: col.data for n, col in dt.columns.items()}
+        valids = {n: col.validity for n, col in dt.columns.items()}
+        scalars = runtime._prep_scalars(prog.compiled, dt)
+        fn = prog.donate_fn() if donate else prog.packed_fn
+        # the declared trace signature (dispatch_registry:
+        # fragment.packed / fragment.donate) — everything the jit cache
+        # key may depend on; a second trace for the SAME key is the
+        # retrace tax and a sanitizer budget violation
+        with retrace_sanitizer.dispatch_scope(
+                "fragment.donate" if donate else "fragment.packed",
+                (id(prog), dt.capacity, out_cap, strategy, dims,
+                 tuple(s.shape for s in scalars))):
+            return fn(arrays, valids, dt.row_mask, scalars,
+                      out_cap=out_cap, strategy=strategy, dims=dims)
 
 
 #: dense-strategy slot ceiling: K = prod(dim+1) static slots per dispatch;
@@ -583,6 +596,7 @@ def drain_fused_agg_table(tok: InflightFusedAgg):
     ceiling."""
     import time as _time
 
+    from .. import tracing
     from . import pipeline
     prog, dt = tok.prog, tok.dt
     t_drain0 = _time.perf_counter()
@@ -591,11 +605,17 @@ def drain_fused_agg_table(tok: InflightFusedAgg):
         _ledger_global(prog, dt.row_count, dt.capacity,
                        tok.submitted_s + (_time.perf_counter() - t_drain0),
                        1)
-        return _decode_packed_global(prog, packed, tok.agg_fields)
+        with tracing.span("device:decode", lane="device",
+                          attrs={"tables": 1, "groups": 1}):
+            return _decode_packed_global(prog, packed, tok.agg_fields)
     while True:
         packed = np.asarray(pipeline.fetch_host(tok.packed))
-        out = _decode_packed_grouped(prog, packed, tok.dt, tok.group_exprs,
-                                     tok.key_fields, tok.agg_fields)
+        with tracing.span("device:decode", lane="device",
+                          attrs={"tables": 1}) as sp:
+            out = _decode_packed_grouped(prog, packed, tok.dt,
+                                         tok.group_exprs, tok.key_fields,
+                                         tok.agg_fields)
+            sp.set("groups", len(out) if out is not None else 0)
         if out is not None:
             # per-strategy accounting: an overflow ladder can MIX
             # strategies (hash saturation falls back to sort), and each
@@ -698,7 +718,12 @@ def submit_fused_agg_tables(prog: FusedAggProgram, tables,
     # dense first, per table: each morsel carries its own dictionaries
     # (pow2-bucketed, so same-scan tables share one traced program); a
     # table that misses the slot budget rides the batch strategy instead
-    plans = [dense_plan(prog, dt, _max_out_cap(prog, dt)) for dt in tables]
+    from .. import tracing
+    with tracing.span("device:dispatch", lane="device",
+                      attrs={"program": "fused_agg", "strategy": "plan",
+                             "tables": len(tables)}):
+        plans = [dense_plan(prog, dt, _max_out_cap(prog, dt))
+                 for dt in tables]
     if all(p is not None for p in plans):
         tok.strategy, tok.lf = "dense", 0.0
         try:
@@ -774,24 +799,31 @@ def drain_fused_agg_tables(tok: InflightFusedAggBatch):
     results: list = [None] * len(tables)
     retry: list = []  # (index, out_cap) — re-dispatched as ONE batch, not
     # per-table (each serial round trip pays the link RTT)
-    for i, (dt, mat) in enumerate(zip(tables, stacked)):
-        try:
-            if prog.nk == 0:
-                results[i] = _decode_packed_global(prog, mat, agg_fields)
-                continue
-            out = _decode_packed_grouped(prog, mat, dt, group_exprs,
-                                         key_fields, agg_fields)
-            if out is not None:
-                results[i] = out
-                continue
-            g = int(mat[0, 0])
-            cap_limit = _max_out_cap(prog, dt)
-            if g <= cap_limit:  # else: stays None → host fallback
-                retry.append((i, min(dcol.bucket_capacity(max(g, _OUT_CAP0)),
-                                     cap_limit)))
-        except Exception as exc:
-            runtime.device_failed("fragment.fused_agg_tables.decode", exc)
-            results[i] = None
+    from .. import tracing
+    with tracing.span("device:decode", lane="device",
+                      attrs={"tables": len(tables)}) as sp:
+        for i, (dt, mat) in enumerate(zip(tables, stacked)):
+            try:
+                if prog.nk == 0:
+                    results[i] = _decode_packed_global(prog, mat,
+                                                       agg_fields)
+                    continue
+                out = _decode_packed_grouped(prog, mat, dt, group_exprs,
+                                             key_fields, agg_fields)
+                if out is not None:
+                    results[i] = out
+                    continue
+                g = int(mat[0, 0])
+                cap_limit = _max_out_cap(prog, dt)
+                if g <= cap_limit:  # else: stays None → host fallback
+                    retry.append(
+                        (i, min(dcol.bucket_capacity(max(g, _OUT_CAP0)),
+                                cap_limit)))
+            except Exception as exc:
+                runtime.device_failed("fragment.fused_agg_tables.decode",
+                                      exc)
+                results[i] = None
+        sp.set("groups", sum(len(r) for r in results if r is not None))
     if retry:
         # a grown bucket can flip the strategy (table slot ceiling);
         # re-ask per retried table
@@ -809,17 +841,19 @@ def drain_fused_agg_tables(tok: InflightFusedAggBatch):
         except Exception as exc:
             runtime.device_failed("fragment.fused_agg_tables.retry", exc)
             mats = [None] * len(retry)
-        for (i, _cap), mat in zip(retry, mats):
-            if mat is None:
-                continue
-            try:
-                results[i] = _decode_packed_grouped(
-                    prog, mat, tables[i], group_exprs, key_fields,
-                    agg_fields)
-            except Exception as exc:
-                runtime.device_failed(
-                    "fragment.fused_agg_tables.decode", exc)
-                results[i] = None
+        with tracing.span("device:decode", lane="device",
+                          attrs={"tables": len(retry)}):
+            for (i, _cap), mat in zip(retry, mats):
+                if mat is None:
+                    continue
+                try:
+                    results[i] = _decode_packed_grouped(
+                        prog, mat, tables[i], group_exprs, key_fields,
+                        agg_fields)
+                except Exception as exc:
+                    runtime.device_failed(
+                        "fragment.fused_agg_tables.decode", exc)
+                    results[i] = None
     return results
 
 
@@ -1022,16 +1056,20 @@ class InflightRegion:
 
 def _dispatch_region(prog: FusedRegionProgram, dt: dcol.DeviceTable,
                      out_w: int, donate: bool = False):
+    from .. import tracing
     from ..analysis import retrace_sanitizer
-    arrays = {n: col.data for n, col in dt.columns.items()}
-    valids = {n: col.validity for n, col in dt.columns.items()}
-    scalars = runtime._prep_scalars(prog.compiled, dt)
-    fn = prog.donate_fn() if donate else prog.packed_fn
-    with retrace_sanitizer.dispatch_scope(
-            "region.topk" if prog.shape == "topk" else "region.chain",
-            (id(prog), dt.capacity, out_w,
-             tuple(s.shape for s in scalars))):
-        return fn(arrays, valids, dt.row_mask, scalars, out_w=out_w)
+    with tracing.span("device:dispatch", lane="device",
+                      attrs={"program": "region", "capacity": dt.capacity,
+                             "strategy": prog.shape}):
+        arrays = {n: col.data for n, col in dt.columns.items()}
+        valids = {n: col.validity for n, col in dt.columns.items()}
+        scalars = runtime._prep_scalars(prog.compiled, dt)
+        fn = prog.donate_fn() if donate else prog.packed_fn
+        with retrace_sanitizer.dispatch_scope(
+                "region.topk" if prog.shape == "topk" else "region.chain",
+                (id(prog), dt.capacity, out_w,
+                 tuple(s.shape for s in scalars))):
+            return fn(arrays, valids, dt.row_mask, scalars, out_w=out_w)
 
 
 def submit_region(prog: FusedRegionProgram, batch, exprs, out_schema: Schema
@@ -1070,18 +1108,21 @@ def drain_region(tok: InflightRegion):
         live = int(packed[0, 0])
         w = packed.shape[1]
         if live <= w:
+            from .. import tracing
             from ..recordbatch import RecordBatch
             dtypes = prog.meta["region_dtypes"]
             nout = prog.nout
-            rows = packed[1:]
-            cols = []
-            for i, (e, f) in enumerate(zip(tok.exprs, tok.fields)):
-                v = _unpack_i64(rows[i][:live], dtypes[i])
-                m = _unpack_i64(rows[nout + i][:live],
-                                dtypes[nout + i]).astype(np.bool_)
-                cols.append(runtime.decode_group_key(e, f, v, m, tok.dt,
-                                                     live))
-            out = RecordBatch.from_series(cols)
+            with tracing.span("device:decode", lane="device",
+                              attrs={"tables": 1, "groups": live}):
+                rows = packed[1:]
+                cols = []
+                for i, (e, f) in enumerate(zip(tok.exprs, tok.fields)):
+                    v = _unpack_i64(rows[i][:live], dtypes[i])
+                    m = _unpack_i64(rows[nout + i][:live],
+                                    dtypes[nout + i]).astype(np.bool_)
+                    cols.append(runtime.decode_group_key(e, f, v, m,
+                                                         tok.dt, live))
+                out = RecordBatch.from_series(cols)
             if prog.has_pred and prog.shape != "topk":
                 prog.w_hint[tok.dt.capacity] = min(
                     dcol.bucket_capacity(max(live, _OUT_CAP0)),
@@ -1295,23 +1336,28 @@ class InflightJoinAgg:
 
 def _dispatch_join_agg(prog: FusedJoinAggProgram, dt: dcol.DeviceTable,
                        build: RegionBuild, W: int, out_cap: int):
+    from .. import tracing
     from ..analysis import retrace_sanitizer
-    p_arrays = {n: col.data for n, col in dt.columns.items()}
-    p_valids = {n: col.validity for n, col in dt.columns.items()}
-    b_arrays = {n: col.data for n, col in build.dt.columns.items()}
-    b_valids = {n: col.validity for n, col in build.dt.columns.items()}
-    p_scalars = runtime._prep_scalars(prog.c_pred, dt) \
-        if prog.c_pred is not None else ()
-    post_scalars = _prep_scalars_joined(prog.c_post, dt, build.dt)
-    with retrace_sanitizer.dispatch_scope(
-            "region.join_agg",
-            (id(prog), dt.capacity, build.dt.capacity, W, out_cap,
-             tuple(s.shape for s in p_scalars),
-             tuple(s.shape for s in post_scalars))):
-        return prog.packed_fn(p_arrays, p_valids, dt.row_mask, p_scalars,
-                              b_arrays, b_valids, build.sorted_key,
-                              build.perm, build.live_count, post_scalars,
-                              W=W, out_cap=out_cap)
+    with tracing.span("device:dispatch", lane="device",
+                      attrs={"program": "region", "capacity": dt.capacity,
+                             "strategy": "join_agg"}):
+        p_arrays = {n: col.data for n, col in dt.columns.items()}
+        p_valids = {n: col.validity for n, col in dt.columns.items()}
+        b_arrays = {n: col.data for n, col in build.dt.columns.items()}
+        b_valids = {n: col.validity
+                    for n, col in build.dt.columns.items()}
+        p_scalars = runtime._prep_scalars(prog.c_pred, dt) \
+            if prog.c_pred is not None else ()
+        post_scalars = _prep_scalars_joined(prog.c_post, dt, build.dt)
+        with retrace_sanitizer.dispatch_scope(
+                "region.join_agg",
+                (id(prog), dt.capacity, build.dt.capacity, W, out_cap,
+                 tuple(s.shape for s in p_scalars),
+                 tuple(s.shape for s in post_scalars))):
+            return prog.packed_fn(
+                p_arrays, p_valids, dt.row_mask, p_scalars, b_arrays,
+                b_valids, build.sorted_key, build.perm, build.live_count,
+                post_scalars, W=W, out_cap=out_cap)
 
 
 def _prep_scalars_joined(c: compiler.Compiled, p_dt: dcol.DeviceTable,
@@ -1390,24 +1436,28 @@ def drain_join_agg(tok: InflightJoinAgg):
             tok.packed = _dispatch_join_agg(prog, tok.dt, tok.build,
                                             tok.W, tok.out_cap)
             continue
+        from .. import tracing
         from ..recordbatch import RecordBatch
         dtypes = prog.meta["grouped_dtypes"]
         nk, nv = prog.nk, len(tok.agg_fields)
-        rows = packed[1:]
-        cols = []
-        for i, (e, f) in enumerate(zip(tok.group_exprs, tok.key_fields)):
-            kv = _unpack_i64(rows[i][:g], dtypes[i])
-            km = _unpack_i64(rows[nk + i][:g],
-                             dtypes[nk + i]).astype(np.bool_)
-            dc = dcol.DeviceColumn(kv, km, f.dtype, None)
-            cols.append(dcol.decode_column(f.name, dc, g))
-        for i, f in enumerate(tok.agg_fields):
-            vv = _unpack_i64(rows[2 * nk + i][:g], dtypes[2 * nk + i])
-            vm = _unpack_i64(rows[2 * nk + nv + i][:g],
-                             dtypes[2 * nk + nv + i]).astype(np.bool_)
-            dc = dcol.DeviceColumn(vv, vm, f.dtype, None)
-            cols.append(dcol.decode_column(f.name, dc, g))
-        out = RecordBatch.from_series(cols)
+        with tracing.span("device:decode", lane="device",
+                          attrs={"tables": 1, "groups": g}):
+            rows = packed[1:]
+            cols = []
+            for i, (e, f) in enumerate(zip(tok.group_exprs,
+                                           tok.key_fields)):
+                kv = _unpack_i64(rows[i][:g], dtypes[i])
+                km = _unpack_i64(rows[nk + i][:g],
+                                 dtypes[nk + i]).astype(np.bool_)
+                dc = dcol.DeviceColumn(kv, km, f.dtype, None)
+                cols.append(dcol.decode_column(f.name, dc, g))
+            for i, f in enumerate(tok.agg_fields):
+                vv = _unpack_i64(rows[2 * nk + i][:g], dtypes[2 * nk + i])
+                vm = _unpack_i64(rows[2 * nk + nv + i][:g],
+                                 dtypes[2 * nk + nv + i]).astype(np.bool_)
+                dc = dcol.DeviceColumn(vv, vm, f.dtype, None)
+                cols.append(dcol.decode_column(f.name, dc, g))
+            out = RecordBatch.from_series(cols)
         n_ops = max(len(prog.fused_ops), 3)
         secs = tok.submitted_s + (_time.perf_counter() - t_drain0)
         costmodel.ledger_record(

@@ -363,49 +363,53 @@ def grouped_agg_block_impl(keys, key_valids, vals, val_valids, row_mask,
     re-runs at a grown bucket when group_count > out_cap).
     """
     C = row_mask.shape[0]
-    codes = _sort_codes(keys, key_valids, row_mask,
-                        (False,) * len(keys), (False,) * len(keys))
-    perm, s_words = _packed_argsort(codes, C, want_words=True)
-    # dead bit is the MSB of the first sorted word: live rows sort first
-    s_live = (s_words[0] >> np.uint64(63)) == 0
+    with jax.named_scope("sort/argsort"):
+        codes = _sort_codes(keys, key_valids, row_mask,
+                            (False,) * len(keys), (False,) * len(keys))
+        perm, s_words = _packed_argsort(codes, C, want_words=True)
+        # dead bit is the MSB of the first sorted word: live rows sort first
+        s_live = (s_words[0] >> np.uint64(63)) == 0
 
-    # group boundaries on the sorted packed words — word equality ⟺
-    # (null_rank, value) equality for every key, and the words come free
-    # from the sort outputs (no payload gathers)
-    diff = jnp.zeros(C, dtype=jnp.bool_).at[0].set(True)
-    for w in s_words:
-        diff = diff | (w != jnp.concatenate([w[:1], w[:-1]]))
-    flags = diff & s_live
-    segf = jnp.cumsum(flags.astype(jnp.int32)) - 1
-    group_count = jnp.sum(flags.astype(jnp.int32))
-    seg_sorted = jnp.where(s_live, jnp.minimum(segf, out_cap),
-                           out_cap).astype(jnp.int32)
-    # invert the permutation with one more (cheap, 2-operand) sort: the
-    # segment id of every ORIGINAL row
-    seg = lax.sort((perm, seg_sorted), num_keys=1, is_stable=True)[1]
+    with jax.named_scope("sort/segments"):
+        # group boundaries on the sorted packed words — word equality ⟺
+        # (null_rank, value) equality for every key, and the words come free
+        # from the sort outputs (no payload gathers)
+        diff = jnp.zeros(C, dtype=jnp.bool_).at[0].set(True)
+        for w in s_words:
+            diff = diff | (w != jnp.concatenate([w[:1], w[:-1]]))
+        flags = diff & s_live
+        segf = jnp.cumsum(flags.astype(jnp.int32)) - 1
+        group_count = jnp.sum(flags.astype(jnp.int32))
+        seg_sorted = jnp.where(s_live, jnp.minimum(segf, out_cap),
+                               out_cap).astype(jnp.int32)
+        # invert the permutation with one more (cheap, 2-operand) sort: the
+        # segment id of every ORIGINAL row
+        seg = lax.sort((perm, seg_sorted), num_keys=1, is_stable=True)[1]
 
-    j = jnp.arange(out_cap, dtype=jnp.int32)
-    starts = jnp.searchsorted(seg_sorted, j, side="left")
-    starts_c = jnp.clip(starts, 0, C - 1)
-    live_group = j < group_count
+    with jax.named_scope("sort/keys"):
+        j = jnp.arange(out_cap, dtype=jnp.int32)
+        starts = jnp.searchsorted(seg_sorted, j, side="left")
+        starts_c = jnp.clip(starts, 0, C - 1)
+        live_group = j < group_count
 
-    # group keys: [out_cap]-sized gathers from the ORIGINAL key planes
-    # through perm∘starts (the packed words no longer carry the raw
-    # values, but two tiny composed gathers are as cheap as one)
-    first_row = jnp.take(perm, starts_c)
-    out_keys = tuple(jnp.take(k, first_row) for k in keys)
-    out_kvalids = tuple(jnp.take(kv & row_mask, first_row) & live_group
-                        for kv in key_valids)
+        # group keys: [out_cap]-sized gathers from the ORIGINAL key planes
+        # through perm∘starts (the packed words no longer carry the raw
+        # values, but two tiny composed gathers are as cheap as one)
+        first_row = jnp.take(perm, starts_c)
+        out_keys = tuple(jnp.take(k, first_row) for k in keys)
+        out_kvalids = tuple(jnp.take(kv & row_mask, first_row) & live_group
+                            for kv in key_valids)
 
     # One-hot matmul rides the MXU but materializes [C, out_cap]; past a
     # width threshold that escalates to HBM-exhausting sizes (overflow
     # retries grow out_cap ×16), so wide group blocks fall back to the
     # O(C)-memory scatter segment-sum. HIGHEST precision keeps the f32
     # matmul in true f32 (TPU default would drop the operands to bf16).
-    f32_ok = all(v.dtype != jnp.float64 for v in vals)
-    acc_dt = jnp.float32 if f32_ok else jnp.float64
-    use_matmul = out_cap <= 2048
-    oh = jax.nn.one_hot(seg, out_cap, dtype=acc_dt) if use_matmul else None
+    with jax.named_scope("sort/onehot"):
+        f32_ok = all(v.dtype != jnp.float64 for v in vals)
+        acc_dt = jnp.float32 if f32_ok else jnp.float64
+        use_matmul = out_cap <= 2048
+        oh = jax.nn.one_hot(seg, out_cap, dtype=acc_dt) if use_matmul else None
 
     def matmul_sum(x):
         if use_matmul:
@@ -415,70 +419,71 @@ def grouped_agg_block_impl(keys, key_valids, vals, val_valids, row_mask,
         return jax.ops.segment_sum(x.astype(acc_dt), seg,
                                    num_segments=out_cap + 1)[:out_cap]
 
-    idx = jnp.arange(C, dtype=jnp.int32)
-    out_vals = []
-    out_valids = []
-    for v, vv, op in zip(vals, val_valids, ops):
-        contrib = row_mask & vv  # ORIGINAL row order — no gathers
-        cnt = matmul_sum(contrib)  # counts < 2^24 → exact in f32
-        has = live_group & (cnt > 0)
-        if op == "count":
-            out_vals.append(cnt.astype(jnp.int64))
-            out_valids.append(live_group)
-            continue
-        if op in ("sum", "mean", "var", "stddev"):
-            if jnp.issubdtype(v.dtype, jnp.integer) or v.dtype == jnp.bool_:
-                # exact integer sums: scatter segment-add at block width
-                x = jnp.where(contrib, v, jnp.zeros((), v.dtype)) \
-                    .astype(jnp.int64)
-                s1 = jax.ops.segment_sum(x, seg,
-                                         num_segments=out_cap + 1)[:out_cap]
-            else:
-                s1 = matmul_sum(jnp.where(contrib, v,
-                                          jnp.zeros((), v.dtype)))
-            if op == "sum":
-                out_vals.append(s1)
+    with jax.named_scope("sort/reduce"):
+        idx = jnp.arange(C, dtype=jnp.int32)
+        out_vals = []
+        out_valids = []
+        for v, vv, op in zip(vals, val_valids, ops):
+            contrib = row_mask & vv  # ORIGINAL row order — no gathers
+            cnt = matmul_sum(contrib)  # counts < 2^24 → exact in f32
+            has = live_group & (cnt > 0)
+            if op == "count":
+                out_vals.append(cnt.astype(jnp.int64))
+                out_valids.append(live_group)
+                continue
+            if op in ("sum", "mean", "var", "stddev"):
+                if jnp.issubdtype(v.dtype, jnp.integer) or v.dtype == jnp.bool_:
+                    # exact integer sums: scatter segment-add at block width
+                    x = jnp.where(contrib, v, jnp.zeros((), v.dtype)) \
+                        .astype(jnp.int64)
+                    s1 = jax.ops.segment_sum(x, seg,
+                                             num_segments=out_cap + 1)[:out_cap]
+                else:
+                    s1 = matmul_sum(jnp.where(contrib, v,
+                                              jnp.zeros((), v.dtype)))
+                if op == "sum":
+                    out_vals.append(s1)
+                    out_valids.append(has)
+                    continue
+                # widest float the backend supports (f64, or f32 under TPU x32)
+                # — mirrors grouped_agg_impl so int means don't round at f32
+                fdt = s1.astype(jnp.float64).dtype if s1.dtype != jnp.float32 \
+                    else jnp.float32
+                safe = jnp.maximum(cnt, 1).astype(fdt)
+                mean = s1.astype(fdt) / safe
+                if op == "mean":
+                    out_vals.append(mean)
+                    out_valids.append(has)
+                    continue
+                xf = jnp.where(contrib, v, jnp.zeros((), v.dtype)).astype(fdt)
+                if fdt == acc_dt:
+                    s2 = matmul_sum(xf * xf)
+                else:  # keep the wide accumulator (matmul lanes run in acc_dt)
+                    s2 = jax.ops.segment_sum(xf * xf, seg,
+                                             num_segments=out_cap + 1)[:out_cap]
+                var = jnp.maximum(s2 / safe - mean * mean, 0.0)
+                out_vals.append(jnp.sqrt(var) if op == "stddev" else var)
                 out_valids.append(has)
                 continue
-            # widest float the backend supports (f64, or f32 under TPU x32)
-            # — mirrors grouped_agg_impl so int means don't round at f32
-            fdt = s1.astype(jnp.float64).dtype if s1.dtype != jnp.float32 \
-                else jnp.float32
-            safe = jnp.maximum(cnt, 1).astype(fdt)
-            mean = s1.astype(fdt) / safe
-            if op == "mean":
-                out_vals.append(mean)
+            if op in ("min", "max", "bool_and", "bool_or"):
+                base = v.astype(jnp.int8) if v.dtype == jnp.bool_ else v
+                red = "min" if op in ("min", "bool_and") else "max"
+                ident = _identity_for(base.dtype, red)
+                x = jnp.where(contrib, base, ident)
+                fn = jax.ops.segment_min if red == "min" else jax.ops.segment_max
+                r = fn(x, seg, num_segments=out_cap + 1)[:out_cap]
+                if v.dtype == jnp.bool_:
+                    r = r.astype(jnp.bool_)
+                out_vals.append(r)
                 out_valids.append(has)
                 continue
-            xf = jnp.where(contrib, v, jnp.zeros((), v.dtype)).astype(fdt)
-            if fdt == acc_dt:
-                s2 = matmul_sum(xf * xf)
-            else:  # keep the wide accumulator (matmul lanes run in acc_dt)
-                s2 = jax.ops.segment_sum(xf * xf, seg,
+            if op == "any_value":
+                fi = jax.ops.segment_min(jnp.where(contrib, idx, C - 1), seg,
                                          num_segments=out_cap + 1)[:out_cap]
-            var = jnp.maximum(s2 / safe - mean * mean, 0.0)
-            out_vals.append(jnp.sqrt(var) if op == "stddev" else var)
-            out_valids.append(has)
-            continue
-        if op in ("min", "max", "bool_and", "bool_or"):
-            base = v.astype(jnp.int8) if v.dtype == jnp.bool_ else v
-            red = "min" if op in ("min", "bool_and") else "max"
-            ident = _identity_for(base.dtype, red)
-            x = jnp.where(contrib, base, ident)
-            fn = jax.ops.segment_min if red == "min" else jax.ops.segment_max
-            r = fn(x, seg, num_segments=out_cap + 1)[:out_cap]
-            if v.dtype == jnp.bool_:
-                r = r.astype(jnp.bool_)
-            out_vals.append(r)
-            out_valids.append(has)
-            continue
-        if op == "any_value":
-            fi = jax.ops.segment_min(jnp.where(contrib, idx, C - 1), seg,
-                                     num_segments=out_cap + 1)[:out_cap]
-            out_vals.append(jnp.take(v, jnp.clip(fi, 0, C - 1)))
-            out_valids.append(has)
-            continue
-        raise ValueError(f"unsupported device agg {op}")
+                out_vals.append(jnp.take(v, jnp.clip(fi, 0, C - 1)))
+                out_valids.append(has)
+                continue
+            raise ValueError(f"unsupported device agg {op}")
 
     return out_keys, out_kvalids, tuple(out_vals), tuple(out_valids), \
         group_count
@@ -518,12 +523,13 @@ def grouped_agg_dense_impl(keys, key_valids, vals, val_valids, row_mask,
     if K > out_cap:
         raise ValueError("dense dispatch requires K <= out_cap")
     # mixed-radix group id per ORIGINAL row (no gathers, no sort)
-    gid = jnp.zeros(C, dtype=jnp.int32)
-    for k, kv, d in zip(keys, key_valids, dims):
-        comp = jnp.where(kv & row_mask,
-                         jnp.clip(k.astype(jnp.int32), 0, d), d)
-        gid = gid * (d + 1) + comp
-    seg = jnp.where(row_mask, gid, out_cap).astype(jnp.int32)
+    with jax.named_scope("dense/pack"):
+        gid = jnp.zeros(C, dtype=jnp.int32)
+        for k, kv, d in zip(keys, key_valids, dims):
+            comp = jnp.where(kv & row_mask,
+                             jnp.clip(k.astype(jnp.int32), 0, d), d)
+            gid = gid * (d + 1) + comp
+        seg = jnp.where(row_mask, gid, out_cap).astype(jnp.int32)
 
     # ONE [C, K] one-hot shared by every additive reduction below: the
     # per-slot sums become a single stacked matmul instead of a scatter
@@ -532,9 +538,10 @@ def grouped_agg_dense_impl(keys, key_valids, vals, val_valids, row_mask,
     # [C, K]·[K] GEMM is multithreaded there and rides the MXU on TPU.
     # K is the tiny static slot count (dictionary product), NOT out_cap,
     # so the materialized one-hot stays ~C·K·8 bytes.
-    acc_dt = jnp.float64 if any(
-        v.dtype == jnp.float64 for v in vals) else jnp.float32
-    oh = jax.nn.one_hot(jnp.where(row_mask, gid, K), K, dtype=acc_dt)
+    with jax.named_scope("dense/onehot-matmul"):
+        acc_dt = jnp.float64 if any(
+            v.dtype == jnp.float64 for v in vals) else jnp.float32
+        oh = jax.nn.one_hot(jnp.where(row_mask, gid, K), K, dtype=acc_dt)
 
     def slot_pad(x):
         """[K] slot vector → [out_cap] (slots past K are empty)."""
@@ -545,132 +552,135 @@ def grouped_agg_dense_impl(keys, key_valids, vals, val_valids, row_mask,
     # single GEMM against the shared one-hot. Integer sums keep the
     # exact int64 scatter, and min/max/any/bool reductions scatter too
     # (no additive form).
-    mm_cols = []
-    col_ix = {}
+    with jax.named_scope("dense/pack"):
+        mm_cols = []
+        col_ix = {}
 
-    def want(i, tag, x, src):
-        # queries reuse planes (q1 sums l_quantity three ways over one
-        # validity mask) — identical sources collapse to one matrix row
-        shared = (tag,) + src
-        ix = col_ix.get(shared)
-        if ix is None:
-            ix = len(mm_cols)
-            mm_cols.append(x.astype(acc_dt))
-            col_ix[shared] = ix
-        col_ix[(i, tag)] = ix
+        def want(i, tag, x, src):
+            # queries reuse planes (q1 sums l_quantity three ways over one
+            # validity mask) — identical sources collapse to one matrix row
+            shared = (tag,) + src
+            ix = col_ix.get(shared)
+            if ix is None:
+                ix = len(mm_cols)
+                mm_cols.append(x.astype(acc_dt))
+                col_ix[shared] = ix
+            col_ix[(i, tag)] = ix
 
-    want(-1, "occ", row_mask, (id(row_mask),))
-    for i, (v, vv, op) in enumerate(zip(vals, val_valids, ops)):
-        contrib = row_mask & vv
-        want(i, "cnt", contrib, (id(vv),))
-        if op in ("sum", "mean", "var", "stddev") \
-                and jnp.issubdtype(v.dtype, jnp.floating):
-            x = jnp.where(contrib, v, jnp.zeros((), v.dtype))
-            want(i, "s1", x, (id(v), id(vv)))
-            if op in ("var", "stddev"):
-                xa = x.astype(acc_dt)
-                want(i, "s2", xa * xa, (id(v), id(vv)))
-    # stack along axis 0 (each column lands contiguously) and contract
-    # the row axis directly — the axis=1/transpose formulation pays an
-    # extra interleaving copy of the whole matrix
-    M = jnp.stack(mm_cols, axis=0)
-    R = jnp.matmul(M, oh, precision=lax.Precision.HIGHEST)  # [ncols, K]
+        want(-1, "occ", row_mask, (id(row_mask),))
+        for i, (v, vv, op) in enumerate(zip(vals, val_valids, ops)):
+            contrib = row_mask & vv
+            want(i, "cnt", contrib, (id(vv),))
+            if op in ("sum", "mean", "var", "stddev") \
+                    and jnp.issubdtype(v.dtype, jnp.floating):
+                x = jnp.where(contrib, v, jnp.zeros((), v.dtype))
+                want(i, "s1", x, (id(v), id(vv)))
+                if op in ("var", "stddev"):
+                    xa = x.astype(acc_dt)
+                    want(i, "s2", xa * xa, (id(v), id(vv)))
+        # stack along axis 0 (each column lands contiguously) and contract
+        # the row axis directly — the axis=1/transpose formulation pays an
+        # extra interleaving copy of the whole matrix
+        M = jnp.stack(mm_cols, axis=0)
+    with jax.named_scope("dense/onehot-matmul"):
+        R = jnp.matmul(M, oh, precision=lax.Precision.HIGHEST)  # [ncols, K]
 
-    occ = R[col_ix[(-1, "occ")]]
-    occupied = slot_pad(occ > 0.0)
-    group_count = jnp.sum(occ > 0.0).astype(jnp.int32)
-    j = jnp.arange(out_cap, dtype=jnp.int32)
-    # compact occupied slots to the front: one stable [out_cap]-sized
-    # 2-operand sort (ascending slot order — the group order — survives)
-    slot_of = lax.sort((jnp.where(occupied, 0, 1).astype(jnp.int32), j),
-                       num_keys=1, is_stable=True)[1]
-    live_group = j < group_count
+    with jax.named_scope("dense/reduce"):
+        occ = R[col_ix[(-1, "occ")]]
+        occupied = slot_pad(occ > 0.0)
+        group_count = jnp.sum(occ > 0.0).astype(jnp.int32)
+        j = jnp.arange(out_cap, dtype=jnp.int32)
+        # compact occupied slots to the front: one stable [out_cap]-sized
+        # 2-operand sort (ascending slot order — the group order — survives)
+        slot_of = lax.sort((jnp.where(occupied, 0, 1).astype(jnp.int32), j),
+                           num_keys=1, is_stable=True)[1]
+        live_group = j < group_count
 
-    # each slot's key codes come back by mixed-radix decomposition —
-    # nothing is gathered from the row planes
-    strides = []
-    s = 1
-    for d in reversed(dims):
-        strides.append(s)
-        s *= d + 1
-    strides.reverse()
-    out_keys = []
-    out_kvalids = []
-    for k, d, st in zip(keys, dims, strides):
-        comp = (slot_of // st) % (d + 1)
-        out_keys.append(comp.astype(k.dtype))
-        out_kvalids.append(live_group & (comp != d))
+        # each slot's key codes come back by mixed-radix decomposition —
+        # nothing is gathered from the row planes
+        strides = []
+        s = 1
+        for d in reversed(dims):
+            strides.append(s)
+            s *= d + 1
+        strides.reverse()
+        out_keys = []
+        out_kvalids = []
+        for k, d, st in zip(keys, dims, strides):
+            comp = (slot_of // st) % (d + 1)
+            out_keys.append(comp.astype(k.dtype))
+            out_kvalids.append(live_group & (comp != d))
 
-    def slot_take(r):
-        """[K] slot sums → compacted [out_cap] group order."""
-        return jnp.take(slot_pad(r), slot_of)
+        def slot_take(r):
+            """[K] slot sums → compacted [out_cap] group order."""
+            return jnp.take(slot_pad(r), slot_of)
 
-    def red_scatter(x, fn=jax.ops.segment_sum):
-        return jnp.take(fn(x, seg, num_segments=out_cap + 1)[:out_cap],
-                        slot_of)
+        def red_scatter(x, fn=jax.ops.segment_sum):
+            return jnp.take(fn(x, seg, num_segments=out_cap + 1)[:out_cap],
+                            slot_of)
 
-    idx = jnp.arange(C, dtype=jnp.int32)
-    out_vals = []
-    out_vvalids = []
-    for i, (v, vv, op) in enumerate(zip(vals, val_valids, ops)):
-        contrib = row_mask & vv
-        cntf = slot_take(R[col_ix[(i, "cnt")]])  # counts exact in float
-        cnt = cntf.astype(jnp.int64)
-        has = live_group & (cnt > 0)
-        if op == "count":
-            out_vals.append(cnt)
-            out_vvalids.append(live_group)
-            continue
-        if op in ("sum", "mean", "var", "stddev"):
-            if (i, "s1") in col_ix:
-                s1 = slot_take(R[col_ix[(i, "s1")]])
-            else:  # integer/bool input: exact int64 scatter sum
-                x = jnp.where(contrib, v, jnp.zeros((), v.dtype)) \
-                    .astype(jnp.int64)
-                s1 = red_scatter(x)
-            if op == "sum":
-                out_vals.append(s1)
+        idx = jnp.arange(C, dtype=jnp.int32)
+        out_vals = []
+        out_vvalids = []
+        for i, (v, vv, op) in enumerate(zip(vals, val_valids, ops)):
+            contrib = row_mask & vv
+            cntf = slot_take(R[col_ix[(i, "cnt")]])  # counts exact in float
+            cnt = cntf.astype(jnp.int64)
+            has = live_group & (cnt > 0)
+            if op == "count":
+                out_vals.append(cnt)
+                out_vvalids.append(live_group)
+                continue
+            if op in ("sum", "mean", "var", "stddev"):
+                if (i, "s1") in col_ix:
+                    s1 = slot_take(R[col_ix[(i, "s1")]])
+                else:  # integer/bool input: exact int64 scatter sum
+                    x = jnp.where(contrib, v, jnp.zeros((), v.dtype)) \
+                        .astype(jnp.int64)
+                    s1 = red_scatter(x)
+                if op == "sum":
+                    out_vals.append(s1)
+                    out_vvalids.append(has)
+                    continue
+                # widest float the backend supports (mirrors the sort path)
+                fdt = s1.astype(jnp.float64).dtype if s1.dtype != jnp.float32 \
+                    else jnp.float32
+                safe = jnp.maximum(cnt, 1).astype(fdt)
+                mean = s1.astype(fdt) / safe
+                if op == "mean":
+                    out_vals.append(mean)
+                    out_vvalids.append(has)
+                    continue
+                if (i, "s2") in col_ix:
+                    s2 = slot_take(R[col_ix[(i, "s2")]]).astype(fdt)
+                else:
+                    xf = jnp.where(contrib, v,
+                                   jnp.zeros((), v.dtype)).astype(fdt)
+                    s2 = red_scatter(xf * xf)
+                var = jnp.maximum(s2 / safe - mean * mean, 0.0)
+                out_vals.append(jnp.sqrt(var) if op == "stddev" else var)
                 out_vvalids.append(has)
                 continue
-            # widest float the backend supports (mirrors the sort path)
-            fdt = s1.astype(jnp.float64).dtype if s1.dtype != jnp.float32 \
-                else jnp.float32
-            safe = jnp.maximum(cnt, 1).astype(fdt)
-            mean = s1.astype(fdt) / safe
-            if op == "mean":
-                out_vals.append(mean)
+            if op in ("min", "max", "bool_and", "bool_or"):
+                base = v.astype(jnp.int8) if v.dtype == jnp.bool_ else v
+                red = "min" if op in ("min", "bool_and") else "max"
+                ident = _identity_for(base.dtype, red)
+                x = jnp.where(contrib, base, ident)
+                fn = jax.ops.segment_min if red == "min" else jax.ops.segment_max
+                r = red_scatter(x, fn)
+                if v.dtype == jnp.bool_:
+                    r = r.astype(jnp.bool_)
+                out_vals.append(r)
                 out_vvalids.append(has)
                 continue
-            if (i, "s2") in col_ix:
-                s2 = slot_take(R[col_ix[(i, "s2")]]).astype(fdt)
-            else:
-                xf = jnp.where(contrib, v,
-                               jnp.zeros((), v.dtype)).astype(fdt)
-                s2 = red_scatter(xf * xf)
-            var = jnp.maximum(s2 / safe - mean * mean, 0.0)
-            out_vals.append(jnp.sqrt(var) if op == "stddev" else var)
-            out_vvalids.append(has)
-            continue
-        if op in ("min", "max", "bool_and", "bool_or"):
-            base = v.astype(jnp.int8) if v.dtype == jnp.bool_ else v
-            red = "min" if op in ("min", "bool_and") else "max"
-            ident = _identity_for(base.dtype, red)
-            x = jnp.where(contrib, base, ident)
-            fn = jax.ops.segment_min if red == "min" else jax.ops.segment_max
-            r = red_scatter(x, fn)
-            if v.dtype == jnp.bool_:
-                r = r.astype(jnp.bool_)
-            out_vals.append(r)
-            out_vvalids.append(has)
-            continue
-        if op == "any_value":
-            fi = jax.ops.segment_min(jnp.where(contrib, idx, C - 1), seg,
-                                     num_segments=out_cap + 1)[:out_cap]
-            fi = jnp.take(jnp.clip(fi, 0, C - 1), slot_of)
-            out_vals.append(jnp.take(v, fi))
-            out_vvalids.append(has)
-            continue
-        raise ValueError(f"unsupported device agg {op}")
+            if op == "any_value":
+                fi = jax.ops.segment_min(jnp.where(contrib, idx, C - 1), seg,
+                                         num_segments=out_cap + 1)[:out_cap]
+                fi = jnp.take(jnp.clip(fi, 0, C - 1), slot_of)
+                out_vals.append(jnp.take(v, fi))
+                out_vvalids.append(has)
+                continue
+            raise ValueError(f"unsupported device agg {op}")
 
     return tuple(out_keys), tuple(out_kvalids), tuple(out_vals), \
         tuple(out_vvalids), group_count

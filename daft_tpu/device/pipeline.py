@@ -103,7 +103,16 @@ def fetch_host(tree):
     window's packed result matrices) cost one batched transfer instead
     of one blocking round-trip per plane."""
     import jax
-    return jax.device_get(tree)
+
+    from .. import tracing
+    with tracing.span("device:fetch", lane="device") as sp:
+        out = jax.device_get(tree)
+        if sp is not tracing._NOOP:
+            leaves = jax.tree_util.tree_leaves(out)
+            sp.set("arrays", len(leaves))
+            sp.set("bytes",
+                   sum(int(getattr(a, "nbytes", 0)) for a in leaves))
+    return out
 
 
 # ------------------------------------------------------------- submit pool
@@ -409,29 +418,31 @@ def run_pipelined(items: Iterator, submit: Callable, drain: Callable, *,
 # ------------------------------------------------------- pipeline spans
 
 def upload_span(seq: int, window: int):
-    """``device:upload`` span covering a slot's host encode + async
-    dispatch (the submit stage), on its own lane with the in-flight
-    slot id annotated — perfetto shows the overlap (or its absence)
-    directly.  Keys are deterministic (morsel sequence), so chaos runs
-    replay bit-identical span ids."""
+    """``device:submit`` span covering a slot's submit stage: resolve
+    (load, encode, put; each a span of its own inside) and the async
+    dispatch, on its own lane with the in-flight slot id annotated —
+    perfetto shows the overlap (or its absence) directly.  Keys are
+    deterministic (morsel sequence), so chaos runs replay bit-identical
+    span ids."""
     from .. import tracing
-    return tracing.span("device:upload", key=f"devpipe.up.{seq}",
+    return tracing.span("device:submit", key=f"devpipe.up.{seq}",
                         attrs={"slot": seq % max(window, 1), "seq": seq},
                         lane="dev:upload")
 
 
 def note_compute_span(seq: int, window: int, t_dispatched_us: int) -> None:
-    """``device:compute`` span from dispatch completion to drain start —
-    the interval the device computes while the host works on neighbor
-    slots.  Emitted at drain time (the host never blocks mid-flight to
-    observe the device)."""
+    """``device:inflight`` span from dispatch completion to drain start:
+    how long the slot's results waited for the host to come for them.
+    Not device time (the device trace has that: a few percent of it).
+    Emitted at drain time, into the recorder only (the host never blocks
+    mid-flight to observe the device)."""
     from .. import tracing
     ctx = tracing.current()
     if ctx is None or not t_dispatched_us:
         return
     rec = ctx.recorder
     now = tracing._now_us()
-    rec.add("device:compute", rec.unique_span_id(f"devpipe.comp.{seq}"),
+    rec.add("device:inflight", rec.unique_span_id(f"devpipe.comp.{seq}"),
             ctx.span_id, t_dispatched_us,
             max(now - t_dispatched_us, 0),
             attrs={"slot": seq % max(window, 1), "seq": seq},
@@ -439,10 +450,10 @@ def note_compute_span(seq: int, window: int, t_dispatched_us: int) -> None:
 
 
 def download_span(seq: int, window: int):
-    """``device:download`` span covering a slot's batched fetch +
-    decode (the drain stage)."""
+    """``device:drain`` span covering a slot's drain stage: the batched
+    fetch and the decode (``device:fetch``, ``device:decode`` inside)."""
     from .. import tracing
-    return tracing.span("device:download", key=f"devpipe.down.{seq}",
+    return tracing.span("device:drain", key=f"devpipe.down.{seq}",
                         attrs={"slot": seq % max(window, 1), "seq": seq},
                         lane="dev:download")
 

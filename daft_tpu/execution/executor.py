@@ -169,9 +169,6 @@ class LocalExecutor:
                     xtrace.stop()
                 self.stats.finish()
                 obs.set_last_stats(self.stats)
-                path = obs.chrome_trace_path()
-                if path and self.stats.tracer is not None:
-                    self.stats.tracer.dump(path)
         return gen()
 
     # ------------------------------------------------------------------
@@ -345,7 +342,7 @@ class LocalExecutor:
                     schema = task.materialized_schema()
                     produced = False
                     try:
-                        for rb in task.stream_batches():
+                        for rb in _loaded_batches(task):
                             st.put(("batch",
                                     MicroPartition.from_recordbatch(
                                         rb.cast_to_schema(schema))))
@@ -354,7 +351,7 @@ class LocalExecutor:
                         if produced:
                             raise  # can't re-stream mid-task: dup rows
                         _time.sleep(0.2)  # transient IO: one clean retry
-                        for rb in task.stream_batches():
+                        for rb in _loaded_batches(task):
                             st.put(("batch",
                                     MicroPartition.from_recordbatch(
                                         rb.cast_to_schema(schema))))
@@ -747,8 +744,9 @@ class LocalExecutor:
         in-flight limit; fallbacks re-read the pristine task (never decode
         the lossy device encoding back)."""
         import itertools
-        from ..device import cache as dcache, column as dcol, fragment
-        from ..device import runtime as drt
+        from .. import tracing
+        from ..device import cache as dcache, column as dcol, costmodel
+        from ..device import fragment, runtime as drt
 
         n_tasks = len(src.tasks)
 
@@ -756,24 +754,32 @@ class LocalExecutor:
             est = t.size_bytes() or 0
             self.mem.acquire(est)
             try:
-                return _load_with_retry(t).combined()
+                mp = _load_with_retry(t)
+                with tracing.span("scan:load", lane="scan",
+                                  attrs={"step": "combine"}):
+                    return mp.combined()
             finally:
                 self.mem.release(est)
 
         def classify(t):
             """Phase A: cache hits are committed device participants;
             too-small / pyobject batches are forced host; the rest are
-            candidates for the cost gate (phase B)."""
+            candidates for the cost gate (phase B). Every task is
+            tallied once by where its table came from, here or in the
+            gate (``costmodel.scan_table_counts``)."""
             fp = dcache.task_fingerprint(t)
             if fp is not None:
                 dt = dcache.get_cache().get_table(fp, prog.compiled.needs_cols)
                 if dt is not None:
+                    costmodel.count_scan_table("from_cache")
                     return ("dev", dt, t)
             rb = load(t)
             if len(rb) < max(drt._min_rows(), 1):
+                costmodel.count_scan_table("host")
                 return ("host", rb, t)
             for nm in prog.compiled.needs_cols:
                 if rb.get_column(nm).is_pyobject():
+                    costmodel.count_scan_table("host")
                     return ("host", rb, t)
             return ("cand", rb, t, fp)
 
@@ -783,7 +789,6 @@ class LocalExecutor:
             same task — but only if the whole scan's working set actually
             FITS the budget (otherwise LRU thrash re-pays the upload every
             query and put_table would refuse oversized tables anyway)."""
-            from ..device import costmodel
             from ..device import fragment as dfrag
             _, rb, t, fp = cand
             packed_out = dfrag.packed_bytes_per_group(
@@ -808,10 +813,12 @@ class LocalExecutor:
                     # overlap pricing when the windows really pipeline
                     # (pwin is assigned before any window resolves)
                     window=pwin):
+                costmodel.count_scan_table("host")
                 return ("host", rb, t)
             try:
                 dt = dcol.encode_batch(rb, prog.compiled.needs_cols)
             except (ValueError, TypeError):
+                costmodel.count_scan_table("host")
                 return ("host", rb, t)
             if fp is not None and fits:
                 # only cache working sets that FIT the budget: caching a
@@ -819,6 +826,7 @@ class LocalExecutor:
                 # queries still repay (SF10 thrash, r4) — the upload then
                 # streams through as a one-shot morsel instead
                 dcache.get_cache().put_table(fp, dt)
+            costmodel.count_scan_table("encoded")
             return ("dev", dt, t)
 
         width = max((os.cpu_count() or 4), 4) * 2
@@ -889,26 +897,30 @@ class LocalExecutor:
         import time as _time
 
         def p_submit(window_tasks, seq, wgate):
-            t0 = _time.perf_counter()
-            resolved = resolve(window_tasks)
-            tables = [dt for kind, dt, _ in resolved if kind == "dev"]
-            est = sum(
-                int(c.data.nbytes) + int(c.validity.nbytes)
-                for dt in tables for c in dt.columns.values())
-            pre_s = _time.perf_counter() - t0
-            slot = dpipe.acquire_slot(wgate, seq, self.mem, est)
-            try:
-                t1 = _time.perf_counter()
-                with dpipe.upload_span(seq, pwin):
+            # device:submit covers the whole submit stage: resolve (load,
+            # encode, put) and the dispatch; the wait for a slot between
+            # them is the window's, and inside it too
+            with dpipe.upload_span(seq, pwin):
+                t0 = _time.perf_counter()
+                resolved = resolve(window_tasks)
+                tables = [dt for kind, dt, _ in resolved if kind == "dev"]
+                est = sum(
+                    int(c.data.nbytes) + int(c.validity.nbytes)
+                    for dt in tables for c in dt.columns.values())
+                pre_s = _time.perf_counter() - t0
+                slot = dpipe.acquire_slot(wgate, seq, self.mem, est)
+                try:
+                    t1 = _time.perf_counter()
                     tok = fragment.submit_fused_agg_tables(
                         prog, tables, src.schema(), node.group_by,
                         agg_cols, node.schema(), groups=groups_ndv)
-                sub_s = pre_s + (_time.perf_counter() - t1)
-            except BaseException:
-                dpipe.release_slot(slot)
-                raise
-            return dpipe.InflightItem(slot, (resolved, tok), sub_s=sub_s,
-                                      t_dispatched_us=dpipe.now_us())
+                    sub_s = pre_s + (_time.perf_counter() - t1)
+                except BaseException:
+                    dpipe.release_slot(slot)
+                    raise
+                return dpipe.InflightItem(slot, (resolved, tok),
+                                          sub_s=sub_s,
+                                          t_dispatched_us=dpipe.now_us())
 
         def p_drain(ret, seq):
             resolved, tok = ret.token
@@ -1415,16 +1427,20 @@ class LocalExecutor:
             if n > 1 and len(buf) > 1 and samples:
                 boundaries = self._sample_boundaries(
                     samples, [e.name() for e in by], desc, nf, n)
+            from .. import tracing
+
+            def sort(p: MicroPartition) -> MicroPartition:
+                with tracing.span("sort:topn", lane="pipeline",
+                                  attrs={"rows": len(p)}):
+                    return p.sort(node.sort_by, node.descending,
+                                  node.nulls_first)
+
             if boundaries is None:
-                yield _gather_all(iter(buf)).sort(node.sort_by,
-                                                  node.descending,
-                                                  node.nulls_first)
+                yield sort(_gather_all(iter(buf)))
                 return
             yield from _ordered_parallel(
                 self._stream_range_buckets(buf, by, boundaries, desc, n,
-                                           node.schema()),
-                lambda p: p.sort(node.sort_by, node.descending,
-                                 node.nulls_first))
+                                           node.schema()), sort)
         finally:
             buf.close()
 
@@ -1497,19 +1513,23 @@ class LocalExecutor:
                 yield MicroPartition.empty(schema)
 
     def _exec_TopN(self, node: pp.TopN):
+        from .. import tracing
+
+        def top(p: MicroPartition) -> MicroPartition:
+            rb = p.combined()
+            with tracing.span("sort:topn", lane="pipeline",
+                              attrs={"rows": len(rb), "k": node.limit}):
+                return MicroPartition.from_recordbatch(
+                    rb.top_n(node.sort_by, node.limit, node.descending,
+                             node.nulls_first))
+
         child = self._exec(node.children[0])
-        tops = list(_ordered_parallel(
-            child, lambda p: MicroPartition.from_recordbatch(
-                p.combined().top_n(node.sort_by, node.limit, node.descending,
-                                   node.nulls_first))))
+        tops = list(_ordered_parallel(child, top))
         if not tops:  # an empty child STREAM (not just empty morsels)
             yield MicroPartition.from_recordbatch(
                 RecordBatch.empty(node.schema()))
             return
-        merged = tops[0].concat(tops[1:]) if len(tops) > 1 else tops[0]
-        yield MicroPartition.from_recordbatch(
-            merged.combined().top_n(node.sort_by, node.limit, node.descending,
-                                    node.nulls_first))
+        yield top(tops[0].concat(tops[1:]) if len(tops) > 1 else tops[0])
 
     # exchanges --------------------------------------------------------
     def _exec_Exchange(self, node: pp.Exchange):
@@ -2090,16 +2110,38 @@ def _decode_mesh_shards(n: int, live_mask: np.ndarray, cols_spec, schema
     return outs
 
 
+def _loaded_batches(task):
+    """``task.stream_batches()`` with each pull (read + decode of the
+    next batch) under a ``scan:load`` span; what the consumer does
+    between pulls stays outside."""
+    from .. import tracing
+    it = iter(task.stream_batches())
+    while True:
+        with tracing.span("scan:load", lane="scan") as sp:
+            try:
+                rb = next(it)
+            except StopIteration:
+                return
+            sp.set("rows", len(rb))
+            sp.set("bytes", rb.size_bytes())
+        yield rb
+
+
 def _load_with_retry(task, tries: int = 2) -> MicroPartition:
     """Scan-task load with transient-IO retry (reference analogue: per-task
     lineage retry in the classic runner / flotilla max_task_retries —
     inputs are re-scannable from storage, so retrying the load is safe)."""
+    from .. import tracing
     tries = max(tries, 1)
     last = None
     for attempt in range(tries):
         mp = MicroPartition.from_scan_task(task)
         try:
-            mp._load()
+            with tracing.span("scan:load", lane="scan",
+                              attrs={"files": len(task.paths)}) as sp:
+                mp._load()
+                sp.set("rows", len(mp))
+                sp.set("bytes", mp.size_bytes())
             return mp
         except OSError as exc:
             last = exc

@@ -144,14 +144,17 @@ def radix_split(rb: RecordBatch, by, n: int, depth: int
     (gcd(n, m) correlation)."""
     if len(rb) == 0:
         return [rb.slice(0, 0) for _ in range(n)]
-    keys = [rb.eval_expression(e) for e in by]
-    h = keys[0].hash()
-    for k in keys[1:]:
-        h = k.hash(seed=h)
-    for _ in range(depth):
-        h = h.hash()
-    pid = (h.to_numpy() % np.uint64(n)).astype(np.int64)
-    return rb._split_by_pid(pid, n)
+    from .. import tracing
+    with tracing.span("exchange:partition", lane="pipeline",
+                      attrs={"rows": len(rb), "parts": n}):
+        keys = [rb.eval_expression(e) for e in by]
+        h = keys[0].hash()
+        for k in keys[1:]:
+            h = k.hash(seed=h)
+        for _ in range(depth):
+            h = h.hash()
+        pid = (h.to_numpy() % np.uint64(n)).astype(np.int64)
+        return rb._split_by_pid(pid, n)
 
 
 def drain_to_store(stream: Iterator[MicroPartition], by, n: int,

@@ -373,9 +373,6 @@ class PushExecutor(LocalExecutor):
                     xtrace.stop()
                 self.stats.finish()
                 obs.set_last_stats(self.stats)
-                path = obs.chrome_trace_path()
-                if path and self.stats.tracer is not None:
-                    self.stats.tracer.dump(path)
         return gen()
 
     # ------------------------------------------------------------ stages

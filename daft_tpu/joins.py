@@ -139,21 +139,31 @@ def match_indices(l_gids: np.ndarray, r_gids: np.ndarray,
         out = _device_match_indices(l_gids, r_gids, l_valid, r_valid)
         if out is not None:
             return out
+    from . import tracing
     n_l = len(l_gids)
-    r_idx = np.flatnonzero(r_valid)
-    r_vals = r_gids[r_idx]
-    order = np.argsort(r_vals, kind="stable")
-    r_sorted_vals = r_vals[order]
-    r_sorted_idx = r_idx[order]
+    # build: the right side's keys, sorted
+    with tracing.span("join:build", lane="pipeline",
+                      attrs={"rows": len(r_gids), "side": "right",
+                             "step": "sort"}):
+        r_idx = np.flatnonzero(r_valid)
+        r_vals = r_gids[r_idx]
+        order = np.argsort(r_vals, kind="stable")
+        r_sorted_vals = r_vals[order]
+        r_sorted_idx = r_idx[order]
 
-    starts = np.searchsorted(r_sorted_vals, l_gids, side="left")
-    ends = np.searchsorted(r_sorted_vals, l_gids, side="right")
-    counts = np.where(l_valid, ends - starts, 0)
-    total = int(counts.sum())
-    li = np.repeat(np.arange(n_l), counts)
-    cum = np.cumsum(counts) - counts  # exclusive prefix, same length as counts
-    offsets = np.arange(total) - np.repeat(cum, counts)
-    ri = r_sorted_idx[np.repeat(starts, counts) + offsets]
+    # probe: each left key's run in them, expanded to index pairs
+    with tracing.span("join:probe", lane="pipeline",
+                      attrs={"rows": n_l, "side": "left",
+                             "step": "match"}) as sp:
+        starts = np.searchsorted(r_sorted_vals, l_gids, side="left")
+        ends = np.searchsorted(r_sorted_vals, l_gids, side="right")
+        counts = np.where(l_valid, ends - starts, 0)
+        total = int(counts.sum())
+        li = np.repeat(np.arange(n_l), counts)
+        cum = np.cumsum(counts) - counts  # exclusive prefix, same length as counts
+        offsets = np.arange(total) - np.repeat(cum, counts)
+        ri = r_sorted_idx[np.repeat(starts, counts) + offsets]
+        sp.set("pairs", total)
     return li, ri, counts
 
 
@@ -275,22 +285,43 @@ def _device_match_indices(l_gids, r_gids, l_valid, r_valid):
 
 def join_recordbatch(left, right, left_on: List[Expression],
                      right_on: List[Expression], how: str = "inner"):
+    from . import tracing
     from .recordbatch import RecordBatch
 
-    l_keys = [left.eval_expression(e) for e in left_on]
-    r_keys = [right.eval_expression(e) for e in right_on]
-    l_gids, r_gids, l_valid, r_valid = _factorize_pair(
-        [k.to_arrow() for k in l_keys], [k.to_arrow() for k in r_keys])
+    # build: both sides' keys to shared dense ids (then, in
+    # ``match_indices``, the right side's ids sorted)
+    with tracing.span("join:build", lane="pipeline",
+                      attrs={"rows": len(left) + len(right),
+                             "side": "both", "step": "keys"}):
+        l_keys = [left.eval_expression(e) for e in left_on]
+        r_keys = [right.eval_expression(e) for e in right_on]
+        l_gids, r_gids, l_valid, r_valid = _factorize_pair(
+            [k.to_arrow() for k in l_keys], [k.to_arrow() for k in r_keys])
 
     if how in ("semi", "anti"):
-        matched_gids = np.unique(r_gids[r_valid])
-        has = np.isin(l_gids, matched_gids) & l_valid
-        mask = has if how == "semi" else ~has
-        return RecordBatch(left.schema,
-                           [c.filter(mask) for c in left.columns()],
-                           int(mask.sum()))
+        with tracing.span("join:probe", lane="pipeline",
+                          attrs={"rows": len(left), "side": "left",
+                                 "step": how}):
+            matched_gids = np.unique(r_gids[r_valid])
+            has = np.isin(l_gids, matched_gids) & l_valid
+            mask = has if how == "semi" else ~has
+            return RecordBatch(left.schema,
+                               [c.filter(mask) for c in left.columns()],
+                               int(mask.sum()))
 
     li, ri, counts = match_indices(l_gids, r_gids, l_valid, r_valid)
+    with tracing.span("join:probe", lane="pipeline",
+                      attrs={"rows": len(li), "side": "both",
+                             "step": "take"}):
+        return _assemble_join(left, right, left_on, right_on, how, r_keys,
+                              li, ri, counts)
+
+
+def _assemble_join(left, right, left_on, right_on, how: str, r_keys,
+                   li, ri, counts):
+    """The join's output rows from the matched index pairs: the
+    unmatched rows of an outer side, then every column taken."""
+    from .recordbatch import RecordBatch
     l_matched_mask = np.ones(len(li), dtype=bool)
     r_matched_mask = np.ones(len(ri), dtype=bool)
 

@@ -116,7 +116,13 @@ class MicroPartition:
     def size_bytes(self) -> int:
         if self._batches is None and self._meta_bytes is not None:
             return self._meta_bytes
-        return sum(b.size_bytes() for b in self._load())
+        # not free: every column is asked for its Arrow form (the spill
+        # buffers and the join's pair budget ask for every partition)
+        from . import tracing
+        with tracing.span("mem:size", lane="pipeline") as sp:
+            nbytes = sum(b.size_bytes() for b in self._load())
+            sp.set("bytes", nbytes)
+        return nbytes
 
     def metadata_num_rows(self) -> Optional[int]:
         """Row count without forcing a load (None if unknown)."""

@@ -1,16 +1,17 @@
-"""Per-operator runtime stats, chrome tracing, and progress reporting.
+"""Per-operator runtime stats and progress reporting.
 
 Capability mirror of the reference's observability stack:
 - per-operator rows/cpu counters (``daft-local-execution/src/runtime_stats.rs:23-75``)
-- chrome-trace layer gated by an env flag
-  (``DAFT_DEV_ENABLE_CHROME_TRACE``, ``src/common/tracing/src/lib.rs:16-17``)
 - progress bars (``progress_bar.rs`` / ``daft/runners/progress_bar.py``)
 - ``explain_analyze`` plan annotation
   (``physical_planner/planner.rs:451-640``)
 
+The reference's chrome-trace layer (``src/common/tracing/src/lib.rs``) is
+the tracing plane's (``tracing.py``): ``DAFT_TPU_TRACE=1`` +
+``DAFT_TPU_TRACE_DIR`` writes one Chrome trace per query, operators
+included (``op:<Operator>`` spans, emitted from here at ``finish()``).
+
 Env flags (same spirit as the reference's ``DAFT_DEV_*``):
-- ``DAFT_TPU_CHROME_TRACE`` — ``1`` or a path; writes a chrome://tracing
-  JSON for the last execution (default ``/tmp/daft_tpu_trace_<pid>.json``)
 - ``DAFT_TPU_PROGRESS`` — ``1`` enables a tqdm partition-progress bar
 """
 
@@ -22,8 +23,6 @@ import os
 import threading
 import time
 from typing import Dict, List, Optional
-
-_START_TS = time.perf_counter()
 
 
 # ------------------------------------------------------------ attribution
@@ -88,6 +87,33 @@ def in_nested_scope() -> bool:
     return getattr(_nested_tl, "n", 0) > 0
 
 
+@contextlib.contextmanager
+def query_scope():
+    """A top-level call that still has work to do once its executor is
+    drained (``DataFrame.to_pydict`` turning the result into Python
+    lists): the per-query exports wait for the end of the block, so that
+    work lies inside the query's trace. Yields a callable giving the span
+    context of the query that ran inside the block (None: none ran, or it
+    is untraced). Inside an outer scope nothing changes: the outermost
+    owner finalizes."""
+    if in_nested_scope():
+        yield lambda: None
+        return
+    before = last_query_stats_local()
+
+    def ran() -> Optional["RuntimeStatsContext"]:
+        ctx = last_query_stats_local()
+        return ctx if ctx is not before else None
+
+    try:
+        with nested_scope():
+            yield lambda: getattr(ran(), "trace_ctx", None)
+    finally:
+        ctx = ran()
+        if ctx is not None:
+            finalize_query(ctx)
+
+
 def run_attributed(ctx, fn, *args, **kwargs):
     """Run ``fn`` with ``ctx`` attributed — the shape pool-submit sites
     use to carry the submitting thread's attribution onto the worker."""
@@ -102,10 +128,6 @@ def bump_plane(plane: str, key: str, n: float = 1) -> None:
     ctx = current_attribution()
     if ctx is not None:
         ctx._bump(plane, key, n)
-
-
-def _now_us() -> int:
-    return int((time.perf_counter() - _START_TS) * 1_000_000)
 
 
 def _ledger_raw() -> Dict[str, dict]:
@@ -256,6 +278,7 @@ class OperatorStats:
     ``RuntimeStatsContext`` counters)."""
 
     __slots__ = ("name", "rows_out", "batches_out", "inclusive_us",
+                 "first_pull_s", "last_pull_s",
                  "morsel_rows_min", "morsel_rows_max", "workers", "lock")
 
     def __init__(self, name: str):
@@ -263,6 +286,11 @@ class OperatorStats:
         self.rows_out = 0
         self.batches_out = 0
         self.inclusive_us = 0
+        # ``time.perf_counter()`` at the first pull's start and the last
+        # pull's end: the interval the operator lived in (its ``op:``
+        # span), where ``inclusive_us`` is only the time inside pulls
+        self.first_pull_s = None
+        self.last_pull_s = None
         # observed morsel sizes: shows the re-chunking buffer honoring
         # execution_config.default_morsel_size in explain_analyze/traces
         self.morsel_rows_min = None
@@ -287,27 +315,6 @@ class OperatorStats:
             self.inclusive_us += dur_us
 
 
-class ChromeTracer:
-    """Collects chrome://tracing 'X' (complete) events; flushed per query."""
-
-    def __init__(self):
-        self._events: List[dict] = []
-        self._lock = threading.Lock()
-
-    def add(self, name: str, ts_us: int, dur_us: int):
-        tid = threading.get_ident() & 0xFFFF
-        with self._lock:
-            self._events.append({"name": name, "ph": "X", "ts": ts_us,
-                                 "dur": dur_us, "pid": os.getpid(), "tid": tid})
-
-    def dump(self, path: str):
-        with self._lock:
-            events = list(self._events)
-        with open(path, "w") as f:
-            json.dump({"traceEvents": events,
-                       "displayTimeUnit": "ms"}, f)
-
-
 class RuntimeStatsContext:
     """Per-query stats: one ``OperatorStats`` per physical-plan node.
 
@@ -318,12 +325,11 @@ class RuntimeStatsContext:
     the reference's push model has the same per-operator granularity.
     """
 
-    def __init__(self, tracer: Optional[ChromeTracer] = None):
+    def __init__(self):
         from . import tracing
         self._ops: Dict[int, OperatorStats] = {}
         self._children: Dict[int, List[int]] = {}
         self._lock = threading.Lock()
-        self.tracer = tracer
         self.wall_us: Optional[int] = None
         self.plan = None  # physical plan root, set by the executor
         self._t0 = time.perf_counter()
@@ -416,19 +422,19 @@ class RuntimeStatsContext:
     def instrument(self, node, it):
         """Wrap a node's output iterator with rows/time accounting."""
         st = self.register(node)
-        tracer = self.tracer
 
         def gen():
             while True:
                 t0 = time.perf_counter()
+                if st.first_pull_s is None:
+                    st.first_pull_s = t0
                 try:
                     item = next(it)
                 except StopIteration:
+                    st.last_pull_s = time.perf_counter()
                     return
-                dur = int((time.perf_counter() - t0) * 1_000_000)
-                st.record(len(item), dur)
-                if tracer is not None:
-                    tracer.add(st.name, _now_us() - dur, dur)
+                t1 = st.last_pull_s = time.perf_counter()
+                st.record(len(item), int((t1 - t0) * 1_000_000))
                 yield item
         return gen()
 
@@ -546,18 +552,29 @@ class RuntimeStatsContext:
         """Fold this executor's per-operator timings into the query
         trace as one span per physical operator (children of the span
         context this executor ran under — the task:run span for worker
-        fragments, the query root locally)."""
+        fragments, the query root locally). The span is the interval the
+        operator lived in, first pull's start to last pull's end;
+        ``busy_us`` is the time inside its pulls (upstream included),
+        ``self_us`` that less its children's."""
         ctx = self.trace_ctx
         if ctx is None:
             return
         rec = ctx.recorder
         try:
             for key, st in list(self._ops.items()):
+                first = st.first_pull_s
+                if first is None:  # registered, never pulled
+                    first = last = self._t0
+                else:
+                    last = max(st.last_pull_s or first, first)
                 rec.add(f"op:{st.name}",
                         rec.unique_span_id(f"op:{st.name}"),
-                        ctx.span_id, self._t0_unix_us, st.inclusive_us,
+                        ctx.span_id,
+                        self._t0_unix_us + int((first - self._t0) * 1e6),
+                        int((last - first) * 1e6),
                         attrs={"rows_out": st.rows_out,
                                "batches": st.batches_out,
+                               "busy_us": st.inclusive_us,
                                "self_us": self.exclusive_us(key)},
                         lane="pipeline")
             self.trace_summary = rec.summary()
@@ -991,7 +1008,9 @@ def xplane_trace_dir() -> Optional[str]:
     TensorBoard) trace per query — the TPU-native analogue of the
     reference's chrome-trace layer (``src/common/tracing``): device kernel
     timelines, HBM transfers and XLA compilation spans land in
-    ``<dir>/plugins/profile``."""
+    ``<dir>/plugins/profile``, and the query's own spans lie on the host
+    lines of the same profile as ``daft:<span>`` (a query profiled this
+    way is traced: ``tracing.profile_requested``)."""
     from .analysis import knobs
     return knobs.env_str("DAFT_TPU_XPLANE_DIR") or None
 
@@ -1036,19 +1055,6 @@ class _XplaneTrace:
                 _xplane_owner = None
 
 
-def chrome_trace_path() -> Optional[str]:
-    from .analysis import knobs
-    v = knobs.env_str("DAFT_TPU_CHROME_TRACE")
-    if not v:
-        return None
-    low = v.strip().lower()
-    if low in ("", "0", "false", "no", "off"):
-        return None
-    if low in ("1", "true", "yes", "on"):
-        return f"/tmp/daft_tpu_trace_{os.getpid()}.json"
-    return v
-
-
 def progress_enabled() -> bool:
     from .analysis import knobs
     return bool(knobs.env_bool("DAFT_TPU_PROGRESS"))
@@ -1056,14 +1062,12 @@ def progress_enabled() -> bool:
 
 def new_query_stats() -> RuntimeStatsContext:
     from . import tracing
-    tracer = ChromeTracer() if chrome_trace_path() else None
-    ctx = RuntimeStatsContext(tracer)
+    ctx = RuntimeStatsContext()
     # fallback trace start for executors driven without a runner (the
     # runners/serving scheduler normally start the trace earlier, so the
     # planner spans land too); nested scopes never start traces — the
     # query-wide sampling decision was the top level's to make
-    if ctx.trace_ctx is None and not in_nested_scope() \
-            and tracing.trace_enabled():
+    if ctx.trace_ctx is None and not in_nested_scope():
         ctx.trace_ctx = tracing.maybe_start_trace("query")
     return ctx
 

@@ -135,32 +135,39 @@ class RecordBatch:
     def eval_expression_list(self, exprs: Sequence[Expression]) -> "RecordBatch":
         """Evaluate a projection; uses the TPU tier when the whole projection
         is device-representable (see device.compiler), else Arrow host compute."""
+        from . import tracing
         from .device import runtime as device_runtime
-        out = device_runtime.try_eval_projection(self, list(exprs))
-        if out is not None:
-            return out
-        cols = self._cols_dict()
-        return RecordBatch.from_series(
-            [eval_expression(e, cols, self._len) for e in exprs])
+        with tracing.span("expr:eval", lane="pipeline",
+                          attrs={"rows": self._len, "step": "project"}):
+            out = device_runtime.try_eval_projection(self, list(exprs))
+            if out is not None:
+                return out
+            cols = self._cols_dict()
+            return RecordBatch.from_series(
+                [eval_expression(e, cols, self._len) for e in exprs])
 
     def eval_expression(self, e: Expression) -> Series:
         return eval_expression(e, self._cols_dict(), self._len)
 
     # ---- row selection ---------------------------------------------------
     def filter(self, predicate: Union[Expression, Series]) -> "RecordBatch":
-        if isinstance(predicate, Expression):
-            from .device import runtime as device_runtime
-            m_np = device_runtime.try_eval_predicate(self, predicate)
-            if m_np is not None:
-                mask = Series.from_arrow(pa.array(m_np), "mask")
+        from . import tracing
+        with tracing.span("expr:eval", lane="pipeline",
+                          attrs={"rows": self._len, "step": "filter"}):
+            if isinstance(predicate, Expression):
+                from .device import runtime as device_runtime
+                m_np = device_runtime.try_eval_predicate(self, predicate)
+                if m_np is not None:
+                    mask = Series.from_arrow(pa.array(m_np), "mask")
+                else:
+                    mask = self.eval_expression(predicate)
             else:
-                mask = self.eval_expression(predicate)
-        else:
-            mask = predicate
-        m = pc.fill_null(mask.to_arrow().cast(pa.bool_()), False)
-        return RecordBatch(self._schema,
-                           [c.filter(Series.from_arrow(m, "m")) for c in self._columns],
-                           int(pc.sum(m).as_py() or 0))
+                mask = predicate
+            m = pc.fill_null(mask.to_arrow().cast(pa.bool_()), False)
+            return RecordBatch(
+                self._schema,
+                [c.filter(Series.from_arrow(m, "m")) for c in self._columns],
+                int(pc.sum(m).as_py() or 0))
 
     def take(self, indices: Union[Series, np.ndarray]) -> "RecordBatch":
         idx = indices.to_numpy() if isinstance(indices, Series) else np.asarray(indices)
@@ -339,12 +346,17 @@ class RecordBatch:
         """Reference: ``ops/partition.rs:53-104``."""
         if self._len == 0:
             return [self.slice(0, 0) for _ in range(num_partitions)]
-        keys = [self.eval_expression(e) for e in exprs]
-        h = keys[0].hash()
-        for k in keys[1:]:
-            h = k.hash(seed=h)
-        pid = (h.to_numpy() % np.uint64(num_partitions)).astype(np.int64)
-        return self._split_by_pid(pid, num_partitions)
+        from . import tracing
+        with tracing.span("exchange:partition", lane="pipeline",
+                          attrs={"rows": self._len,
+                                 "parts": num_partitions}):
+            keys = [self.eval_expression(e) for e in exprs]
+            h = keys[0].hash()
+            for k in keys[1:]:
+                h = k.hash(seed=h)
+            pid = (h.to_numpy() % np.uint64(num_partitions)) \
+                .astype(np.int64)
+            return self._split_by_pid(pid, num_partitions)
 
     def partition_by_random(self, num_partitions: int, seed: int) -> List["RecordBatch"]:
         rng = np.random.default_rng(seed)

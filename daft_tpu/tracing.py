@@ -27,8 +27,13 @@ Design contracts:
 - **near-free when off** — span creation guards on the thread's current
   span context (one ``getattr``); no dicts, no ids, no timestamps are
   built for untraced queries. The per-query enable decision
-  (``DAFT_TPU_TRACE`` × ``DAFT_TPU_TRACE_SAMPLE``) happens once at
-  trace creation.
+  (``DAFT_TPU_TRACE`` × ``DAFT_TPU_TRACE_SAMPLE``, or a profile being
+  taken: see :func:`maybe_start_trace`) happens once at trace creation.
+- **one clock** — while a profile is being taken every live span is
+  also a ``jax.profiler.TraceAnnotation`` named ``daft:<span>`` on the
+  same thread, so the profile holds the program's spans on its host
+  lines, beside the device lines they explain. Both stamp wall-clock
+  time.
 - **deterministic under chaos** — span ids are minted by hashing the
   planner's stable identities (``Stage.task_key`` fault keys, operator
   names, attempt numbers), never RNG, so a seeded
@@ -40,11 +45,13 @@ Design contracts:
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import hashlib
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -83,11 +90,15 @@ def _now_us() -> int:
 class SpanRecorder:
     """One query's span buffer. Bounded; thread-safe; ids deterministic."""
 
-    def __init__(self, trace_id: str, max_spans: Optional[int] = None):
+    def __init__(self, trace_id: str, max_spans: Optional[int] = None,
+                 bridge: bool = False):
         if max_spans is None:
             from .analysis import knobs
             max_spans = knobs.env_int("DAFT_TPU_TRACE_MAX_SPANS")
         self.trace_id = trace_id
+        #: a profile is being taken: every live span is also a
+        #: ``jax.profiler.TraceAnnotation`` (decided once, at the start)
+        self.bridge = bridge
         self.max_spans = max(int(max_spans), 1)
         self._lock = threading.Lock()
         self._spans: List[dict] = []
@@ -96,6 +107,14 @@ class SpanRecorder:
         self.clock_offsets_us: Dict[str, int] = {}
         self.root_id = span_id_from("query")
         self._root_t0 = _now_us()
+        #: the same instant on ``time.perf_counter()``: a reader in this
+        #: process places the trace among its own timings with no offset
+        self._root_perf_s = time.perf_counter()
+        self._root_dur = 0
+        #: counts kept on the root span (``tally``): where each scan
+        #: task's table came from
+        self._tallies: Dict[str, int] = {}
+        self._summary: Optional[dict] = None
         self._finished = False
         self.exported = False
         self.status = "ok"
@@ -148,19 +167,28 @@ class SpanRecorder:
             except (KeyError, TypeError, ValueError):
                 self.dropped += 1
 
+    def tally(self, key: str, n: int = 1) -> None:
+        with self._lock:
+            self._tallies[key] = self._tallies.get(key, 0) + n
+
     def finish(self, status: Optional[str] = None) -> None:
-        """Close the root span (idempotent). ``None`` keeps whatever
+        """Close the root span (idempotent) and keep the trace's summary
+        in the process's ring (:func:`finished`). ``None`` keeps whatever
         status was pre-set on the recorder (a failed query marks it
         ``error`` before the export path finishes the root)."""
         with self._lock:
             if self._finished:
                 return
             self._finished = True
+            tallies = dict(self._tallies)
         if status is not None:
             self.status = status
+        self._root_dur = max(_now_us() - self._root_t0, 0)
         self.add("query", self.root_id, None, self._root_t0,
-                 _now_us() - self._root_t0, lane="driver",
+                 self._root_dur, attrs=tallies or None, lane="driver",
                  status=self.status)
+        self._summary = self._summarize()
+        _finished_ring.append(self._summary)
 
     def spans(self) -> List[dict]:
         with self._lock:
@@ -180,14 +208,101 @@ class SpanRecorder:
             return {s["span_id"] for s in self._spans}
 
     def summary(self) -> dict:
+        """Counts of the buffer; for a finished trace also where its wall
+        went: ``phases`` (per span name), ``covered_us`` (the union of
+        the :data:`LEAF_SPANS`) and ``tables`` (the scan-task tally),
+        computed once, when the root closed."""
+        return self._summary or self._summarize()
+
+    def _summarize(self) -> dict:
         with self._lock:
-            n = len(self._spans)
+            spans = list(self._spans)
             offsets = dict(self.clock_offsets_us)
-        out = {"trace_id": self.trace_id, "spans": n,
+            done = self._finished
+            tallies = dict(self._tallies)
+        out = {"trace_id": self.trace_id, "spans": len(spans),
                "dropped": self.dropped}
         if offsets:
             out["clock_offsets_us"] = offsets
+        if done:
+            lo, hi = self._root_t0, self._root_t0 + self._root_dur
+            out["t0_unix_us"] = lo
+            out["t0_perf_s"] = self._root_perf_s
+            out["wall_us"] = self._root_dur
+            out["phases"] = _phases(spans, self.root_id)
+            out["covered_us"] = _union_us(
+                [(s["ts_us"], s["ts_us"] + s["dur_us"]) for s in spans
+                 if s["name"] in LEAF_SPANS], lo, hi)
+            out["tables"] = {k: tallies.get(k, 0) for k in TABLE_SOURCES}
         return out
+
+
+#: the spans that hold the work itself and do not nest in each other: a
+#: query's wall is covered by their union (``summary()["covered_us"]``)
+LEAF_SPANS = frozenset((
+    "plan:optimize", "plan:translate", "scan:load", "device:encode",
+    "device:put", "device:dispatch", "device:fetch", "device:decode",
+    "agg:host", "join:build", "join:probe", "sort:topn", "expr:eval",
+    "exchange:partition", "mem:size", "result:collect"))
+
+#: where a scan task's table came from, as the device tier's scan path
+#: tallies it (``SpanRecorder.tally`` / :func:`tally`)
+TABLE_SOURCES = ("from_cache", "encoded", "host")
+
+
+def _union_us(intervals, lo: Optional[int] = None,
+              hi: Optional[int] = None) -> int:
+    """Length of the union of ``(start, end)`` intervals, clipped to
+    ``[lo, hi]`` when given: spans of one name on several threads
+    overlap, and a wall must not count the overlap twice."""
+    total = 0
+    at = None
+    for s, e in sorted(intervals):
+        if lo is not None:
+            s = max(s, lo)
+        if hi is not None:
+            e = min(e, hi)
+        if at is None or s > at:
+            at = s
+        if e > at:
+            total += e - at
+            at = e
+    return total
+
+
+def _phases(spans: List[dict], root_id: str) -> Dict[str, dict]:
+    """Per span name: how many, the union of their intervals
+    (``wall_us``, never more than the query's wall), their plain sum
+    (``sum_us``), and the ``bytes`` and ``rows`` attributes summed."""
+    by_name: Dict[str, list] = {}
+    for s in spans:
+        if s["span_id"] != root_id:
+            by_name.setdefault(s["name"], []).append(s)
+    out = {}
+    for name, group in by_name.items():
+        attrs = [s.get("attrs") or {} for s in group]
+        out[name] = {
+            "count": len(group),
+            "wall_us": _union_us([(s["ts_us"], s["ts_us"] + s["dur_us"])
+                                  for s in group]),
+            "sum_us": sum(s["dur_us"] for s in group),
+            "bytes": sum(int(a.get("bytes") or 0) for a in attrs),
+            "rows": sum(int(a.get("rows", a.get("rows_in")) or 0)
+                        for a in attrs)}
+    return out
+
+
+#: summaries of the last finished traces, oldest first (dicts, not spans)
+_finished_ring: "collections.deque" = collections.deque(maxlen=256)
+
+
+def finished(limit: Optional[int] = None) -> List[dict]:
+    """``summary()`` of the most recent finished traces of this process,
+    oldest first (``limit`` keeps the newest)."""
+    out = list(_finished_ring)
+    if limit is None:
+        return out
+    return out[-limit:] if limit > 0 else []
 
 
 class SpanContext:
@@ -262,9 +377,55 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
+#: ``jax.profiler.TraceAnnotation`` once JAX is imported (never imported
+#: from here: a host-only process stays free of it); False if it cannot
+#: be had
+_annotation = None
+
+
+def _annotation_cls():
+    global _annotation
+    if _annotation is None and "jax" in sys.modules:
+        try:
+            from jax.profiler import TraceAnnotation as found
+        except Exception:
+            found = False
+        # daft-lint: allow(unguarded-global-mutation) -- benign
+        # last-wins memo of an import
+        _annotation = found
+    return _annotation or None
+
+
+def _annotate(name: str):
+    """The profiler's twin of a span, entered: ``daft:<name>`` on this
+    thread's host line of the profile being taken. The prefix keeps the
+    program's spans apart from those a harness writes into the same
+    profile under its own names."""
+    cls = _annotation_cls()
+    if cls is None:
+        return None
+    try:
+        ann = cls("daft:" + name)
+        ann.__enter__()
+        return ann
+    except Exception:
+        return None
+
+
+def _close_annotation(ann, attrs: Optional[dict], exc=(None, None, None)):
+    """Leave the twin, with the span's attributes as its metadata (one
+    call, at the end: some are known only then)."""
+    try:
+        if attrs:
+            ann.set_metadata(**attrs)
+        ann.__exit__(*exc)
+    except Exception:
+        pass
+
+
 class _LiveSpan:
     __slots__ = ("_ctx", "_name", "_key", "_attrs", "_lane", "_t0",
-                 "_id", "_prev")
+                 "_id", "_prev", "_ann")
 
     def __init__(self, ctx: SpanContext, name: str, key: Optional[str],
                  attrs: Optional[dict], lane: str):
@@ -282,15 +443,19 @@ class _LiveSpan:
     def __enter__(self):
         rec = self._ctx.recorder
         self._id = rec.unique_span_id(self._key)
+        self._ann = _annotate(self._name) if rec.bridge else None
         self._t0 = _now_us()
         self._prev = _set_current(SpanContext(rec, self._id))
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        dur = _now_us() - self._t0
         _set_current(self._prev)
+        if self._ann is not None:
+            _close_annotation(self._ann, self._attrs, (exc_type, exc, tb))
         self._ctx.recorder.add(
-            self._name, self._id, self._ctx.span_id, self._t0,
-            _now_us() - self._t0, attrs=self._attrs, lane=self._lane,
+            self._name, self._id, self._ctx.span_id, self._t0, dur,
+            attrs=self._attrs, lane=self._lane,
             status="error" if exc_type is not None else "ok")
         return False
 
@@ -315,9 +480,19 @@ def event(name: str, key: Optional[str] = None,
     if ctx is None:
         return
     rec = ctx.recorder
+    ann = _annotate(name) if rec.bridge else None
+    if ann is not None:
+        _close_annotation(ann, attrs)
     rec.add(name, rec.unique_span_id(key or name),
             parent_id or ctx.span_id, _now_us(), 0, attrs=attrs,
             lane=lane)
+
+
+def tally(key: str, n: int = 1) -> None:
+    """Count on the current trace's root span (no-op when untraced)."""
+    ctx = current()
+    if ctx is not None:
+        ctx.recorder.tally(key, n)
 
 
 # ------------------------------------------------------ trace registry
@@ -349,26 +524,44 @@ def unregister_recorder(trace_id: str) -> None:
         _recorders.pop(trace_id, None)
 
 
+def profile_requested() -> bool:
+    """A profile is a request for spans: a ``jax.profiler`` session is
+    live in this process (whoever started it), or ``DAFT_TPU_XPLANE_DIR``
+    will start one for this query (its capture starts after the trace
+    decision). One env read and, once JAX is imported, one C call."""
+    from .analysis import knobs
+    if knobs.env_str("DAFT_TPU_XPLANE_DIR"):
+        return True
+    cls = _annotation_cls()
+    try:
+        return cls is not None and bool(cls.is_enabled())
+    except Exception:
+        return False
+
+
 def maybe_start_trace(kind: str = "query") -> Optional[SpanContext]:
     """Start (and register) a trace for a new top-level query — or
-    return ``None`` when tracing is off, the query loses the sampling
-    draw, or the thread is already inside a trace (the query joins it).
-    The sampling decision hashes the deterministic per-process trace
-    key, never RNG."""
+    return ``None`` when nobody asked for one, the query loses the
+    sampling draw, or the thread is already inside a trace (the query
+    joins it). Asked for by ``DAFT_TPU_TRACE=1`` (sampled by
+    ``DAFT_TPU_TRACE_SAMPLE``; the draw hashes the deterministic
+    per-process trace key, never RNG) or by a profile being taken
+    (:func:`profile_requested`; never sampled away)."""
     if current() is not None:
         return None
-    if not trace_enabled():
+    profiled = profile_requested()
+    if not profiled and not trace_enabled():
         return None
     from .analysis import knobs
     seq = next(_trace_seq)
     trace_key = f"{kind}:{seq}"
-    rate = knobs.env_float("DAFT_TPU_TRACE_SAMPLE")
+    rate = 1.0 if profiled else knobs.env_float("DAFT_TPU_TRACE_SAMPLE")
     if rate < 1.0 and _hash01(trace_key) >= max(rate, 0.0):
         return None
     trace_id = hashlib.sha256(
         f"daft-trace\x1f{os.getpid()}\x1f{trace_key}".encode()
     ).hexdigest()[:32]
-    rec = SpanRecorder(trace_id)
+    rec = SpanRecorder(trace_id, bridge=profiled)
     register_recorder(rec)
     return SpanContext(rec, rec.root_id)
 
@@ -897,5 +1090,6 @@ def reset_for_tests() -> None:
     global _flight_written
     with _reg_lock:
         _recorders.clear()
+    _finished_ring.clear()
     with _flight_lock:
         _flight_written = 0

@@ -285,9 +285,9 @@ def test_pipeline_spans_on_distinct_lanes_with_slot_ids(monkeypatch, pq_dir):
     by_name = {}
     for s in spans:
         by_name.setdefault(s["name"], []).append(s)
-    for name, lane in (("device:upload", "dev:upload"),
-                       ("device:compute", "dev:compute"),
-                       ("device:download", "dev:download")):
+    for name, lane in (("device:submit", "dev:upload"),
+                       ("device:inflight", "dev:compute"),
+                       ("device:drain", "dev:download")):
         assert by_name.get(name), f"missing {name} spans"
         for s in by_name[name]:
             assert s["lane"] == lane

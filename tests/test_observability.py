@@ -44,15 +44,17 @@ def test_explain_analyze_renders(capsys):
 
 
 def test_chrome_trace_written(tmp_path, monkeypatch):
-    path = str(tmp_path / "trace.json")
-    monkeypatch.setenv("DAFT_TPU_CHROME_TRACE", path)
+    # the one chrome-trace writer: DAFT_TPU_TRACE=1 + DAFT_TPU_TRACE_DIR
+    monkeypatch.setenv("DAFT_TPU_TRACE", "1")
+    monkeypatch.setenv("DAFT_TPU_TRACE_DIR", str(tmp_path))
     df = daft.from_pydict({"x": list(range(100))}).where(col("x") % 2 == 0)
     df.collect()
+    (path,) = tmp_path.glob("trace_*.json")
     with open(path) as f:
         trace = json.load(f)
-    names = {e["name"] for e in trace["traceEvents"]}
-    assert "Filter" in names
-    for e in trace["traceEvents"]:
+    spans = [e for e in trace["traceEvents"] if e["ph"] == "X"]
+    assert "op:Filter" in {e["name"] for e in spans}
+    for e in spans:
         assert {"name", "ph", "ts", "dur", "pid", "tid"} <= set(e)
 
 

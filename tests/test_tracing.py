@@ -683,3 +683,247 @@ def test_abort_trace_is_idempotent_and_none_safe():
     assert rec.exported and rec.status == "error"
     with tracing._reg_lock:
         assert rec.trace_id not in tracing._recorders
+
+
+# ----------------------------------------- spans inside the query path
+#
+# One profiler session for the module: a small device-tier aggregate over
+# Parquet and a small host join run under it, and every case below reads
+# what they left behind (the autouse reset empties the ring between
+# tests, so the fixture keeps its own copy).
+
+_AGG_SPANS = ("plan:optimize", "plan:translate", "scan:load",
+              "device:encode", "device:put", "device:dispatch",
+              "device:fetch", "device:decode", "result:collect")
+_JOIN_SPANS = ("plan:optimize", "plan:translate", "join:build",
+               "join:probe", "agg:host", "sort:topn", "result:collect")
+_HARNESS_PREFIXES = ("pass:", "plan:", "execute:", "clear-cache")
+
+
+def _write_lineitem(root, files=4, n=800):
+    import numpy as np
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+    rng = np.random.default_rng(11)
+    for i in range(files):
+        pq.write_table(
+            pa.table({"flag": rng.integers(0, 4, n),
+                      "qty": rng.random(n) * 50,
+                      "price": rng.random(n) * 1000}),
+            str(root / f"part{i}.parquet"))
+    return str(root)
+
+
+def _scan_agg(root):
+    return (daft.read_parquet(f"{root}/*.parquet")
+            .groupby("flag")
+            .agg(col("qty").sum().alias("sum_qty"),
+                 col("qty").count().alias("cnt")))
+
+
+def _small_join():
+    left = daft.from_pydict({"k": [i % 50 for i in range(600)],
+                             "v": [float(i) for i in range(600)]})
+    right = daft.from_pydict({"k": list(range(50)),
+                              "g": [i % 5 for i in range(50)]})
+    return (left.join(right, on="k").groupby("g")
+            .agg(col("v").sum().alias("s")).sort(col("s"), desc=True)
+            .limit(3))
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    import jax
+    from jax.profiler import ProfileData
+    root = _write_lineitem(tmp_path_factory.mktemp("spans_pq"))
+    prof = str(tmp_path_factory.mktemp("spans_prof"))
+    tracing.reset_for_tests()
+    mp = pytest.MonkeyPatch()
+    mp.delenv("DAFT_TPU_TRACE", raising=False)
+    jax.profiler.start_trace(prof)
+    try:
+        mp.setenv("DAFT_TPU_DEVICE_FORCE", "1")
+        agg = _scan_agg(root).to_pydict()
+        mp.delenv("DAFT_TPU_DEVICE_FORCE")
+        join = _small_join().to_pydict()
+    finally:
+        jax.profiler.stop_trace()
+        mp.undo()
+    summaries = tracing.finished()
+    tracing.reset_for_tests()
+    (pb,) = glob.glob(os.path.join(prof, "**", "*.xplane.pb"),
+                      recursive=True)
+    host_events = [e.name for plane in ProfileData.from_file(pb).planes
+                   if plane.name == "/host:CPU"
+                   for line in plane.lines for e in line.events]
+    assert sorted(agg["cnt"]) and len(join["g"]) == 3
+    assert len(summaries) == 2
+    return {"agg": summaries[0], "join": summaries[1],
+            "host_events": host_events}
+
+
+@pytest.mark.parametrize(
+    "query,name",
+    [("agg", n) for n in _AGG_SPANS] + [("join", n) for n in _JOIN_SPANS])
+def test_profiled_query_leaves_its_leaf_spans(profiled, query, name):
+    s = profiled[query]
+    phase = s["phases"].get(name)
+    assert phase and phase["count"] >= 1, sorted(s["phases"])
+    assert 0 <= phase["wall_us"] <= s["wall_us"]
+    assert phase["sum_us"] >= phase["wall_us"]
+    # ... and the same span lies on a host line of the profile
+    assert f"daft:{name}" in set(profiled["host_events"])
+
+
+def test_profiled_summaries_place_and_cover_the_query(profiled):
+    now = time.perf_counter()
+    for s in (profiled["agg"], profiled["join"]):
+        assert 0 < s["t0_perf_s"] < now and s["t0_unix_us"] > 0
+        assert 0 < s["covered_us"] <= s["wall_us"]
+    assert profiled["agg"]["t0_perf_s"] < profiled["join"]["t0_perf_s"]
+    put = profiled["agg"]["phases"]["device:put"]
+    enc = profiled["agg"]["phases"]["device:encode"]
+    assert put["bytes"] == enc["bytes"] > 0 and enc["rows"] > 0
+    tables = profiled["agg"]["tables"]  # small files share a scan task
+    assert tables["encoded"] >= 1
+    assert tables["from_cache"] == tables["host"] == 0
+
+
+@pytest.mark.parametrize("prefix", _HARNESS_PREFIXES)
+def test_program_writes_no_event_under_a_harness_prefix(profiled, prefix):
+    # chipbench/xplane.py attributes device time to the latest-started
+    # host span whose name starts with one of these: they are the
+    # benchmark's, and this module wrote none into the profile
+    assert any(n.startswith("daft:") for n in profiled["host_events"])
+    assert not [n for n in profiled["host_events"] if n.startswith(prefix)]
+
+
+def test_no_profile_and_no_flag_leaves_nothing_behind(monkeypatch):
+    monkeypatch.delenv("DAFT_TPU_TRACE", raising=False)
+    monkeypatch.delenv("DAFT_TPU_XPLANE_DIR", raising=False)
+    assert not tracing.profile_requested()
+    assert _small_join().to_pydict()["g"]
+    assert tracing.finished() == []
+    assert tracing.span("scan:load") is tracing._NOOP
+
+
+def test_xplane_dir_is_a_request_for_spans(tmp_path, monkeypatch):
+    monkeypatch.delenv("DAFT_TPU_TRACE", raising=False)
+    monkeypatch.setenv("DAFT_TPU_XPLANE_DIR", str(tmp_path))
+    # sampled away only when DAFT_TPU_TRACE alone asked
+    monkeypatch.setenv("DAFT_TPU_TRACE_SAMPLE", "0.0")
+    assert tracing.profile_requested()
+    _small_join().to_pydict()
+    (s,) = tracing.finished()
+    assert "join:probe" in s["phases"]
+    assert glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                     recursive=True)
+
+
+@pytest.mark.parametrize("threads", [1, 4])
+def test_phase_wall_is_a_union_over_threads(threads):
+    rec = tracing.SpanRecorder("t" * 32)
+    ctx = tracing.SpanContext(rec, rec.root_id)
+    go = threading.Barrier(threads)
+
+    def work(i):
+        with tracing.attach(ctx):
+            go.wait()
+            with tracing.span("scan:load", key=f"w{i}",
+                              attrs={"rows": 10, "bytes": 100}):
+                time.sleep(0.05)
+
+    ts = [threading.Thread(target=work, args=(i,)) for i in range(threads)]
+    for t in ts:
+        t.start()
+    for t in ts:
+        t.join()
+    rec.finish()
+    (s,) = tracing.finished()
+    p = s["phases"]["scan:load"]
+    assert p["count"] == threads and p["rows"] == 10 * threads
+    assert p["bytes"] == 100 * threads
+    assert p["wall_us"] <= s["wall_us"] and s["covered_us"] <= s["wall_us"]
+    assert p["sum_us"] >= p["wall_us"] >= 45_000
+    if threads > 1:  # overlapping intervals count once in the wall
+        assert p["sum_us"] >= threads * 45_000
+        assert p["wall_us"] < p["sum_us"]
+    assert s["covered_us"] == p["wall_us"]
+
+
+def test_finished_ring_is_bounded_and_newest_last():
+    for i in range(260):
+        tracing.SpanRecorder(f"{i:032d}").finish()
+    ring = tracing.finished()
+    assert len(ring) == 256
+    assert ring[-1]["trace_id"] == f"{259:032d}"
+    assert [s["trace_id"] for s in tracing.finished(2)] == \
+        [f"{258:032d}", f"{259:032d}"]
+    tracing.reset_for_tests()
+    assert tracing.finished() == []
+
+
+def test_operator_spans_are_real_intervals(monkeypatch):
+    monkeypatch.setenv("DAFT_TPU_TRACE", "1")
+    daft.from_pydict({"x": list(range(2000))}).where(
+        col("x") % 2 == 0).collect()
+    rec = obs.last_query_stats().trace_ctx.recorder
+    spans = rec.spans()
+    root = next(s for s in spans if s["span_id"] == rec.root_id)
+    op = next(s for s in spans if s["name"] == "op:Filter")
+    assert root["ts_us"] <= op["ts_us"]
+    assert op["ts_us"] + op["dur_us"] <= root["ts_us"] + root["dur_us"]
+    assert 0 < op["attrs"]["busy_us"] and op["attrs"]["rows_out"] == 1000
+    assert op["attrs"]["self_us"] <= op["attrs"]["busy_us"]
+
+
+@pytest.fixture(scope="module")
+def cold_then_warm(tmp_path_factory):
+    from daft_tpu.device import cache as dcache, costmodel
+    root = _write_lineitem(tmp_path_factory.mktemp("spans_cache_pq"))
+    mp = pytest.MonkeyPatch()
+    mp.setenv("DAFT_TPU_TRACE", "1")
+    mp.setenv("DAFT_TPU_DEVICE_FORCE", "1")
+    tracing.reset_for_tests()
+    dcache.get_cache().clear()
+    out = {}
+    try:
+        for phase in ("cold", "warm"):
+            c0 = dcache.get_cache().stats()
+            t0 = dict(costmodel.scan_table_counts)
+            _scan_agg(root).to_pydict()
+            c1 = dcache.get_cache().stats()
+            out[phase] = {
+                "cache": {k: c1[k] - c0[k] for k in c1},
+                "process": {k: v - t0[k] for k, v in
+                            costmodel.scan_table_counts.items()},
+                "summary": tracing.finished()[-1]}
+    finally:
+        mp.undo()
+        dcache.get_cache().clear()
+        tracing.reset_for_tests()
+    return out
+
+
+@pytest.mark.parametrize("phase,source", [("cold", "encoded"),
+                                          ("warm", "from_cache")])
+def test_cache_counts_and_table_tally(cold_then_warm, phase, source):
+    got = cold_then_warm[phase]
+    tables = got["summary"]["tables"]
+    n = tables[source]  # scan tasks (small files share one)
+    assert n >= 1 and sum(tables.values()) == n
+    assert got["process"] == tables
+    assert n == cold_then_warm["cold"]["summary"]["tables"]["encoded"]
+    put = got["summary"]["phases"].get("device:put", {}).get("bytes", 0)
+    if phase == "cold":
+        assert (got["cache"]["hits"], got["cache"]["misses"]) == (0, n)
+        # what the cache was given is what it now holds; the spans also
+        # see the few bytes that were put and not cached (the partials'
+        # merge runs on the device too when forced)
+        assert got["cache"]["put_bytes"] == got["cache"]["bytes"] > 0
+        assert got["cache"]["put_bytes"] <= put < 2 * got["cache"]["bytes"]
+    else:
+        assert (got["cache"]["hits"], got["cache"]["misses"]) == (n, 0)
+        assert got["cache"]["put_bytes"] == 0 == got["cache"]["bytes"]
+        assert put < cold_then_warm["cold"]["cache"]["put_bytes"] / 4
+    assert got["cache"]["evicted_bytes"] == 0
