@@ -1577,11 +1577,17 @@ class LocalExecutor:
             parts.close()
 
     def _hash_exchange_streaming(self, node, n: int):
-        from . import memory
+        from . import memory, out_of_core as ooc
         from ..device import runtime as drt
         from ..parallel import mesh as pmesh
         by = list(node.by)
         child = self._exec(node.children[0])
+
+        # small morsels are partitioned together (coalescing only: the
+        # bucket index is a contract here, so every row is still hashed)
+        def fan(unit, i):
+            mp, morsels = unit
+            return mp.partition_by_hash(by, n, morsels)
         if drt.device_enabled() and pmesh.mesh_size() >= 2 \
                 and n == pmesh.mesh_size():
             # the ICI collective repartition wants a partition list; fall
@@ -1594,14 +1600,14 @@ class LocalExecutor:
                     yield from mesh_out
                     return
                 yield from self._fan_exchange_streaming(
-                    node, n, lambda mp, i: mp.partition_by_hash(by, n),
-                    stream=iter(parts))
+                    node, n, fan, stream=ooc.coalesce_small(
+                        iter(parts), self._poll_cancel))
             finally:
                 parts.close()
             return
         yield from self._fan_exchange_streaming(
-            node, n, lambda mp, i: mp.partition_by_hash(by, n),
-            stream=child)
+            node, n, fan,
+            stream=ooc.coalesce_small(child, self._poll_cancel))
 
     def _fan_exchange_streaming(self, node, n: int, fan, stream=None):
         """Shared streaming fanout: morsel → n pieces → bucket store; AQE
@@ -1868,12 +1874,13 @@ class LocalExecutor:
         """Drain a stream into an n-bucket store hashed on ``by``. The
         store closes itself when the drain fails; the caller owns it
         once it is returned whole."""
-        from . import memory
+        from . import memory, out_of_core as ooc
         store = memory.PartitionedSpillStore(n)
         try:
-            for mp in stream:
-                self._poll_cancel()
-                for j, piece in enumerate(mp.partition_by_hash(by, n)):
+            for mp, morsels in ooc.coalesce_small(stream,
+                                                  self._poll_cancel):
+                for j, piece in enumerate(
+                        mp.partition_by_hash(by, n, morsels)):
                     if len(piece):
                         store.push(j, piece.combined())
             store.finalize()

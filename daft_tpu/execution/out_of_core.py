@@ -36,7 +36,7 @@ stay bucket-decomposable; group-by NULL keys co-locate the same way.
 from __future__ import annotations
 
 import math
-from typing import Iterator, List, Optional
+from typing import Iterator, List, Optional, Tuple
 
 import numpy as np
 
@@ -134,8 +134,8 @@ def plan_partitions(est_bytes: Optional[float], cfg=None,
 
 # ---------------------------------------------------------- rotated radix
 
-def radix_split(rb: RecordBatch, by, n: int, depth: int
-                ) -> List[RecordBatch]:
+def radix_split(rb: RecordBatch, by, n: int, depth: int,
+                morsels: int = 1) -> List[RecordBatch]:
     """Hash-partition ``rb`` into ``n`` pieces on the ``by`` key chain.
     Depth 0 is bit-identical to ``RecordBatch.partition_by_hash`` (the
     xxh-style chain every exchange/co-partition path uses); depth d > 0
@@ -146,7 +146,8 @@ def radix_split(rb: RecordBatch, by, n: int, depth: int
         return [rb.slice(0, 0) for _ in range(n)]
     from .. import tracing
     with tracing.span("exchange:partition", lane="pipeline",
-                      attrs={"rows": len(rb), "parts": n}):
+                      attrs={"rows": len(rb), "parts": n,
+                             "morsels": morsels}):
         keys = [rb.eval_expression(e) for e in by]
         h = keys[0].hash()
         for k in keys[1:]:
@@ -155,6 +156,53 @@ def radix_split(rb: RecordBatch, by, n: int, depth: int
             h = h.hash()
         pid = (h.to_numpy() % np.uint64(n)).astype(np.int64)
         return rb._split_by_pid(pid, n)
+
+
+#: a hash fan-out pays a fixed cost a call (one take and ``n`` slices a
+#: column, the key hashes, the hand-offs behind it), so morsels under
+#: this many rows are partitioned together: :func:`coalesce_small`
+FANOUT_COALESCE_ROWS = 16384
+
+
+def coalesce_small(stream: Iterator[MicroPartition], poll=None
+                   ) -> Iterator[Tuple[MicroPartition, int]]:
+    """Fan-out units ``(morsel, input morsels folded into it)`` of a
+    stream about to be hash-partitioned. A morsel of
+    :data:`FANOUT_COALESCE_ROWS` rows or more passes through as it is, at
+    once; smaller ones wait, in arrival order, and leave as one
+    concatenation when they reach the threshold together, when a large
+    morsel arrives behind them (they leave first: every bucket keeps
+    arrival order) or when the stream ends. Empty morsels are dropped.
+    ``poll`` (the caller's cancellation poll) runs once an input morsel,
+    buffered or not: a drain of many small morsels stays cancellable.
+    Hash fan-outs only: rows land in the buckets and the order they
+    would have without it, which a fan-out that seeds by morsel index
+    (the random exchange) cannot say."""
+    buf: List[MicroPartition] = []
+    rows = 0
+
+    def flush():
+        nonlocal buf, rows
+        unit = (buf[0].concat(buf[1:]) if len(buf) > 1 else buf[0],
+                len(buf))
+        buf, rows = [], 0
+        return unit
+
+    for mp in stream:
+        if poll is not None:
+            poll()
+        n = len(mp)
+        if n >= FANOUT_COALESCE_ROWS:
+            if buf:
+                yield flush()
+            yield mp, 1
+        elif n:
+            buf.append(mp)
+            rows += n
+            if rows >= FANOUT_COALESCE_ROWS:
+                yield flush()
+    if buf:
+        yield flush()
 
 
 def drain_to_store(stream: Iterator[MicroPartition], by, n: int,
@@ -167,11 +215,9 @@ def drain_to_store(stream: Iterator[MicroPartition], by, n: int,
     closes itself if the drain fails; callers own it once returned."""
     store = memory.PartitionedSpillStore(n, budget=budget)
     try:
-        for mp in stream:
-            if poll is not None:
-                poll()
+        for mp, morsels in coalesce_small(stream, poll):
             for j, piece in enumerate(radix_split(mp.combined(), by, n,
-                                                  depth)):
+                                                  depth, morsels)):
                 if len(piece):
                     store.push(j, piece)
         store.finalize()

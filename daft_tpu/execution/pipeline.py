@@ -433,8 +433,10 @@ class PushExecutor(LocalExecutor):
                                est_state=None) -> Channel:
         """Partitioned-by-hash dispatcher fused with the final grouped
         aggregation (reference ``dispatcher.rs:24-60`` Partitioned +
-        ``sinks/grouped_aggregate.rs:54-151``): the dispatcher hashes each
-        incoming partial-agg morsel into k slices, worker i streams
+        ``sinks/grouped_aggregate.rs:54-151``): the dispatcher hashes the
+        incoming partial-agg morsels into k slices (small ones together:
+        ``out_of_core.coalesce_small``; an input that fits that buffer
+        whole goes unhashed to reducer 0), worker i streams
         partition i, incrementally merging its state every
         ``_REAGG_ROWS`` buffered rows, and emits its final state at
         close. Replaces Exchange(hash) + per-bucket map agg: no
@@ -453,6 +455,8 @@ class PushExecutor(LocalExecutor):
         the ``AGG_DECOMPOSITION`` merge expressions — an unbounded-NDV
         group-by streams in one pass at peak RSS ≈ budget + one bucket,
         recursing (bounded) on a bucket skew redominates."""
+        from .. import tracing
+        from . import out_of_core as ooc
         k = _default_workers()
         if self.stats is not None:
             self.stats.register(node).workers = k
@@ -465,12 +469,41 @@ class PushExecutor(LocalExecutor):
         out = Channel(self.pipe, self.CHANNEL_CAPACITY, producers=k)
         name = type(node).__name__
 
+        def fan(mp, morsels):
+            for i, part in enumerate(mp.partition_by_hash(by, k, morsels)):
+                if len(part):
+                    in_q[i].put(part)
+
         def dispatch():
             try:
-                for mp in child:
-                    for i, part in enumerate(mp.partition_by_hash(by, k)):
-                        if len(part):
-                            in_q[i].put(part)
+                units = ooc.coalesce_small(child)
+                head = next(units, None)
+                if head is None:
+                    return
+                mp, morsels = head
+                if len(mp) < ooc.FANOUT_COALESCE_ROWS:
+                    # a small head left the buffer because the stream
+                    # ended or a large morsel is already behind it: the
+                    # look-ahead waits for nothing
+                    behind = next(units, None)
+                    if behind is None:
+                        # the whole input fitted the buffer. Reducers
+                        # need only be key-disjoint, which one is: hash
+                        # nothing, and the other k - 1 see a closed
+                        # channel, as an empty partition does
+                        with tracing.span("exchange:gather",
+                                          lane="pipeline",
+                                          attrs={"rows": len(mp),
+                                                 "morsels": morsels}):
+                            in_q[0].put(MicroPartition.from_recordbatch(
+                                mp.combined()))
+                        return
+                    fan(mp, morsels)
+                    mp, morsels = behind
+                # once a morsel went out by hash, all that follow do too
+                fan(mp, morsels)
+                for mp, morsels in units:
+                    fan(mp, morsels)
             except PipelineCancelled:
                 pass
             except BaseException as exc:  # noqa: BLE001
@@ -520,7 +553,7 @@ class PushExecutor(LocalExecutor):
 
         def spill_reducer(i):
             from ..expressions import col as _col
-            from . import memory, out_of_core as ooc, spill_io
+            from . import memory, spill_io
             skeys = [_col(g.name()) for g in node.group_by]
             m = ooc.agg_state_fanout(est_state, k, self.cfg)
             depth_max = ooc.spill_max_depth(self.cfg)

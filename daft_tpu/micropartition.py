@@ -181,9 +181,11 @@ class MicroPartition:
             batches.extend(o.batches())
         return MicroPartition.from_recordbatches(batches, self._schema)
 
-    def partition_by_hash(self, exprs, num_partitions) -> List["MicroPartition"]:
+    def partition_by_hash(self, exprs, num_partitions,
+                          morsels: int = 1) -> List["MicroPartition"]:
         return [MicroPartition.from_recordbatch(b)
-                for b in self.combined().partition_by_hash(exprs, num_partitions)]
+                for b in self.combined().partition_by_hash(
+                    exprs, num_partitions, morsels)]
 
     def partition_by_random(self, num_partitions, seed) -> List["MicroPartition"]:
         return [MicroPartition.from_recordbatch(b)

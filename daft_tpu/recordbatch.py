@@ -342,14 +342,18 @@ class RecordBatch:
 
     # ---- partitioning ----------------------------------------------------
     def partition_by_hash(self, exprs: Sequence[Expression],
-                          num_partitions: int) -> List["RecordBatch"]:
-        """Reference: ``ops/partition.rs:53-104``."""
+                          num_partitions: int,
+                          morsels: int = 1) -> List["RecordBatch"]:
+        """Reference: ``ops/partition.rs:53-104``. ``morsels`` says how
+        many input morsels the caller folded into this batch
+        (``out_of_core.coalesce_small``); it is recorded, not used."""
         if self._len == 0:
             return [self.slice(0, 0) for _ in range(num_partitions)]
         from . import tracing
         with tracing.span("exchange:partition", lane="pipeline",
                           attrs={"rows": self._len,
-                                 "parts": num_partitions}):
+                                 "parts": num_partitions,
+                                 "morsels": morsels}):
             keys = [self.eval_expression(e) for e in exprs]
             h = keys[0].hash()
             for k in keys[1:]:

@@ -243,7 +243,8 @@ LEAF_SPANS = frozenset((
     "plan:optimize", "plan:translate", "scan:load", "device:encode",
     "device:put", "device:dispatch", "device:fetch", "device:decode",
     "agg:host", "join:build", "join:probe", "sort:topn", "expr:eval",
-    "exchange:partition", "mem:size", "result:collect"))
+    "exchange:partition", "exchange:gather", "mem:size",
+    "result:collect"))
 
 #: where a scan task's table came from, as the device tier's scan path
 #: tallies it (``SpanRecorder.tally`` / :func:`tally`)

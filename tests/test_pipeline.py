@@ -250,6 +250,192 @@ def test_partitioned_agg_declines_on_huge_footer_ndv(many_files, monkeypatch):
     assert out_small["s"] == pytest.approx([expected[g] for g in range(7)])
 
 
+# ------------------------------------- small morsels ahead of a hash fan-out
+
+def _traced_spans(monkeypatch, build):
+    """Run ``build()`` traced; its answer and the spans it left."""
+    from daft_tpu import observability as obs
+    monkeypatch.setenv("DAFT_TPU_TRACE", "1")
+    out = build().to_pydict()
+    spans = obs.last_query_stats().trace_ctx.recorder.spans()
+    monkeypatch.delenv("DAFT_TPU_TRACE")
+    return out, spans
+
+
+def _named(spans, name):
+    return [s["attrs"] for s in spans if s["name"] == name]
+
+
+def _interp(build):
+    with dt.execution_config_ctx(local_executor="interp"):
+        return build().to_pydict()
+
+
+def _coalesce_property(seed):
+    """Per bucket the same rows in the same order as the per-morsel
+    fan-out, every row once, large morsels untouched."""
+    import numpy as np
+    from daft_tpu.execution import out_of_core as ooc
+    from daft_tpu.micropartition import MicroPartition
+    rng = np.random.default_rng(seed)
+    t = ooc.FANOUT_COALESCE_ROWS
+    sizes = rng.choice([0, 1, 4, t // 3, t - 1, t, t + 1, 3 * t],
+                       size=int(rng.integers(1, 14)))
+    morsels, at = [], 0
+    for n in map(int, sizes):
+        ids = np.arange(at, at + n)
+        morsels.append(MicroPartition.from_pydict(
+            {"id": ids, "k": ids * 7919 % 101, "s": [f"s{i % 13}" for i in ids]}))
+        at += n
+    by, parts = [col("k"), col("s")], 5
+
+    def buckets(pieces_of_each_unit):
+        out = [[] for _ in range(parts)]
+        for pieces in pieces_of_each_unit:
+            for j, piece in enumerate(pieces):
+                out[j].extend(piece.to_pydict()["id"])
+        return out
+
+    units = list(ooc.coalesce_small(iter(morsels)))
+    assert buckets(mp.partition_by_hash(by, parts) for mp, _ in units) \
+        == buckets(mp.partition_by_hash(by, parts) for mp in morsels)
+    assert sum(len(mp) for mp, _ in units) == at
+    assert sum(m for _, m in units) == sum(1 for n in sizes if n)
+    large = [mp for mp in morsels if len(mp) >= t]
+    passed = [mp for mp, m in units if m == 1 and len(mp) >= t]
+    assert len(large) == len(passed) \
+        and all(a is b for a, b in zip(large, passed))
+    # a unit under the threshold is the last, or has a large one behind it
+    for (mp, _), (after, m) in zip(units, units[1:]):
+        assert len(mp) >= t or (m == 1 and len(after) >= t)
+
+
+def _small_input_gathers(many_files, monkeypatch):
+    """16 files, 7 groups, host tier: the 16 partial morsels reach one
+    reducer unhashed."""
+    monkeypatch.setenv("DAFT_TPU_DEVICE", "0")
+    glob, n = many_files
+
+    def build():
+        return (dt.read_parquet(glob).groupby("g")
+                .agg(col("v").sum().alias("sv"), col("id").count().alias("c"))
+                .sort("g"))
+    out, spans = _traced_spans(monkeypatch, build)
+    assert _named(spans, "exchange:partition") == []
+    (gather,) = _named(spans, "exchange:gather")
+    assert gather["morsels"] > 1 and gather["rows"] == 7 * gather["morsels"]
+    assert out == _interp(build) and sum(out["c"]) == n
+
+
+def _crossing_threshold_hashes(tmp_path, monkeypatch):
+    """small, small, large, small: once one unit went out by hash the
+    rest do, and no group comes out of two reducers."""
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+    from daft_tpu.execution import out_of_core as ooc
+    monkeypatch.setenv("DAFT_TPU_DEVICE", "0")
+    monkeypatch.setattr(ooc, "FANOUT_COALESCE_ROWS", 50)
+    tmp_path.mkdir()
+    for i, groups in enumerate((3, 3, 200, 3)):
+        pq.write_table(pa.table({"g": [j % groups for j in range(400)],
+                                 "v": [float(j) for j in range(400)]}),
+                       tmp_path / f"part-{i}.parquet")
+
+    def build():
+        return (dt.read_parquet(str(tmp_path / "*.parquet")).groupby("g")
+                .agg(col("v").sum().alias("sv"), col("v").count().alias("c"))
+                .sort("g"))
+    out, spans = _traced_spans(monkeypatch, build)
+    assert _named(spans, "exchange:gather") == []
+    fans = _named(spans, "exchange:partition")
+    assert [(f["morsels"], f["rows"]) for f in fans] \
+        == [(2, 6), (1, 200), (1, 3)]
+    assert out["g"] == list(range(200)) and sum(out["c"]) == 1600
+    assert out == _interp(build)
+
+
+def _copartitioned_join_sanitized(many_files, monkeypatch):
+    """One side of a co-partitioned join arrives in 500-row morsels and
+    is partitioned in one call; the bucket index still pairs the sides
+    (the sanitizer re-hashes every bucket it sees)."""
+    from daft_tpu.analysis import plan_sanitizer as ps
+    glob, n = many_files
+    right = dt.from_pydict({"rk": list(range(0, n, 2)),
+                            "w": list(range(n // 2))})
+
+    def build():
+        return (dt.read_parquet(glob).join(right.into_partitions(4),
+                                           left_on="id", right_on="rk")
+                .sort("id"))
+    was_enabled = ps.is_enabled()
+    ps.enable()
+    try:
+        before = ps.counters_snapshot()
+        with dt.execution_config_ctx(broadcast_join_size_bytes_threshold=1):
+            out, spans = _traced_spans(monkeypatch, build)
+            interp = _interp(build)
+        delta = ps.counters_delta(before, ps.counters_snapshot())
+    finally:
+        if not was_enabled:
+            ps.disable()
+    assert delta["membership_parts"] > 0 and delta["violations"] == 0
+    fans = _named(spans, "exchange:partition")
+    assert {"rows": n, "morsels": 16} in \
+        [{k: f[k] for k in ("rows", "morsels")} for f in fans]
+    assert out == interp and out["id"] == list(range(0, n, 2))
+
+
+def _spill_reducer_sums(many_files, monkeypatch, threshold):
+    """The spill-partitioned reducer behind the hand-over (the default
+    threshold: 8 000 partial rows fit the buffer) and behind coalesced
+    hash fan-outs (a threshold of 1 024 rows)."""
+    from daft_tpu.execution import out_of_core as ooc, pipeline
+    monkeypatch.setenv("DAFT_TPU_DEVICE", "0")
+    monkeypatch.setattr(pipeline, "_REAGG_ROWS", 256)
+    monkeypatch.setattr(pipeline, "_FUSE_MAX_GROUPS", 100)
+    if threshold:
+        monkeypatch.setattr(ooc, "FANOUT_COALESCE_ROWS", threshold)
+    glob, n = many_files
+
+    def build():
+        return (dt.read_parquet(glob).groupby("id")
+                .agg(col("v").sum().alias("s")).sort("id"))
+    out, spans = _traced_spans(monkeypatch, build)
+    assert out["id"] == list(range(n))
+    assert out["s"] == [float(i % 500) for i in range(n)]
+    # (the reducers' own radix splits are ``exchange:partition`` too)
+    gathers = _named(spans, "exchange:gather")
+    if threshold:
+        assert gathers == []
+        assert any(f["morsels"] > 1 and f["rows"] >= threshold
+                   for f in _named(spans, "exchange:partition"))
+    else:
+        assert gathers == [{"rows": n, "morsels": 16}]
+
+
+@pytest.mark.parametrize("case", [
+    "property_seed0", "property_seed1", "property_seed2", "property_seed3",
+    "property_seed4", "small_input_gathers", "crossing_threshold_hashes",
+    "copartitioned_join_sanitized", "spill_reducer_behind_gather",
+    "spill_reducer_behind_hash",
+])
+def test_small_morsels_are_fanned_out_together(case, many_files, tmp_path,
+                                               monkeypatch):
+    """``out_of_core.coalesce_small`` at the hash fan-outs: the fused
+    dispatcher, the Exchange operator and the spill reducer behind it."""
+    if case.startswith("property_seed"):
+        _coalesce_property(int(case[-1]))
+    elif case == "small_input_gathers":
+        _small_input_gathers(many_files, monkeypatch)
+    elif case == "crossing_threshold_hashes":
+        _crossing_threshold_hashes(tmp_path / "cross", monkeypatch)
+    elif case == "copartitioned_join_sanitized":
+        _copartitioned_join_sanitized(many_files, monkeypatch)
+    else:
+        _spill_reducer_sums(many_files, monkeypatch,
+                            1024 if case.endswith("hash") else None)
+
+
 # --------------------------------------------------- interp executor tier
 
 @pytest.fixture(scope="module")

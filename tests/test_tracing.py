@@ -851,6 +851,43 @@ def test_phase_wall_is_a_union_over_threads(threads):
     assert s["covered_us"] == p["wall_us"]
 
 
+@pytest.mark.parametrize("name", ["exchange:partition", "exchange:gather"])
+def test_exchange_spans_are_leaves_and_count_as_covered(name):
+    assert name in tracing.LEAF_SPANS
+    rec = tracing.SpanRecorder("e" * 32)
+    with tracing.attach(tracing.SpanContext(rec, rec.root_id)):
+        with tracing.span(name, attrs={"rows": 4, "morsels": 2}):
+            time.sleep(0.01)
+    rec.finish()
+    (s,) = tracing.finished()
+    p = s["phases"][name]
+    assert p["count"] == 1 and p["rows"] == 4
+    assert s["covered_us"] == p["wall_us"] >= 9_000
+
+
+def test_fanouts_say_how_many_morsels_they_fold(monkeypatch):
+    monkeypatch.setenv("DAFT_TPU_TRACE", "1")
+    monkeypatch.setenv("DAFT_TPU_DEVICE", "0")
+    left = daft.from_pydict({"k": [i % 50 for i in range(600)],
+                             "v": [float(i) for i in range(600)]})
+    right = daft.from_pydict({"k": list(range(50)),
+                              "g": [i % 5 for i in range(50)]})
+    with daft.execution_config_ctx(broadcast_join_size_bytes_threshold=1):
+        out = (left.into_partitions(3).join(right.into_partitions(2), on="k")
+               .groupby("g").agg(col("v").sum().alias("s")).to_pydict())
+    assert sorted(out["g"]) == [0, 1, 2, 3, 4]
+    spans = obs.last_query_stats().trace_ctx.recorder.spans()
+    fans = [s["attrs"] for s in spans if s["name"] == "exchange:partition"]
+    # each join side's morsels are under the threshold: one call a side
+    assert sorted((f["rows"], f["morsels"]) for f in fans) \
+        == [(50, 2), (600, 3)]
+    # ... and the final aggregate's few partial rows are hashed by no one
+    (gather,) = [s["attrs"] for s in spans if s["name"] == "exchange:gather"]
+    assert gather["morsels"] >= 1 and gather["rows"] >= 5
+    (s,) = tracing.finished()
+    assert s["phases"]["exchange:gather"]["count"] == 1
+
+
 def test_finished_ring_is_bounded_and_newest_last():
     for i in range(260):
         tracing.SpanRecorder(f"{i:032d}").finish()
