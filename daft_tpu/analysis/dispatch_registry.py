@@ -90,12 +90,12 @@ SITES: Tuple[DispatchSite, ...] = (
     _s("fragment.packed", "daft_tpu/device/fragment.py",
        ("get_fused_agg",),
        "(program, capacity class, out_cap bucket, strategy, donate, "
-       "scalar-plane shapes)",
+       "scalar-plane shapes, the table's chip)",
        "one trace per (schema, size-class, strategy), not per row count"),
     _s("fragment.donate", "daft_tpu/device/fragment.py",
        ("donate_fn",),
        "(program, capacity class, out_cap bucket, strategy, "
-       "scalar-plane shapes)",
+       "scalar-plane shapes, the table's chip)",
        "donating twin of fragment.packed; same signature contract"),
     _s("region.chain", "daft_tpu/device/fragment.py",
        ("get_fused_region",),

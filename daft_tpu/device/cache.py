@@ -10,9 +10,14 @@ partitions host-side, we cache *encoded scan columns* device-side, keyed by
 scan-task fingerprint.
 
 Granularity is (task, column): different queries touching different column
-subsets of the same file share entries. Entries are LRU-evicted to a byte
-budget (``DAFT_TPU_HBM_CACHE_BYTES``, default 8 GiB — leaves headroom on a
-16 GiB v5e chip for kernel workspace).
+subsets of the same file share entries. The cache spans every chip the scan
+path places tables on (``parallel.mesh.scan_devices()``), and everything it
+counts is **per chip**: a task's columns, validity and row mask live whole
+on one chip (a table is never split), each chip has its own LRU and its own
+byte budget (``DAFT_TPU_HBM_CACHE_BYTES``, default 8 GiB of a v5e chip's
+16 GiB — headroom for kernel workspace), and filling one chip evicts only
+that chip's entries. A task's chip is remembered with its entries, so a
+later scan of the same file finds it, and runs, where the first put it.
 
 Invalidation: the fingerprint covers file paths, sizes, mtimes, row-group
 selection and row-affecting pushdowns, so a changed file re-encodes.
@@ -33,10 +38,11 @@ from . import column as dcol
 
 
 def _budget() -> int:
-    # 8 GiB of a 16 GiB v5e: encoded columns are compact (f64 rides f32,
-    # strings ride i32 codes), and the grouped-agg workspace peaks well
-    # under the remaining half. 4 GiB (r4) turned away SF10's ~3.4 GiB
-    # hot-column set that residency would have repaid.
+    # one chip's budget, 8 GiB of a 16 GiB v5e: encoded columns are
+    # compact (f64 rides f32, strings ride i32 codes), and the
+    # grouped-agg workspace peaks well under the remaining half. 4 GiB
+    # (r4) turned away SF10's ~3.4 GiB hot-column set that residency
+    # would have repaid.
     from ..analysis import knobs
     return knobs.env_bytes("DAFT_TPU_HBM_CACHE_BYTES")
 
@@ -71,51 +77,95 @@ class _Entry:
         self.nbytes = nbytes
 
 
+class _Chip:
+    """One chip's share of the cache: its own LRU order, bytes and
+    evictions."""
+    __slots__ = ("cols", "masks", "bytes", "evicted_bytes")
+
+    def __init__(self):
+        self.cols: "OrderedDict[Tuple, _Entry]" = OrderedDict()
+        # fp -> (row mask, rows, capacity, DeviceTable.chip)
+        self.masks: "OrderedDict[Tuple, Tuple]" = OrderedDict()
+        self.bytes = 0
+        self.evicted_bytes = 0
+
+    def drop(self, fp: Tuple) -> None:
+        self.masks.pop(fp, None)
+        for key in [k for k in self.cols if k[0] == fp]:
+            self.bytes -= self.cols.pop(key).nbytes
+
+
 class DeviceColumnCache:
     def __init__(self):
         self._lock = threading.Lock()
-        self._cols: "OrderedDict[Tuple, _Entry]" = OrderedDict()
-        self._masks: "OrderedDict[Tuple, Tuple]" = OrderedDict()  # fp -> (mask, rows, cap)
-        self._bytes = 0
+        #: chip index -> its share; a table with ``chip`` None (one
+        #: visible chip) is kept under 0
+        self._chips: Dict[int, _Chip] = {}
         # since the process started (``clear()`` empties the cache, not
-        # these): whole-table lookups served / not, bytes put, bytes
-        # pushed out by the budget
+        # these): whole-table lookups served / not, bytes put
         self._hits = self._misses = 0
-        self._put_bytes = self._evicted_bytes = 0
+        self._put_bytes = 0
 
     # ------------------------------------------------------------------
-    def stats(self) -> Dict[str, int]:
+    def stats(self) -> Dict:
+        """``entries``, ``bytes`` and ``evicted_bytes`` summed over the
+        chips, and per chip under ``chips`` (chip index -> the same three;
+        only chips that ever held a table)."""
         with self._lock:
-            return {"entries": len(self._cols), "bytes": self._bytes,
-                    "hits": self._hits, "misses": self._misses,
-                    "put_bytes": self._put_bytes,
-                    "evicted_bytes": self._evicted_bytes}
+            chips = {k: {"entries": len(c.cols), "bytes": c.bytes,
+                         "evicted_bytes": c.evicted_bytes}
+                     for k, c in sorted(self._chips.items())}
+            out = {key: sum(c[key] for c in chips.values())
+                   for key in ("entries", "bytes", "evicted_bytes")}
+            out.update(hits=self._hits, misses=self._misses,
+                       put_bytes=self._put_bytes, chips=chips)
+            return out
 
     def clear(self) -> None:
+        """Empty every chip."""
         with self._lock:
-            self._cols.clear()
-            self._masks.clear()
-            self._bytes = 0
+            for c in self._chips.values():
+                c.cols.clear()
+                c.masks.clear()
+                c.bytes = 0
+
+    def _find_locked(self, fp: Tuple):
+        """(the chip's share that holds this task, its mask entry), or
+        (None, None)."""
+        for c in self._chips.values():
+            mask = c.masks.get(fp)
+            if mask is not None:
+                return c, mask
+        return None, None
+
+    def home(self, fp: Tuple) -> Optional[int]:
+        """The chip that holds this task's planes (``DeviceTable.chip``
+        as they were put: None too for a table put unplaced, with one
+        chip visible), or None when no chip holds any: a query that
+        needs more of the task's columns puts them beside the others."""
+        with self._lock:
+            mask = self._find_locked(fp)[1]
+        return None if mask is None else mask[3]
 
     # ------------------------------------------------------------------
     def get_table(self, fp: Tuple, cols: List[str]
                   ) -> Optional[dcol.DeviceTable]:
         """All requested columns cached → assembled DeviceTable, else None."""
         with self._lock:
-            mask = self._masks.get(fp)
-            entries = [self._cols.get((fp, c)) for c in cols] \
+            c, mask = self._find_locked(fp)
+            entries = [c.cols.get((fp, n)) for n in cols] \
                 if mask is not None else [None]
             if any(e is None for e in entries):
                 self._misses += 1
                 return None
             self._hits += 1
-            for c in cols:
-                self._cols.move_to_end((fp, c))
-            self._masks.move_to_end(fp)
-            row_mask, rows, cap = mask
+            for n in cols:
+                c.cols.move_to_end((fp, n))
+            c.masks.move_to_end(fp)
+            row_mask, rows, cap, chip = mask
             return dcol.DeviceTable(
-                {c: e.col for c, e in zip(cols, entries)}, row_mask, rows,
-                cap, resident=True)
+                {n: e.col for n, e in zip(cols, entries)}, row_mask, rows,
+                cap, resident=True, chip=chip)
 
     def put_table(self, fp: Tuple, dt: dcol.DeviceTable) -> None:
         from .. import tracing
@@ -127,34 +177,41 @@ class DeviceColumnCache:
             add += nbytes
         if add > _budget():
             return
+        at = dt.chip or 0
         # bookkeeping only: the planes were put by ``encode_batch``,
         # whose ``device:put`` spans carry their bytes
         with tracing.span("device:put", lane="device",
-                          attrs={"cached": 1, "cached_bytes": add}):
+                          attrs={"cached": 1, "cached_bytes": add,
+                                 "chip": at}):
             # the caller's table now SHARES buffers with the cache — it
             # must never be donated to a fused program from here on
             dt.resident = True
             with self._lock:
-                self._masks[fp] = (dt.row_mask, dt.row_count, dt.capacity)
+                for k, other in self._chips.items():
+                    if k != at:     # one table, one chip
+                        other.drop(fp)
+                c = self._chips.setdefault(at, _Chip())
+                c.masks[fp] = (dt.row_mask, dt.row_count, dt.capacity,
+                               dt.chip)
                 for name, col, nbytes in sized:
                     key = (fp, name)
-                    old = self._cols.pop(key, None)
+                    old = c.cols.pop(key, None)
                     if old is not None:
-                        self._bytes -= old.nbytes
-                    self._cols[key] = _Entry(col, nbytes)
-                    self._bytes += nbytes
+                        c.bytes -= old.nbytes
+                    c.cols[key] = _Entry(col, nbytes)
+                    c.bytes += nbytes
                 self._put_bytes += add
-                self._evict_locked()
+                self._evict_locked(c)
 
-    def _evict_locked(self) -> None:
+    def _evict_locked(self, c: _Chip) -> None:
         budget = _budget()
-        while self._bytes > budget and self._cols:
-            _, e = self._cols.popitem(last=False)
-            self._bytes -= e.nbytes
-            self._evicted_bytes += e.nbytes
-        live_fps = {k[0] for k in self._cols}
-        for fp in [f for f in self._masks if f not in live_fps]:
-            del self._masks[fp]
+        while c.bytes > budget and c.cols:
+            _, e = c.cols.popitem(last=False)
+            c.bytes -= e.nbytes
+            c.evicted_bytes += e.nbytes
+        live_fps = {k[0] for k in c.cols}
+        for fp in [f for f in c.masks if f not in live_fps]:
+            del c.masks[fp]
 
 
 _cache: Optional[DeviceColumnCache] = None

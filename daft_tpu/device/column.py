@@ -200,9 +200,23 @@ class DeviceTable:
     #: True for HBM-cache-resident tables — their buffers are SHARED with
     #: the cache and must never be donated to a fused program
     resident: bool = False
+    #: the chip that holds every plane (data, validity, row mask), as an
+    #: index into ``parallel.mesh.scan_devices()``; None: the default
+    #: device, which is where everything lives when one chip is visible
+    chip: Optional[int] = None
 
     def schema(self) -> Schema:
         return Schema([Field(n, c.dtype) for n, c in self.columns.items()])
+
+
+def put_plane(x, chip: Optional[int] = None) -> jax.Array:
+    """One host plane onto the device: the default one, or the chip a
+    scan task's table was given (committed there, so the programs that
+    read it run there and nothing crosses between chips)."""
+    if chip is None:
+        return jnp.asarray(x)
+    from ..parallel import mesh
+    return jax.device_put(x, mesh.scan_devices()[chip])
 
 
 def _np_encode(s: Series) -> "tuple[np.ndarray, np.ndarray, Optional[pa.Array]]":
@@ -250,7 +264,8 @@ def _np_encode(s: Series) -> "tuple[np.ndarray, np.ndarray, Optional[pa.Array]]"
 
 
 def encode_series(s: Series, capacity: int,
-                  allow_resident: bool = False) -> DeviceColumn:
+                  allow_resident: bool = False,
+                  chip: Optional[int] = None) -> DeviceColumn:
     # device-resident hand-off (round 17): a series decoded from a device
     # op whose planes are still resident re-enters the device without a
     # host round trip (pipeline.py bounds + reaps the registry).  Opt-in
@@ -278,8 +293,10 @@ def encode_series(s: Series, capacity: int,
     # the host's time in the two puts; the copy's device side is the
     # profile's transfer events. Bytes as the HBM cache counts them.
     with tracing.span("device:put", lane="device",
-                      attrs={"bytes": nbytes, "cached": 0}):
-        return DeviceColumn(jnp.asarray(vals), jnp.asarray(validity),
+                      attrs={"bytes": nbytes, "cached": 0,
+                             "chip": chip or 0}):
+        return DeviceColumn(put_plane(vals, chip),
+                            put_plane(validity, chip),
                             s.datatype(), dictionary)
 
 
@@ -325,22 +342,29 @@ def encoded_nbytes(batch, columns) -> int:
     return total
 
 
-def encode_batch(batch, columns: Optional[List[str]] = None) -> DeviceTable:
+def encode_batch(batch, columns: Optional[List[str]] = None,
+                 chip: Optional[int] = None) -> DeviceTable:
+    """``chip``: where a scan task's table goes (see ``DeviceTable.chip``);
+    planes handed over from an earlier device op lie on the default
+    device, so only an unplaced table may reuse them."""
     names = columns if columns is not None else batch.column_names()
     n = len(batch)
     cap = bucket_capacity(n)
-    resident = _resident_batch(batch, names, n, cap)
+    resident = _resident_batch(batch, names, n, cap) if chip is None \
+        else None
     if resident is not None:
         return resident
-    cols = {nm: encode_series(batch.get_column(nm), cap) for nm in names}
+    cols = {nm: encode_series(batch.get_column(nm), cap, chip=chip)
+            for nm in names}
     mask = np.zeros(cap, dtype=np.bool_)
     mask[:n] = True
     from .. import tracing
     # the live-row mask is a put the HBM cache's byte count leaves out
     with tracing.span("device:put", lane="device",
-                      attrs={"bytes": 0, "mask_bytes": cap, "cached": 0}):
-        row_mask = jnp.asarray(mask)
-    return DeviceTable(cols, row_mask, n, cap)
+                      attrs={"bytes": 0, "mask_bytes": cap, "cached": 0,
+                             "chip": chip or 0}):
+        row_mask = put_plane(mask, chip)
+    return DeviceTable(cols, row_mask, n, cap, chip=chip)
 
 
 def _resident_batch(batch, names, n: int, cap: int

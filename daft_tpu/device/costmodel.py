@@ -613,12 +613,31 @@ _counts_lock = threading.Lock()
 scan_table_counts: dict = dict.fromkeys(_tracing.TABLE_SOURCES, 0)
 
 
-def count_scan_table(source: str) -> None:
+def count_scan_table(source: str, chip: Optional[int] = None,
+                     rows: int = 0) -> None:
     """Tally one scan task's table, process-wide and on the current
-    query's trace (its root span and ``summary()["tables"]``)."""
+    query's trace (its root span and ``summary()["tables"]``). A table
+    that runs on the device is also tallied, with its rows, under the
+    chip that holds it (``summary()["chips"]``; ``chip`` None is the
+    default device, chip 0)."""
     with _counts_lock:
         scan_table_counts[source] += 1
     _tracing.tally(source)
+    if source != "host":
+        _tracing.tally_chip(chip or 0, tables=1, rows=rows)
+
+
+def note_resident_chips(n_chips: int) -> None:
+    """On the current query's trace, the HBM column cache's bytes on each
+    of the ``n_chips`` chips as they stand now (a chip that holds
+    nothing reads 0). No-op when untraced."""
+    if _tracing.current() is None:
+        return
+    from . import cache
+    held = cache.get_cache().stats()["chips"]
+    for chip in range(n_chips):
+        _tracing.tally_chip(
+            chip, resident_bytes=held.get(chip, {}).get("bytes", 0))
 
 
 def _log(kind: str, device: bool, host_s: float, dev_s: float,

@@ -268,7 +268,8 @@ def _dispatch_packed(prog: FusedAggProgram, dt: dcol.DeviceTable,
     with tracing.span("device:dispatch", lane="device",
                       attrs={"program": "fused_agg",
                              "capacity": dt.capacity,
-                             "strategy": strategy}):
+                             "strategy": strategy,
+                             "chip": dt.chip or 0}):
         arrays = {n: col.data for n, col in dt.columns.items()}
         valids = {n: col.validity for n, col in dt.columns.items()}
         scalars = runtime._prep_scalars(prog.compiled, dt)
@@ -280,7 +281,8 @@ def _dispatch_packed(prog: FusedAggProgram, dt: dcol.DeviceTable,
         with retrace_sanitizer.dispatch_scope(
                 "fragment.donate" if donate else "fragment.packed",
                 (id(prog), dt.capacity, out_cap, strategy, dims,
-                 tuple(s.shape for s in scalars))):
+                 tuple(s.shape for s in scalars), dt.chip)):
+            # runs where its arguments lie: on the table's chip
             return fn(arrays, valids, dt.row_mask, scalars,
                       out_cap=out_cap, strategy=strategy, dims=dims)
 

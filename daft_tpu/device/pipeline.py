@@ -100,8 +100,9 @@ def fetch_host(tree):
 
     JAX starts the host copy of every leaf asynchronously and waits for
     all of them together, so a table's data+validity planes (or a
-    window's packed result matrices) cost one batched transfer instead
-    of one blocking round-trip per plane."""
+    window's packed result matrices, which may lie on several chips:
+    every chip's copy is started before any is waited for) cost one
+    batched transfer instead of one blocking round-trip per plane."""
     import jax
 
     from .. import tracing
@@ -112,6 +113,12 @@ def fetch_host(tree):
             sp.set("arrays", len(leaves))
             sp.set("bytes",
                    sum(int(getattr(a, "nbytes", 0)) for a in leaves))
+            on = sorted({d.id for a in jax.tree_util.tree_leaves(tree)
+                         if isinstance(a, jax.Array)
+                         for d in a.devices()})
+            sp.set("chips", len(on))
+            if len(on) == 1:
+                sp.set("chip", on[0])
     return out
 
 

@@ -218,7 +218,9 @@ def _prep_scalars(c: compiler.Compiled, dt: dcol.DeviceTable):
         d = dt.columns[spec.col].dictionary
         if d is None:
             d = pa.array([], type=pa.large_string())
-        scalars.append(jnp.asarray(spec.fn(d)))
+        # beside the table's planes, so a dispatch on another chip
+        # moves nothing from the default one
+        scalars.append(dcol.put_plane(spec.fn(d), dt.chip))
     return tuple(scalars)
 
 

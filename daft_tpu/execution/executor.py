@@ -749,6 +749,20 @@ class LocalExecutor:
         from ..device import fragment, runtime as drt
 
         n_tasks = len(src.tasks)
+        # the chips the tables are spread over; with one, nothing is
+        # placed (``chip`` None: the default device, as ever)
+        from ..parallel import mesh as pmesh
+        n_chips = max(len(pmesh.scan_devices()), 1)
+
+        def chip_for(i, fp):
+            """A task's chip: where the HBM cache already holds planes of
+            it, else its index in the scan modulo the chips, so that a
+            scan's tables are spread evenly and a repeated scan finds
+            each one where the first put it."""
+            if n_chips == 1:
+                return None
+            home = dcache.get_cache().home(fp) if fp is not None else None
+            return i % n_chips if home is None else home
 
         def load(t) -> RecordBatch:
             est = t.size_bytes() or 0
@@ -761,17 +775,19 @@ class LocalExecutor:
             finally:
                 self.mem.release(est)
 
-        def classify(t):
+        def classify(it):
             """Phase A: cache hits are committed device participants;
             too-small / pyobject batches are forced host; the rest are
             candidates for the cost gate (phase B). Every task is
             tallied once by where its table came from, here or in the
             gate (``costmodel.scan_table_counts``)."""
+            i, t = it
             fp = dcache.task_fingerprint(t)
             if fp is not None:
                 dt = dcache.get_cache().get_table(fp, prog.compiled.needs_cols)
                 if dt is not None:
-                    costmodel.count_scan_table("from_cache")
+                    costmodel.count_scan_table("from_cache", dt.chip,
+                                               dt.row_count)
                     return ("dev", dt, t)
             rb = load(t)
             if len(rb) < max(drt._min_rows(), 1):
@@ -781,20 +797,23 @@ class LocalExecutor:
                 if rb.get_column(nm).is_pyobject():
                     costmodel.count_scan_table("host")
                     return ("host", rb, t)
-            return ("cand", rb, t, fp)
+            return ("cand", rb, t, fp, chip_for(i, fp))
 
         def gate(cand, n_sharing):
             """Phase B: measured cost gate. A cacheable upload is an
             investment the HBM cache repays on every later scan of the
             same task — but only if the whole scan's working set actually
             FITS the budget (otherwise LRU thrash re-pays the upload every
-            query and put_table would refuse oversized tables anyway)."""
+            query and put_table would refuse oversized tables anyway).
+            The budget is a chip's, so the test is the fullest chip's:
+            its share of the tasks against one budget."""
             from ..device import fragment as dfrag
-            _, rb, t, fp = cand
+            _, rb, t, fp, chip = cand
             packed_out = dfrag.packed_bytes_per_group(
                 prog.nk, len(prog.ops)) * dfrag._OUT_CAP0
             col_bytes = dcol.encoded_nbytes(rb, prog.compiled.needs_cols)
-            fits = col_bytes * max(n_tasks, 1) <= dcache._budget()
+            fits = col_bytes * -(-max(n_tasks, 1) // n_chips) \
+                <= dcache._budget()
             # the packed fetch's round trips amortize over the tasks that
             # actually SHARE the transfer: committed cache hits + gate
             # candidates (r4 advisor: dividing by the whole window length
@@ -816,7 +835,8 @@ class LocalExecutor:
                 costmodel.count_scan_table("host")
                 return ("host", rb, t)
             try:
-                dt = dcol.encode_batch(rb, prog.compiled.needs_cols)
+                dt = dcol.encode_batch(rb, prog.compiled.needs_cols,
+                                       chip=chip)
             except (ValueError, TypeError):
                 costmodel.count_scan_table("host")
                 return ("host", rb, t)
@@ -826,7 +846,7 @@ class LocalExecutor:
                 # queries still repay (SF10 thrash, r4) — the upload then
                 # streams through as a one-shot morsel instead
                 dcache.get_cache().put_table(fp, dt)
-            costmodel.count_scan_table("encoded")
+            costmodel.count_scan_table("encoded", chip, dt.row_count)
             return ("dev", dt, t)
 
         width = max((os.cpu_count() or 4), 4) * 2
@@ -842,7 +862,7 @@ class LocalExecutor:
             width = max(1, min(width, -(-n_tasks // max(pwin + 1, 1))))
 
         def windows():
-            it = iter(src.tasks)
+            it = iter(enumerate(src.tasks))
             while True:
                 w = list(itertools.islice(it, width))
                 if not w:
@@ -857,6 +877,7 @@ class LocalExecutor:
                 iter([c for c in classified if c[0] == "cand"]),
                 lambda c: gate(c, n_sharing))
             gated_it = iter(list(gated))
+            costmodel.note_resident_chips(n_chips)
             return [c if c[0] != "cand" else next(gated_it)
                     for c in classified]
 

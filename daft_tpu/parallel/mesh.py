@@ -16,6 +16,7 @@ from typing import Optional
 _lock = threading.RLock()   # re-entrant: get_mesh holds it across mesh_size
 _mesh = None
 _size: Optional[int] = None
+_scan_devices: Optional[list] = None
 
 
 def mesh_size() -> int:
@@ -39,6 +40,23 @@ def mesh_size() -> int:
             n = min(n, cap)
         _size = n
         return n
+
+
+def scan_devices() -> list:
+    """The chips the scan path spreads its tables over: the first
+    :func:`mesh_size` of ``jax.devices()`` (so ``DAFT_TPU_MESH_DEVICES``
+    caps both). A scan task's table lives whole on one of them
+    (``DeviceTable.chip`` indexes this list) and the HBM column cache
+    keeps one budget per entry. One entry or none: nothing is placed,
+    every plane goes to the default device as before."""
+    global _scan_devices
+    if _scan_devices is None:
+        n = mesh_size()
+        with _lock:
+            if _scan_devices is None:
+                import jax
+                _scan_devices = list(jax.devices()[:n]) if n else []
+    return _scan_devices
 
 
 #: legacy static admission floor, now only the FALLBACK when the cost
@@ -89,7 +107,8 @@ def get_mesh():
 
 
 def reset_for_tests() -> None:
-    global _mesh, _size
+    global _mesh, _size, _scan_devices
     with _lock:
         _mesh = None
         _size = None
+        _scan_devices = None

@@ -114,6 +114,9 @@ class SpanRecorder:
         #: counts kept on the root span (``tally``): where each scan
         #: task's table came from
         self._tallies: Dict[str, int] = {}
+        #: per chip (``tally_chip``): the tables it ran, their rows, and
+        #: the HBM column cache's bytes on it as last noted
+        self._chips: Dict[int, Dict[str, int]] = {}
         self._summary: Optional[dict] = None
         self._finished = False
         self.exported = False
@@ -171,6 +174,16 @@ class SpanRecorder:
         with self._lock:
             self._tallies[key] = self._tallies.get(key, 0) + n
 
+    def tally_chip(self, chip: int, tables: int = 0, rows: int = 0,
+                   resident_bytes: Optional[int] = None) -> None:
+        with self._lock:
+            c = self._chips.setdefault(
+                chip, {"tables": 0, "rows": 0, "resident_bytes": 0})
+            c["tables"] += tables
+            c["rows"] += rows
+            if resident_bytes is not None:
+                c["resident_bytes"] = resident_bytes
+
     def finish(self, status: Optional[str] = None) -> None:
         """Close the root span (idempotent) and keep the trace's summary
         in the process's ring (:func:`finished`). ``None`` keeps whatever
@@ -210,7 +223,9 @@ class SpanRecorder:
     def summary(self) -> dict:
         """Counts of the buffer; for a finished trace also where its wall
         went: ``phases`` (per span name), ``covered_us`` (the union of
-        the :data:`LEAF_SPANS`) and ``tables`` (the scan-task tally),
+        the :data:`LEAF_SPANS`), ``tables`` (the scan-task tally) and
+        ``chips`` (the same tally per chip: ``chip``, ``tables``, ``rows``
+        and ``resident_bytes``, one entry a chip, in chip order),
         computed once, when the root closed."""
         return self._summary or self._summarize()
 
@@ -220,6 +235,8 @@ class SpanRecorder:
             offsets = dict(self.clock_offsets_us)
             done = self._finished
             tallies = dict(self._tallies)
+            chips = [{"chip": k, **c}
+                     for k, c in sorted(self._chips.items())]
         out = {"trace_id": self.trace_id, "spans": len(spans),
                "dropped": self.dropped}
         if offsets:
@@ -234,11 +251,16 @@ class SpanRecorder:
                 [(s["ts_us"], s["ts_us"] + s["dur_us"]) for s in spans
                  if s["name"] in LEAF_SPANS], lo, hi)
             out["tables"] = {k: tallies.get(k, 0) for k in TABLE_SOURCES}
+            out["chips"] = chips
         return out
 
 
 #: the spans that hold the work itself and do not nest in each other: a
-#: query's wall is covered by their union (``summary()["covered_us"]``)
+#: query's wall is covered by their union (``summary()["covered_us"]``).
+#: ``device:put`` and ``device:dispatch`` carry the ``chip`` their planes
+#: or program went to (an index of ``parallel.mesh.scan_devices()``; 0
+#: when one chip is visible), ``device:fetch`` the number of ``chips``
+#: its results lay on (and ``chip`` when that is one)
 LEAF_SPANS = frozenset((
     "plan:optimize", "plan:translate", "scan:load", "device:encode",
     "device:put", "device:dispatch", "device:fetch", "device:decode",
@@ -494,6 +516,16 @@ def tally(key: str, n: int = 1) -> None:
     ctx = current()
     if ctx is not None:
         ctx.recorder.tally(key, n)
+
+
+def tally_chip(chip: int, tables: int = 0, rows: int = 0,
+               resident_bytes: Optional[int] = None) -> None:
+    """Count a device table (and its rows) under the chip that ran it,
+    or note the HBM column cache's bytes on that chip, on the current
+    trace (no-op when untraced)."""
+    ctx = current()
+    if ctx is not None:
+        ctx.recorder.tally_chip(chip, tables, rows, resident_bytes)
 
 
 # ------------------------------------------------------ trace registry

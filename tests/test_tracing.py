@@ -931,7 +931,8 @@ def cold_then_warm(tmp_path_factory):
             _scan_agg(root).to_pydict()
             c1 = dcache.get_cache().stats()
             out[phase] = {
-                "cache": {k: c1[k] - c0[k] for k in c1},
+                "cache": {k: c1[k] - c0[k] for k in c1 if k != "chips"},
+                "chips": c1["chips"],
                 "process": {k: v - t0[k] for k, v in
                             costmodel.scan_table_counts.items()},
                 "summary": tracing.finished()[-1]}
@@ -964,3 +965,11 @@ def test_cache_counts_and_table_tally(cold_then_warm, phase, source):
         assert got["cache"]["put_bytes"] == 0 == got["cache"]["bytes"]
         assert put < cold_then_warm["cold"]["cache"]["put_bytes"] / 4
     assert got["cache"]["evicted_bytes"] == 0
+    # the same tally per chip: every device table under the chip that
+    # holds it, and the cache's bytes there as the query left them
+    chips = got["summary"]["chips"]
+    assert sum(c["tables"] for c in chips) == n
+    assert sum(c["rows"] for c in chips) > 0
+    assert {c["chip"]: c["resident_bytes"] for c in chips
+            if c["resident_bytes"]} == \
+        {k: v["bytes"] for k, v in got["chips"].items() if v["bytes"]}
