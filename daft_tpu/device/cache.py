@@ -20,7 +20,10 @@ that chip's entries. A task's chip is remembered with its entries, so a
 later scan of the same file finds it, and runs, where the first put it.
 
 Invalidation: the fingerprint covers file paths, sizes, mtimes, row-group
-selection and row-affecting pushdowns, so a changed file re-encodes.
+selection and row-affecting pushdowns, so a changed file re-encodes. The
+same ``(path, st_size, st_mtime_ns)`` identity is what ``io/footers.py``'s
+store keeps a file's Parquet footer under, so a scan is planned from held
+metadata exactly as long as its columns may be served from here.
 In-memory / generator-backed tasks have no stable identity and bypass the
 cache.
 """

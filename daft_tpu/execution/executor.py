@@ -2008,7 +2008,7 @@ def _task_column_ndv(tasks, name: str):
     range would underestimate scans range-partitioned on the key and let
     a non-reductive grouping through the gate."""
     try:
-        import pyarrow.parquet as pq
+        from ..io import footers
         lo = hi = None
         seen = set()
         for t in tasks:
@@ -2020,7 +2020,8 @@ def _task_column_ndv(tasks, name: str):
                     continue
                 seen.add(path)
                 md = md_cached if md_cached is not None \
-                    and len(t.paths) == 1 else pq.ParquetFile(path).metadata
+                    and len(t.paths) == 1 \
+                    else footers.footer(path, t.io_config).metadata
                 idx = {md.schema.column(i).name: i
                        for i in range(md.num_columns)}.get(name)
                 if idx is None:

@@ -22,6 +22,7 @@ from ..expressions import Expression
 from ..recordbatch import RecordBatch
 from ..schema import Field, Schema
 from ..series import Series
+from . import footers
 from .scan import Pushdowns, ScanTask
 
 
@@ -122,7 +123,8 @@ def _head_range_schema(path: str, file_format: str,
 def infer_schema(path: str, file_format: str,
                  options: Dict[str, Any], io_config=None) -> Schema:
     if file_format == "parquet":
-        return Schema.from_arrow(pq.read_schema(_open_ranged(path, io_config)))
+        return Schema.from_arrow(footers.footer(path, io_config)
+                                 .metadata.schema.to_arrow_schema())
     if file_format == "csv":
         if _is_remote(path):
             s = _head_range_schema(path, "csv", options, io_config)
@@ -167,23 +169,26 @@ def make_scan_tasks(path: str, file_format: str, schema: Schema,
                     pushdowns: Pushdowns, options: Dict[str, Any],
                     partition_values: Dict[str, Any],
                     io_config=None) -> List[ScanTask]:
-    """Per-file scan tasks, with parquet row-group pruning + split."""
+    """Per-file scan tasks, with parquet row-group pruning + split. A local
+    file's footer comes from ``footers``' store when the file is the one
+    the store read (one ``stat``); the task list is built anew each call."""
     if file_format == "parquet":
         try:
-            md = pq.ParquetFile(_open_ranged(path, io_config)).metadata
+            footer = footers.footer(path, io_config)
         except Exception:
-            md = None
-        if md is not None:
-            groups = _prune_row_groups(md, pushdowns.filters, schema)
-            nrows = sum(md.row_group(g).num_rows for g in groups) \
-                if groups is not None else md.num_rows
-            size = sum(md.row_group(g).total_byte_size for g in groups) \
-                if groups is not None else \
-                sum(md.row_group(i).total_byte_size for i in range(md.num_row_groups))
+            footer = None
+        if footer is not None:
+            groups = _prune_row_groups(footer, pushdowns.filters)
+            if groups is None:
+                nrows, size = footer.num_rows, footer.total_bytes
+            else:
+                nrows = sum(footer.group_rows[g] for g in groups)
+                size = sum(footer.group_bytes[g] for g in groups)
             task = ScanTask([path], "parquet", schema, pushdowns, nrows, size,
                             [groups] if groups is not None else None,
                             options, partition_values, io_config=io_config)
-            task.pq_metadata = md  # reused by split_scan_tasks: one footer read
+            # reused by split_scan_tasks, the reader and the NDV gates
+            task.pq_metadata = footer.metadata
             return [task]
     if _is_remote(path):
         try:
@@ -197,46 +202,42 @@ def make_scan_tasks(path: str, file_format: str, schema: Schema,
                      options, partition_values, io_config=io_config)]
 
 
-def _prune_row_groups(md, filters: Optional[Expression],
-                      schema: Schema) -> Optional[List[int]]:
+def _prune_row_groups(footer: "footers.Footer",
+                      filters: Optional[Expression]) -> Optional[List[int]]:
     """Zone-map pruning: drop row groups whose min/max can't satisfy the
     filter (reference: ``daft-parquet/src/statistics``). Conservative — only
-    simple ``col <op> literal`` conjuncts are used."""
+    simple ``col <op> literal`` conjuncts are used. Reads the footer's
+    digest of plain values, so it builds no pyarrow object."""
     if filters is None:
         return None
     bounds = _extract_bounds(filters)
     if not bounds:
         return None
+    # a column the file lacks bounds nothing
+    bounds = [(footer.columns[cname], op, lit) for (cname, op, lit) in bounds
+              if cname in footer.columns]
     keep = []
-    name_to_idx = None
-    for g in range(md.num_row_groups):
-        rg = md.row_group(g)
-        if name_to_idx is None:
-            name_to_idx = {rg.column(i).path_in_schema: i
-                           for i in range(rg.num_columns)}
+    for g, rows in enumerate(footer.group_rows):
         ok = True
-        for (cname, op, lit) in bounds:
-            ci = name_to_idx.get(cname)
-            if ci is None:
-                continue
-            stats = rg.column(ci).statistics
+        for (chunks, op, lit) in bounds:
+            stats = chunks[g]
             if stats is None:
                 continue
+            has_min_max, mn, mx, null_count = stats
             if op in ("is_null", "not_null"):
                 # null_count statistics: a group with zero nulls can't
                 # satisfy is_null; an all-null group can't satisfy not_null
-                if not getattr(stats, "has_null_count", False):
+                if null_count is None:
                     continue
-                if op == "is_null" and stats.null_count == 0:
+                if op == "is_null" and null_count == 0:
                     ok = False
-                elif op == "not_null" and stats.null_count >= rg.num_rows:
+                elif op == "not_null" and null_count >= rows:
                     ok = False
                 if not ok:
                     break
                 continue
-            if not stats.has_min_max:
+            if not has_min_max:
                 continue
-            mn, mx = stats.min, stats.max
             try:
                 if op == "lt" and not (mn < lit):
                     ok = False

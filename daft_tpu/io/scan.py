@@ -16,12 +16,12 @@ from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tupl
 
 import pyarrow as pa
 import pyarrow.dataset as pads
-import pyarrow.parquet as pq
 
 from ..datatype import DataType
 from ..expressions import Expression, col
 from ..recordbatch import RecordBatch
 from ..schema import Field, Schema
+from . import footers
 
 
 @dataclasses.dataclass(frozen=True)
@@ -238,7 +238,10 @@ def glob_paths(path_or_paths, io_config=None) -> List[str]:
 class GlobScanOperator(ScanOperator):
     """Scan over globbed files with schema inference from the first file
     (reference: ``glob.rs:28``) plus hive partition-value inference
-    (``hive.rs``)."""
+    (``hive.rs``). A local Parquet file is ``stat``-ed when the scan is
+    planned (``to_scan_tasks``) and its footer taken from
+    ``footers.FooterStore`` under ``(path, st_size, st_mtime_ns)``, the
+    identity ``device/cache.task_fingerprint`` keeps its columns under."""
 
     def __init__(self, paths, file_format: str,
                  schema: Optional[Schema] = None,
@@ -343,7 +346,7 @@ def split_scan_tasks(tasks: List[ScanTask], max_size: int,
         md = getattr(t, "pq_metadata", None)
         if md is None:
             try:
-                md = pq.ParquetFile(t.paths[0]).metadata
+                md = footers.footer(t.paths[0], t.io_config).metadata
             except Exception:
                 out.append(t)
                 continue

@@ -223,19 +223,20 @@ def _source_column_range(node: lp.Source, name: str) -> Optional[float]:
         lo = hi = None
         seen_paths = set()
         if tasks:
-            import pyarrow.parquet as pq
+            from ..io import footers
             for t in tasks:
                 if t.file_format != "parquet":
                     raise ValueError
                 # split tasks share a file; one footer per path, reusing
-                # the reader's cached footer when the task carries one
+                # the footer the task was planned from when it carries one
                 md_cached = getattr(t, "pq_metadata", None)
                 for p in t.paths:
                     if p in seen_paths:
                         continue
                     seen_paths.add(p)
                     md = md_cached if md_cached is not None \
-                        and len(t.paths) == 1 else pq.ParquetFile(p).metadata
+                        and len(t.paths) == 1 \
+                        else footers.footer(p, t.io_config).metadata
                     idx = {md.schema.column(i).name: i
                            for i in range(md.num_columns)}.get(name)
                     if idx is None:

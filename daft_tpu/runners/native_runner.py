@@ -49,8 +49,10 @@ class NativeRunner(Runner):
         tctx = tracing.maybe_start_trace("query")
         try:
             with tracing.attach(tctx):
-                with tracing.span("plan:optimize", lane="planner"):
+                with tracing.span("plan:optimize", lane="planner") as sp:
                     optimized = builder.optimize()
+                    for k, n in tracing.footer_counts().items():
+                        sp.set("footers_" + k, n)
                 with tracing.span("plan:translate", lane="planner"):
                     pplan = translate(optimized.plan)
                 executor = make_local_executor(cfg)

@@ -223,7 +223,8 @@ class SpanRecorder:
     def summary(self) -> dict:
         """Counts of the buffer; for a finished trace also where its wall
         went: ``phases`` (per span name), ``covered_us`` (the union of
-        the :data:`LEAF_SPANS`), ``tables`` (the scan-task tally) and
+        the :data:`LEAF_SPANS`), ``tables`` (the scan-task tally),
+        ``footers`` (Parquet footers planned ``from_store`` or ``read``) and
         ``chips`` (the same tally per chip: ``chip``, ``tables``, ``rows``
         and ``resident_bytes``, one entry a chip, in chip order),
         computed once, when the root closed."""
@@ -251,6 +252,7 @@ class SpanRecorder:
                 [(s["ts_us"], s["ts_us"] + s["dur_us"]) for s in spans
                  if s["name"] in LEAF_SPANS], lo, hi)
             out["tables"] = {k: tallies.get(k, 0) for k in TABLE_SOURCES}
+            out["footers"] = _footer_counts(tallies)
             out["chips"] = chips
         return out
 
@@ -271,6 +273,13 @@ LEAF_SPANS = frozenset((
 #: where a scan task's table came from, as the device tier's scan path
 #: tallies it (``SpanRecorder.tally`` / :func:`tally`)
 TABLE_SOURCES = ("from_cache", "encoded", "host")
+
+
+def _footer_counts(tallies: Dict[str, int]) -> Dict[str, int]:
+    """The local Parquet footers a trace's scans were planned from: held
+    by ``io.footers``' store, or read from the file (and stored)."""
+    return {"from_store": tallies.get("footers_from_store", 0),
+            "read": tallies.get("footers_read", 0)}
 
 
 def _union_us(intervals, lo: Optional[int] = None,
@@ -516,6 +525,16 @@ def tally(key: str, n: int = 1) -> None:
     ctx = current()
     if ctx is not None:
         ctx.recorder.tally(key, n)
+
+
+def footer_counts() -> Dict[str, int]:
+    """``{"from_store", "read"}`` of the current trace so far (empty when
+    untraced)."""
+    ctx = current()
+    if ctx is None:
+        return {}
+    with ctx.recorder._lock:
+        return _footer_counts(ctx.recorder._tallies)
 
 
 def tally_chip(chip: int, tables: int = 0, rows: int = 0,

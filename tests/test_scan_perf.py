@@ -493,8 +493,8 @@ def test_hive_union_across_mixed_key_paths(tmp_path):
 # ---------------------------------------------------- pruning satellite
 
 def test_prune_null_count_and_is_in(tmp_path):
+    from daft_tpu.io.footers import Footer
     from daft_tpu.io.readers import _prune_row_groups
-    from daft_tpu.schema import Schema
 
     p = str(tmp_path / "t.parquet")
     t = pa.table({
@@ -503,26 +503,43 @@ def test_prune_null_count_and_is_in(tmp_path):
                       + list(range(200, 290)) + [None] * 10),
     })
     pq.write_table(t, p, row_group_size=100)
-    md = pq.ParquetFile(p).metadata
-    schema = Schema.from_arrow(pq.read_schema(p))
+    md = Footer(pq.ParquetFile(p).metadata)
 
     # is_null: zero-null groups prune
-    assert _prune_row_groups(md, col("a").is_null(), schema) == [1, 2]
+    assert _prune_row_groups(md, col("a").is_null()) == [1, 2]
     # not_null: the all-null group prunes
-    assert _prune_row_groups(md, col("a").not_null(), schema) == [0, 2]
+    assert _prune_row_groups(md, col("a").not_null()) == [0, 2]
     # is_in: min/max containment (g1 has no min/max → kept conservatively)
-    assert _prune_row_groups(md, col("a").is_in([250, 270]), schema) \
+    assert _prune_row_groups(md, col("a").is_in([250, 270])) \
         == [1, 2]
-    assert _prune_row_groups(md, col("a").is_in([50]), schema) == [0, 1]
+    assert _prune_row_groups(md, col("a").is_in([50])) == [0, 1]
     # conjunct composes with the existing comparison bounds
     assert _prune_row_groups(
-        md, col("a").is_in([250]) & (col("a") > 240), schema) == [1, 2]
+        md, col("a").is_in([250]) & (col("a") > 240)) == [1, 2]
     # end-to-end answers agree with the pruned plan
     out = dt.read_parquet(p).where(col("a").is_in([50, 250])) \
         .to_pydict()
     assert sorted(out["a"]) == [50, 250]
     out = dt.read_parquet(p).where(col("a").is_null()).to_pydict()
     assert len(out["a"]) == 110
+
+
+def test_remote_parquet_is_planned_without_the_footer_store(
+        remote_dataset, monkeypatch):
+    """A remote file has no ``(size, mtime_ns)`` to be trusted by: its
+    footer is fetched for every plan, kept by nobody and tallied nowhere."""
+    from daft_tpu import tracing
+    from daft_tpu.io import footers
+    monkeypatch.setenv("DAFT_TPU_DEVICE", "0")
+    monkeypatch.setenv("DAFT_TPU_TRACE", "1")
+    footers.get_store().clear()
+    for _ in range(2):
+        out = dt.read_parquet(remote_dataset).where(col("seq") < 150) \
+            .select("seq").to_pydict()
+        assert sorted(out["seq"]) == list(range(150))
+        assert tracing.finished()[-1]["footers"] == \
+            {"from_store": 0, "read": 0}
+    assert len(footers.get_store()) == 0
 
 
 # -------------------------------------------------- inference satellite
