@@ -2167,177 +2167,6 @@ def run_obs_smoke() -> int:
             os.environ.pop(k, None)
 
 
-def run_kernels_bench():
-    """``--kernels``: the hash-vs-sort device kernel sweep (round 12).
-
-    Sweeps the grouped-agg family over rows × NDV × key widths and the
-    join family over rows × match shapes, running BOTH strategies on
-    every point: parity is asserted (order-insensitive group maps,
-    order-EXACT join pair lists), the cost model's per-dispatch pick is
-    recorded next to what it would pick on silicon, and on a real chip
-    each strategy is re-timed in-jit (``lax.fori_loop``, the r6 harness)
-    so the hash-vs-sort ratio is a roofline claim. On a CPU dev box the
-    Pallas kernels run under the interpreter — a timing there measures
-    the emulator, not silicon — so the artifact reports interpreter-mode
-    parity plus the statically re-proven dispatch contracts instead of
-    MFU (the acceptance evidence tier-1 can actually produce)."""
-    import numpy as np
-
-    import jax
-    import jax.numpy as jnp
-
-    from daft_tpu.analysis import rule_jit
-    from daft_tpu.device import backend as dbackend
-    from daft_tpu.device import costmodel, kernels as K, mfu
-    from daft_tpu.device import pallas_kernels as pk
-
-    interpret = pk.interpret_default()
-    out = {
-        "interpret": interpret,
-        "backend": dbackend.backend_name() or "cpu",
-        "agg_sweep": [], "join_sweep": [],
-    }
-
-    def silicon_pick(fn, *args, **kw):
-        """What the strategy model would decide with a hash-capable
-        backend attached (the CPU sweep's 'on silicon this dispatch
-        goes hash' column)."""
-        real = costmodel._hash_capable_backend
-        costmodel._hash_capable_backend = lambda: True
-        try:
-            return fn(*args, **kw)
-        finally:
-            costmodel._hash_capable_backend = real
-
-    def agg_map(res, nk, nv):
-        ok, okv, ov, ovv, g = res
-        g = int(np.asarray(jax.device_get(g)))
-        ok = [np.asarray(x) for x in ok]
-        okv = [np.asarray(x) for x in okv]
-        ov = [np.asarray(x) for x in ov]
-        ovv = [np.asarray(x) for x in ovv]
-        return {tuple(k[i].item() if kv[i] else None
-                      for k, kv in zip(ok, okv)):
-                tuple(round(v[i].item(), 3) if vv[i] else None
-                      for v, vv in zip(ov, ovv))
-                for i in range(g)}
-
-    # ---- grouped-agg: rows × NDV × key widths (1 word / 2 words / wide)
-    key_cfgs = (("1xi32", 1), ("2xi64", 2), ("3xi64", 3))
-    rows_list = (1 << 12, 1 << 14) if interpret else (1 << 16, 1 << 20)
-    parity_all = True
-    for C in rows_list:
-        for ndv in (16, 256, 2048):
-            if ndv * 4 > C:
-                continue
-            v = np.arange(C) % ndv
-            for name, nk in key_cfgs:
-                if nk == 1:
-                    keys = (jnp.asarray(v.astype(np.int32)),)
-                    dts = [np.dtype("int32")]
-                else:
-                    parts = [(v >> (4 * i)) & 0xF for i in range(nk - 1)]
-                    parts.append(v >> (4 * (nk - 1)))
-                    keys = tuple(jnp.asarray(p.astype(np.int64))
-                                 for p in parts)
-                    dts = [np.dtype("int64")] * nk
-                ones = jnp.ones(C, bool)
-                kvalids = (ones,) * nk
-                vals = (jnp.asarray((v % 97).astype(np.float32)),
-                        jnp.asarray(np.ones(C, np.float32)))
-                vvalids = (ones, ones)
-                ops = ("sum", "count")
-                out_cap = max(ndv, 128)
-                entry = {"rows": C, "ndv": ndv, "keys": name,
-                         "hash_fits": pk.hash_pack_words(dts) is not None,
-                         "auto_pick": costmodel.groupby_strategy(
-                             C, float(ndv), dts, out_cap, log=False)[0],
-                         "silicon_pick": silicon_pick(
-                             lambda: costmodel.groupby_strategy(
-                                 C, float(ndv), dts, out_cap,
-                                 log=False)[0])}
-                sort_res = K.grouped_agg_block_impl(
-                    keys, kvalids, vals, vvalids, ones, ops, out_cap)
-                if entry["hash_fits"]:
-                    hash_res = pk.hash_grouped_agg_impl(
-                        keys, kvalids, vals, vvalids, ones, ops, out_cap)
-                    entry["parity"] = (
-                        agg_map(hash_res, nk, 2) == agg_map(sort_res,
-                                                            nk, 2))
-                    entry["load_factor"] = round(
-                        ndv / pk.table_capacity(out_cap), 3)
-                else:
-                    # wide key sets route to the LSD-radix sort path —
-                    # the fallback IS the tested behaviour
-                    entry["parity"] = entry["silicon_pick"] == "sort"
-                parity_all &= entry["parity"]
-                out["agg_sweep"].append(entry)
-
-    # ---- join: rows × match shape (fk-shaped vs heavy duplicates)
-    join_rows = ((1 << 11, 1 << 9), (1 << 11, 32), (1 << 13, 1 << 11)) \
-        if interpret else ((1 << 16, 1 << 14), (1 << 16, 1 << 8))
-    for C, ndv in join_rows:
-        rng = np.random.default_rng(C + ndv)
-        lk = jnp.asarray(rng.integers(0, ndv, C).astype(np.int64))
-        rk = jnp.asarray(rng.integers(0, ndv, C).astype(np.int64))
-        ones = jnp.ones(C, bool)
-        cap = 1 << int(np.ceil(np.log2(4 * C * max(C // ndv, 1))))
-        hashed = np.asarray(pk.hash_join_impl(
-            lk, ones, ones, rk, ones, ones, cap))
-        sorted_ = np.asarray(K.join_fused_impl(
-            lk, ones, ones, rk, ones, ones, cap))
-        total = int(hashed[2].sum())
-        match = total <= cap \
-            and np.array_equal(hashed[:2, :total], sorted_[:2, :total]) \
-            and np.array_equal(hashed[2], sorted_[2])
-        parity_all &= match
-        out["join_sweep"].append({
-            "rows": C, "build_ndv": ndv, "pairs": total,
-            "parity_pair_exact": match,
-            "auto_pick": costmodel._join_strategy(C, C),
-            "silicon_pick": silicon_pick(
-                lambda: costmodel._join_strategy(C, C))})
-    out["parity_all"] = parity_all
-
-    # ---- dispatch contracts, re-proven from freshly built jaxprs (the
-    # same single-sourced checker `python -m daft_tpu.analysis` runs)
-    findings = rule_jit.check_dispatch_contracts()
-    out["dispatch_contracts"] = {
-        "clean": not findings,
-        "findings": [str(f)[:160] for f in findings][:5],
-        "hash_agg_pallas_calls": rule_jit.HASH_AGG_PALLAS_CALLS,
-        "hash_join_pallas_calls": rule_jit.HASH_JOIN_PALLAS_CALLS,
-        "hash_join_sort_free": True,
-    }
-
-    # ---- roofline: silicon-only (the interpreter would time the
-    # emulator); the r05 baseline rows are the ledger numbers this round
-    # exists to beat — grouped-agg 0.067% of the HBM roofline, join
-    # 0.004% MFU (BENCH_r05 `mfu` block)
-    if not interpret:
-        rep = mfu.report(n=1 << 20)
-        out["mfu"] = rep
-        agg_h = rep.get("grouped_agg_hash", {}).get("roofline_pct")
-        agg_s = rep.get("grouped_agg", {}).get("roofline_pct")
-        join_h = rep.get("join_hash", {}).get("roofline_pct")
-        join_s = rep.get("join", {}).get("roofline_pct")
-        if agg_h and agg_s:
-            out["agg_improvement_vs_sort"] = round(agg_h / agg_s, 2)
-        if join_h and join_s:
-            out["join_improvement_vs_sort"] = round(join_h / join_s, 2)
-        out["r05_baseline"] = {"grouped_agg_roofline_pct": 0.067,
-                               "join_mfu_pct": 0.004}
-        if agg_h:
-            out["agg_improvement_vs_r05"] = round(agg_h / 0.067, 1)
-    else:
-        out["mfu"] = {
-            "skipped": "interpreter backend — parity + dispatch "
-                       "contracts are the CPU evidence; roofline claims "
-                       "come from silicon runs (see the device child's "
-                       "mfu block)"}
-    return out
-
-
 def run_arrow_baseline():
     import pyarrow.compute as pc
     import pyarrow.dataset as pads
@@ -3110,13 +2939,6 @@ def main():
         if r is not None:
             detail["warmup_bench"] = r
 
-    if "--kernels" in sys.argv:
-        # hash-vs-sort kernel sweep: parity over NDV × rows × key widths,
-        # dispatch-contract re-proof, roofline ratios on silicon
-        r = section("kernels", run_kernels_bench, min_needed=40.0)
-        if r is not None:
-            detail["kernels_bench"] = r
-
     if "--obs" in sys.argv:
         # tracing-overhead measurement: off vs sampled vs full tracing on
         # the serve-bench mixed workload (QPS/p99 deltas, <5% full gate)
@@ -3300,13 +3122,6 @@ def main():
                 ad.get("calibrated", {}).get("decision_changed"),
             "ndv_ratio":
                 ad.get("calibrated", {}).get("observed_ndv_ratio")}
-    kb = detail.get("kernels_bench")
-    if isinstance(kb, dict) and "error" not in kb:
-        compact["kernels"] = {
-            "parity": kb.get("parity_all"),
-            "contracts": kb.get("dispatch_contracts", {}).get("clean"),
-            "agg_x": kb.get("agg_improvement_vs_sort"),
-            "join_x": kb.get("join_improvement_vs_sort")}
     sv = detail.get("serve_bench")
     if isinstance(sv, dict) and "error" not in sv:
         compact["serve"] = {
@@ -3337,7 +3152,7 @@ def main():
     if errors:
         compact["n_errors"] = len(errors)
     # hard cap: drop optional keys until the line fits the driver's window
-    for drop in ("obs", "fleet", "kernels", "serve", "scan", "adaptive",
+    for drop in ("obs", "fleet", "serve", "scan", "adaptive",
                  "spill", "shuffle", "mesh", "chaos", "ledger_dispatches",
                  "mfu", "families", "q1_winner", "backend"):
         if len(json.dumps(compact)) <= 1500:

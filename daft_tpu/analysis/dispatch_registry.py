@@ -77,16 +77,6 @@ SITES: Tuple[DispatchSite, ...] = (
        ("join_fused_kernel",),
        "(capacity classes, out_capacity bucket, donate)",
        "one trace per build/probe size class x out bucket"),
-    _s("pallas.hash_agg", "daft_tpu/device/pallas_kernels.py",
-       ("hash_grouped_agg_kernel", "_agg_build_call"),
-       "(n_keys, n_vals, ops, out_cap, table_cap, interpret, block)",
-       "one trace per hash-agg program shape (memoized in "
-       "_hash_agg_jit_cache)"),
-    _s("pallas.hash_join", "daft_tpu/device/pallas_kernels.py",
-       ("hash_join_kernel", "_join_build_call", "_join_probe_call"),
-       "(donate, out_capacity, interpret, block sizes)",
-       "one trace per hash-join program shape (memoized in "
-       "_hash_join_jit_cache)"),
     _s("fragment.packed", "daft_tpu/device/fragment.py",
        ("get_fused_agg",),
        "(program, capacity class, out_cap bucket, strategy, donate, "
@@ -130,8 +120,7 @@ SITES: Tuple[DispatchSite, ...] = (
        "callers: runtime._projection_cache / fragment._fused_cache)",
        memo="caller"),
     _s("mfu.bench", "daft_tpu/device/mfu.py",
-       ("measure_grouped_agg", "measure_hash_grouped_agg",
-        "measure_join", "measure_hash_join", "measure_argsort"),
+       ("measure_grouped_agg", "measure_join", "measure_argsort"),
        "(bench shape grid)",
        "roofline harness: re-times compiles on purpose", exempt=True),
     # warmup.aot constructs no programs of its own — it .lower()s the

@@ -104,7 +104,7 @@ _KNOBS: List[Knob] = [
     _k("DAFT_TPU_AOT_WARMUP", "bool", False, "daft_tpu/device/warmup.py",
        "device", "`1` AOT-compiles (`jit(...).lower().compile()`) the "
        "device kernel library — and any already-compiled fused "
-       "fragments — over the size-class x strategy grid at serving "
+       "fragments — over the size-class grid at serving "
        "startup, so first queries re-enter warm programs; the "
        "persistent compile cache (`JAX_COMPILATION_CACHE_DIR`, else "
        "`<repo>/.cache/jax` off-CPU) makes them survive restarts",
@@ -531,43 +531,6 @@ _KNOBS: List[Knob] = [
     _k("DAFT_TPU_SLOW_QUERY_MS", "float", 0.0, "daft_tpu/tracing.py",
        "observability", "wall-time threshold flagging a flight-recorder "
        "entry `slow: true` (`0` disables the flag)"),
-    # -------------------------------------------------------- kernels
-    _k("DAFT_TPU_KERNEL_GROUPBY", "str", "auto",
-       "daft_tpu/device/costmodel.py", "kernels",
-       "grouped-agg kernel strategy: `hash`/`sort` force one path, "
-       "`auto` lets the cost model price one-pass hash vs radix-sort per "
-       "dispatch (footer NDV evidence, load factor, key width)"),
-    _k("DAFT_TPU_KERNEL_JOIN", "str", "auto",
-       "daft_tpu/device/costmodel.py", "kernels",
-       "device join kernel strategy: `hash`/`sort` force one path, "
-       "`auto` prices hash build/probe vs the fused sort-merge per "
-       "dispatch"),
-    _k("DAFT_TPU_KERNEL_HASH_LOAD", "float", 0.5,
-       "daft_tpu/device/pallas_kernels.py", "kernels",
-       "max hash-table load factor: the table holds "
-       "`out_cap / load` slots (lower = shorter probe chains, more HBM)"),
-    _k("DAFT_TPU_KERNEL_HASH_MAX_BITS", "int", 128,
-       "daft_tpu/device/pallas_kernels.py", "kernels",
-       "widest packed key set (bits, ≤128) the hash kernels accept; "
-       "wider key sets fall back to the LSD-radix sort path"),
-    _k("DAFT_TPU_KERNEL_HASH_NDV_FRAC", "float", 0.5,
-       "daft_tpu/device/costmodel.py", "kernels",
-       "NDV/rows ratio above which the hash grouped-agg declines "
-       "(near-unique keys make the table as large as the data — the "
-       "one-pass advantage is gone)"),
-    _k("DAFT_TPU_KERNEL_MAX_TABLE", "int", 1 << 20,
-       "daft_tpu/device/pallas_kernels.py", "kernels",
-       "hash-table slot ceiling (the table planes must fit on-chip "
-       "memory; larger group budgets stay on the sort path)",
-       default_str="1Mi"),
-    _k("DAFT_TPU_KERNEL_BLOCK", "int", 1024,
-       "daft_tpu/device/pallas_kernels.py", "kernels",
-       "rows per Pallas grid step (rounded down to a power of two)"),
-    _k("DAFT_TPU_KERNEL_INTERPRET", "str", None,
-       "daft_tpu/device/pallas_kernels.py", "kernels",
-       "`1` forces Pallas interpreter mode, `0` forces compiled kernels; "
-       "unset: interpreter on CPU backends, compiled on silicon",
-       default_str="auto"),
 ]
 
 REGISTRY: Dict[str, Knob] = {k.name: k for k in _KNOBS}

@@ -41,7 +41,6 @@ from .rule_resources import _header_parts, walk_local
 DEVICE_MODULES = (
     "daft_tpu/device/fragment.py",
     "daft_tpu/device/kernels.py",
-    "daft_tpu/device/pallas_kernels.py",
     "daft_tpu/device/runtime.py",
 )
 
@@ -265,9 +264,9 @@ def _check_donated_reads(sf: SourceFile, idx: ModuleIndex,
             if stmt is None:
                 continue
             # taint flows from the dispatch's NORMAL successors only: an
-            # exception raised BY the dispatch (a trace-time failure like
-            # HashKeyWidthError) means no executable consumed the
-            # buffers, so that path re-dispatches legitimately
+            # exception raised BY the dispatch (a trace-time failure)
+            # means no executable consumed the buffers, so that path
+            # re-dispatches legitimately
             start_nodes = []
             for node in cfg.nodes_for(stmt):
                 start_nodes.extend(t for t, is_exc in node.succ

@@ -19,13 +19,7 @@ Static rules: ``host-effect-in-jit``, ``np-in-jit``.
 
 Contract re-verification (``check_dispatch_contracts``): rebuilds the
 jaxprs and re-proves PR 1's numbers — ``dispatch-contract`` findings on
-violation. PR 7 extends the same discipline to the hash-strategy Pallas
-kernels: the hash grouped-agg is exactly ONE ``pallas_call`` (plus a
-2-operand slot-compaction sort, within the ≤3-operand budget), the hash
-join is exactly TWO ``pallas_call``s (build + probe) with zero
-``lax.sort``, both are free of host-callback primitives, and key sets
-wider than the 128-bit hash budget keep routing to the sort path. The
-jaxpr-walking helpers here (:func:`max_sort_operands`,
+violation. The jaxpr-walking helpers here (:func:`max_sort_operands`,
 :func:`count_primitive`, the ``*_jaxpr`` builders) are the single
 source tests use too (``tests/test_device_kernels.py``).
 """
@@ -192,21 +186,6 @@ ARGSORT_CASES = ((1, "int64"), (2, "float32"), (3, "int64"),
 FORBIDDEN_IN_FUSED_JOIN = ("pure_callback", "io_callback",
                            "debug_callback", "callback")
 
-PALLAS_PATH = "daft_tpu/device/pallas_kernels.py"
-#: PR 7's hash-kernel contracts: the hash grouped-agg is ONE Pallas
-#: program (build) plus a tiny 2-operand slot-compaction sort — within
-#: the ≤3-operand budget; the hash join is exactly TWO Pallas programs
-#: (build + probe) fused into one jit program with ZERO lax.sort. Both
-#: contain zero host-callback primitives (same single-dispatch
-#: discipline as the fused sort join). The >hash-budget key-width case
-#: must keep returning None from ``hash_pack_words`` so dispatch sites
-#: route wide key sets to the LSD-radix sort path.
-HASH_AGG_PALLAS_CALLS = 1
-HASH_JOIN_PALLAS_CALLS = 2
-HASH_JOIN_MAX_SORT_OPERANDS = 0  # no sort anywhere in build/probe
-#: 3 i64 keys pack to 195 bits — beyond the ≤128-bit hash-key budget
-HASH_UNFIT_KEY_DTYPES = ("int64", "int64", "int64")
-
 
 def max_sort_operands(jaxpr) -> int:
     """Deepest ``lax.sort`` operand count anywhere in a (closed) jaxpr."""
@@ -268,38 +247,6 @@ def join_fused_jaxpr(capacity: int = 64):
     return jax.make_jaxpr(
         lambda lk, lv, lm, rk, rv, rm: K.join_fused_impl(
             lk, lv, lm, rk, rv, rm, capacity))(
-        key, ones, ones, key, ones, ones)
-
-
-def hash_agg_jaxpr(n_keys: int = 2):
-    """Jaxpr of the hash grouped-agg (interpret=True so the trace needs
-    no silicon; the program structure is identical either way). i32 keys:
-    two of them pack to 66 bits — a 2-word hash key within the budget."""
-    import jax
-    import numpy as np
-    from ..device import pallas_kernels as pk
-    C = 64
-    keys = tuple(np.arange(C, dtype=np.int32) for _ in range(n_keys))
-    ones = tuple(np.ones(C, bool) for _ in range(n_keys))
-    mask = np.ones(C, bool)
-    vals = (np.ones(C, np.float32),)
-    return jax.make_jaxpr(
-        lambda ks, kv, v, vv, m: pk.hash_grouped_agg_impl(
-            ks, kv, v, vv, m, ("sum",), 16, interpret=True, block=16))(
-        keys, ones, vals, (mask,), mask)
-
-
-def hash_join_jaxpr(capacity: int = 128):
-    """Jaxpr of the fused hash build/probe join."""
-    import jax
-    import numpy as np
-    from ..device import pallas_kernels as pk
-    C = 64
-    key = np.arange(C, dtype=np.int64)
-    ones = np.ones(C, bool)
-    return jax.make_jaxpr(
-        lambda lk, lv, lm, rk, rv, rm: pk.hash_join_impl(
-            lk, lv, lm, rk, rv, rm, capacity, interpret=True, block=16))(
         key, ones, ones, key, ones, ones)
 
 
@@ -448,67 +395,10 @@ def check_dispatch_contracts() -> List[Finding]:
                 "dispatch-contract", KERNELS_PATH, 1,
                 f"join_fused_impl build-side sort exceeds "
                 f"{ARGSORT_MAX_SORT_OPERANDS} operands"))
-        out.extend(_check_hash_contracts())
         out.extend(check_fusion_region_contracts())
     except Exception as exc:   # can't verify ⇒ say so, don't pass silently
         out.append(Finding(
             "dispatch-contract", KERNELS_PATH, 1,
             f"could not re-verify dispatch contracts: {exc!r} (run with "
             f"--no-contracts to skip)"))
-    return out
-
-
-def _check_hash_contracts() -> List[Finding]:
-    """Re-prove PR 7's hash-kernel contracts from freshly-built jaxprs."""
-    out: List[Finding] = []
-    ha = hash_agg_jaxpr()
-    n = count_primitive(ha.jaxpr, "pallas_call")
-    if n != HASH_AGG_PALLAS_CALLS:
-        out.append(Finding(
-            "dispatch-contract", PALLAS_PATH, 1,
-            f"hash_grouped_agg_impl contains {n} pallas_call(s) "
-            f"(contract: exactly {HASH_AGG_PALLAS_CALLS} — one table-build "
-            f"program, single-dispatch)"))
-    ops = max_sort_operands(ha.jaxpr)
-    if ops > ARGSORT_MAX_SORT_OPERANDS:
-        out.append(Finding(
-            "dispatch-contract", PALLAS_PATH, 1,
-            f"hash_grouped_agg_impl slot compaction sorts with {ops} "
-            f"operands (contract: ≤{ARGSORT_MAX_SORT_OPERANDS})"))
-    hj = hash_join_jaxpr()
-    n = count_primitive(hj.jaxpr, "pallas_call")
-    if n != HASH_JOIN_PALLAS_CALLS:
-        out.append(Finding(
-            "dispatch-contract", PALLAS_PATH, 1,
-            f"hash_join_impl contains {n} pallas_call(s) (contract: "
-            f"exactly {HASH_JOIN_PALLAS_CALLS} — build + probe fused in "
-            f"one jit program)"))
-    if max_sort_operands(hj.jaxpr) > HASH_JOIN_MAX_SORT_OPERANDS:
-        out.append(Finding(
-            "dispatch-contract", PALLAS_PATH, 1,
-            "hash_join_impl contains a lax.sort — the hash build/probe "
-            "contract is sort-free (the sort formulation is the OTHER "
-            "strategy)"))
-    for jx, fn in ((ha, "hash_grouped_agg_impl"), (hj, "hash_join_impl")):
-        for prim in FORBIDDEN_IN_FUSED_JOIN:
-            k = count_primitive(jx.jaxpr, prim)
-            if k:
-                out.append(Finding(
-                    "dispatch-contract", PALLAS_PATH, 1,
-                    f"{fn} contains {k} {prim} primitive(s) — the "
-                    f"single-dispatch contract forbids host round-trips "
-                    f"inside the fused program"))
-    # the width gate: key sets wider than the hash budget must keep
-    # falling back (hash_pack_words → None routes dispatch sites to the
-    # any-width LSD-radix sort path, itself re-proven above)
-    import numpy as np
-    from ..device import pallas_kernels as pk
-    if pk.hash_pack_words([np.dtype(d) for d in
-                           HASH_UNFIT_KEY_DTYPES]) is not None:
-        out.append(Finding(
-            "dispatch-contract", PALLAS_PATH, 1,
-            f"hash_pack_words accepted a "
-            f"{len(HASH_UNFIT_KEY_DTYPES)}-wide i64 key set (> the "
-            f"128-bit hash-key budget) — wide keys must route to the "
-            f"sort path"))
     return out

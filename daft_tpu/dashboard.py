@@ -48,7 +48,7 @@ def broadcast_query(stats) -> None:
             # bytes fetched vs used, prefetch overlap
             "io": dict(getattr(stats, "io", {}) or {}),
             # device kernels: per-family dispatch/byte/MFU ledger delta,
-            # incl. the hash-vs-sort strategy + table load factor (r12)
+            # incl. the dense/sort strategy that ran
             "device_kernels": dict(
                 getattr(stats, "device_kernels", {}) or {}),
             # self-tuning feedback plane (r20): calibration observations

@@ -154,9 +154,8 @@ def device_ready() -> bool:
 
 def is_accelerator() -> bool:
     """True when the initialized backend is an accelerator, not the CPU
-    tier — the SINGLE predicate for buffer donation and for "never drop
-    to the Pallas interpreter on your own". New backend strings get
-    classified here once, not at every dispatch site."""
+    tier — the SINGLE predicate for buffer donation. New backend
+    strings get classified here once, not at every dispatch site."""
     return (backend_name() or "cpu") != "cpu"
 
 

@@ -12,11 +12,11 @@ once a sample-count floor is met.
 
 Calibrated names (one entry each, same units as the costmodel constant):
 
-- ``DEV_VECTOR_BPS`` / ``DEV_AGG_BPS`` / ``DEV_AGG_HASH_BPS`` — achieved
-  device bytes/s per kernel family+strategy, observed at every real
-  dispatch through ``costmodel.ledger_record``;
-- ``DEV_SORT_ROWS_PER_S`` / ``DEV_JOIN_ROWS_PER_S`` /
-  ``DEV_JOIN_HASH_ROWS_PER_S`` — achieved rows/s, same chokepoint;
+- ``DEV_VECTOR_BPS`` / ``DEV_AGG_BPS`` — achieved device bytes/s per
+  kernel family, observed at every real dispatch through
+  ``costmodel.ledger_record``;
+- ``DEV_SORT_ROWS_PER_S`` / ``DEV_JOIN_ROWS_PER_S`` — achieved rows/s,
+  same chokepoint;
 - ``SHUFFLE_WIRE_BPS`` — achieved shuffle-fetch bytes/s, observed at
   ``shuffle_service.fetch_partition`` (sizable fetches only: tiny
   partitions measure RTT, not bandwidth);
@@ -24,8 +24,8 @@ Calibrated names (one entry each, same units as the costmodel constant):
   ``costmodel._measure_ici`` runs;
 - ``NDV_FOOTER_RATIO`` — observed actual-groups / footer-NDV ratio
   (parquet min/max range NDV systematically OVER-predicts: a sparse key
-  set reads as near-unique). ``shuffle_combine_wins`` and
-  ``groupby_strategy`` damp footer NDV evidence by this ratio.
+  set reads as near-unique). ``shuffle_combine_wins`` damps footer NDV
+  evidence by this ratio.
 
 Contract with the chaos-determinism rules (r10/r14): under
 ``DAFT_TPU_CHAOS_SERIALIZE=1`` or an active fault plan the profile is
@@ -239,7 +239,7 @@ def ingest_flight_history(limit: int = 200) -> int:
                 continue
             try:
                 n += _observe_family(
-                    kind, d.get("strategy"),
+                    kind,
                     rows=float(d.get("rows", 0) or 0),
                     nbytes=float(d.get("bytes", 0) or 0),
                     seconds=float(d.get("seconds", 0) or 0),
@@ -311,14 +311,10 @@ def flush() -> None:
     _persist(snap)
 
 
-_FAMILY_BYTES = {("grouped_agg", "hash"): "DEV_AGG_HASH_BPS",
-                 ("grouped_agg", "sort"): "DEV_AGG_BPS",
-                 ("grouped_agg", None): "DEV_AGG_BPS",
-                 ("projection", None): "DEV_VECTOR_BPS"}
-_FAMILY_ROWS = {("argsort", None): "DEV_SORT_ROWS_PER_S",
-                ("join", "hash"): "DEV_JOIN_HASH_ROWS_PER_S",
-                ("join", "sort"): "DEV_JOIN_ROWS_PER_S",
-                ("join", None): "DEV_JOIN_ROWS_PER_S"}
+_FAMILY_BYTES = {"grouped_agg": "DEV_AGG_BPS",
+                 "projection": "DEV_VECTOR_BPS"}
+_FAMILY_ROWS = {"argsort": "DEV_SORT_ROWS_PER_S",
+                "join": "DEV_JOIN_ROWS_PER_S"}
 
 #: dispatches below these floors measure launch overhead / RTT, not the
 #: kernel rate the constants model — skip them
@@ -327,39 +323,36 @@ _MIN_OBS_ROWS = 1 << 12
 _MIN_OBS_SECONDS = 1e-5
 
 
-def _observe_family(kind: str, strategy: Optional[str], rows: float,
-                    nbytes: float, seconds: float,
+def _observe_family(kind: str, rows: float, nbytes: float, seconds: float,
                     dispatches: float = 1.0) -> int:
     """One ledger-shaped observation → the matching calibrated constant
     (per-dispatch achieved rate, dispatch overhead subtracted so a small
     batch doesn't read as a slow kernel). Returns 1 when recorded."""
     if seconds <= _MIN_OBS_SECONDS or dispatches <= 0:
         return 0
-    skey = strategy if strategy in ("hash", "sort") else None
     from . import costmodel
     eff_s = max(seconds - costmodel.DEV_DISPATCH_S * dispatches,
                 seconds * 0.1)
-    name = _FAMILY_BYTES.get((kind, skey)) or _FAMILY_BYTES.get((kind, None))
+    name = _FAMILY_BYTES.get(kind)
     if name is not None and nbytes >= _MIN_OBS_BYTES:
         observe(name, nbytes / eff_s, weight=dispatches)
         return 1
-    name = _FAMILY_ROWS.get((kind, skey)) or _FAMILY_ROWS.get((kind, None))
+    name = _FAMILY_ROWS.get(kind)
     if name is not None and rows >= _MIN_OBS_ROWS:
         observe(name, rows / eff_s, weight=dispatches)
         return 1
     return 0
 
 
-def observe_dispatch(kind: str, strategy: Optional[str], rows: float,
-                     nbytes: float, seconds: float,
+def observe_dispatch(kind: str, rows: float, nbytes: float, seconds: float,
                      dispatches: float = 1.0) -> None:
     """Live chokepoint, called by ``costmodel.ledger_record`` at every
     real dispatch. Cheap gate first: the common (calibration-off) path
     is one function call and a dict read."""
     if not enabled():
         return
-    _observe_family(kind, strategy, rows=rows, nbytes=nbytes,
-                    seconds=seconds, dispatches=dispatches)
+    _observe_family(kind, rows=rows, nbytes=nbytes, seconds=seconds,
+                    dispatches=dispatches)
 
 
 # ------------------------------------------------------------------ reads
@@ -500,10 +493,8 @@ def costmodel_defaults() -> Dict[str, float]:
     return {
         "DEV_VECTOR_BPS": cm.DEV_VECTOR_BPS,
         "DEV_AGG_BPS": cm.DEV_AGG_BPS,
-        "DEV_AGG_HASH_BPS": cm.DEV_AGG_HASH_BPS,
         "DEV_SORT_ROWS_PER_S": cm.DEV_SORT_ROWS_PER_S,
         "DEV_JOIN_ROWS_PER_S": cm.DEV_JOIN_ROWS_PER_S,
-        "DEV_JOIN_HASH_ROWS_PER_S": cm.DEV_JOIN_HASH_ROWS_PER_S,
         "SHUFFLE_WIRE_BPS":
             (knobs.REGISTRY["DAFT_TPU_SHUFFLE_WIRE_MBPS"].default or 1000.0)
             * 1e6,

@@ -51,16 +51,9 @@ HOST_PIL_BPS = 85e6             # per-image PIL resize, input bytes/s
 # device-side terms: without these a zero-cost link (CPU backend, local
 # HBM) degenerates to "device always wins" no matter how slow the kernel
 DEV_VECTOR_BPS = 8.0e9      # fused elementwise XLA, per byte touched
-DEV_AGG_BPS = 4.0e9         # fused grouped-agg (sort strategy), per byte
-DEV_AGG_HASH_BPS = 8.0e9    # one-pass hash grouped-agg, per byte touched
-DEV_AGG_DENSE_BPS = 1.6e10  # direct-indexed dense grouped-agg (round 21):
-#                             pure arithmetic group ids + one scatter pass
-#                             per plane — no sort, no table
+DEV_AGG_BPS = 4.0e9         # fused grouped-agg, per byte touched
 DEV_SORT_ROWS_PER_S = 50.0e6    # XLA multi-key sort, rows/s
 DEV_JOIN_ROWS_PER_S = 40.0e6    # sort/searchsorted/expand join, rows/s
-DEV_JOIN_HASH_ROWS_PER_S = 80.0e6  # hash build/probe join, rows/s: ONE
-#                             pass per side instead of the build-side
-#                             radix sort's ≥2 passes per plane
 DEV_DISPATCH_S = 2.0e-3     # per-decision executable launch + (amortized)
 #                             shape-bucket compile overhead
 INVEST_MAX_RATIO = 8.0      # max cache-fill cost vs one host pass (see
@@ -366,20 +359,17 @@ kernel_ledger: dict = {}
 _ledger_lock = threading.Lock()
 
 _LEDGER_RAW = ("dispatches", "rows", "bytes", "flops", "seconds")
-#: strategy accounting (round 12): per-family hash/sort dispatch counts
-#: plus the summed hash-table load factor — the per-query stats block
-#: derives `strategy` and the mean `load_factor` from these.  ``serial_s``
+#: strategy accounting: per-family sort/dense dispatch counts — the
+#: per-query stats block derives `strategy` from these.  ``serial_s``
 #: (round 17) is the serial-equivalent stage seconds the async pipeline
 #: measured against its pipelined wall — the overlap evidence.
-_LEDGER_STRATEGY = ("strategy_hash", "strategy_sort", "strategy_dense",
-                    "lf_sum", "serial_s", "fused_ops", "rt_saved",
-                    "fusion_serial_s")
+_LEDGER_STRATEGY = ("strategy_sort", "strategy_dense", "serial_s",
+                    "fused_ops", "rt_saved", "fusion_serial_s")
 
 
 def ledger_record(kind: str, *, rows: int = 0, nbytes: float = 0.0,
                   flops: float = 0.0, seconds: float = 0.0,
                   dispatches: int = 1, strategy: Optional[str] = None,
-                  load_factor: Optional[float] = None,
                   serial_seconds: Optional[float] = None,
                   fused_ops: Optional[int] = None,
                   round_trips_saved: Optional[int] = None,
@@ -391,9 +381,8 @@ def ledger_record(kind: str, *, rows: int = 0, nbytes: float = 0.0,
     LOWER bound on silicon utilization (the synthetic ``mfu.report``
     isolates the silicon with in-jit repetition). ``nbytes``/``flops``
     are the kernel's modeled HBM traffic / arithmetic, conservative.
-    ``strategy`` (``hash``/``sort``/``dense``) and the hash table's
-    achieved ``load_factor`` land in the same family row for the stats
-    block. The ``region`` family (round 21) additionally carries
+    ``strategy`` (``sort``/``dense``) lands in the same family row for
+    the stats block. The ``region`` family (round 21) additionally carries
     ``fused_ops`` (operators compiled into the region programs),
     ``round_trips_saved`` (host round-trips the fusion eliminated vs the
     per-fragment chain), and ``fusion_serial_seconds`` — the modeled
@@ -403,10 +392,8 @@ def ledger_record(kind: str, *, rows: int = 0, nbytes: float = 0.0,
     fields = [("dispatches", dispatches), ("rows", rows),
               ("bytes", float(nbytes)), ("flops", float(flops)),
               ("seconds", float(seconds))]
-    if strategy in ("hash", "sort", "dense"):
+    if strategy in ("sort", "dense"):
         fields.append((f"strategy_{strategy}", dispatches))
-    if load_factor is not None:
-        fields.append(("lf_sum", float(load_factor) * dispatches))
     if serial_seconds is not None:
         fields.append(("serial_s", float(serial_seconds)))
     if fused_ops is not None:
@@ -432,7 +419,7 @@ def ledger_record(kind: str, *, rows: int = 0, nbytes: float = 0.0,
     # rate feeds the learned cost-model profile (no-op unless
     # DAFT_TPU_CALIBRATION is on and the chaos freeze is off)
     from . import calibration
-    calibration.observe_dispatch(kind, strategy, rows=rows, nbytes=nbytes,
+    calibration.observe_dispatch(kind, rows=rows, nbytes=nbytes,
                                  seconds=seconds, dispatches=dispatches)
     # tracing plane: one span per real dispatch, carrying the ledger's
     # roofline story onto the query timeline (guard-checked: untraced
@@ -443,8 +430,6 @@ def ledger_record(kind: str, *, rows: int = 0, nbytes: float = 0.0,
         attrs = {"rows": rows, "bytes": int(nbytes), "flops": int(flops)}
         if strategy:
             attrs["strategy"] = strategy
-        if load_factor is not None:
-            attrs["load_factor"] = round(float(load_factor), 3)
         if seconds > 0:
             attrs["gbps"] = round(nbytes / seconds / 1e9, 3)
             for key, rate, peak in (
@@ -476,16 +461,13 @@ def _derive(d: dict) -> dict:
             if pct is not None:
                 out["mfu_pct"] = pct
     counts = {nm: int(d.get(f"strategy_{nm}", 0))
-              for nm in ("hash", "sort", "dense")}
+              for nm in ("sort", "dense")}
     ran = [nm for nm, c in counts.items() if c]
     if ran:
         out["strategy"] = ran[0] if len(ran) == 1 else "mixed"
         if len(ran) > 1:
             for nm in ran:
                 out[f"strategy_{nm}"] = counts[nm]
-    nh = counts["hash"]
-    if nh and d.get("lf_sum"):
-        out["load_factor"] = round(d["lf_sum"] / nh, 3)
     ser = d.get("serial_s", 0.0)
     if ser and s > 0:
         # round 17 overlap evidence: serial-equivalent stage seconds vs
@@ -736,7 +718,7 @@ def argsort_wins(n_rows: int, key_bytes: float, n_keys: int) -> bool:
 def agg_upload_wins(bytes_up: float, bytes_down: float,
                     cacheable: bool, round_trips: float = 2.0,
                     host_bytes: Optional[float] = None,
-                    strategy: str = "sort", window: int = 1) -> bool:
+                    window: int = 1) -> bool:
     """Aggregation whose inputs are NOT already device-resident.
 
     ``bytes_up`` is the WIRE cost (encoded device bytes: f64 rides f32,
@@ -769,13 +751,7 @@ def agg_upload_wins(bytes_up: float, bytes_down: float,
     lp = link_profile()
     host_s = (host_bytes if host_bytes is not None else bytes_up) \
         / HOST_AGG_BPS
-    # round 12: the fused-agg gate prices the kernel at the strategy the
-    # dispatch would actually take — the one-pass hash kernel streams the
-    # data once where the sort strategy pays ≥2 passes per packed plane
-    bps = _cal("DEV_AGG_HASH_BPS", DEV_AGG_HASH_BPS) if strategy == "hash" \
-        else _cal("DEV_AGG_DENSE_BPS", DEV_AGG_DENSE_BPS) \
-        if strategy == "dense" else _cal("DEV_AGG_BPS", DEV_AGG_BPS)
-    kernel_s = DEV_DISPATCH_S + bytes_up / bps
+    kernel_s = DEV_DISPATCH_S + bytes_up / _cal("DEV_AGG_BPS", DEV_AGG_BPS)
     # round 17: with the async pipeline active (window ≥ 2 in-flight
     # morsel slots) the transfer legs overlap neighbor morsels' compute,
     # so the dispatch is priced at the steady-state bottleneck instead
@@ -1141,23 +1117,17 @@ def spill_plan_wins(nbytes: float, resident_budget: float) -> bool:
 
 def join_wins(n_left: int, n_right: int, bytes_up: float,
               bytes_down: float, window: int = 1) -> bool:
-    """Equi-join as one fused device program (hash build/probe when the
-    strategy model picks it, else sort/searchsorted/expand): output is
-    one packed index matrix; host cost is a hash build+probe. ONE
-    dispatch and ONE result transfer (the r5 three-phase pipeline paid 3
-    dispatches + 4 round trips). Round 12 re-pricing: when the hash
-    strategy would run, the kernel term uses the one-pass hash rate
-    instead of the radix-sort rate — the device now affords joins the
-    sort pricing declined."""
+    """Equi-join as one fused device program (sort/searchsorted/expand):
+    output is one packed index matrix; host cost is a hash build+probe.
+    ONE dispatch and ONE result transfer (the r5 three-phase pipeline
+    paid 3 dispatches + 4 round trips)."""
     f = _forced()
     if f is not None:
         return f
     n = n_left + n_right
     host_s = n / HOST_JOIN_ROWS_PER_S
-    rate = _cal("DEV_JOIN_HASH_ROWS_PER_S", DEV_JOIN_HASH_ROWS_PER_S) \
-        if _join_strategy(n_left, n_right) == "hash" \
-        else _cal("DEV_JOIN_ROWS_PER_S", DEV_JOIN_ROWS_PER_S)
-    kernel_s = DEV_DISPATCH_S + n / rate
+    kernel_s = DEV_DISPATCH_S \
+        + n / _cal("DEV_JOIN_ROWS_PER_S", DEV_JOIN_ROWS_PER_S)
     lp = link_profile()
     # round 17: overlap pricing when the async pipeline is active (the
     # join's upload/download legs hide behind neighbor dispatches)
@@ -1169,133 +1139,13 @@ def join_wins(n_left: int, n_right: int, bytes_up: float,
     return dev_s < host_s
 
 
-# ------------------------------------------------ kernel strategy (round 12)
-
-#: backends whose compiler has been SHOWN to take the Pallas hash kernels
-#: (``pallas_kernels.hash_grouped_agg_impl`` / ``hash_join_impl``) with
-#: ``interpret=False``. Empty: asked for a described ``v5e:2x2`` (JAX 0.9.0
-#: / libtpu 0.0.34, PR 23) the TPU kernel compiler answers, for both,
-#:   NotImplementedError: Unimplemented primitive in Pallas TPU lowering
-#:   for KernelType.TC: dynamic_slice
-#: — the kernel bodies index vectors with traced scalars, scatter with
-#: ``.at[j].set/add/min/max`` inside ``fori_loop``/``while_loop`` and carry
-#: ``uint64`` words, none of which Mosaic takes; ``dynamic_slice`` is only
-#: the first refusal. The CPU runs them under the Pallas interpreter, which
-#: exists for parity, not speed. A PR that makes them lower adds its
-#: backend here, with ``tests/test_tpu_compile.py`` cases to prove it.
-_HASH_KERNELS_COMPILE_ON: frozenset = frozenset()
-
-
-def _hash_capable_backend() -> bool:
-    """Do the Pallas hash kernels COMPILE for the attached backend? Today
-    no backend qualifies (see ``_HASH_KERNELS_COMPILE_ON``), so ``auto``
-    resolves to the XLA sort strategy everywhere. A forced
-    ``DAFT_TPU_KERNEL_GROUPBY=hash`` / ``DAFT_TPU_KERNEL_JOIN=hash`` is
-    honoured as asked: on the CPU the interpreter runs it, on an
-    accelerator the compiler's refusal reaches the user — it never drops
-    to the interpreter and never silently gives way to sort."""
-    from . import backend
-    return (backend.backend_name() or "cpu") in _HASH_KERNELS_COMPILE_ON
-
-
-def _join_strategy(n_left: int, n_right: int) -> str:
-    """Hash-vs-sort for the device join, without logging (join_wins
-    pre-prices with it; ``join_strategy`` is the logged decision the
-    dispatch site acts on)."""
-    from ..analysis import knobs
-    from . import pallas_kernels as pk
-    forced = (knobs.env_str("DAFT_TPU_KERNEL_JOIN") or "auto").lower()
-    if forced in ("hash", "sort"):
-        return forced
-    if not _hash_capable_backend():
-        return "sort"
-    from .column import bucket_capacity
-    if pk.join_table_capacity(bucket_capacity(max(n_right, 1))) \
-            > pk.max_table_slots():
-        return "sort"  # build table exceeds the on-chip budget
-    if bucket_capacity(max(n_left, n_right, 1)) > pk.max_table_slots():
-        # the probe kernel pins two output-capacity-sized index planes
-        # on-chip (whole-plane BlockSpecs), and the first dispatch's
-        # bucket is sized from the larger side — past the slot ceiling
-        # those planes belong to the sort kernel, whose buffers live
-        # in HBM
-        return "sort"
-    # the hash build streams each side once; the sort build pays ≥2
-    # passes over the build planes — one-pass wins whenever it fits
-    return "hash"
-
-
-def join_strategy(n_left: int, n_right: int) -> str:
-    """The join kernel strategy for this dispatch, logged like every
-    other decision (``join_strategy`` in decision_counts / the dispatch
-    log; "device" = hash)."""
-    s = _join_strategy(n_left, n_right)
-    _log("join_strategy", s == "hash", 0.0, 0.0,
-         n_left=n_left, n_right=n_right, strategy=s)
-    return s
-
-
-def groupby_strategy(rows: int, groups: Optional[float],
-                     key_dtypes, out_cap: int,
-                     log: bool = True) -> Tuple[str, float]:
-    """Hash-vs-sort for one grouped-agg dispatch → ``(strategy,
-    est_load_factor)``. ``log=False`` for pricing-only pre-asks (upload
-    gates) so decision_counts tallies acted-on dispatches, not estimates.
-
-    Evidence, best-first: the parquet-footer NDV that already flows to
-    the fused-agg gate (``groups``), else the group budget ``out_cap``.
-    The hash path declines when (a) the key set packs wider than the
-    table key budget (``pallas_kernels.hash_pack_words`` → sort handles
-    any width as an LSD radix), (b) the table exceeds the on-chip slot
-    ceiling, (c) footer evidence shows near-unique keys
-    (``DAFT_TPU_KERNEL_HASH_NDV_FRAC``: the table grows as large as the
-    data and the one-pass advantage is gone — TPC-H Q18's shape; absent
-    evidence is NOT evidence of high NDV, matching the fused-agg gate's
-    optimistic default), or (d) the backend's compiler does not take the
-    Pallas kernels (``_hash_capable_backend``: every backend, today).
-    ``DAFT_TPU_KERNEL_GROUPBY=hash|sort`` force-overrides (hash still
-    requires a packable key set). Logged under ``groupby_strategy``
-    ("device" = hash)."""
-    from ..analysis import knobs
-    from . import calibration
-    from . import pallas_kernels as pk
-    words = pk.hash_pack_words(key_dtypes) if key_dtypes else None
-    table = pk.table_capacity(max(out_cap, 1))
-    # footer NDV evidence damped by the calibrated actual/footer ratio
-    # (round 20): over-predicted NDV pushed dispatches onto the sort
-    # path whose one-pass hash rival would have won
-    ndv = max(groups * calibration.ndv_ratio(), 1.0) if groups \
-        else float(out_cap)
-    lf = min(ndv / table, 1.0)
-    forced = (knobs.env_str("DAFT_TPU_KERNEL_GROUPBY") or "auto").lower()
-    if forced == "sort" or words is None:
-        s = "sort"
-    elif forced == "hash":
-        s = "hash"
-    elif not _hash_capable_backend():
-        s = "sort"
-    elif table > pk.max_table_slots():
-        s = "sort"
-    elif groups and rows > 0 and ndv / rows > knobs.env_float(
-            "DAFT_TPU_KERNEL_HASH_NDV_FRAC"):
-        s = "sort"
-    else:
-        from . import mfu
-        sort_bytes = mfu.grouped_agg_models(
-            rows, out_cap, max(len(key_dtypes), 1), 1)[1]
-        hash_bytes = mfu.hash_agg_models(rows, out_cap, table, words, 1)[1]
-        s = "hash" if hash_bytes < sort_bytes else "sort"
-    if log:
-        log_strategy_decision("groupby_strategy", s, rows=rows,
-                              groups=float(ndv), out_cap=out_cap,
-                              load_factor=lf)
-    return s, lf
-
+# ------------------------------------------------------ kernel strategy
 
 def log_strategy_decision(kind: str, strategy: str, **extras) -> None:
-    """Tally an ACTED-ON kernel-strategy decision. Dispatch sites call
-    this once the strategy really ran (after width-gate fallbacks);
-    pricing-only pre-asks pass ``log=False`` to the strategy model and
-    stay out of ``decision_counts`` — the counts and the dispatch log
-    describe what dispatched, not what was estimated."""
-    _log(kind, strategy == "hash", 0.0, 0.0, strategy=strategy, **extras)
+    """Tally the grouped-aggregate strategy (``dense`` / ``sort``) a
+    dispatch site really ran, once a dispatch. The tally lands on the
+    "host" side of ``decision_counts``: its "device" side stood for a
+    third strategy that is gone, and the benchmark's
+    ``device_decisions_pct`` sums every kind, so moving the tally would
+    move that metric."""
+    _log(kind, False, 0.0, 0.0, strategy=strategy, **extras)

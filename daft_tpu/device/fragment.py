@@ -31,7 +31,7 @@ from jax import lax
 from ..expressions.expressions import Expression
 from ..schema import Schema
 from . import column as dcol
-from . import compiler, kernels, pallas_kernels, runtime
+from . import compiler, kernels, runtime
 
 _fused_cache: Dict[Tuple, object] = {}
 _fused_counters: Dict[str, int] = {"hits": 0, "misses": 0}
@@ -86,9 +86,6 @@ class FusedAggProgram:
         self.ops = ops
         self.has_pred = has_pred
         self.meta = meta                # trace-time dtype layout
-        #: the hash kernel raised (key set packs wider than the table key
-        #: budget at trace time) — every later dispatch stays on sort
-        self.hash_unfit = False
         #: column → device numpy dtype (set by get_fused_agg; None when
         #: an input is not device-representable) — the AOT warm-up grid
         self.in_np_dtypes = None
@@ -110,19 +107,6 @@ class FusedAggProgram:
                 static_argnames=("out_cap", "strategy", "dims"),
                 donate_argnums=(0, 1))
         return self._donate_fn
-
-    def key_plane_dtypes(self):
-        """Device dtypes of the group-key planes, for the hash-vs-sort
-        strategy width check. String/binary keys ride sorted-dictionary
-        codes (int32, ``column._np_encode``); the kernel's own trace
-        re-derives the exact pack from the real planes and raises if this
-        estimate was too narrow (dispatch sites catch → sort)."""
-        out = []
-        for f in self.compiled.out_fields[:self.nk]:
-            rep = f.dtype.device_repr() \
-                if not (f.dtype.is_string() or f.dtype.is_binary()) else None
-            out.append(np.dtype(rep) if rep is not None else np.dtype("int32"))
-        return out
 
 
 def get_fused_agg(group_exprs: List[Expression], child_exprs: List[Expression],
@@ -178,17 +162,14 @@ def get_fused_agg(group_exprs: List[Expression], child_exprs: List[Expression],
             meta["global_dtypes"] = [x.dtype for x in flat]
             with jax.named_scope("pack-i64"):
                 return jnp.stack([_pack_i64(x.reshape(())) for x in flat])
-        # round 12: the whole scan→filter→project→agg chain stays ONE jit
-        # program either way — `strategy` only swaps the reduction's inner
-        # loop (dense direct slot indexing vs one-pass Pallas hash table
-        # vs radix sort + segment reduce)
+        # the whole scan→filter→project→agg chain stays ONE jit program
+        # either way — `strategy` only swaps the reduction's inner loop
+        # (dense direct slot indexing vs radix sort + segment reduce)
         if strategy == "dense":
             ok, okv, ov, ovv, g = kernels.grouped_agg_dense_impl(
                 keys, kvalids, vals, vvalids, row_mask, ops, out_cap, dims)
         else:
-            impl = pallas_kernels.hash_grouped_agg_impl \
-                if strategy == "hash" else kernels.grouped_agg_block_impl
-            ok, okv, ov, ovv, g = impl(
+            ok, okv, ov, ovv, g = kernels.grouped_agg_block_impl(
                 keys, kvalids, vals, vvalids, row_mask, ops, out_cap)
         flat = list(ok) + list(okv) + list(ov) + list(ovv)
         meta["grouped_dtypes"] = [x.dtype for x in flat]
@@ -233,16 +214,15 @@ def fused_programs() -> List[FusedAggProgram]:
 
 
 def run_fused_agg(prog: FusedAggProgram, batch, group_exprs, agg_exprs,
-                  out_schema: Schema, groups: Optional[float] = None):
+                  out_schema: Schema):
     """Execute the fused program on one RecordBatch; returns a RecordBatch of
     partial groups (or None → caller falls back to the host chain)."""
-    tok = submit_fused_agg(prog, batch, group_exprs, agg_exprs, out_schema,
-                           groups=groups)
+    tok = submit_fused_agg(prog, batch, group_exprs, agg_exprs, out_schema)
     return None if tok is None else drain_fused_agg_table(tok)
 
 
 def submit_fused_agg(prog: FusedAggProgram, batch, group_exprs, agg_exprs,
-                     out_schema: Schema, groups: Optional[float] = None):
+                     out_schema: Schema):
     """Pipeline submit half of :func:`run_fused_agg`: host encode +
     asynchronous dispatch of the first ladder rung, NO blocking fetch.
     Returns an in-flight token for :func:`drain_fused_agg_table`, or
@@ -253,7 +233,6 @@ def submit_fused_agg(prog: FusedAggProgram, batch, group_exprs, agg_exprs,
     dt = dcol.encode_batch(batch, prog.compiled.needs_cols)
     return submit_fused_agg_table(
         prog, dt, batch.schema, group_exprs, agg_exprs, out_schema,
-        groups=groups,
         # the donating fast path invalidates the input planes; an overflow
         # re-dispatch re-encodes from the host batch we still hold
         reencode=lambda: dcol.encode_batch(batch, prog.compiled.needs_cols))
@@ -289,7 +268,7 @@ def _dispatch_packed(prog: FusedAggProgram, dt: dcol.DeviceTable,
 
 #: dense-strategy slot ceiling: K = prod(dim+1) static slots per dispatch;
 #: past this the slot planes outgrow the group blocks they stand in for
-#: and hash/sort territory begins anyway
+#: and sort territory begins anyway
 DENSE_MAX_SLOTS = 4096
 
 
@@ -342,35 +321,6 @@ def _donation_ok(dt: dcol.DeviceTable) -> bool:
     ignores donation there and warns per executable)."""
     from . import backend
     return backend.is_accelerator() and not dt.resident
-
-
-def gate_strategy(prog: FusedAggProgram, rows: int,
-                  groups: Optional[float] = None) -> str:
-    """Pricing-only strategy pre-ask for the upload gates (unlogged —
-    decision_counts should tally acted-on dispatches, not estimates)."""
-    from . import costmodel
-    if prog.nk == 0 or prog.hash_unfit:
-        return "sort"
-    return costmodel.groupby_strategy(rows, groups,
-                                      prog.key_plane_dtypes(), _OUT_CAP0,
-                                      log=False)[0]
-
-
-def strategy_for(prog: FusedAggProgram, dt: dcol.DeviceTable, out_cap: int,
-                 groups: Optional[float] = None) -> Tuple[str, float]:
-    """Hash-vs-sort for one fused-agg dispatch → ``(strategy, load)``.
-    Evidence, best-first: the planner's parquet-footer NDV (``groups``),
-    else the group-capacity bucket. A program whose key set already proved
-    unpackable stays on sort without re-asking. UNLOGGED — the dispatch
-    sites call ``costmodel.log_strategy_decision`` once the dispatch
-    really ran (a width-gate trace failure can still flip the answer),
-    so decision_counts describes what dispatched, not what was asked."""
-    from . import costmodel
-    if prog.nk == 0 or prog.hash_unfit:
-        return "sort", 0.0
-    return costmodel.groupby_strategy(dt.row_count, groups,
-                                      prog.key_plane_dtypes(), out_cap,
-                                      log=False)
 
 
 def _decode_packed_global(prog: FusedAggProgram, packed: np.ndarray,
@@ -450,29 +400,17 @@ def _max_out_cap(prog: FusedAggProgram, dt: dcol.DeviceTable) -> int:
 
 def _ledger_grouped(prog: FusedAggProgram, rows: int, cap: int,
                     out_cap: int, seconds: float, dispatches: int,
-                    strategy: str = "sort", load_factor: float = 0.0
-                    ) -> None:
+                    strategy: str) -> None:
     """Per-dispatch MFU accounting for the fused grouped-agg family; the
     byte model follows the strategy the dispatch actually ran."""
     from . import costmodel, mfu
-    if strategy == "hash":
-        words = pallas_kernels.hash_pack_words(prog.key_plane_dtypes()) or 2
-        flops, nbytes = mfu.hash_agg_models(
-            cap, out_cap, pallas_kernels.table_capacity(out_cap), words,
-            len(prog.ops))
-    elif strategy == "dense":
-        flops, nbytes = mfu.dense_agg_models(cap, out_cap,
-                                             max(prog.nk, 1),
-                                             len(prog.ops))
-    else:
-        flops, nbytes = mfu.grouped_agg_models(cap, out_cap,
-                                               max(prog.nk, 1),
-                                               len(prog.ops))
+    model = mfu.dense_agg_models if strategy == "dense" \
+        else mfu.grouped_agg_models
+    flops, nbytes = model(cap, out_cap, max(prog.nk, 1), len(prog.ops))
     costmodel.ledger_record("grouped_agg", rows=rows,
                             nbytes=dispatches * nbytes,
                             flops=dispatches * flops, seconds=seconds,
-                            dispatches=dispatches, strategy=strategy,
-                            load_factor=load_factor or None)
+                            dispatches=dispatches, strategy=strategy)
 
 
 def _ledger_global(prog: FusedAggProgram, rows: int, cap: int,
@@ -491,28 +429,26 @@ def _ledger_global(prog: FusedAggProgram, rows: int, cap: int,
 class InflightFusedAgg:
     """One in-flight fused-agg dispatch: the device-side packed result
     plus the ladder state a drain needs to finish (overflow re-dispatch,
-    per-strategy ledger accounting)."""
+    ledger accounting)."""
 
     __slots__ = ("prog", "dt", "group_exprs", "key_fields", "agg_fields",
-                 "groups", "reencode", "cap_limit", "out_cap", "donate",
-                 "strategy", "lf", "dims", "packed", "t0", "submitted_s",
-                 "acct")
+                 "reencode", "cap_limit", "out_cap", "donate",
+                 "strategy", "dims", "packed", "t0", "submitted_s",
+                 "dispatches")
 
     def __init__(self, prog, dt, group_exprs, key_fields, agg_fields,
-                 groups, reencode):
+                 reencode):
         import time as _time
         self.prog = prog
         self.dt = dt
         self.group_exprs = group_exprs
         self.key_fields = key_fields
         self.agg_fields = agg_fields
-        self.groups = groups
         self.reencode = reencode
         self.cap_limit = 0
         self.out_cap = _OUT_CAP0
         self.donate = False
-        self.strategy: Optional[str] = None
-        self.lf = 0.0
+        self.strategy = "sort"
         self.dims: Tuple[int, ...] = ()
         self.packed = None
         self.t0 = _time.perf_counter()
@@ -521,54 +457,25 @@ class InflightFusedAgg:
         #: async window would include time the token sat undrained and
         #: deflate the achieved-GB/s evidence
         self.submitted_s = 0.0
-        self.acct: Dict[str, list] = {}  # strategy → [dispatches, lf, cap]
+        self.dispatches = 0   # ladder rungs dispatched so far
 
 
 def _ladder_dispatch(tok: InflightFusedAgg) -> None:
-    """Dispatch the current ladder rung asynchronously (no fetch),
-    handling the hash width-gate fallback and decision logging."""
+    """Dispatch the current ladder rung asynchronously (no fetch) and
+    tally the strategy it ran."""
     from . import costmodel
-    while True:
-        if tok.strategy is None:
-            # dense first: a direct-indexed dispatch streams the rows once
-            # with no sort and no table, so whenever the key dictionaries
-            # fit the slot budget it dominates both rivals
-            plan = dense_plan(tok.prog, tok.dt, tok.cap_limit)
-            if plan is not None:
-                tok.dims, tok.out_cap = plan
-                tok.strategy, tok.lf = "dense", 0.0
-            else:
-                tok.dims = ()
-                tok.strategy, tok.lf = strategy_for(tok.prog, tok.dt,
-                                                    tok.out_cap, tok.groups)
-        try:
-            tok.packed = _dispatch_packed(tok.prog, tok.dt, tok.out_cap,
-                                          tok.strategy, tok.donate,
-                                          tok.dims)
-        except pallas_kernels.HashKeyWidthError:
-            # key set packs wider than the hash-table key budget — the
-            # kernel's trace is the exact check; remember and re-dispatch
-            # on the sort path (donation untouched: the trace failed
-            # before any executable could consume the buffers). Any
-            # OTHER error propagates — it is a real defect, not a
-            # routing signal.
-            tok.prog.hash_unfit = True
-            tok.strategy, tok.lf = "sort", 0.0
-            continue
-        tok.acct[tok.strategy] = [
-            tok.acct.get(tok.strategy, [0])[0] + 1, tok.lf, tok.out_cap]
-        # the decision that actually dispatched (post width-gate fallback)
-        costmodel.log_strategy_decision(
-            "groupby_strategy", tok.strategy, rows=tok.dt.row_count,
-            out_cap=tok.out_cap, load_factor=tok.lf)
-        return
+    tok.packed = _dispatch_packed(tok.prog, tok.dt, tok.out_cap,
+                                  tok.strategy, tok.donate, tok.dims)
+    tok.dispatches += 1
+    costmodel.log_strategy_decision(
+        "groupby_strategy", tok.strategy, rows=tok.dt.row_count,
+        out_cap=tok.out_cap)
 
 
 def submit_fused_agg_table(prog: FusedAggProgram, dt: dcol.DeviceTable,
                            in_schema: Schema, group_exprs, agg_exprs,
                            out_schema: Schema,
-                           start_out_cap: int = _OUT_CAP0,
-                           groups: Optional[float] = None, reencode=None
+                           start_out_cap: int = _OUT_CAP0, reencode=None
                            ) -> InflightFusedAgg:
     """Async submit half of :func:`run_fused_agg_table`: dispatch the
     first ladder rung and return without blocking on the result — the
@@ -577,7 +484,7 @@ def submit_fused_agg_table(prog: FusedAggProgram, dt: dcol.DeviceTable,
     agg_fields = [out_schema[e.name()] for e in agg_exprs]
     import time as _time
     tok = InflightFusedAgg(prog, dt, group_exprs, key_fields, agg_fields,
-                           groups, reencode)
+                           reencode)
     if prog.nk == 0:
         tok.packed = _dispatch_packed(prog, dt, _OUT_CAP0)
         tok.submitted_s = _time.perf_counter() - tok.t0
@@ -585,6 +492,13 @@ def submit_fused_agg_table(prog: FusedAggProgram, dt: dcol.DeviceTable,
     tok.cap_limit = _max_out_cap(prog, dt)
     tok.out_cap = min(start_out_cap, tok.cap_limit)
     tok.donate = reencode is not None and _donation_ok(dt)
+    # dense first: a direct-indexed dispatch streams the rows once with
+    # no sort, so whenever the key dictionaries fit the slot budget it
+    # wins; its bucket holds every slot, so only sort ever climbs a rung
+    plan = dense_plan(prog, dt, tok.cap_limit)
+    if plan is not None:
+        tok.strategy = "dense"
+        tok.dims, tok.out_cap = plan
     _ladder_dispatch(tok)
     tok.submitted_s = _time.perf_counter() - tok.t0
     return tok
@@ -619,50 +533,30 @@ def drain_fused_agg_table(tok: InflightFusedAgg):
                                          tok.agg_fields)
             sp.set("groups", len(out) if out is not None else 0)
         if out is not None:
-            # per-strategy accounting: an overflow ladder can MIX
-            # strategies (hash saturation falls back to sort), and each
-            # family row must count its own dispatches and byte model.
-            # The row count and whole-ladder wall go to the completing
-            # strategy's record. Submit wall + drain wall — NOT
-            # t0→now, which under the async window would charge time
-            # the token sat undrained behind its predecessors.
-            secs = tok.submitted_s + (_time.perf_counter() - t_drain0)
-            for s_, (cnt, l_, oc) in tok.acct.items():
-                final = s_ == tok.strategy
-                _ledger_grouped(prog, tok.dt.row_count if final else 0,
-                                tok.dt.capacity, oc,
-                                secs if final else 0.0, cnt, s_, l_)
+            # submit wall + drain wall — NOT t0→now, which under the
+            # async window would charge time the token sat undrained
+            # behind its predecessors
+            _ledger_grouped(prog, tok.dt.row_count, tok.dt.capacity,
+                            tok.out_cap,
+                            tok.submitted_s
+                            + (_time.perf_counter() - t_drain0),
+                            tok.dispatches, tok.strategy)
             return out
-        # the packed header carries the group count — TRUE for the sort
-        # strategy; the hash strategy saturates at the table size, so a
-        # saturated count is only a LOWER bound on the real NDV
+        # the packed header carries the true group count
         g = int(packed[0, 0])
         if g > tok.cap_limit:
             return None
         if tok.donate:
             tok.dt = tok.reencode()
-        saturated = tok.strategy == "hash" \
-            and g >= pallas_kernels.table_capacity(tok.out_cap)
         tok.out_cap = min(dcol.bucket_capacity(max(g, _OUT_CAP0)),
                           tok.cap_limit)
-        if saturated:
-            # a completely full table means the true count is unknown
-            # and high — re-dispatch on the sort path, whose header is
-            # exact, instead of geometrically doubling the hash bucket
-            # one full row pass (and, when donating, one re-encode) at
-            # a time; NDV this high is sort's territory anyway
-            tok.strategy, tok.lf = "sort", 0.0
-        else:
-            # the bucket changed: re-ask the strategy model (a grown
-            # group budget can push the table past the slot ceiling)
-            tok.strategy = None
         _ladder_dispatch(tok)
 
 
 def run_fused_agg_table(prog: FusedAggProgram, dt: dcol.DeviceTable,
                         in_schema: Schema, group_exprs, agg_exprs,
                         out_schema: Schema, start_out_cap: int = _OUT_CAP0,
-                        groups: Optional[float] = None, reencode=None):
+                        reencode=None):
     """Execute on one encoded DeviceTable (possibly HBM-cache-resident).
     Returns None (→ host fallback) when the group count exceeds the
     link-budgeted packed-output ceiling. With ``reencode`` (a thunk
@@ -673,7 +567,7 @@ def run_fused_agg_table(prog: FusedAggProgram, dt: dcol.DeviceTable,
     synchronous chaos-degradation path run the same ladder.)"""
     return drain_fused_agg_table(submit_fused_agg_table(
         prog, dt, in_schema, group_exprs, agg_exprs, out_schema,
-        start_out_cap=start_out_cap, groups=groups, reencode=reencode))
+        start_out_cap=start_out_cap, reencode=reencode))
 
 
 class InflightFusedAggBatch:
@@ -681,11 +575,11 @@ class InflightFusedAggBatch:
     DeviceTable) awaiting ONE batched pytree fetch."""
 
     __slots__ = ("prog", "tables", "in_schema", "group_exprs", "agg_exprs",
-                 "out_schema", "groups", "key_fields", "agg_fields",
-                 "strategy", "lf", "packs", "t0", "submitted_s", "failed")
+                 "out_schema", "key_fields", "agg_fields",
+                 "strategy", "packs", "t0", "submitted_s", "failed")
 
     def __init__(self, prog, tables, in_schema, group_exprs, agg_exprs,
-                 out_schema, groups):
+                 out_schema):
         import time as _time
         self.prog = prog
         self.tables = tables
@@ -693,11 +587,9 @@ class InflightFusedAggBatch:
         self.group_exprs = group_exprs
         self.agg_exprs = agg_exprs
         self.out_schema = out_schema
-        self.groups = groups
         self.key_fields = [e.to_field(in_schema) for e in group_exprs]
         self.agg_fields = [out_schema[e.name()] for e in agg_exprs]
         self.strategy = "sort"
-        self.lf = 0.0
         self.packs: list = []
         self.t0 = _time.perf_counter()
         self.submitted_s = 0.0   # dispatch wall (see InflightFusedAgg)
@@ -706,15 +598,13 @@ class InflightFusedAggBatch:
 
 def submit_fused_agg_tables(prog: FusedAggProgram, tables,
                             in_schema: Schema, group_exprs, agg_exprs,
-                            out_schema: Schema,
-                            groups: Optional[float] = None
-                            ) -> InflightFusedAggBatch:
+                            out_schema: Schema) -> InflightFusedAggBatch:
     """Async submit half of :func:`run_fused_agg_tables`: dispatch every
     table's fused program (no fetch).  Dispatch failures mark the token
     failed → the drain falls back per-table."""
     import time as _time
     tok = InflightFusedAggBatch(prog, tables, in_schema, group_exprs,
-                                agg_exprs, out_schema, groups)
+                                agg_exprs, out_schema)
     if not tables:
         return tok
     # dense first, per table: each morsel carries its own dictionaries
@@ -727,7 +617,7 @@ def submit_fused_agg_tables(prog: FusedAggProgram, tables,
         plans = [dense_plan(prog, dt, _max_out_cap(prog, dt))
                  for dt in tables]
     if all(p is not None for p in plans):
-        tok.strategy, tok.lf = "dense", 0.0
+        tok.strategy = "dense"
         try:
             tok.packs = [
                 _dispatch_packed(prog, dt, p[1], "dense", dims=p[0])
@@ -736,18 +626,13 @@ def submit_fused_agg_tables(prog: FusedAggProgram, tables,
             return tok
         except Exception as exc:
             # resource exhaustion only (anything else propagates): fall
-            # through to the hash/sort batch path, counted
+            # through to the sort batch path, counted
             runtime.device_failed("fragment.fused_agg_tables.dense", exc)
             tok.packs = []
-    tok.strategy, tok.lf = strategy_for(prog, tables[0], _OUT_CAP0, groups)
+    tok.strategy = "sort"
     try:
-        tok.packs = [_dispatch_packed(prog, dt, _OUT_CAP0, tok.strategy)
+        tok.packs = [_dispatch_packed(prog, dt, _OUT_CAP0, "sort")
                      for dt in tables]
-    except pallas_kernels.HashKeyWidthError:
-        prog.hash_unfit = True
-        return submit_fused_agg_tables(prog, tables, in_schema,
-                                       group_exprs, agg_exprs, out_schema,
-                                       groups)
     except Exception as exc:
         runtime.device_failed("fragment.fused_agg_tables.submit", exc)
         tok.failed = True
@@ -768,10 +653,9 @@ def drain_fused_agg_tables(tok: InflightFusedAggBatch):
         return []
     if tok.failed:
         return [None] * len(tables)
-    in_schema, group_exprs = tok.in_schema, tok.group_exprs
-    agg_exprs, out_schema, groups = tok.agg_exprs, tok.out_schema, tok.groups
+    group_exprs = tok.group_exprs
     key_fields, agg_fields = tok.key_fields, tok.agg_fields
-    strategy, lf = tok.strategy, tok.lf
+    strategy = tok.strategy
     t_drain0 = _time.perf_counter()
     try:
         stacked = [np.asarray(m) for m in pipeline.fetch_host(tok.packs)]
@@ -780,19 +664,18 @@ def drain_fused_agg_tables(tok: InflightFusedAggBatch):
         return [None] * len(tables)
     if prog.nk:
         from . import costmodel
-        # ONE decision acted on across the whole batch (post any
-        # width-gate recursion above)
+        # ONE decision acted on across the whole batch
         costmodel.log_strategy_decision(
             "groupby_strategy", strategy,
             rows=sum(dt.row_count for dt in tables), out_cap=_OUT_CAP0,
-            load_factor=lf, tables=len(tok.packs))
+            tables=len(tok.packs))
         # submit wall + fetch wall, excluding any in-window queue wait
         # between them (see InflightFusedAgg.submitted_s)
         _ledger_grouped(prog, sum(dt.row_count for dt in tables),
                         max(dt.capacity for dt in tables), _OUT_CAP0,
                         tok.submitted_s
                         + (_time.perf_counter() - t_drain0),
-                        len(tok.packs), strategy, lf)
+                        len(tok.packs), strategy)
     else:
         _ledger_global(prog, sum(dt.row_count for dt in tables),
                        max(dt.capacity for dt in tables),
@@ -827,19 +710,17 @@ def drain_fused_agg_tables(tok: InflightFusedAggBatch):
                 results[i] = None
         sp.set("groups", sum(len(r) for r in results if r is not None))
     if retry:
-        # a grown bucket can flip the strategy (table slot ceiling);
-        # re-ask per retried table
-        retry_strats = [strategy_for(prog, tables[i], cap, groups)
-                        for i, cap in retry]
+        # only the sort strategy overflows (a dense bucket holds every
+        # slot): the retried tables re-run it at their grown buckets
         try:
-            packs2 = [_dispatch_packed(prog, tables[i], cap, s)
-                      for (i, cap), (s, _l) in zip(retry, retry_strats)]
+            packs2 = [_dispatch_packed(prog, tables[i], cap, "sort")
+                      for i, cap in retry]
             mats = [np.asarray(m) for m in pipeline.fetch_host(packs2)]
             from . import costmodel
-            for (i, cap), (s, l_) in zip(retry, retry_strats):
+            for i, cap in retry:
                 costmodel.log_strategy_decision(
-                    "groupby_strategy", s, rows=tables[i].row_count,
-                    out_cap=cap, load_factor=l_)
+                    "groupby_strategy", "sort", rows=tables[i].row_count,
+                    out_cap=cap)
         except Exception as exc:
             runtime.device_failed("fragment.fused_agg_tables.retry", exc)
             mats = [None] * len(retry)
@@ -860,8 +741,7 @@ def drain_fused_agg_tables(tok: InflightFusedAggBatch):
 
 
 def run_fused_agg_tables(prog: FusedAggProgram, tables, in_schema: Schema,
-                         group_exprs, agg_exprs, out_schema: Schema,
-                         groups: Optional[float] = None):
+                         group_exprs, agg_exprs, out_schema: Schema):
     """Batched execution over many DeviceTables: dispatch every fused
     program asynchronously, then fetch ALL packed results in a single
     batched device→host transfer (one round of transfers for the whole
@@ -872,8 +752,7 @@ def run_fused_agg_tables(prog: FusedAggProgram, tables, in_schema: Schema,
     anyway.  (Single-sourced as submit + drain so the async pipeline
     overlaps window N+1's submit with window N's drain.)"""
     return drain_fused_agg_tables(submit_fused_agg_tables(
-        prog, tables, in_schema, group_exprs, agg_exprs, out_schema,
-        groups))
+        prog, tables, in_schema, group_exprs, agg_exprs, out_schema))
 
 
 # ---------------------------------------------------------------------------

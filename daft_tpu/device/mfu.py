@@ -107,49 +107,17 @@ def grouped_agg_models(cap: int, out_cap: int, n_keys: int,
     return flops, nbytes
 
 
-def hash_agg_models(cap: int, out_cap: int, table_cap: int, n_words: int,
-                    n_vals: int, val_bytes: int = 4):
-    """(flops, bytes) of one HASH grouped-agg dispatch (round 12): ONE
-    streaming pass over the packed key word(s) + liveness + each value
-    plane with its contrib mask, plus the table writeback (the table
-    planes live in on-chip memory across the row stream — the grid
-    revisits one block — so probe traffic never touches HBM). This is
-    the whole point next to :func:`grouped_agg_models`: the sort
-    formulation re-streams every packed plane ≥2x per radix pass and
-    pays the inverse-permutation sort on top. No MXU flops to claim —
-    the family is bandwidth-bound, so the roofline%% is the currency."""
-    row_bytes = cap * (8 * n_words + 1 + n_vals * (val_bytes + 1))
-    # key words + occupancy/first-row + ~3 state planes at 8B each
-    slot_bytes = 8 * n_words + 8 + (n_vals + 1) * 8
-    return 0.0, int(row_bytes + table_cap * slot_bytes)
-
-
 def dense_agg_models(cap: int, out_cap: int, n_keys: int, n_vals: int,
                      val_bytes: int = 4):
     """(flops, bytes) of one DENSE direct-indexed grouped-agg dispatch:
     one pass over each key-code plane (the mixed-radix group id is pure
     arithmetic), one scatter pass per reduced plane (values + the count
-    plane), and the [out_cap] slot planes. No sort, no table — the
-    lightest byte model of the three strategies, which is exactly why
-    the dispatch sites prefer it whenever the dictionaries fit."""
+    plane), and the [out_cap] slot planes. No sort — the lighter byte
+    model of the two strategies, which is exactly why the dispatch sites
+    prefer it whenever the dictionaries fit."""
     row_bytes = cap * (n_keys * 4 + 1 + (n_vals + 1) * (val_bytes + 1))
     slot_bytes = out_cap * (n_vals + 2) * 8
     return 0.0, int(row_bytes + slot_bytes)
-
-
-def hash_join_bytes_model(c_l: int, c_r: int, out_cap: int) -> int:
-    """Modeled HBM traffic of one hash join dispatch: one pass over each
-    side's key+liveness planes, the chain-link plane (written once per
-    build row, read once per emitted pair), the table writeback, and the
-    output pair/count writes — vs ``join_bytes_model``'s ≥2 sort passes
-    over the build planes plus two searchsorted probes."""
-    from . import pallas_kernels as pk
-    table = pk.join_table_capacity(c_r)
-    return int(c_r * (8 + 1 + 4)          # build keys + live + next-link
-               + table * (8 + 4 + 4 + 4)  # key/occ/head/tail writeback
-               + c_l * (8 + 1)            # probe keys + live
-               + out_cap * (4 + 4 + 4)    # owner/ridx/chain-read per pair
-               + c_l * 4)                 # counts
 
 
 # ------------------------------------------------------- timing harness
@@ -203,50 +171,6 @@ def measure_grouped_agg(n: int = 1 << 20, groups: int = 256,
         mfu_pct=flops / t, roofline_pct=bytes_touched / t)
 
 
-def measure_hash_grouped_agg(n: int = 1 << 20, groups: int = 256,
-                             n_vals: int = 2) -> Dict:
-    """Roofline % of the ONE-PASS hash grouped-agg (round 12): same shape
-    as :func:`measure_grouped_agg` so the two rows are directly
-    comparable — the hash row's win over the sort row IS the ledger's
-    promised improvement. interpret/block resolve OUTSIDE the jit (the
-    jit-hygiene contract), and the in-jit ``lax.fori_loop`` repetition
-    keeps link RTT out of the number, exactly like the sort kernels."""
-    from . import pallas_kernels as pk
-    rng = np.random.default_rng(0)
-    keys = jnp.asarray(rng.integers(0, groups, n).astype(np.int64))
-    valid = jnp.ones(n, dtype=bool)
-    vals = tuple(jnp.asarray(rng.uniform(0, 100, n).astype(np.float32))
-                 for _ in range(n_vals))
-    mask = jnp.ones(n, dtype=bool)
-    out_cap = max(256, groups)
-    ops = ("sum",) * n_vals
-    interpret = pk.interpret_default()
-    block = pk.block_rows(n)
-    table = pk.table_capacity(out_cap)
-
-    @partial(jax.jit, static_argnames=("iters",))
-    def run(k, kv, v, vv, m, iters: int):
-        def body(i, carry):
-            # 0/1 key perturbation: defeats loop-invariant code motion
-            # without changing the group structure's shape
-            k2 = k + carry.astype(k.dtype)
-            _, _, ov, _, g = pk.hash_grouped_agg_impl(
-                (k2,), (kv,), v, vv, m, ops, out_cap,
-                interpret=interpret, block=block)
-            return (g % 2).astype(jnp.int32)
-        return lax.fori_loop(0, iters, body, jnp.int32(0))
-
-    t = _timed_iters(run, (keys, valid, vals, (valid,) * n_vals, mask))
-    _, bytes_touched = hash_agg_models(n, out_cap, table, 1, n_vals)
-    return _with_pcts(
-        {"kernel": "grouped_agg_hash", "strategy": "hash", "rows": n,
-         "groups": groups, "table_slots": table,
-         "interpret": interpret, "iters": _ITERS,
-         "time_s": round(t, 6), "bytes": bytes_touched,
-         "achieved_gbps": round(bytes_touched / t / 1e9, 2)},
-        roofline_pct=bytes_touched / t)
-
-
 def measure_join(n: int = 1 << 20) -> Dict:
     """Roofline % of the FUSED sort-merge join kernel (one dispatch:
     build sort + probe counts + prefix-sum expansion)."""
@@ -268,42 +192,6 @@ def measure_join(n: int = 1 << 20) -> Dict:
     return _with_pcts(
         {"kernel": "join_fused", "strategy": "sort", "rows": n,
          "iters": _ITERS,
-         "time_s": round(t, 6), "bytes": bytes_touched,
-         "achieved_gbps": round(bytes_touched / t / 1e9, 2)},
-        roofline_pct=bytes_touched / t)
-
-
-def measure_hash_join(n: int = 1 << 20) -> Dict:
-    """Roofline % of the hash build/probe join — same key distribution
-    as :func:`measure_join` so the rows compare directly. ``n`` is
-    clamped so the measured configuration is one the strategy model
-    would actually dispatch: the build table is 2×``n`` slots and must
-    stay within ``DAFT_TPU_KERNEL_MAX_TABLE`` (an inadmissible config
-    fails to lower on silicon and would erase the roofline row)."""
-    from . import pallas_kernels as pk
-    n = max(min(n, pk.max_table_slots() // 2), 128)
-    rng = np.random.default_rng(1)
-    r_key = jnp.asarray(rng.integers(0, n // 2, n).astype(np.int64))
-    l_key = jnp.asarray(rng.integers(0, n // 2, n).astype(np.int64))
-    ones = jnp.ones(n, dtype=bool)
-    interpret = pk.interpret_default()
-    block = pk.block_rows(n)
-
-    @partial(jax.jit, static_argnames=("iters",))
-    def run(lk, rk, m, iters: int):
-        def body(i, carry):
-            packed = pk.hash_join_impl(
-                lk + carry.astype(lk.dtype), m, m, rk, m, m, n,
-                interpret=interpret, block=block)
-            return packed[2, 0] % 2
-        return lax.fori_loop(0, iters, body, jnp.int32(0))
-
-    t = _timed_iters(run, (l_key, r_key, ones))
-    bytes_touched = hash_join_bytes_model(n, n, n)
-    return _with_pcts(
-        {"kernel": "join_hash", "strategy": "hash", "rows": n,
-         "table_slots": pk.join_table_capacity(n),
-         "interpret": interpret, "iters": _ITERS,
          "time_s": round(t, 6), "bytes": bytes_touched,
          "achieved_gbps": round(bytes_touched / t / 1e9, 2)},
         roofline_pct=bytes_touched / t)
@@ -354,18 +242,5 @@ def report(n: int = 1 << 20) -> Dict:
         out["argsort"] = measure_argsort(n)
     except Exception as exc:  # a wedged backend must not kill the bench
         out["error"] = str(exc)[:200]
-    # hash-strategy rows (round 12). Under the Pallas INTERPRETER (CPU
-    # dev box) the kernels run as a python-level emulation — timings
-    # would measure the emulator, not silicon — so the rows shrink to a
-    # smoke size and are flagged `interpret`; roofline claims come from
-    # real-chip runs only (bench --kernels reports parity + dispatch
-    # contracts instead on CPU).
-    from . import pallas_kernels as pk
-    n_hash = n if not pk.interpret_default() else min(n, 1 << 12)
-    try:
-        out["grouped_agg_hash"] = measure_hash_grouped_agg(n_hash)
-        out["join_hash"] = measure_hash_join(n_hash)
-    except Exception as exc:
-        out["hash_error"] = str(exc)[:200]
     out["ledger"] = costmodel.ledger_snapshot()
     return out

@@ -436,26 +436,14 @@ def try_agg(batch, to_agg: List[Expression], group_by: List[Expression]):
     for nm in c.needs_cols:
         if batch.get_column(nm).is_pyobject():
             return None
-    # in-memory batch: no HBM-cache identity, the upload is one-shot.
-    # The strategy model runs FIRST so the gate prices the kernel the
-    # dispatch would actually take (one-pass hash vs radix sort) —
-    # UNLOGGED here: the gate below may still decline the upload, and
-    # decision_counts tallies acted-on dispatches, not estimates.
-    nk = len(group_by)
-    cap = dcol.bucket_capacity(max(len(batch), 1))
-    strategy, load_factor = ("sort", 0.0) if nk == 0 else \
-        costmodel.groupby_strategy(
-            len(batch), None,
-            [np.dtype(f.dtype.device_repr() or "int32")
-             for f in key_fields], cap, log=False)
+    # in-memory batch: no HBM-cache identity, the upload is one-shot
     from .fragment import _OUT_CAP0, packed_bytes_per_group
     packed_out = packed_bytes_per_group(len(group_by),
                                         len(to_agg)) * _OUT_CAP0
     if not costmodel.agg_upload_wins(
             dcol.encoded_nbytes(batch, c.needs_cols),
             packed_out, cacheable=False,
-            host_bytes=_batch_cols_nbytes(batch, c.needs_cols),
-            strategy=strategy):
+            host_bytes=_batch_cols_nbytes(batch, c.needs_cols)):
         return None
 
     dt, outs = _run_compiled(c, batch, proj)
@@ -501,56 +489,31 @@ def try_agg(batch, to_agg: List[Expression], group_by: List[Expression]):
 
     keys_b = [bcast(v, m) for v, m in key_outs]
     vals_b = [bcast(v, m) for v, m in val_outs]
-    from . import mfu, pallas_kernels as pk
+    from . import mfu
     t0 = _time.perf_counter()
     karg = (tuple(v for v, _ in keys_b), tuple(m for _, m in keys_b),
             tuple(v for v, _ in vals_b), tuple(m for _, m in vals_b),
             dt.row_mask, ops)
     kdtypes = tuple(str(v.dtype) for v, _ in keys_b)
     vdtypes = tuple(str(v.dtype) for v, _ in vals_b)
-    if strategy == "hash":
-        try:
-            # [capacity]-wide group budget: groups ≤ live rows ≤ capacity,
-            # so the hash path can never overflow here
-            with retrace_sanitizer.dispatch_scope(
-                    "pallas.hash_agg",
-                    (ops, kdtypes, vdtypes, dt.capacity)):
-                out_keys, out_kvalids, out_vals, out_valids, gcount = \
-                    pk.hash_grouped_agg_kernel(*karg, out_cap=dt.capacity)
-        except pk.HashKeyWidthError:
-            # key set packs wider than the table key budget (the pre-ask
-            # estimated from declared dtypes; the kernel's own trace is
-            # the exact check) — run the any-width sort path instead
-            strategy, load_factor = "sort", 0.0
-    if strategy == "sort":
-        with retrace_sanitizer.dispatch_scope(
-                "kernels.grouped_agg",
-                (ops, kdtypes, vdtypes, dt.capacity)):
-            out_keys, out_kvalids, out_vals, out_valids, gcount = \
-                kernels.grouped_agg_kernel(*karg)
-    # the decision that actually dispatched (post width-gate fallback)
-    costmodel.log_strategy_decision("groupby_strategy", strategy,
-                                    rows=len(batch), out_cap=cap,
-                                    load_factor=load_factor)
+    with retrace_sanitizer.dispatch_scope(
+            "kernels.grouped_agg", (ops, kdtypes, vdtypes, dt.capacity)):
+        out_keys, out_kvalids, out_vals, out_valids, gcount = \
+            kernels.grouped_agg_kernel(*karg)
+    costmodel.log_strategy_decision("groupby_strategy", "sort",
+                                    rows=len(batch), out_cap=dt.capacity)
     # ONE batched transfer for the group count and every output plane
     # (round 17: this path issued 1 + 2×(nk+nvals) sequential gets)
     from . import pipeline as dpipe
     g, out_keys, out_kvalids, out_vals, out_valids = dpipe.fetch_host(
         (gcount, out_keys, out_kvalids, out_vals, out_valids))
     g = int(g)
-    # both formulations are bytes-bound: no MXU flops to claim
-    if strategy == "hash":
-        words = pk.hash_pack_words([v.dtype for v, _ in keys_b]) or 2
-        _, nbytes = mfu.hash_agg_models(
-            dt.capacity, dt.capacity, pk.table_capacity(dt.capacity),
-            words, len(ops))
-    else:
-        _, nbytes = mfu.grouped_agg_models(dt.capacity, dt.capacity, nk,
-                                           len(ops))
+    # bytes-bound: no MXU flops to claim
+    _, nbytes = mfu.grouped_agg_models(dt.capacity, dt.capacity, nk,
+                                       len(ops))
     costmodel.ledger_record("grouped_agg", rows=len(batch), nbytes=nbytes,
                             seconds=_time.perf_counter() - t0,
-                            strategy=strategy,
-                            load_factor=load_factor or None)
+                            strategy="sort")
     cols = []
     for e, f, kv, km in zip(group_by, key_fields, out_keys, out_kvalids):
         cols.append(decode_group_key(e, f, kv, km, dt, g))

@@ -19,10 +19,10 @@ Two grids, both over the ``column.size_classes`` ladder:
 - :func:`warmup_kernels` — the shared device kernel library (argsort,
   grouped-agg, compaction) at representative key layouts;
 - :func:`warmup_fragments` — every fused-agg program compiled so far
-  (``fragment.fused_programs()``), per strategy, at the first-dispatch
-  out-cap bucket.  Fragments with data-dependent scalar planes (string
-  dictionaries) are skipped and counted: their shapes aren't knowable
-  ahead of data.
+  (``fragment.fused_programs()``), at the sort strategy and the
+  first-dispatch out-cap bucket.  Fragments with data-dependent scalar
+  planes (string dictionaries) are skipped and counted: their shapes
+  aren't knowable ahead of data.
 
 All compiles run under the ``warmup.aot`` dispatch scope, which the
 dispatch registry marks exempt — the retrace sanitizer counts them but
@@ -80,21 +80,19 @@ def warmup_kernels(classes: List[int]) -> Dict[str, int]:
 
 def warmup_fragments(classes: List[int],
                      progs: Optional[list] = None) -> Dict[str, int]:
-    """AOT-compile the fused fragment library over size class x
-    strategy.  Returns program/skip/error counts."""
+    """AOT-compile the fused fragment library over the size classes,
+    at the sort strategy (a dense program's ``dims`` are data-shaped).
+    Returns program/skip/error counts."""
     import jax
 
     from ..analysis import retrace_sanitizer
-    from . import fragment, pallas_kernels
+    from . import fragment
     progs = fragment.fused_programs() if progs is None else progs
     programs = skipped = errors = 0
     for prog in progs:
         if prog.in_np_dtypes is None or prog.compiled.scalar_specs:
             skipped += 1   # string-scalar planes are data-shaped
             continue
-        strategies = ["sort"]
-        if prog.nk and not prog.hash_unfit:
-            strategies.append("hash")
         for cap in classes:
             arrays = {n: jax.ShapeDtypeStruct((cap,), dt)
                       for n, dt in prog.in_np_dtypes.items()}
@@ -102,20 +100,15 @@ def warmup_fragments(classes: List[int],
                       for n in prog.in_np_dtypes}
             mask = jax.ShapeDtypeStruct((cap,), np.bool_)
             out_cap = min(fragment._OUT_CAP0, cap)
-            for strategy in strategies:
-                with retrace_sanitizer.dispatch_scope(
-                        "warmup.aot", ("fragment", id(prog), cap,
-                                       strategy)):
-                    try:
-                        prog.packed_fn.lower(
-                            arrays, valids, mask, (),
-                            out_cap=out_cap,
-                            strategy=strategy).compile()
-                        programs += 1
-                    except pallas_kernels.HashKeyWidthError:
-                        prog.hash_unfit = True
-                    except Exception:
-                        errors += 1
+            with retrace_sanitizer.dispatch_scope(
+                    "warmup.aot", ("fragment", id(prog), cap, "sort")):
+                try:
+                    prog.packed_fn.lower(
+                        arrays, valids, mask, (),
+                        out_cap=out_cap, strategy="sort").compile()
+                    programs += 1
+                except Exception:
+                    errors += 1
     return {"programs": programs, "skipped": skipped, "errors": errors}
 
 

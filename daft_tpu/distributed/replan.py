@@ -14,9 +14,9 @@ into the remaining plan —
 - **estimate rewrites** — ``Aggregate.group_rows_est`` /
   ``Aggregate.group_ndv`` and ``HashJoin.left/right_bytes_est`` inside
   the not-yet-dispatched fragment are replaced with measured boundary
-  actuals, so the kernel strategy ladder (``groupby_strategy``), the
-  fused-gate, and the grace-join spill fanout (``plan_partitions`` /
-  ``spill_plan_wins``) price from evidence instead of footer guesses;
+  actuals, so the fused-gate and the grace-join spill fanout
+  (``plan_partitions`` / ``spill_plan_wins``) price from evidence
+  instead of footer guesses;
 - **combine gating** — ``shuffle_combine_wins`` re-priced with the
   stage's measured input rows and (when affordable) the EXACT key NDV
   of in-memory sources: a near-unique boundary flips a default-accepted

@@ -613,8 +613,6 @@ class LocalExecutor:
                     packed_out, cacheable=False,
                     host_bytes=drt._batch_cols_nbytes(
                         rb, prog.compiled.needs_cols),
-                    strategy=fragment.gate_strategy(
-                        prog, len(rb), getattr(node, "group_ndv", None)),
                     window=window):
                 return None
             return prog
@@ -624,8 +622,7 @@ class LocalExecutor:
             device declined at submit (pyobject / lowering failure)."""
             try:
                 return fragment.submit_fused_agg(
-                    prog, rb, node.group_by, agg_cols, node.schema(),
-                    groups=getattr(node, "group_ndv", None))
+                    prog, rb, node.group_by, agg_cols, node.schema())
             except Exception as exc:
                 # lowering/compile errors propagate; only device resource
                 # exhaustion degrades to the host, counted
@@ -827,8 +824,6 @@ class LocalExecutor:
                     round_trips=2.0 / max(1, n_sharing),
                     host_bytes=drt._batch_cols_nbytes(
                         rb, prog.compiled.needs_cols),
-                    strategy=dfrag.gate_strategy(
-                        prog, len(rb), getattr(node, "group_ndv", None)),
                     # overlap pricing when the windows really pipeline
                     # (pwin is assigned before any window resolves)
                     window=pwin):
@@ -850,7 +845,6 @@ class LocalExecutor:
             return ("dev", dt, t)
 
         width = max((os.cpu_count() or 4), 4) * 2
-        groups_ndv = getattr(node, "group_ndv", None)
         from ..device import pipeline as dpipe
         pwin = dpipe.inflight_window()
         if pwin > 0:
@@ -904,8 +898,7 @@ class LocalExecutor:
                 outs = fragment.run_fused_agg_tables(
                     prog,
                     [dt for kind, dt, _ in resolved if kind == "dev"],
-                    src.schema(), node.group_by, agg_cols, node.schema(),
-                    groups=groups_ndv)
+                    src.schema(), node.group_by, agg_cols, node.schema())
                 yield from emit(resolved, outs)
             return
 
@@ -934,7 +927,7 @@ class LocalExecutor:
                     t1 = _time.perf_counter()
                     tok = fragment.submit_fused_agg_tables(
                         prog, tables, src.schema(), node.group_by,
-                        agg_cols, node.schema(), groups=groups_ndv)
+                        agg_cols, node.schema())
                     sub_s = pre_s + (_time.perf_counter() - t1)
                 except BaseException:
                     dpipe.release_slot(slot)

@@ -16,8 +16,8 @@ primitives):
   pair budget re-partitions with a ROTATED radix (rehash of the hash —
   depth d is decorrelated from depth d-1's ``h % n`` residue) up to
   ``DAFT_TPU_SPILL_MAX_DEPTH``. Per-pair joins reuse the ordinary
-  ``hash_join`` kernel stack, so the r12 device hash/sort kernels (and
-  their overflow re-dispatch contract) apply per partition unchanged.
+  ``hash_join`` kernel stack, so the device's fused sort join (and its
+  overflow re-dispatch contract) applies per partition unchanged.
 - **spill-partitioned aggregation** — the fused partitioned-agg reducer
   (``execution/pipeline.py``) spills overflowing group state as PARTIAL
   state rows into a rotated-radix store and merges each bucket on read

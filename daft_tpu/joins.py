@@ -179,17 +179,11 @@ def _take_nullable(s: Series, idx: np.ndarray, valid: np.ndarray) -> Series:
 
 
 def _device_match_indices(l_gids, r_gids, l_valid, r_valid):
-    """Fused single-dispatch device join index generation, at the
-    strategy the cost model picks per dispatch (round 12):
-
-    - ``hash``: Pallas build/probe — ONE streaming pass per side through
-      an HBM/VMEM-resident chained hash table
-      (``pallas_kernels.hash_join_kernel``);
-    - ``sort``: build-side sort + probe counts + prefix-sum expansion
-      (``kernels.join_fused_kernel``, the r6 kernel).
-
-    Either way it is ONE jit program returning ONE packed index matrix
-    (r5's three-phase pipeline paid two host round-trips between phases).
+    """Fused single-dispatch device join index generation: build-side
+    sort + probe counts + prefix-sum expansion
+    (``kernels.join_fused_kernel``), ONE jit program returning ONE packed
+    index matrix (r5's three-phase pipeline paid two host round-trips
+    between phases).
     The output bucket is sized FK-shaped (≈ one match per probe row); a
     larger true total re-dispatches once at the fitting bucket, the
     grouped-agg overflow discipline. None on device-off."""
@@ -201,7 +195,6 @@ def _device_match_indices(l_gids, r_gids, l_valid, r_valid):
     import jax.numpy as jnp
 
     from .device import costmodel, kernels as K, mfu
-    from .device import pallas_kernels as pk
     from .device.column import bucket_capacity
 
     def pad(a, cap, fill=0):
@@ -215,23 +208,19 @@ def _device_match_indices(l_gids, r_gids, l_valid, r_valid):
     lmask[:n_l] = True
     rmask = np.zeros(c_r, bool)
     rmask[:n_r] = True
-    strategy = costmodel.join_strategy(n_l, n_r)
-    kernel = pk.hash_join_kernel if strategy == "hash" \
-        else K.join_fused_kernel
 
     def dispatch(cap):
-        # device arrays are rebuilt per dispatch: both kernels DONATE the
+        # device arrays are rebuilt per dispatch: the kernel DONATES the
         # build side's buffers on real chips, so an overflow re-dispatch
         # cannot reuse them
         from .analysis import retrace_sanitizer
-        site = "pallas.hash_join" if kernel is pk.hash_join_kernel \
-            else "kernels.join_fused"
         # declared trace signature: build/probe capacity classes + the
         # out-capacity bucket; the same signature must re-enter the jit
         # cache, never re-trace
         from .device import pipeline as dpipe
-        with retrace_sanitizer.dispatch_scope(site, (c_l, c_r, cap)):
-            return np.asarray(dpipe.fetch_host(kernel(
+        with retrace_sanitizer.dispatch_scope("kernels.join_fused",
+                                              (c_l, c_r, cap)):
+            return np.asarray(dpipe.fetch_host(K.join_fused_kernel(
                 jnp.asarray(pad(l_gids.astype(np.int64), c_l)),
                 jnp.asarray(pad(l_valid, c_l)), jnp.asarray(lmask),
                 jnp.asarray(pad(r_gids.astype(np.int64), c_r)),
@@ -243,42 +232,16 @@ def _device_match_indices(l_gids, r_gids, l_valid, r_valid):
     packed = dispatch(cap)
     counts = packed[2, :n_l].astype(np.int64)
     total = int(counts.sum())
-    hist = [(strategy, cap)]  # one entry per dispatch that ran
+    dispatches, nbytes = 1, mfu.join_bytes_model(c_l, c_r, cap)
     if total > cap:  # rare: many-to-many blowup past the FK estimate
         cap = bucket_capacity(total)
-        if strategy == "hash" and cap > pk.max_table_slots():
-            # the probe kernel pins two cap-sized output index planes
-            # on-chip (whole-plane BlockSpecs); a many-to-many blowup
-            # bucket past the slot ceiling belongs to the sort kernel,
-            # whose buffers live in HBM
-            strategy, kernel = "sort", K.join_fused_kernel
         packed = dispatch(cap)
-        hist.append((strategy, cap))
-
-    def _model(strat, c):
-        return mfu.hash_join_bytes_model(c_l, c_r, c) if strat == "hash" \
-            else mfu.join_bytes_model(c_l, c_r, c)
-
-    # per-strategy accounting (the overflow re-dispatch can switch the
-    # ladder to sort): each family record carries its own dispatch count
-    # and byte model; the row count and whole-ladder wall go to the
-    # completing strategy's record — the same discipline as the fused-agg
-    # ladder in device/fragment.py
-    secs = _time.perf_counter() - t0
-    acct: dict = {}
-    for s_, c_ in hist:
-        d = acct.setdefault(s_, [0, 0])
-        d[0] += 1
-        d[1] += _model(s_, c_)
-    for s_, (n_disp, nbytes) in acct.items():
-        final = s_ == strategy
-        # live build rows over the 2× build-capacity table: ≤ 0.5 by
-        # construction (the table can never fill)
-        lf = n_r / pk.join_table_capacity(c_r) if s_ == "hash" else None
-        costmodel.ledger_record(
-            "join", rows=(n_l + n_r) if final else 0, nbytes=nbytes,
-            seconds=secs if final else 0.0, dispatches=n_disp,
-            strategy=s_, load_factor=lf)
+        dispatches += 1
+        nbytes += mfu.join_bytes_model(c_l, c_r, cap)
+    costmodel.ledger_record(
+        "join", rows=n_l + n_r, nbytes=nbytes,
+        seconds=_time.perf_counter() - t0, dispatches=dispatches,
+        strategy="sort")
     return (packed[0, :total].astype(np.int64),
             packed[1, :total].astype(np.int64), counts)
 

@@ -634,8 +634,6 @@ class RuntimeStatsContext:
                     extra += f" {d['mfu_pct']}% MFU"
                 if "strategy" in d:
                     extra += f" strategy={d['strategy']}"
-                    if "load_factor" in d:
-                        extra += f" load={d['load_factor']}"
                 if "overlap_x" in d:
                     # r17 async pipeline: serial-equivalent stage seconds
                     # vs pipelined wall (>1 = overlap really hid work)

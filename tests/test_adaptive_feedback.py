@@ -124,9 +124,13 @@ def test_active_fault_plan_freezes_calibration(monkeypatch):
 def test_ledger_record_feeds_observations(monkeypatch):
     monkeypatch.setenv("DAFT_TPU_CALIBRATION", "1")
     monkeypatch.setenv("DAFT_TPU_CALIBRATION_MIN_SAMPLES", "1")
-    costmodel.ledger_record("grouped_agg", rows=1 << 16, nbytes=1 << 24,
-                            seconds=0.1, strategy="hash")
-    assert cal.const("DEV_AGG_HASH_BPS", 0.0) > 0
+    # dense and sort dispatches calibrate the one rate the gate prices
+    for n, strategy in enumerate(("dense", "sort"), 1):
+        costmodel.ledger_record("grouped_agg", rows=1 << 16,
+                                nbytes=1 << 24, seconds=0.1,
+                                strategy=strategy)
+        assert cal.summary()["DEV_AGG_BPS"]["samples"] == n
+    assert cal.const("DEV_AGG_BPS", 0.0) > 0
     costmodel.ledger_record("argsort", rows=1 << 16, nbytes=1 << 20,
                             seconds=0.05)
     assert cal.const("DEV_SORT_ROWS_PER_S", 0.0) > 0

@@ -9,8 +9,6 @@ density, each asserting EXACT permutation agreement with the pyarrow
 host path (both sides are stable sorts, so ties must agree too).
 """
 
-import os
-
 import numpy as np
 import pytest
 
@@ -339,18 +337,17 @@ def test_fused_gate_respects_memory_budget(monkeypatch):
     assert pl._fused_groups_admissible(n)
 
 
-# ------------------------------------------- hash kernels (round 12)
+# ------------------------------------- grouped-agg strategies: dense, sort
 #
-# The hash grouped-agg / hash join are STRATEGY swaps for the sort
-# kernels above: same argument shapes, same return contracts, same
-# overflow discipline. Parity is proven three ways — kernel-vs-kernel
-# (hash vs sort over seeded random configurations), kernel-vs-numpy
-# (an independent host reference), and engine-vs-host (forced-hash
-# queries against the pure host path). On this CPU tier every Pallas
-# program runs under the interpreter (`interpret=True`), which is
-# itself a tested contract: tier-1 proves parity without silicon.
+# The device has two grouped-aggregate formulations with one argument and
+# return contract: ``dense`` (direct slot indexing over dictionary codes —
+# all of the device time behind the benchmark's ``agg_hbm_pct``) and
+# ``sort`` (packed-key radix sort + segment reduce). Parity is proven
+# three ways — kernel-vs-kernel over seeded random configurations,
+# kernel-vs-numpy (an independent host reference), and engine-vs-host
+# with the kernel ledger naming the strategy that dispatched.
 
-from daft_tpu.device import mfu, pallas_kernels as pk  # noqa: E402
+from daft_tpu.device import column as dcol, fragment  # noqa: E402
 
 
 def _agg_args(rng, C, nk, nv, null_keys=True):
@@ -381,23 +378,50 @@ def _agg_args(rng, C, nk, nv, null_keys=True):
             jnp.asarray(mask), tuple(ops))
 
 
-def _agg_map(out, nk, nv):
-    """{group key tuple: value tuple} for the live groups of a kernel
-    result — strategy-order-insensitive (hash emits slot order, sort
-    emits key order; engine-wide, grouped output order is unspecified)."""
+def _dense_args(rng, C, nk, nv):
+    """:func:`_agg_args` with every key replaced by a dictionary-coded
+    plane (int32 codes under a random dictionary size, NULLs kept) and
+    the pow2 slot width ``dims`` the dispatch site would derive."""
+    _, kvalids, vals, vvalids, mask, ops = _agg_args(rng, C, nk, nv)
+    keys, dims = [], []
+    for _ in range(nk):
+        d = int(rng.integers(1, 7))
+        keys.append(jnp.asarray(rng.integers(0, d, C).astype(np.int32)))
+        dims.append(max(1 << (d - 1).bit_length(), 1))
+    return tuple(keys), kvalids, vals, vvalids, mask, ops, tuple(dims)
+
+
+def _grouped(strategy, keys, kvalids, vals, vvalids, mask, ops, out_cap,
+             dims):
+    """One kernel call at the strategy ``run_packed`` would take."""
+    if strategy == "dense":
+        return K.grouped_agg_dense_impl(keys, kvalids, vals, vvalids, mask,
+                                        ops, out_cap, dims)
+    return K.grouped_agg_block_impl(keys, kvalids, vals, vvalids, mask,
+                                    ops, out_cap)
+
+
+def _agg_rows(out):
+    """[(group key tuple, value tuple)] for the live groups of a kernel
+    result, in the order the kernel emitted them."""
     ok, okv, ov, ovv, g = out
     g = int(np.asarray(jax.device_get(g)))
     ok = [np.asarray(k) for k in ok]
     okv = [np.asarray(k) for k in okv]
     ov = [np.asarray(v) for v in ov]
     ovv = [np.asarray(v) for v in ovv]
-    m = {}
-    for i in range(g):
-        key = tuple(k[i].item() if kv[i] else None
-                    for k, kv in zip(ok, okv))
-        m[key] = tuple(v[i].item() if vv[i] else None
-                       for v, vv in zip(ov, ovv))
-    return m
+    return [(tuple(k[i].item() if kv[i] else None
+                   for k, kv in zip(ok, okv)),
+             tuple(v[i].item() if vv[i] else None
+                   for v, vv in zip(ov, ovv)))
+            for i in range(g)]
+
+
+def _agg_map(out, nk, nv):
+    """{group key tuple: value tuple} for the live groups of a kernel
+    result — order-insensitive (engine-wide, grouped output order is
+    unspecified)."""
+    return dict(_agg_rows(out))
 
 
 def _maps_close(a, b):
@@ -412,40 +436,53 @@ def _maps_close(a, b):
 
 
 @pytest.mark.parametrize("seed", range(10))
-def test_hash_agg_matches_sort_kernel_property(seed):
-    """Seeded-property parity: the one-pass hash table and the
+def test_dense_agg_matches_sort_kernel_property(seed):
+    """Seeded-property parity: direct slot indexing and the
     sort+segment-reduce formulation agree on every group and every
-    aggregate over random dtypes × null densities × op mixes."""
+    aggregate over dictionary sizes × null densities × op mixes."""
     rng = np.random.default_rng(seed)
     C = int(rng.choice([64, 128, 256]))
     nk = int(rng.integers(1, 3))
     nv = int(rng.integers(1, 3))
-    keys, kvalids, vals, vvalids, mask, ops = _agg_args(rng, C, nk, nv)
-    if pk.hash_pack_words([k.dtype for k in keys]) is None:
-        pytest.skip("key set too wide for the hash budget")
-    out_cap = C
-    hashed = pk.hash_grouped_agg_impl(
-        keys, kvalids, vals, vvalids, mask, ops, out_cap,
-        interpret=True, block=int(rng.choice([16, 32, C])))
-    sorted_ = K.grouped_agg_block_impl(
-        keys, kvalids, vals, vvalids, mask, ops, out_cap)
-    _maps_close(_agg_map(hashed, nk, nv), _agg_map(sorted_, nk, nv))
+    *args, dims = _dense_args(rng, C, nk, nv)
+    dense = _grouped("dense", *args, out_cap=C, dims=dims)
+    sorted_ = _grouped("sort", *args, out_cap=C, dims=dims)
+    _maps_close(_agg_map(dense, nk, nv), _agg_map(sorted_, nk, nv))
 
 
-def test_hash_agg_matches_numpy_reference():
-    """Independent host reference: sums/counts/min over known data with
-    NULL keys and NULL values, computed with numpy, no engine code."""
+@pytest.mark.parametrize("nk", [1, 2])
+def test_dense_emits_groups_in_the_sort_strategys_order(nk):
+    """What ``grouped_agg_dense_impl`` promises: occupied slots enumerate
+    groups in ascending key order with NULLs last — the order the sort
+    strategy emits, so a strategy swap is invisible above the dispatch
+    site."""
+    rng = np.random.default_rng(40 + nk)
+    *args, dims = _dense_args(rng, 128, nk, 1)
+    args[1] = tuple(jnp.asarray(rng.random(128) > 0.25) for _ in range(nk))
+    dense = [k for k, _ in _agg_rows(_grouped("dense", *args, out_cap=128,
+                                              dims=dims))]
+    sorted_ = [k for k, _ in _agg_rows(_grouped("sort", *args, out_cap=128,
+                                                dims=dims))]
+    assert dense == sorted_
+    assert dense == sorted(
+        dense, key=lambda t: tuple((x is None, x or 0) for x in t))
+    assert any(None in k for k in dense), "the case must hold a NULL key"
+
+
+@pytest.mark.parametrize("strategy", ["dense", "sort"])
+def test_grouped_agg_matches_numpy_reference(strategy):
+    """Independent host reference: sums over known data with NULL keys
+    and NULL values, computed with numpy, no engine code."""
     C = 64
-    k = np.array([1, 2, 1, 3, 2, 1, 0, 3] + [0] * (C - 8), np.int64)
+    k = np.array([1, 2, 1, 3, 2, 1, 0, 3] + [0] * (C - 8), np.int32)
     kv = np.array([1, 1, 1, 1, 1, 0, 1, 1] + [1] * (C - 8), bool)
     v = np.arange(C, dtype=np.float32)
     vv = np.array([1, 1, 0, 1, 1, 1, 1, 1] + [1] * (C - 8), bool)
     mask = np.zeros(C, bool)
     mask[:8] = True
-    out = pk.hash_grouped_agg_impl(
-        (jnp.asarray(k),), (jnp.asarray(kv),), (jnp.asarray(v),),
-        (jnp.asarray(vv),), jnp.asarray(mask), ("sum",), C,
-        interpret=True, block=16)
+    out = _grouped(
+        strategy, (jnp.asarray(k),), (jnp.asarray(kv),), (jnp.asarray(v),),
+        (jnp.asarray(vv),), jnp.asarray(mask), ("sum",), C, (4,))
     got = _agg_map(out, 1, 1)
     ref = {}
     for i in range(8):
@@ -457,77 +494,142 @@ def test_hash_agg_matches_numpy_reference():
     _maps_close(got, want)
 
 
-def test_hash_agg_all_duplicate_and_all_unique_keys():
+@pytest.mark.parametrize("strategy", ["dense", "sort"])
+def test_grouped_agg_all_duplicate_and_all_unique_keys(strategy):
     """Adversarial cardinalities: one group total, and one group per
-    row (the table at its load-factor ceiling)."""
+    row (every dense slot of the dictionary occupied)."""
     C = 128
     ones = jnp.ones(C, bool)
-    dup = pk.hash_grouped_agg_impl(
-        (jnp.full(C, 7, jnp.int64),), (ones,),
-        (jnp.ones(C, jnp.float32),), (ones,), ones, ("sum",), C,
-        interpret=True, block=32)
+    dup = _grouped(
+        strategy, (jnp.full(C, 7, jnp.int32),), (ones,),
+        (jnp.ones(C, jnp.float32),), (ones,), ones, ("sum",), 256, (8,))
     assert int(np.asarray(dup[-1])) == 1
     assert np.asarray(dup[2][0])[0] == C
-    uniq = pk.hash_grouped_agg_impl(
-        (jnp.arange(C, dtype=jnp.int64),), (ones,),
-        (jnp.ones(C, jnp.float32),), (ones,), ones, ("count",), C,
-        interpret=True, block=32)
+    uniq = _grouped(
+        strategy, (jnp.arange(C, dtype=jnp.int32),), (ones,),
+        (jnp.ones(C, jnp.float32),), (ones,), ones, ("count",), 256, (C,))
     assert int(np.asarray(uniq[-1])) == C
     m = _agg_map(uniq, 1, 1)
     assert len(m) == C and all(v == (1,) for v in m.values())
 
 
-def test_hash_agg_overflow_signals_and_redispatch_recovers():
-    """More groups than ``out_cap``: the returned group count exceeds the
-    bucket (the r6 overflow contract — the caller re-dispatches at a
-    grown bucket), and the re-dispatch at a fitting bucket is complete
-    and sort-parity."""
-    C = 256
-    ndv = 200
+def test_dense_keeps_int_sums_exact_and_f64_sums_in_f64():
+    """The one-hot matmul accumulates floats at the widest value dtype
+    and leaves integer sums to an exact int64 scatter: neither may fall
+    to f32 (2**24 + 1 does not survive it)."""
+    C = 64
     ones = jnp.ones(C, bool)
-    keys = (jnp.asarray(np.arange(C) % ndv, jnp.int64),)
-    vals = (jnp.ones(C, jnp.float32),)
-    args = (keys, (ones,), vals, (ones,), ones, ("sum",))
-    small = pk.hash_grouped_agg_impl(*args, out_cap=128, interpret=True,
-                                     block=64)
-    assert int(np.asarray(small[-1])) > 128  # overflow signalled
-    big = pk.hash_grouped_agg_impl(*args, out_cap=256, interpret=True,
-                                   block=64)
-    ref = K.grouped_agg_block_impl(*args, out_cap=256)
-    _maps_close(_agg_map(big, 1, 1), _agg_map(ref, 1, 1))
+    big = (1 << 24) + 1
+    keys = (jnp.asarray((np.arange(C) % 2).astype(np.int32)),)
+    vals = (jnp.full(C, big, jnp.int64), jnp.full(C, float(big), jnp.float64))
+    args = (keys, (ones,), vals, (ones, ones), ones, ("sum", "sum"))
+    dense = _agg_map(_grouped("dense", *args, out_cap=C, dims=(2,)), 1, 2)
+    assert dense == {(0,): (32 * big, 32.0 * big),
+                     (1,): (32 * big, 32.0 * big)}
+    assert dense == _agg_map(_grouped("sort", *args, out_cap=C, dims=(2,)),
+                             1, 2)
 
 
-def test_hash_agg_wide_key_sets_raise_and_route_to_sort():
-    """>128-bit packed key sets: ``hash_pack_words`` declines (the
-    dispatch-site routing signal) and the kernel itself raises — wide
-    keys always run as the sort path's LSD radix."""
-    assert pk.hash_pack_words([np.dtype(d) for d in
-                               rule_jit.HASH_UNFIT_KEY_DTYPES]) is None
+def test_dense_refuses_a_bucket_smaller_than_its_slots():
+    """``K = prod(d + 1)`` slots must fit ``out_cap``: the kernel raises
+    at trace time instead of dropping groups (``dense_plan`` sizes the
+    bucket, so no dispatch site reaches this)."""
     C = 32
     ones = jnp.ones(C, bool)
-    k = jnp.asarray(np.arange(C), jnp.int64)
-    with pytest.raises(ValueError):
-        pk.hash_grouped_agg_impl(
-            (k, k, k), (ones,) * 3, (jnp.ones(C, jnp.float32),), (ones,),
-            ones, ("sum",), C, interpret=True, block=16)
-    # the strategy model never picks hash for them, even when forced
-    s, _ = costmodel.groupby_strategy(
-        1000, 10.0, [np.dtype("int64")] * 3, 128, log=False)
-    assert s == "sort"
+    k = jnp.zeros(C, jnp.int32)
+    with pytest.raises(ValueError, match="K <= out_cap"):
+        K.grouped_agg_dense_impl((k, k), (ones, ones),
+                                 (jnp.ones(C, jnp.float32),), (ones,), ones,
+                                 ("sum",), 16, (4, 4))
 
 
-def test_interpreter_mode_is_the_cpu_default():
-    """Tier-1 runs every Pallas program under the interpreter: the CPU
-    backend auto-selects it, and the knob force-overrides both ways."""
-    assert pk.interpret_default() is True  # JAX_PLATFORMS=cpu in tier-1
-    os.environ["DAFT_TPU_KERNEL_INTERPRET"] = "0"
-    try:
-        assert pk.interpret_default() is False
-    finally:
-        del os.environ["DAFT_TPU_KERNEL_INTERPRET"]
+def test_sort_agg_overflow_signals_and_redispatch_recovers():
+    """More groups than ``out_cap``: the returned group count exceeds the
+    bucket (the overflow contract — the caller re-dispatches at a grown
+    bucket), and the re-dispatch at a fitting bucket is complete."""
+    C, ndv = 256, 200
+    ones = jnp.ones(C, bool)
+    keys = (jnp.asarray(np.arange(C) % ndv, jnp.int64),)
+    args = (keys, (ones,), (jnp.ones(C, jnp.float32),), (ones,), ones,
+            ("sum",))
+    small = K.grouped_agg_block_impl(*args, out_cap=128)
+    assert int(np.asarray(small[-1])) == ndv > 128  # overflow signalled
+    big = _agg_map(K.grouped_agg_block_impl(*args, out_cap=256), 1, 1)
+    assert big == {(i,): (2.0 if i < C - ndv else 1.0,)
+                   for i in range(ndv)}
 
 
-# --------------------------------------------------- hash join (round 12)
+# ------------------------------------------------ dense_dims / dense_plan
+
+def _fused_prog_and_table(data, keys):
+    """(fused program, encoded DeviceTable) of ``sum(v) group by keys``."""
+    rb = RecordBatch.from_pydict(data)
+    prog = fragment.get_fused_agg(
+        [daft_tpu.col(k) for k in keys],
+        [daft_tpu.col("v").alias("__v0__")], ("sum",), None, rb.schema)
+    assert prog is not None
+    return prog, dcol.encode_batch(rb, prog.compiled.needs_cols)
+
+
+def _strings(n_distinct, n=200):
+    return [f"s{i % n_distinct:03d}" for i in range(n)]
+
+
+_PLAN_CASES = {
+    # name: (data, keys, cap_limit, dims, plan)
+    "two_dictionary_keys": (
+        {"a": _strings(3), "b": _strings(2)}, ("a", "b"), 1 << 20,
+        (4, 2), ((4, 2), 128)),
+    "pow2_bucket_of_the_dictionary": (
+        {"a": _strings(5), "b": _strings(1)}, ("a", "b"), 1 << 20,
+        (8, 1), ((8, 1), 128)),
+    "bucket_grows_with_the_slots": (
+        {"a": _strings(60), "b": _strings(20)}, ("a", "b"), 1 << 20,
+        (64, 32), ((64, 32), 4096)),
+    "non_dictionary_key": (
+        {"a": _strings(3), "b": list(range(200))}, ("a", "b"), 1 << 20,
+        None, None),
+    "slot_product_past_the_ceiling": (
+        {"a": _strings(64), "b": _strings(33)}, ("a", "b"), 1 << 20,
+        None, None),
+    "out_cap_past_the_cap_limit": (
+        {"a": _strings(60), "b": _strings(20)}, ("a", "b"), 2048,
+        (64, 32), None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PLAN_CASES))
+def test_dense_plan_cases(case):
+    """``dense_plan`` is the whole strategy choice: dense iff every key
+    is a dictionary-coded passthrough, the pow2-bucketed slot product is
+    within ``DENSE_MAX_SLOTS`` and its bucket within the link-budgeted
+    ``cap_limit``; else the sort strategy runs."""
+    data, keys, cap_limit, dims, plan = _PLAN_CASES[case]
+    data = dict(data, v=[1.0] * 200)
+    prog, dt = _fused_prog_and_table(data, keys)
+    assert fragment.dense_dims(prog, dt) == dims
+    assert fragment.dense_plan(prog, dt, cap_limit) == plan
+    if plan is not None:
+        K_ = int(np.prod([d + 1 for d in plan[0]]))
+        assert K_ <= fragment.DENSE_MAX_SLOTS and K_ <= plan[1]
+
+
+def test_dense_plan_needs_every_keys_dictionary():
+    """A table whose key plane lost its dictionary (or lacks the column)
+    cannot be slot-indexed: no dims, no plan."""
+    prog, dt = _fused_prog_and_table(
+        {"a": _strings(3), "b": _strings(2), "v": [1.0] * 200}, ("a", "b"))
+    assert fragment.dense_dims(prog, dt) == (4, 2)
+    col_b = dt.columns["b"]
+    dt.columns["b"] = dcol.DeviceColumn(col_b.data, col_b.validity,
+                                        col_b.dtype, None)
+    assert fragment.dense_dims(prog, dt) is None
+    assert fragment.dense_plan(prog, dt, 1 << 20) is None
+    del dt.columns["b"]
+    assert fragment.dense_dims(prog, dt) is None
+
+
+# ------------------------------------------------------ fused sort join
 
 def _join_pairs(packed, n_l):
     """(pairs list, counts) from the packed [3, W] result matrix."""
@@ -538,30 +640,31 @@ def _join_pairs(packed, n_l):
 
 
 @pytest.mark.parametrize("seed", range(6))
-def test_hash_join_matches_sort_kernel_property(seed):
-    """Pair-exact parity between the Pallas hash build/probe and the
-    fused sort join — including pair ORDER (left-major, ascending right
-    row), the contract that makes the strategies drop-in swaps."""
+def test_fused_join_matches_numpy_nested_loop(seed):
+    """Pair-exact agreement of the fused sort join with a nested loop
+    over the live, non-NULL rows — including pair ORDER (left-major,
+    ascending right row) and the per-left-row match counts."""
     rng = np.random.default_rng(seed)
     C = int(rng.choice([64, 128]))
-    lk = jnp.asarray(rng.integers(0, C // 3, C).astype(np.int64))
-    rk = jnp.asarray(rng.integers(0, C // 3, C).astype(np.int64))
-    lv = jnp.asarray(rng.random(C) > 0.15)
-    rv = jnp.asarray(rng.random(C) > 0.15)
-    lm = jnp.asarray(np.arange(C) < int(rng.integers(4, C)))
-    rm = jnp.asarray(np.arange(C) < int(rng.integers(4, C)))
+    lk = rng.integers(0, C // 3, C).astype(np.int64)
+    rk = rng.integers(0, C // 3, C).astype(np.int64)
+    lv = rng.random(C) > 0.15
+    rv = rng.random(C) > 0.15
+    lm = np.arange(C) < int(rng.integers(4, C))
+    rm = np.arange(C) < int(rng.integers(4, C))
     cap = 4 * C
-    hashed = np.asarray(pk.hash_join_impl(lk, lv, lm, rk, rv, rm, cap,
-                                          interpret=True, block=32))
-    sorted_ = np.asarray(K.join_fused_impl(lk, lv, lm, rk, rv, rm, cap))
-    hp, hc = _join_pairs(hashed, C)
-    sp, sc = _join_pairs(sorted_, C)
-    assert int(hc.sum()) <= cap, "grow the cap for this seed"
-    assert hp == sp
-    assert hc.tolist() == sc.tolist()
+    got = np.asarray(K.join_fused_impl(*map(jnp.asarray,
+                                            (lk, lv, lm, rk, rv, rm)), cap))
+    want = [(i, j) for i in range(C) if lm[i] and lv[i]
+            for j in range(C) if rm[j] and rv[j] and lk[i] == rk[j]]
+    assert len(want) <= cap, "grow the cap for this seed"
+    pairs, counts = _join_pairs(got, C)
+    assert pairs == want
+    assert counts.tolist() == [sum(1 for i, _ in want if i == r)
+                               for r in range(C)]
 
 
-def test_hash_join_null_keys_never_match():
+def test_fused_join_null_keys_never_match():
     """NULL-keyed rows (validity False) on either side produce no pairs,
     even when their padded key words are bit-equal."""
     C = 16
@@ -569,124 +672,77 @@ def test_hash_join_null_keys_never_match():
     valid_l = jnp.asarray(np.arange(C) == 0)   # one live left row
     valid_r = jnp.asarray(np.arange(C) < 2)    # two live right rows
     ones = jnp.ones(C, bool)
-    packed = np.asarray(pk.hash_join_impl(
-        k, valid_l, ones, k, valid_r, ones, 64, interpret=True, block=16))
+    packed = np.asarray(K.join_fused_impl(k, valid_l, ones, k, valid_r,
+                                          ones, 64))
     pairs, counts = _join_pairs(packed, C)
     assert pairs == [(0, 0), (0, 1)]
     assert counts.tolist() == [2] + [0] * (C - 1)
 
 
-def test_engine_join_hash_single_dispatch_matches_host(monkeypatch):
-    """`DAFT_TPU_KERNEL_JOIN=hash` routes `_device_match_indices` through
-    the Pallas kernel — exactly ONE dispatch, host-identical indices."""
+def test_join_ledger_counts_every_dispatch_under_the_one_strategy():
+    """One kernel, one ledger row: the overflow re-dispatch adds a
+    dispatch and its bytes to the ``join`` family's ``sort`` record."""
     from daft_tpu import joins
-    monkeypatch.setenv("DAFT_TPU_DEVICE_FORCE", "1")
-    monkeypatch.setenv("DAFT_TPU_KERNEL_JOIN", "hash")
+    costmodel.ledger_reset()
     lk, rk, lv, rv = _join_keys()
-    calls = {"n": 0}
-    real = pk.hash_join_kernel
-
-    def counting(*a, **kw):
-        calls["n"] += 1
-        return real(*a, **kw)
-
-    monkeypatch.setattr(pk, "hash_join_kernel", counting)
+    joins._device_match_indices(lk, rk, lv, rv)
+    one = costmodel.ledger_snapshot()["join"]
+    assert (one["dispatches"], one["strategy"]) == (1, "sort")
     costmodel.ledger_reset()
-    out = joins._device_match_indices(lk, rk, lv, rv)
-    assert out is not None
-    assert calls["n"] == 1, f"expected ONE dispatch, saw {calls['n']}"
-    dli, dri, dcnt = out
+    n = 1200  # many-to-many blowup: re-dispatched once at a grown bucket
+    z, ones = np.zeros(n, np.int64), np.ones(n, bool)
+    joins._device_match_indices(z, z, ones, ones)
+    two = costmodel.ledger_snapshot()["join"]
+    assert (two["dispatches"], two["strategy"]) == (2, "sort")
+    assert two["rows"] == 2 * n and two["bytes"] > one["bytes"]
+    costmodel.ledger_reset()
+
+
+def test_engine_join_on_the_device_tier_matches_host(monkeypatch):
+    """Whole-engine parity: a DataFrame join routed to the device
+    (`DAFT_TPU_DEVICE_JOIN=1`) equals the host join, and the kernel
+    ledger says the fused sort join ran."""
+    rng = np.random.default_rng(21)
+    left = {"k": [None if rng.random() < 0.1 else int(x)
+                  for x in rng.integers(0, 60, 500)],
+            "a": list(range(500))}
+    right = {"k": rng.integers(0, 60, 200).tolist(),
+             "b": list(range(200))}
+
+    def run():
+        return (daft_tpu.from_pydict(left)
+                .join(daft_tpu.from_pydict(right), on="k")
+                .sort(["a", "b"]).to_pydict())
+
     monkeypatch.setenv("DAFT_TPU_DEVICE_JOIN", "0")
-    hli, hri, hcnt = joins.match_indices(lk, rk, lv, rv)
-    assert sorted(zip(dli.tolist(), dri.tolist())) == \
-        sorted(zip(hli.tolist(), hri.tolist()))
-    assert np.array_equal(dcnt, hcnt)
-    snap = costmodel.ledger_snapshot()
-    assert snap["join"]["strategy"] == "hash"
-    assert 0 < snap["join"]["load_factor"] <= 0.5  # 2x-capacity table
+    host = run()
+    monkeypatch.setenv("DAFT_TPU_DEVICE_JOIN", "1")
     costmodel.ledger_reset()
+    dev = run()
+    snap = costmodel.ledger_snapshot()
+    costmodel.ledger_reset()
+    assert dev == host and len(dev["k"]) > 500
+    assert snap["join"]["strategy"] == "sort"
+    assert snap["join"]["dispatches"] >= 1
 
 
-def test_engine_join_hash_overflow_redispatches_once(monkeypatch):
-    """A many-to-many blowup past the FK-shaped output estimate re-runs
-    the HASH kernel at the fitting bucket — two dispatches, correct."""
-    from daft_tpu import joins
-    monkeypatch.setenv("DAFT_TPU_DEVICE_FORCE", "1")
-    monkeypatch.setenv("DAFT_TPU_KERNEL_JOIN", "hash")
-    n = 400  # 400*400 pairs >> bucket_capacity(400) slots
-    lk = np.zeros(n, np.int64)
-    rk = np.zeros(n, np.int64)
-    ones = np.ones(n, bool)
-    calls = {"n": 0}
-    real = pk.hash_join_kernel
+# ----------------------------------------------- strategy in the ledger
 
-    def counting(*a, **kw):
-        calls["n"] += 1
-        return real(*a, **kw)
-
-    monkeypatch.setattr(pk, "hash_join_kernel", counting)
-    dli, dri, dcnt = joins._device_match_indices(lk, rk, ones, ones)
-    assert calls["n"] == 2
-    assert len(dli) == n * n
-    assert dcnt.tolist() == [n] * n
-
-
-# ------------------------------------- strategy model + ledger (round 12)
-
-def test_groupby_strategy_decision_rule(monkeypatch):
-    """The hash-vs-sort decision ladder: silicon-only in auto, forced by
-    the knob, NDV-fraction decline, table-ceiling decline."""
-    dts = [np.dtype("int64")]
-    # CPU backend in auto mode: the interpreter exists for parity, not
-    # speed — stays on sort
-    assert costmodel.groupby_strategy(10_000, 64.0, dts, 128,
-                                      log=False)[0] == "sort"
-    monkeypatch.setenv("DAFT_TPU_KERNEL_GROUPBY", "hash")
-    s, lf = costmodel.groupby_strategy(10_000, 64.0, dts, 128, log=False)
-    assert s == "hash" and 0 < lf <= 1.0
-    monkeypatch.setenv("DAFT_TPU_KERNEL_GROUPBY", "sort")
-    assert costmodel.groupby_strategy(10_000, 64.0, dts, 128,
-                                      log=False)[0] == "sort"
-    # auto + silicon: hash at aggregation-shaped NDV …
-    monkeypatch.setenv("DAFT_TPU_KERNEL_GROUPBY", "auto")
-    monkeypatch.setattr(costmodel, "_hash_capable_backend", lambda: True)
-    assert costmodel.groupby_strategy(10_000, 64.0, dts, 128,
-                                      log=False)[0] == "hash"
-    # … sort on near-unique keys (the table grows as large as the data)
-    assert costmodel.groupby_strategy(10_000, 9_000.0, dts, 16384,
-                                      log=False)[0] == "sort"
-    # … sort when the table exceeds the on-chip slot ceiling
-    monkeypatch.setenv("DAFT_TPU_KERNEL_MAX_TABLE", "256")
-    assert costmodel.groupby_strategy(10_000, 64.0, dts, 4096,
-                                      log=False)[0] == "sort"
-
-
-def test_join_strategy_decision_rule(monkeypatch):
-    assert costmodel.join_strategy(1000, 1000) == "sort"  # CPU auto
-    monkeypatch.setenv("DAFT_TPU_KERNEL_JOIN", "hash")
-    assert costmodel.join_strategy(1000, 1000) == "hash"
-    monkeypatch.setenv("DAFT_TPU_KERNEL_JOIN", "auto")
-    monkeypatch.setattr(costmodel, "_hash_capable_backend", lambda: True)
-    assert costmodel.join_strategy(1000, 1000) == "hash"
-    monkeypatch.setenv("DAFT_TPU_KERNEL_MAX_TABLE", "256")
-    assert costmodel.join_strategy(100_000, 100_000) == "sort"
-
-
-def test_ledger_carries_strategy_and_load_factor():
-    """`strategy`/`load_factor` ride the same per-family ledger rows the
-    stats block and dashboard render."""
+def test_ledger_carries_strategy():
+    """`strategy` rides the same per-family ledger rows the stats block
+    and dashboard render; a family that ran both reads `mixed` with a
+    count each."""
     costmodel.ledger_reset()
     costmodel.ledger_record("grouped_agg", rows=10, nbytes=1e6,
-                            seconds=0.1, strategy="hash", load_factor=0.4)
+                            seconds=0.1, strategy="dense")
     snap = costmodel.ledger_snapshot()
-    assert snap["grouped_agg"]["strategy"] == "hash"
-    assert snap["grouped_agg"]["load_factor"] == 0.4
+    assert snap["grouped_agg"]["strategy"] == "dense"
     costmodel.ledger_record("grouped_agg", rows=5, nbytes=1e6,
-                            seconds=0.1, strategy="sort")
+                            seconds=0.1, strategy="sort", dispatches=2)
     snap = costmodel.ledger_snapshot()
     assert snap["grouped_agg"]["strategy"] == "mixed"
-    assert snap["grouped_agg"]["strategy_hash"] == 1
-    assert snap["grouped_agg"]["strategy_sort"] == 1
+    assert snap["grouped_agg"]["strategy_dense"] == 1
+    assert snap["grouped_agg"]["strategy_sort"] == 2
     costmodel.ledger_reset()
 
 
@@ -696,29 +752,14 @@ def test_query_stats_render_strategy(monkeypatch):
     costmodel.ledger_reset()
     ctx = obs.new_query_stats()
     costmodel.ledger_record("grouped_agg", rows=9, nbytes=1e6,
-                            seconds=0.01, strategy="hash",
-                            load_factor=0.25)
+                            seconds=0.01, strategy="dense")
     ctx.finish()
-    assert ctx.device_kernels["grouped_agg"]["strategy"] == "hash"
-    assert ctx.device_kernels["grouped_agg"]["load_factor"] == 0.25
-    assert "strategy=hash" in ctx.render()
-    assert "load=0.25" in ctx.render()
+    assert ctx.device_kernels["grouped_agg"]["strategy"] == "dense"
+    assert "strategy=dense" in ctx.render()
     costmodel.ledger_reset()
 
 
-def test_hash_byte_models_beat_sort_at_agg_shapes():
-    """The pricing the strategy model acts on: at aggregation-shaped NDV
-    the one-pass hash model touches fewer bytes than the multi-pass sort
-    model; both are positive."""
-    rows, out_cap = 1 << 20, 256
-    table = pk.table_capacity(out_cap)
-    _, sort_b = mfu.grouped_agg_models(rows, out_cap, 1, 2)
-    _, hash_b = mfu.hash_agg_models(rows, out_cap, table, 1, 2)
-    assert 0 < hash_b < sort_b
-    assert mfu.hash_join_bytes_model(1 << 16, 1 << 16, 1 << 16) > 0
-
-
-# -------------------------------------- engine end-to-end (forced hash)
+# -------------------------------------------- engine end-to-end (device)
 
 def _host_groupby(data, keys, aggs, monkeypatch):
     import daft_tpu as dtpu
@@ -728,11 +769,10 @@ def _host_groupby(data, keys, aggs, monkeypatch):
     return df.groupby(*keys).agg(*aggs).sort(list(keys)).to_pydict()
 
 
-def _device_groupby(data, keys, aggs, monkeypatch, strategy="hash"):
+def _device_groupby(data, keys, aggs, monkeypatch):
     import daft_tpu as dtpu
     monkeypatch.setenv("DAFT_TPU_DEVICE", "1")
     monkeypatch.setenv("DAFT_TPU_DEVICE_FORCE", "1")
-    monkeypatch.setenv("DAFT_TPU_KERNEL_GROUPBY", strategy)
     df = dtpu.from_pydict(data)
     return df.groupby(*keys).agg(*aggs).sort(list(keys)).to_pydict()
 
@@ -747,10 +787,42 @@ def _pydicts_close(a, b):
                 assert x == y, c
 
 
-def test_engine_groupby_forced_hash_matches_host(monkeypatch):
-    """Whole-engine parity: a forced-hash grouped aggregation (NULL keys
-    included) agrees with the pure host path, and the query's ledger row
-    says the hash strategy really ran."""
+def _strategy_counts():
+    """(dense, sort) dispatches of the grouped_agg family so far."""
+    d = costmodel.ledger_snapshot(raw=True).get("grouped_agg", {})
+    return d.get("strategy_dense", 0), d.get("strategy_sort", 0)
+
+
+def test_engine_string_key_groupby_dispatches_dense_once(monkeypatch):
+    """Whole-engine parity: a group-by on string keys (NULLs included)
+    agrees with the pure host path and its fused fragment runs the
+    dense strategy ONCE for its one table — no overflow rung. (The other
+    dispatch is the merge of the partials, an in-memory batch on the
+    sort kernel.)"""
+    rng = np.random.default_rng(11)
+    n = 500
+    data = {
+        "k": [None if rng.random() < 0.1 else f"g{int(x):02d}"
+              for x in rng.integers(0, 40, n)],
+        "j": [("x", "y", None)[int(x)] for x in rng.integers(0, 3, n)],
+        "v": rng.uniform(-10, 10, n).round(3).tolist(),
+    }
+    aggs = (daft_tpu.col("v").sum().alias("s"),
+            daft_tpu.col("v").mean().alias("m"),
+            daft_tpu.col("v").count().alias("c"))
+    host = _host_groupby(data, ("k", "j"), aggs, monkeypatch)
+    costmodel.ledger_reset()
+    dev = _device_groupby(data, ("k", "j"), aggs, monkeypatch)
+    dense, sort = _strategy_counts()
+    costmodel.ledger_reset()
+    _pydicts_close(dev, host)
+    assert (dense, sort) == (1, 1)
+
+
+def test_engine_int_key_groupby_dispatches_sort(monkeypatch):
+    """An integer key has no dictionary to index: every dispatch of the
+    query (NULL keys included) rides the sort strategy and the answer is
+    the host's."""
     rng = np.random.default_rng(11)
     n = 500
     data = {
@@ -765,265 +837,179 @@ def test_engine_groupby_forced_hash_matches_host(monkeypatch):
     costmodel.ledger_reset()
     dev = _device_groupby(data, ("k",), aggs, monkeypatch)
     snap = costmodel.ledger_snapshot()
-    _pydicts_close(dev, host)
-    assert snap["grouped_agg"]["strategy"] == "hash"
-    assert snap["grouped_agg"]["load_factor"] > 0
     costmodel.ledger_reset()
-
-
-def test_engine_groupby_hash_overflow_grows_bucket(monkeypatch):
-    """More groups than the first packed-output bucket (128) but fewer
-    than the first hash TABLE's slots: the fused path re-dispatches the
-    HASH program at a grown bucket and the answer is still host-exact.
-    (NDV past the table size saturates it and switches the ladder to
-    sort — covered by test_saturated_hash_overflow_switches_to_sort.)"""
-    n = 2000
-    ndv = 200  # > _OUT_CAP0, < table_capacity(_OUT_CAP0) so never saturated
-    data = {"k": [int(i % ndv) for i in range(n)],
-            "v": [float(i) for i in range(n)]}
-    aggs = (daft_tpu.col("v").sum().alias("s"),)
-    host = _host_groupby(data, ("k",), aggs, monkeypatch)
-    costmodel.ledger_reset()
-    dev = _device_groupby(data, ("k",), aggs, monkeypatch)
-    snap = costmodel.ledger_snapshot()
-    _pydicts_close(dev, host)
-    assert snap["grouped_agg"]["strategy"] == "hash"
-    costmodel.ledger_reset()
-
-
-def test_engine_groupby_wide_keys_fall_back_to_sort(monkeypatch):
-    """Three i64 key columns pack past the 128-bit hash budget: even
-    forced-hash queries route to the sort path and stay host-exact."""
-    rng = np.random.default_rng(5)
-    n = 300
-    big = 1 << 60
-    data = {
-        "a": (rng.integers(-big, big, n)).tolist(),
-        "b": (rng.integers(-big, big, n) | 1).tolist(),
-        "c": rng.integers(0, 3, n).tolist(),
-        "v": rng.uniform(0, 10, n).round(2).tolist(),
-    }
-    # only 3 distinct (a, b, c) triples → grouping is real
-    for col_ in ("a", "b"):
-        data[col_] = [data[col_][i % 3] for i in range(n)]
-    aggs = (daft_tpu.col("v").sum().alias("s"),)
-    host = _host_groupby(data, ("a", "b", "c"), aggs, monkeypatch)
-    costmodel.ledger_reset()
-    dev = _device_groupby(data, ("a", "b", "c"), aggs, monkeypatch)
-    snap = costmodel.ledger_snapshot()
     _pydicts_close(dev, host)
     assert snap["grouped_agg"]["strategy"] == "sort"
-    costmodel.ledger_reset()
 
 
-# ------------------------------------------ hash dispatch contracts
-
-def test_hash_agg_jaxpr_contracts():
-    """Single-sourced with the lint rule: ONE pallas_call (the table
-    build), slot compaction within the ≤3-operand sort budget, zero
-    host callbacks."""
-    jx = rule_jit.hash_agg_jaxpr()
-    assert rule_jit.count_primitive(jx.jaxpr, "pallas_call") \
-        == rule_jit.HASH_AGG_PALLAS_CALLS
-    assert rule_jit.max_sort_operands(jx.jaxpr) \
-        <= rule_jit.ARGSORT_MAX_SORT_OPERANDS
-    for prim in rule_jit.FORBIDDEN_IN_FUSED_JOIN:
-        assert rule_jit.count_primitive(jx.jaxpr, prim) == 0
-
-
-def test_hash_join_jaxpr_contracts():
-    """TWO pallas_calls (build + probe) fused in one jit program, NO
-    lax.sort anywhere, zero host callbacks."""
-    jx = rule_jit.hash_join_jaxpr()
-    assert rule_jit.count_primitive(jx.jaxpr, "pallas_call") \
-        == rule_jit.HASH_JOIN_PALLAS_CALLS
-    assert rule_jit.max_sort_operands(jx.jaxpr) \
-        <= rule_jit.HASH_JOIN_MAX_SORT_OPERANDS
-    for prim in rule_jit.FORBIDDEN_IN_FUSED_JOIN:
-        assert rule_jit.count_primitive(jx.jaxpr, prim) == 0
-
-
-def test_mfu_report_has_hash_rows_with_strategy():
-    """`mfu.report()` times the hash kernels in-jit too (shrunk smoke
-    size under the interpreter) and tags every row with its strategy."""
-    r = mfu.report(n=1 << 10)
-    assert "hash_error" not in r, r.get("hash_error")
-    assert r["grouped_agg_hash"]["strategy"] == "hash"
-    assert r["grouped_agg_hash"]["interpret"] is True
-    assert r["join_hash"]["strategy"] == "hash"
-    assert r["grouped_agg"]["strategy"] == "sort"
-    assert r["join"]["strategy"] == "sort"
-
-
-# ----------------------------------- review-hardening regressions (r12)
-
-def test_load_factor_one_cannot_silently_drop_groups(monkeypatch):
-    """`DAFT_TPU_KERNEL_HASH_LOAD=1.0` used to make the table exactly
-    `out_cap` slots — it filled silently instead of signalling
-    `group_count > out_cap`, truncating the answer. The clamp now keeps
-    the table strictly larger than the group budget, so overflow always
-    signals."""
-    monkeypatch.setenv("DAFT_TPU_KERNEL_HASH_LOAD", "1.0")
-    assert pk.table_capacity(128) > 128
-    C, ndv = 256, 200
-    ones = jnp.ones(C, bool)
-    out = pk.hash_grouped_agg_impl(
-        (jnp.asarray(np.arange(C) % ndv, jnp.int64),), (ones,),
-        (jnp.ones(C, jnp.float32),), (ones,), ones, ("sum",), 128,
-        interpret=True, block=64)
-    assert int(np.asarray(out[-1])) > 128  # overflow signalled, not eaten
-
-
-def test_saturated_hash_overflow_switches_to_sort(monkeypatch):
-    """A completely FULL hash table reports only a lower bound on the
-    group count, so the overflow re-dispatch switches to the sort
-    strategy (whose header is exact) instead of doubling the hash
-    bucket one full row pass at a time: hash@128 (saturated) →
-    sort (true count) → hash at the fitting bucket = 3 dispatches."""
+def _run_fused(data, key, monkeypatch):
+    """``sum(v) group by key`` through the fused-fragment ladder alone
+    (no merge stage) → ({key: sum}, ledger row, strategy tally)."""
     from daft_tpu.aggs import split_agg_expr
-    from daft_tpu.device import fragment
     monkeypatch.setenv("DAFT_TPU_DEVICE", "1")
     monkeypatch.setenv("DAFT_TPU_DEVICE_FORCE", "1")
-    monkeypatch.setenv("DAFT_TPU_KERNEL_GROUPBY", "hash")
-    n, ndv = 2048, 1500
-    rb = RecordBatch.from_pydict(
-        {"k": [int(i % ndv) for i in range(n)],
-         "v": [float(i % 7) for i in range(n)]})
+    rb = RecordBatch.from_pydict(data)
     agg = daft_tpu.col("v").sum().alias("s")
     op, child, name, _pred = split_agg_expr(agg)
-    gexprs = [daft_tpu.col("k")]
-    prog = fragment.get_fused_agg(
-        gexprs, [(child if child is not None else daft_tpu.lit(True))
-                 .alias("__v0__")], (op,), None, rb.schema)
+    gexprs = [daft_tpu.col(key)]
+    prog = fragment.get_fused_agg(gexprs, [child.alias("__v0__")], (op,),
+                                  None, rb.schema)
     assert prog is not None
-    host = rb.agg([agg], gexprs)
-    costmodel.ledger_reset()
-    out = fragment.run_fused_agg(prog, rb, gexprs, [daft_tpu.col(name)],
-                                 host.schema)
-    snap = costmodel.ledger_snapshot()
-    costmodel.ledger_reset()
-    assert out is not None
-    got = dict(zip(out.to_pydict()["k"], out.to_pydict()["s"]))
-    want = dict(zip(host.to_pydict()["k"], host.to_pydict()["s"]))
-    assert len(got) == ndv
-    for k in want:
-        assert got[k] == pytest.approx(want[k], rel=1e-5)
-    assert snap["grouped_agg"]["dispatches"] == 3, snap["grouped_agg"]
-
-
-def test_interpret_knob_auto_means_autodetect(monkeypatch):
-    """Exporting the knob's documented default spelling (`auto`) must
-    mean backend autodetection, not force-the-emulator — on silicon that
-    would silently run every hash kernel as a python-level emulation."""
-    from daft_tpu.device import backend
-    monkeypatch.setenv("DAFT_TPU_KERNEL_INTERPRET", "auto")
-    monkeypatch.setattr(backend, "backend_name", lambda: "tpu")
-    assert pk.interpret_default() is False   # autodetect follows silicon
-    monkeypatch.setenv("DAFT_TPU_KERNEL_INTERPRET", "1")
-    assert pk.interpret_default() is True    # explicit force still wins
-    monkeypatch.setattr(backend, "backend_name", lambda: "cpu")
-    monkeypatch.setenv("DAFT_TPU_KERNEL_INTERPRET", "0")
-    assert pk.interpret_default() is False
-
-
-def test_join_overflow_past_table_ceiling_switches_to_sort(monkeypatch):
-    """A many-to-many blowup whose grown output bucket exceeds the
-    on-chip slot ceiling re-dispatches on the SORT kernel (the hash
-    probe pins two cap-sized index planes on-chip; XLA's buffers live in
-    HBM) — and the ledger accounts each strategy's dispatch separately."""
-    from daft_tpu import joins
-    monkeypatch.setenv("DAFT_TPU_DEVICE_FORCE", "1")
-    monkeypatch.setenv("DAFT_TPU_KERNEL_JOIN", "hash")
-    monkeypatch.setenv("DAFT_TPU_KERNEL_MAX_TABLE", "2048")
-    n = 400  # 400*400 pairs → bucket_capacity(160000) >> 2048 slots
-    lk = np.zeros(n, np.int64)
-    rk = np.zeros(n, np.int64)
-    ones = np.ones(n, bool)
-    calls = {"hash": 0, "sort": 0}
-    real_h, real_s = pk.hash_join_kernel, K.join_fused_kernel
-
-    def counting_h(*a, **kw):
-        calls["hash"] += 1
-        return real_h(*a, **kw)
-
-    def counting_s(*a, **kw):
-        calls["sort"] += 1
-        return real_s(*a, **kw)
-
-    monkeypatch.setattr(pk, "hash_join_kernel", counting_h)
-    monkeypatch.setattr(K, "join_fused_kernel", counting_s)
-    costmodel.ledger_reset()
-    dli, dri, dcnt = joins._device_match_indices(lk, rk, ones, ones)
-    snap = costmodel.ledger_snapshot()
-    costmodel.ledger_reset()
-    assert calls == {"hash": 1, "sort": 1}
-    assert len(dli) == n * n
-    assert dcnt.tolist() == [n] * n
-    assert snap["join"]["strategy"] == "mixed"
-    assert snap["join"]["strategy_hash"] == 1
-    assert snap["join"]["strategy_sort"] == 1
-    assert snap["join"]["dispatches"] == 2
-
-
-def test_join_strategy_declines_oversized_probe_output(monkeypatch):
-    """Auto mode declines hash when the FIRST dispatch's output bucket
-    (sized from the larger side) already exceeds the slot ceiling — the
-    probe kernel's cap-sized output planes must fit on-chip like the
-    build table."""
-    monkeypatch.setattr(costmodel, "_hash_capable_backend", lambda: True)
-    monkeypatch.delenv("DAFT_TPU_KERNEL_JOIN", raising=False)
-    monkeypatch.setenv("DAFT_TPU_KERNEL_MAX_TABLE", "2048")
-    assert costmodel._join_strategy(128, 128) == "hash"
-    assert costmodel._join_strategy(100_000, 128) == "sort"
-
-
-def test_mfu_hash_join_measures_admissible_config(monkeypatch):
-    """measure_hash_join clamps its row count so the measured config is
-    one the strategy model would dispatch: the 2× build table must stay
-    within the slot ceiling (an inadmissible config fails to lower on
-    silicon and would erase the roofline row)."""
-    monkeypatch.setenv("DAFT_TPU_KERNEL_MAX_TABLE", "512")
-    out = mfu.measure_hash_join(1 << 20)
-    assert out["rows"] == 256
-    assert out["table_slots"] <= 512
-
-
-def test_hash_join_kernel_block_knob_retrace(monkeypatch):
-    """The block size is resolved OUTSIDE the trace and passed into the
-    jitted program (jit hygiene): changing `DAFT_TPU_KERNEL_BLOCK`
-    re-traces at the new block and the answer is unchanged."""
-    rng = np.random.default_rng(11)
-    C = 64
-    lk = jnp.asarray(rng.integers(0, 8, C).astype(np.int64))
-    rk = jnp.asarray(rng.integers(0, 8, C).astype(np.int64))
-    ones = jnp.ones(C, bool)
-    monkeypatch.setenv("DAFT_TPU_KERNEL_BLOCK", "32")
-    a = np.asarray(pk.hash_join_kernel(lk, ones, ones, rk, ones, ones,
-                                       out_capacity=1024))
-    monkeypatch.setenv("DAFT_TPU_KERNEL_BLOCK", "16")
-    b = np.asarray(pk.hash_join_kernel(lk, ones, ones, rk, ones, ones,
-                                       out_capacity=1024))
-    assert np.array_equal(a, b)
-
-
-def test_fused_agg_strategy_counts_tally_dispatches(monkeypatch):
-    """decision_counts describes what DISPATCHED: one fused forced-hash
-    group-by tallies exactly its acted-on dispatches (strategy_for is a
-    pure ask — the old pre-dispatch logging double-counted re-asks and
-    missed width-gate fallbacks entirely)."""
-    n, ndv = 1000, 64  # fits the first bucket: no overflow ladder
-    data = {"k": [int(i % ndv) for i in range(n)],
-            "v": [float(i) for i in range(n)]}
-    aggs = (daft_tpu.col("v").sum().alias("s"),)
-    host = _host_groupby(data, ("k",), aggs, monkeypatch)
+    out_schema = rb.agg([agg], gexprs).schema
     with costmodel._counts_lock:
         costmodel.decision_counts.pop("groupby_strategy", None)
     costmodel.ledger_reset()
-    dev = _device_groupby(data, ("k",), aggs, monkeypatch)
-    snap = costmodel.ledger_snapshot()
+    out = fragment.run_fused_agg(prog, rb, gexprs, [daft_tpu.col(name)],
+                                 out_schema)
+    snap = costmodel.ledger_snapshot()["grouped_agg"]
     costmodel.ledger_reset()
+    assert out is not None
+    got = out.to_pydict()
+    return (dict(zip(got[key], got["s"])), snap,
+            dict(costmodel.decision_counts["groupby_strategy"]))
+
+
+def test_sort_overflow_ladder_grows_its_bucket(monkeypatch):
+    """More groups than the first packed-output bucket (128): the header
+    carries the true count, the ladder re-dispatches the sort program
+    ONCE at the fitting bucket, the answer is exact, and the ledger row
+    counts both rungs under `sort`."""
+    n, ndv = 2048, 1500
+    data = {"k": [int(i % ndv) for i in range(n)],
+            "v": [float(i % 7) for i in range(n)]}
+    got, snap, tally = _run_fused(data, "k", monkeypatch)
+    want = {}
+    for k, v in zip(data["k"], data["v"]):
+        want[k] = want.get(k, 0.0) + v
+    assert got == pytest.approx(want)
+    assert (snap["dispatches"], snap["strategy"]) == (2, "sort")
+    assert snap["rows"] == n
+    assert tally == {"device": 0, "host": 2}   # one tally a rung
+
+
+def test_dense_ladder_is_one_rung_whatever_the_groups(monkeypatch):
+    """The same 1 500 groups behind a string key: the dictionary's pow2
+    bucket (2 048 + the NULL slot) is within the slot budget, the bucket
+    holds every slot, and the ladder stops at its first rung."""
+    n, ndv = 8192, 1500
+    data = {"k": [f"k{i % ndv:04d}" for i in range(n)],
+            "v": [float(i % 7) for i in range(n)]}
+    got, snap, tally = _run_fused(data, "k", monkeypatch)
+    want = {}
+    for k, v in zip(data["k"], data["v"]):
+        want[k] = want.get(k, 0.0) + v
+    assert got == pytest.approx(want)
+    assert (snap["dispatches"], snap["strategy"]) == (1, "dense")
+    assert tally == {"device": 0, "host": 1}
+
+
+@pytest.mark.parametrize("big_file,strategy", [(False, "dense"),
+                                               (True, "sort")])
+def test_scan_batch_rides_one_strategy(tmp_path, monkeypatch, big_file,
+                                       strategy):
+    """The batch path plans dense per table and dispatches it only when
+    EVERY table of the window fits the slot budget; one file whose
+    dictionary outgrows it (5 000 strings -> 8 193 slots) sends the
+    whole window down the sort strategy (its 5 000 groups re-dispatched
+    at a grown bucket) and the ledger says so. Either way the answer is
+    the host's."""
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+    from daft_tpu.context import execution_config_ctx
+    from daft_tpu.device import cache as dcache
+    n_files = 3
+    for i in range(n_files):
+        ndv = 5000 if (big_file and i == 0) else 3
+        n = 6000
+        pq.write_table(
+            pa.table({"k": [f"k{(j * 7 + i) % ndv:04d}" for j in range(n)],
+                      "v": [float(j % 11) for j in range(n)]}),
+            str(tmp_path / f"part{i}.parquet"))
+
+    def run():
+        # one scan task a file, one synchronous window over all of them
+        with execution_config_ctx(scan_tasks_min_size_bytes=1):
+            return (daft_tpu.read_parquet(f"{tmp_path}/*.parquet")
+                    .groupby("k").agg(daft_tpu.col("v").sum().alias("s"))
+                    .sort("k").to_pydict())
+
+    monkeypatch.setenv("DAFT_TPU_DEVICE_INFLIGHT", "0")
+    monkeypatch.setenv("DAFT_TPU_DEVICE", "0")
+    host = run()
+    monkeypatch.setenv("DAFT_TPU_DEVICE", "1")
+    monkeypatch.setenv("DAFT_TPU_DEVICE_FORCE", "1")
+    dcache.get_cache().clear()
+    with costmodel._counts_lock:
+        costmodel.decision_counts.pop("groupby_strategy", None)
+    costmodel.ledger_reset()
+    dev = run()
+    dense, sort = _strategy_counts()
+    tally = costmodel.decision_counts["groupby_strategy"]["host"]
+    costmodel.ledger_reset()
+    dcache.get_cache().clear()
     _pydicts_close(dev, host)
-    counts = costmodel.decision_counts.get("groupby_strategy")
-    assert counts["host"] == 0  # forced hash: no sort decision tallied
-    assert counts["device"] == snap["grouped_agg"]["dispatches"], \
-        (counts, snap["grouped_agg"])
+    # beside the window's dispatches: the merge of the partials, an
+    # in-memory batch on the sort kernel (one dispatch, one tally)
+    if strategy == "dense":
+        assert (dense, sort) == (n_files, 1)   # one a table, no retry
+        assert tally == 1 + 1                  # the window, the merge
+    else:
+        assert (dense, sort) == (0, n_files + 1)
+        assert tally == 1 + 1 + 1   # the window, its retried table, merge
+
+
+def test_fused_agg_strategy_counts_tally_dispatches(monkeypatch):
+    """decision_counts describes what DISPATCHED: a group-by tallies one
+    `groupby_strategy` decision a dispatch of its grouped_agg family, on
+    the "host" side of the counts whichever strategy ran — the side the
+    benchmark's `device_decisions_pct` has in its denominator."""
+    n, ndv = 1000, 64  # fits the first bucket: no overflow ladder
+    data = {"k": [f"k{i % ndv:02d}" for i in range(n)],
+            "i": [int(i % ndv) for i in range(n)],
+            "v": [float(i) for i in range(n)]}
+    aggs = (daft_tpu.col("v").sum().alias("s"),)
+    for key, want in (("k", (1, 1)), ("i", (0, 2))):
+        host = _host_groupby(data, (key,), aggs, monkeypatch)
+        with costmodel._counts_lock:
+            costmodel.decision_counts.pop("groupby_strategy", None)
+        costmodel.ledger_reset()
+        dev = _device_groupby(data, (key,), aggs, monkeypatch)
+        assert _strategy_counts() == want
+        snap = costmodel.ledger_snapshot()
+        costmodel.ledger_reset()
+        _pydicts_close(dev, host)
+        counts = costmodel.decision_counts.get("groupby_strategy")
+        assert counts["device"] == 0
+        assert counts["host"] == snap["grouped_agg"]["dispatches"] == 2, \
+            (counts, snap["grouped_agg"])
+
+
+def test_pow2_dims_share_one_traced_program(monkeypatch):
+    """Per-morsel dictionaries drift in size; their pow2 buckets — the
+    static `dims` of the dense program — do not, so tables with 3 and 4
+    distinct keys re-enter ONE trace and a 5th key costs one more."""
+    traces = []
+    real = K.grouped_agg_dense_impl
+
+    def counting(*a, **kw):
+        traces.append(a[-1])
+        return real(*a, **kw)
+
+    monkeypatch.setattr(K, "grouped_agg_dense_impl", counting)
+    fragment._fused_cache.clear()   # a fresh program: nothing traced yet
+    for ndv in (3, 4, 5):
+        got, snap, _ = _run_fused(
+            {"k": _strings(ndv), "v": [1.0] * 200}, "k", monkeypatch)
+        assert len(got) == ndv and snap["strategy"] == "dense"
+    assert traces == [(4,), (8,)]
+
+
+def test_warmup_compiles_one_sort_program_a_size_class():
+    """The AOT warm-up asks the compiler for the sort twin of each fused
+    program at each size class and for nothing it cannot build: no
+    errors to count."""
+    from daft_tpu.device import warmup
+    prog, _ = _fused_prog_and_table(
+        {"a": list(range(200)), "v": [1.0] * 200}, ("a",))
+    st = warmup.warmup_fragments([128, 256], progs=[prog])
+    assert st == {"programs": 2, "skipped": 0, "errors": 0}

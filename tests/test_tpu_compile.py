@@ -2,15 +2,16 @@
 own compiler, for a described (not attached) ``v5e:2x2``, at no chip time
 (``/opt/skills/guides/on-chip-measurement`` section 2).
 
-Interpret-mode and CPU-backend tests cannot see what the TPU compiler
-refuses (PR 23 found the Pallas hash kernels refused outright), so the
-programs TPC-H Q1/Q6/Q3 dispatch are lowered and compiled here: the sort
-grouped-agg in Q1's key/agg layout, the fused join, the packed-key argsort,
-one fused scan->filter->agg fragment, and the mesh grouped-agg collective on
-a 4-device ``Mesh`` of the described devices. Capacities are moderate on
-purpose (16384-row sorts, ~25 s each): ``lax.sort`` compile time on this
-compiler grows with the bucket (ROADMAP A8), and the whole file must stay
-under ~3 minutes in one worker.
+CPU-backend tests cannot see what the TPU compiler refuses (PR 23 found a
+set of Pallas kernels refused outright; they are gone), so the programs
+TPC-H Q1/Q6/Q3 dispatch are lowered and compiled here: Q1's fused fragment
+at the dense strategy every benchmark cell runs, the sort grouped-agg in
+Q1's key/agg layout (what a non-dictionary key falls to), the fused join,
+the packed-key argsort, Q6's fused scan->filter->agg fragment, and the mesh
+grouped-agg collective on a 4-device ``Mesh`` of the described devices.
+Capacities are moderate on purpose (16384-row sorts, ~25 s each):
+``lax.sort`` compile time on this compiler grows with the bucket (ROADMAP
+A8), and the whole file must stay under ~3 minutes in one worker.
 
 Rules this file keeps (the guide's, because pytest-xdist imports every test
 file in every worker and only ONE process may load libtpu): the topology is
@@ -31,7 +32,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from daft_tpu.device import costmodel, kernels
+from daft_tpu.device import kernels
 
 
 @pytest.fixture(scope="module")
@@ -59,7 +60,9 @@ def one_chip(topo):
 def _q1_sort_grouped_agg(S):
     """kernels.grouped_agg_impl in TPC-H Q1's layout: two int32 dictionary
     -code keys (l_returnflag, l_linestatus), seven f32 sums (f64 rides f32
-    on the TPU) — the sort strategy every TPU group-by resolves to."""
+    on the TPU) — the sort strategy, which a group-by takes when
+    ``fragment.dense_plan`` declines (a key without a dictionary, or more
+    than 4 096 slots); Q1 itself runs ``_fused_q1_dense``."""
     C = 16384
     ops = ("sum",) * 7
     keys = (S((C,), jnp.int32),) * 2
@@ -121,7 +124,53 @@ def _fused_scan_filter_agg(S):
                                 dims=())
 
 
+def _fused_q1_dense(S):
+    """TPC-H Q1's fused scan->filter->project->agg fragment at
+    ``strategy="dense"``: the program all five benchmark cells dispatch,
+    once a lineitem file (``agg_hbm_pct`` is its device time). Two string
+    keys as dictionary codes with their pow2 ``dims`` (3 return flags -> 4,
+    2 line statuses -> 2: 15 slots in the 128 bucket), the date predicate,
+    and the partial aggregates the planner hands the fragment: the query's
+    four sums, and a (sum, count) pair for each of its three means, then
+    its count."""
+    import datetime
+
+    from daft_tpu import DataType, col, lit
+    from daft_tpu.device import fragment
+    from daft_tpu.schema import Field, Schema
+    f32 = DataType.float32()
+    schema = Schema([Field("l_returnflag", DataType.string()),
+                     Field("l_linestatus", DataType.string()),
+                     Field("l_quantity", f32),
+                     Field("l_extendedprice", f32),
+                     Field("l_discount", f32), Field("l_tax", f32),
+                     Field("l_shipdate", DataType.date())])
+    disc_price = col("l_extendedprice") * (1 - col("l_discount"))
+    charge = disc_price * (1 + col("l_tax"))
+    qty, price, disc = (col("l_quantity"), col("l_extendedprice"),
+                        col("l_discount"))
+    children = [qty, price, disc_price, charge,
+                qty, qty, price, price, disc, disc, qty]
+    ops = ("sum", "sum", "sum", "sum",
+           "sum", "count", "sum", "count", "sum", "count", "count")
+    prog = fragment.get_fused_agg(
+        [col(k).alias(k) for k in ("l_returnflag", "l_linestatus")],
+        [c.alias(f"__v{i}__") for i, c in enumerate(children)], ops,
+        col("l_shipdate") <= lit(datetime.date(1998, 9, 2)), schema)
+    assert prog is not None, "Q1-shaped fragment must be device-compilable"
+    assert prog.key_sources == ("l_returnflag", "l_linestatus")
+    C = 524288   # the bucket of _fused_scan_filter_agg: SF1 in 16 parts
+    arrays = {n: S((C,), prog.in_np_dtypes[n])
+              for n in prog.compiled.needs_cols}
+    valids = {n: S((C,), jnp.bool_) for n in prog.compiled.needs_cols}
+    assert not prog.compiled.scalar_specs
+    return prog.packed_fn.lower(arrays, valids, S((C,), jnp.bool_), (),
+                                out_cap=fragment._OUT_CAP0, strategy="dense",
+                                dims=(4, 2))
+
+
 ONE_CHIP_PROGRAMS = {
+    "fused_scan_filter_agg_q1_dense": _fused_q1_dense,
     "sort_grouped_agg_q1_layout": _q1_sort_grouped_agg,
     "join_fused": _join_fused,
     "packed_key_argsort": _packed_argsort,
@@ -161,69 +210,3 @@ def test_sharded_grouped_agg_compiles_for_four_chip_mesh(topo):
                                   S(jnp.float32), S(jnp.float32), b, b, b)
     text = lowered.compile().as_text()
     assert "all-to-all" in text
-
-
-def _hash_agg(S):
-    from daft_tpu.device import pallas_kernels as pk
-    C = 1024
-    fn = jax.jit(lambda k, kv, v, vv, m: pk.hash_grouped_agg_impl(
-        (k,), (kv,), (v,), (vv,), m, ("sum",), C, interpret=False))
-    return fn.lower(S((C,), jnp.int32), S((C,), jnp.bool_),
-                    S((C,), jnp.float32), S((C,), jnp.bool_),
-                    S((C,), jnp.bool_))
-
-
-def _hash_join(S):
-    from daft_tpu.device import pallas_kernels as pk
-    C = 1024
-    fn = jax.jit(lambda a, b, c, d, e, f: pk.hash_join_impl(
-        a, b, c, d, e, f, out_capacity=C, interpret=False))
-    return fn.lower(S((C,), jnp.int64), S((C,), jnp.bool_),
-                    S((C,), jnp.bool_), S((C,), jnp.int64),
-                    S((C,), jnp.bool_), S((C,), jnp.bool_))
-
-
-@pytest.mark.parametrize("build", [_hash_agg, _hash_join],
-                         ids=["hash_grouped_agg", "hash_join"])
-def test_pallas_hash_kernels_are_refused_by_the_tpu_compiler(build,
-                                                             one_chip):
-    """Pins the verdict ``costmodel._HASH_KERNELS_COMPILE_ON`` records: with
-    ``interpret=False`` the TPU kernel compiler refuses both Pallas hash
-    kernels, so a forced ``DAFT_TPU_KERNEL_GROUPBY/JOIN=hash`` on the chip
-    raises. A PR that makes them lower flips this test and the gate
-    together."""
-    def S(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    with pytest.raises(Exception, match="Pallas TPU lowering|Mosaic|"
-                                        "[Uu]nimplemented|not implemented"):
-        build(S).compile()
-
-
-def test_auto_never_selects_hash_kernels_on_tpu(monkeypatch):
-    """The honest kernel gate: with the backend reported as ``tpu``,
-    ``auto`` resolves both strategy models to ``sort`` at every size (the
-    Pallas hash kernels do not compile for the TPU), the interpreter is
-    never chosen for an accelerator, and a FORCED hash is honoured so the
-    compiler's refusal reaches the user instead of a silent sort run."""
-    from daft_tpu.device import backend, pallas_kernels as pk
-    monkeypatch.setattr(backend, "backend_name", lambda wait=True: "tpu")
-    monkeypatch.delenv("DAFT_TPU_KERNEL_GROUPBY", raising=False)
-    monkeypatch.delenv("DAFT_TPU_KERNEL_JOIN", raising=False)
-    monkeypatch.delenv("DAFT_TPU_KERNEL_INTERPRET", raising=False)
-    assert backend.is_accelerator()
-    assert not costmodel._hash_capable_backend()
-    assert pk.interpret_default() is False
-    for rows in (1 << 10, 1 << 14, 1 << 17, 1 << 20):
-        for groups in (None, 4.0, rows / 2):
-            s, _ = costmodel.groupby_strategy(
-                rows, groups, [np.dtype("int32")] * 2, 1024, log=False)
-            assert s == "sort", (rows, groups)
-        for n_right in (1 << 8, 1 << 12, rows):
-            assert costmodel._join_strategy(rows, n_right) == "sort"
-    monkeypatch.setenv("DAFT_TPU_KERNEL_GROUPBY", "hash")
-    monkeypatch.setenv("DAFT_TPU_KERNEL_JOIN", "hash")
-    assert costmodel.groupby_strategy(
-        1 << 14, 4.0, [np.dtype("int32")], 1024, log=False)[0] == "hash"
-    assert costmodel._join_strategy(1 << 14, 1 << 10) == "hash"
-    assert pk.interpret_default() is False   # forced hash still compiles
