@@ -20,7 +20,7 @@ time to reverse the packing host-side.
 from __future__ import annotations
 
 import threading as _threading
-from typing import Dict, List, Optional, Tuple
+from typing import TYPE_CHECKING, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -32,6 +32,9 @@ from ..expressions.expressions import Expression
 from ..schema import Schema
 from . import column as dcol
 from . import compiler, kernels, runtime
+
+if TYPE_CHECKING:
+    from ..recordbatch import RecordBatch
 
 _fused_cache: Dict[Tuple, object] = {}
 _fused_counters: Dict[str, int] = {"hits": 0, "misses": 0}
@@ -323,45 +326,81 @@ def _donation_ok(dt: dcol.DeviceTable) -> bool:
     return backend.is_accelerator() and not dt.resident
 
 
-def _decode_packed_global(prog: FusedAggProgram, packed: np.ndarray,
-                          agg_fields):
-    from ..recordbatch import RecordBatch
-    dtypes = prog.meta["global_dtypes"]
-    nv = len(agg_fields)
-    cols = []
-    for i, f in enumerate(agg_fields):
-        v = _unpack_i64(packed[i:i + 1], dtypes[i])
-        m = _unpack_i64(packed[nv + i:nv + i + 1], dtypes[nv + i])
-        cols.append(runtime._decode_scalar(f.name, f.dtype, v,
-                                           m.astype(np.bool_)))
-    return RecordBatch.from_series(cols)
+def _overflowed(mat: np.ndarray, dt: dcol.DeviceTable) -> bool:
+    """A packed group block whose header counts more groups than its
+    bucket holds (the caller re-runs the table at a grown bucket)."""
+    out_cap = mat.shape[1]
+    return int(mat[0, 0]) > out_cap and out_cap < dt.capacity
 
 
-def _decode_packed_grouped(prog: FusedAggProgram, packed: np.ndarray,
-                           dt: dcol.DeviceTable, group_exprs, key_fields,
-                           agg_fields):
-    """Unpack one packed group-block matrix → RecordBatch, or None when the
-    group count overflowed the packed capacity (caller re-runs bigger)."""
+def _key_dictionary_runs(src: str, tables) -> List[Tuple[int, int]]:
+    """``[a, b)`` stretches of ``tables`` whose dictionaries of column
+    ``src`` are one object or ``equals()`` each other: a key lane carries
+    codes of each table's OWN dictionary, so only such a stretch decodes
+    through one ``take``."""
+    def same(d0, d1):
+        return d1 is d0 or (d0 is not None and d1 is not None
+                            and d1.equals(d0))
+
+    runs, a = [], 0
+    for b in range(1, len(tables)):
+        if not same(tables[a].columns[src].dictionary,
+                    tables[b].columns[src].dictionary):
+            runs.append((a, b))
+            a = b
+    runs.append((a, len(tables)))
+    return runs
+
+
+def _decode_lanes(tok, mats, tables):
+    """Packed results of ``tables`` (one ``mats`` entry each; a grouped
+    block must hold its groups, see :func:`_overflowed`) -> ONE
+    RecordBatch, the tables' partial rows in order, and ``ends``: table
+    ``k``'s rows of it are ``ends[k]:ends[k + 1]``. Every lane is
+    unpacked and decoded once over all the tables: what a table costs
+    here is a slice and its share of a concatenation, not a round of
+    Arrow arrays for a handful of groups."""
+    from .. import tracing
     from ..recordbatch import RecordBatch
-    g = int(packed[0, 0])
-    out_cap = packed.shape[1]
-    if g > out_cap and out_cap < dt.capacity:
-        return None
-    dtypes = prog.meta["grouped_dtypes"]
+    from ..series import Series
+    prog, agg_fields = tok.prog, tok.agg_fields
     nk, nv = prog.nk, len(agg_fields)
-    rows = packed[1:]
+    if nk == 0:
+        dtypes = prog.meta["global_dtypes"]
+        # [T, 2*nv] -> one contiguous row a lane
+        lanes = np.ascontiguousarray(np.stack(mats).T)
+        counts = [1] * len(mats)
+    else:
+        dtypes = prog.meta["grouped_dtypes"]
+        # blocks of a window differ in out_cap (dense dims are bucketed
+        # per table, sort starts at _OUT_CAP0): cut each to its groups
+        counts = [int(m[0, 0]) for m in mats]
+        lanes = np.concatenate(
+            [m[1:, :g] for m, g in zip(mats, counts)], axis=1)
+    ends = np.cumsum([0] + counts).tolist()
+
+    def lane(v, m):
+        # run_packed's layout: keys, key validity, values, value validity
+        return (_unpack_i64(lanes[v], dtypes[v]),
+                _unpack_i64(lanes[m], dtypes[m]).astype(np.bool_))
+
     cols = []
-    for i, (e, f) in enumerate(zip(group_exprs, key_fields)):
-        kv = _unpack_i64(rows[i][:g], dtypes[i])
-        km = _unpack_i64(rows[nk + i][:g], dtypes[nk + i]).astype(np.bool_)
-        cols.append(runtime.decode_group_key(e, f, kv, km, dt, g))
+    for i, (e, f) in enumerate(zip(tok.group_exprs, tok.key_fields)):
+        kv, km = lane(i, nk + i)
+        coded = f.dtype.is_string() or f.dtype.is_binary()
+        runs = _key_dictionary_runs(runtime._string_out_source(e), tables) \
+            if coded else [(0, len(tables))]
+        parts = [runtime.decode_group_key(
+            e, f, kv[ends[a]:ends[b]], km[ends[a]:ends[b]], tables[a],
+            ends[b] - ends[a]) for a, b in runs]
+        cols.append(parts[0] if len(parts) == 1 else Series.concat(parts))
     for i, f in enumerate(agg_fields):
-        vv = _unpack_i64(rows[2 * nk + i][:g], dtypes[2 * nk + i])
-        vm = _unpack_i64(rows[2 * nk + nv + i][:g],
-                         dtypes[2 * nk + nv + i]).astype(np.bool_)
-        dc = dcol.DeviceColumn(vv, vm, f.dtype, None)
-        cols.append(dcol.decode_column(f.name, dc, g))
-    return RecordBatch.from_series(cols)
+        vv, vm = lane(2 * nk + i, 2 * nk + nv + i)
+        cols.append(dcol.decode_column(
+            f.name, dcol.DeviceColumn(vv, vm, f.dtype, None), ends[-1]))
+    tracing.tally("decode_tables", len(mats))
+    tracing.tally("decode_batches")
+    return RecordBatch.from_series(cols), ends
 
 
 def packed_bytes_per_group(nk: int, nops: int) -> int:
@@ -522,17 +561,18 @@ def drain_fused_agg_table(tok: InflightFusedAgg):
                        tok.submitted_s + (_time.perf_counter() - t_drain0),
                        1)
         with tracing.span("device:decode", lane="device",
-                          attrs={"tables": 1, "groups": 1}):
-            return _decode_packed_global(prog, packed, tok.agg_fields)
+                          attrs={"tables": 1, "batches": 1, "groups": 1}):
+            return _decode_lanes(tok, [packed], [dt])[0]
     while True:
         packed = np.asarray(pipeline.fetch_host(tok.packed))
-        with tracing.span("device:decode", lane="device",
-                          attrs={"tables": 1}) as sp:
-            out = _decode_packed_grouped(prog, packed, tok.dt,
-                                         tok.group_exprs, tok.key_fields,
-                                         tok.agg_fields)
-            sp.set("groups", len(out) if out is not None else 0)
-        if out is not None:
+        # the packed header carries the true group count
+        g = int(packed[0, 0])
+        if not _overflowed(packed, tok.dt):
+            # a window of one table
+            with tracing.span("device:decode", lane="device",
+                              attrs={"tables": 1, "batches": 1,
+                                     "groups": g}):
+                out, _ = _decode_lanes(tok, [packed], [tok.dt])
             # submit wall + drain wall — NOT t0→now, which under the
             # async window would charge time the token sat undrained
             # behind its predecessors
@@ -542,8 +582,6 @@ def drain_fused_agg_table(tok: InflightFusedAgg):
                             + (_time.perf_counter() - t_drain0),
                             tok.dispatches, tok.strategy)
             return out
-        # the packed header carries the true group count
-        g = int(packed[0, 0])
         if g > tok.cap_limit:
             return None
         if tok.donate:
@@ -570,19 +608,31 @@ def run_fused_agg_table(prog: FusedAggProgram, dt: dcol.DeviceTable,
         start_out_cap=start_out_cap, reencode=reencode))
 
 
+class DecodedRun(NamedTuple):
+    """Neighbouring tables of a window and the ONE record batch their
+    partial rows were decoded into, in task order; ``batch`` None: one
+    table the device did not answer (the caller runs it on the host)."""
+    tables: int
+    batch: "Optional[RecordBatch]"
+
+
 class InflightFusedAggBatch:
     """A window's worth of in-flight fused-agg dispatches (one per
     DeviceTable) awaiting ONE batched pytree fetch."""
 
-    __slots__ = ("prog", "tables", "in_schema", "group_exprs", "agg_exprs",
-                 "out_schema", "key_fields", "agg_fields",
+    __slots__ = ("prog", "tables", "places", "in_schema", "group_exprs",
+                 "agg_exprs", "out_schema", "key_fields", "agg_fields",
                  "strategy", "packs", "t0", "submitted_s", "failed")
 
-    def __init__(self, prog, tables, in_schema, group_exprs, agg_exprs,
-                 out_schema):
+    def __init__(self, prog, tables, places, in_schema, group_exprs,
+                 agg_exprs, out_schema):
         import time as _time
         self.prog = prog
         self.tables = tables
+        #: each table's place among the window's tasks: tables are
+        #: neighbours, and may share a run, where these are consecutive
+        self.places = list(range(len(tables))) if places is None \
+            else list(places)
         self.in_schema = in_schema
         self.group_exprs = group_exprs
         self.agg_exprs = agg_exprs
@@ -598,13 +648,14 @@ class InflightFusedAggBatch:
 
 def submit_fused_agg_tables(prog: FusedAggProgram, tables,
                             in_schema: Schema, group_exprs, agg_exprs,
-                            out_schema: Schema) -> InflightFusedAggBatch:
+                            out_schema: Schema, places=None
+                            ) -> InflightFusedAggBatch:
     """Async submit half of :func:`run_fused_agg_tables`: dispatch every
     table's fused program (no fetch).  Dispatch failures mark the token
     failed → the drain falls back per-table."""
     import time as _time
-    tok = InflightFusedAggBatch(prog, tables, in_schema, group_exprs,
-                                agg_exprs, out_schema)
+    tok = InflightFusedAggBatch(prog, tables, places, in_schema,
+                                group_exprs, agg_exprs, out_schema)
     if not tables:
         return tok
     # dense first, per table: each morsel carries its own dictionaries
@@ -640,28 +691,96 @@ def submit_fused_agg_tables(prog: FusedAggProgram, tables,
     return tok
 
 
-def drain_fused_agg_tables(tok: InflightFusedAggBatch):
+def _decode_window(tok: InflightFusedAggBatch, idx, mats, pieces) -> list:
+    """Decode the packed results ``mats`` of the tables ``idx`` into one
+    batch and note each table's rows of it in ``pieces`` as ``(batch,
+    lo, hi)``. A grouped block that overflowed its bucket is left out:
+    returned as ``(table, grown out_cap)`` to re-run, or, past the
+    link-budgeted ceiling, left None in ``pieces`` (host fallback)."""
+    from .. import tracing
+    prog, tables = tok.prog, tok.tables
+    retry, fit = [], []
+    with tracing.span("device:decode", lane="device",
+                      attrs={"tables": len(idx)}) as sp:
+        for i, mat in zip(idx, mats):
+            if not prog.nk or not _overflowed(mat, tables[i]):
+                fit.append((i, mat))
+                continue
+            g = int(mat[0, 0])
+            cap_limit = _max_out_cap(prog, tables[i])
+            if g <= cap_limit:
+                retry.append(
+                    (i, min(dcol.bucket_capacity(max(g, _OUT_CAP0)),
+                            cap_limit)))
+        if not fit:
+            return retry
+        try:
+            batch, ends = _decode_lanes(tok, [m for _, m in fit],
+                                        [tables[i] for i, _ in fit])
+        except Exception as exc:
+            runtime.device_failed("fragment.fused_agg_tables.decode", exc)
+            return retry
+        for k, (i, _) in enumerate(fit):
+            pieces[i] = (batch, ends[k], ends[k + 1])
+        sp.set("batches", 1)
+        sp.set("groups", len(batch))
+    return retry
+
+
+def _runs(pieces, places) -> List[DecodedRun]:
+    """Tables in task order -> runs: neighbours (consecutive ``places``)
+    whose ``pieces`` follow each other in one batch leave as that batch,
+    or the slice of it they cover; a table with no piece stands alone."""
+    runs: List[DecodedRun] = []
+    cur = None  # [batch, lo, hi, tables, place of the last one]
+
+    def close():
+        if cur is not None:
+            batch, lo, hi, n, _ = cur
+            runs.append(DecodedRun(n, batch if hi - lo == len(batch)
+                                   else batch.slice(lo, hi)))
+
+    for piece, at in zip(pieces, places):
+        if piece is None:
+            close()
+            cur = None
+            runs.append(DecodedRun(1, None))
+        elif cur is not None and piece[0] is cur[0] \
+                and piece[1] == cur[2] and at == cur[4] + 1:
+            cur[2], cur[3], cur[4] = piece[2], cur[3] + 1, at
+        else:
+            close()
+            cur = [*piece, 1, at]
+    close()
+    return runs
+
+
+def drain_fused_agg_tables(tok: InflightFusedAggBatch) -> List[DecodedRun]:
     """Blocking drain half: ALL packed results come back in a single
     pytree ``device_get`` (one batched transfer for the whole window —
-    per-task gets would serialize one round trip each), then
-    decode; overflowed tables re-dispatch as one batch."""
+    per-task gets would serialize one round trip each), then decode
+    lane by lane over all the window's tables at once
+    (:func:`_decode_lanes`); overflowed tables re-dispatch as one batch
+    and decode as one. Returns the window's :class:`DecodedRun` s in
+    task order, every table in exactly one: a batch for each stretch of
+    neighbours decoded together, ``batch`` None for a table that failed
+    (the caller falls back for that table alone)."""
     import time as _time
 
     from . import pipeline
     prog, tables = tok.prog, tok.tables
     if not tables:
         return []
+    failed = [DecodedRun(1, None)] * len(tables)
     if tok.failed:
-        return [None] * len(tables)
-    group_exprs = tok.group_exprs
-    key_fields, agg_fields = tok.key_fields, tok.agg_fields
+        return failed
     strategy = tok.strategy
     t_drain0 = _time.perf_counter()
     try:
         stacked = [np.asarray(m) for m in pipeline.fetch_host(tok.packs)]
     except Exception as exc:
         runtime.device_failed("fragment.fused_agg_tables.fetch", exc)
-        return [None] * len(tables)
+        return failed
     if prog.nk:
         from . import costmodel
         # ONE decision acted on across the whole batch
@@ -681,34 +800,10 @@ def drain_fused_agg_tables(tok: InflightFusedAggBatch):
                        max(dt.capacity for dt in tables),
                        tok.submitted_s + (_time.perf_counter() - t_drain0),
                        len(tok.packs))
-    results: list = [None] * len(tables)
-    retry: list = []  # (index, out_cap) — re-dispatched as ONE batch, not
-    # per-table (each serial round trip pays the link RTT)
-    from .. import tracing
-    with tracing.span("device:decode", lane="device",
-                      attrs={"tables": len(tables)}) as sp:
-        for i, (dt, mat) in enumerate(zip(tables, stacked)):
-            try:
-                if prog.nk == 0:
-                    results[i] = _decode_packed_global(prog, mat,
-                                                       agg_fields)
-                    continue
-                out = _decode_packed_grouped(prog, mat, dt, group_exprs,
-                                             key_fields, agg_fields)
-                if out is not None:
-                    results[i] = out
-                    continue
-                g = int(mat[0, 0])
-                cap_limit = _max_out_cap(prog, dt)
-                if g <= cap_limit:  # else: stays None → host fallback
-                    retry.append(
-                        (i, min(dcol.bucket_capacity(max(g, _OUT_CAP0)),
-                                cap_limit)))
-            except Exception as exc:
-                runtime.device_failed("fragment.fused_agg_tables.decode",
-                                      exc)
-                results[i] = None
-        sp.set("groups", sum(len(r) for r in results if r is not None))
+    pieces: list = [None] * len(tables)
+    # overflowed tables are re-dispatched as ONE batch, not per table
+    # (each serial round trip pays the link RTT)
+    retry = _decode_window(tok, range(len(tables)), stacked, pieces)
     if retry:
         # only the sort strategy overflows (a dense bucket holds every
         # slot): the retried tables re-run it at their grown buckets
@@ -723,36 +818,31 @@ def drain_fused_agg_tables(tok: InflightFusedAggBatch):
                     out_cap=cap)
         except Exception as exc:
             runtime.device_failed("fragment.fused_agg_tables.retry", exc)
-            mats = [None] * len(retry)
-        with tracing.span("device:decode", lane="device",
-                          attrs={"tables": len(retry)}):
-            for (i, _cap), mat in zip(retry, mats):
-                if mat is None:
-                    continue
-                try:
-                    results[i] = _decode_packed_grouped(
-                        prog, mat, tables[i], group_exprs, key_fields,
-                        agg_fields)
-                except Exception as exc:
-                    runtime.device_failed(
-                        "fragment.fused_agg_tables.decode", exc)
-                    results[i] = None
-    return results
+            mats = None
+        if mats is not None:
+            # a table that overflows its grown bucket too stays None
+            _decode_window(tok, [i for i, _ in retry], mats, pieces)
+    return _runs(pieces, tok.places)
 
 
 def run_fused_agg_tables(prog: FusedAggProgram, tables, in_schema: Schema,
-                         group_exprs, agg_exprs, out_schema: Schema):
+                         group_exprs, agg_exprs, out_schema: Schema,
+                         places=None) -> List[DecodedRun]:
     """Batched execution over many DeviceTables: dispatch every fused
     program asynchronously, then fetch ALL packed results in a single
     batched device→host transfer (one round of transfers for the whole
-    scan instead of one per task). Returns a list parallel to ``tables``
-    (None → caller falls back per-table). Inputs are never donated here:
+    scan instead of one per task) and decode them together. Returns the
+    :class:`DecodedRun` s of :func:`drain_fused_agg_tables`; ``places``
+    (each table's index among the window's tasks; default: they are all
+    neighbours) keeps a run from spanning a task another tier answers.
+    Inputs are never donated here:
     the batched overflow retry re-dispatches over the same tables, and
     cache-resident tables share their buffers with the HBM column cache
     anyway.  (Single-sourced as submit + drain so the async pipeline
     overlaps window N+1's submit with window N's drain.)"""
     return drain_fused_agg_tables(submit_fused_agg_tables(
-        prog, tables, in_schema, group_exprs, agg_exprs, out_schema))
+        prog, tables, in_schema, group_exprs, agg_exprs, out_schema,
+        places))
 
 
 # ---------------------------------------------------------------------------

@@ -875,19 +875,31 @@ class LocalExecutor:
             return [c if c[0] != "cand" else next(gated_it)
                     for c in classified]
 
-        def emit(resolved, outs):
-            di = 0
+        def dev_tables(resolved):
+            """The window's device tables and each one's place among its
+            tasks: the drain decodes neighbours into one batch, and a
+            task the host answers between two of them keeps them apart."""
+            at = [j for j, r in enumerate(resolved) if r[0] == "dev"]
+            return [resolved[j][1] for j in at], at
+
+        def emit(resolved, runs):
+            """The window's partial aggregates in task order: one
+            partition for each run of device tables that decoded together
+            (``fragment.DecodedRun``), a host result for every other task."""
+            runs, inside = iter(runs), 0
             for kind, val, t in resolved:
-                if kind == "dev":
-                    out = outs[di]
-                    di += 1
-                    if out is None:  # device failure → pristine host re-read
+                if kind != "dev":
+                    yield host_agg(val)
+                elif inside:    # its rows left with its run's batch
+                    inside -= 1
+                else:
+                    n, batch = next(runs)
+                    inside = n - 1
+                    if batch is None:  # device failure → pristine host re-read
                         yield host_agg(load(t))
                     else:
                         yield MicroPartition.from_recordbatch(
-                            out.cast_to_schema(node.schema()))
-                else:
-                    yield host_agg(val)
+                            batch.cast_to_schema(node.schema()))
 
         if pwin <= 0:
             # synchronous window loop, kept verbatim as the chaos /
@@ -895,11 +907,11 @@ class LocalExecutor:
             # window N's fetch, exactly the pre-pipeline event order
             for w in windows():
                 resolved = resolve(w)
-                outs = fragment.run_fused_agg_tables(
-                    prog,
-                    [dt for kind, dt, _ in resolved if kind == "dev"],
-                    src.schema(), node.group_by, agg_cols, node.schema())
-                yield from emit(resolved, outs)
+                tables, at = dev_tables(resolved)
+                runs = fragment.run_fused_agg_tables(
+                    prog, tables, src.schema(), node.group_by, agg_cols,
+                    node.schema(), at)
+                yield from emit(resolved, runs)
             return
 
         # round 17 async pipeline over windows: window N+1's classify /
@@ -917,7 +929,7 @@ class LocalExecutor:
             with dpipe.upload_span(seq, pwin):
                 t0 = _time.perf_counter()
                 resolved = resolve(window_tasks)
-                tables = [dt for kind, dt, _ in resolved if kind == "dev"]
+                tables, at = dev_tables(resolved)
                 est = sum(
                     int(c.data.nbytes) + int(c.validity.nbytes)
                     for dt in tables for c in dt.columns.values())
@@ -927,7 +939,7 @@ class LocalExecutor:
                     t1 = _time.perf_counter()
                     tok = fragment.submit_fused_agg_tables(
                         prog, tables, src.schema(), node.group_by,
-                        agg_cols, node.schema())
+                        agg_cols, node.schema(), at)
                     sub_s = pre_s + (_time.perf_counter() - t1)
                 except BaseException:
                     dpipe.release_slot(slot)
@@ -940,13 +952,13 @@ class LocalExecutor:
             resolved, tok = ret.token
             dpipe.note_compute_span(seq, pwin, ret.t_dispatched_us)
             with dpipe.download_span(seq, pwin):
-                outs = fragment.drain_fused_agg_tables(tok)
+                runs = fragment.drain_fused_agg_tables(tok)
             # release BEFORE emitting: a device-failure fallback re-reads
             # its task through load()'s own admission, which must not
             # wait on this very slot's bytes (release_slot is idempotent
             # — the driver's release after drain becomes a no-op)
             dpipe.release_slot(ret.slot)
-            return list(emit(resolved, outs))
+            return list(emit(resolved, runs))
 
         for outs in dpipe.run_pipelined(windows(), p_submit, p_drain,
                                         window=pwin, width=pwin + 1,

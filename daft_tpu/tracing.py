@@ -224,7 +224,9 @@ class SpanRecorder:
         """Counts of the buffer; for a finished trace also where its wall
         went: ``phases`` (per span name), ``covered_us`` (the union of
         the :data:`LEAF_SPANS`), ``tables`` (the scan-task tally),
-        ``footers`` (Parquet footers planned ``from_store`` or ``read``) and
+        ``footers`` (Parquet footers planned ``from_store`` or ``read``),
+        ``decode`` (the packed results of how many device ``tables`` were
+        decoded into how many record ``batches``) and
         ``chips`` (the same tally per chip: ``chip``, ``tables``, ``rows``
         and ``resident_bytes``, one entry a chip, in chip order),
         computed once, when the root closed."""
@@ -253,6 +255,8 @@ class SpanRecorder:
                  if s["name"] in LEAF_SPANS], lo, hi)
             out["tables"] = {k: tallies.get(k, 0) for k in TABLE_SOURCES}
             out["footers"] = _footer_counts(tallies)
+            out["decode"] = {"tables": tallies.get("decode_tables", 0),
+                             "batches": tallies.get("decode_batches", 0)}
             out["chips"] = chips
         return out
 

@@ -495,3 +495,303 @@ def test_join_overlap_pricing_admits_more(monkeypatch):
         assert cm.join_wins(n_l, n_r, up, down, window=2)
     finally:
         cm.reset_for_tests()
+
+
+# ------------------------------------- a window decodes as one record batch
+
+def _ref_decode_global(prog, packed, agg_fields):
+    """The per-table decoder the lane decoder replaced (PR 37), kept as
+    the reference: one Series a lane a table."""
+    from daft_tpu.device import fragment, runtime as drt
+    from daft_tpu.recordbatch import RecordBatch
+    dtypes = prog.meta["global_dtypes"]
+    nv = len(agg_fields)
+    cols = []
+    for i, f in enumerate(agg_fields):
+        v = fragment._unpack_i64(packed[i:i + 1], dtypes[i])
+        m = fragment._unpack_i64(packed[nv + i:nv + i + 1], dtypes[nv + i])
+        cols.append(drt._decode_scalar(f.name, f.dtype, v,
+                                       m.astype(np.bool_)))
+    return RecordBatch.from_series(cols)
+
+
+def _ref_decode_grouped(prog, packed, dt, group_exprs, key_fields,
+                        agg_fields):
+    """As above, for a packed group block; None when it overflowed."""
+    from daft_tpu.device import fragment, runtime as drt
+    from daft_tpu.recordbatch import RecordBatch
+    g = int(packed[0, 0])
+    out_cap = packed.shape[1]
+    if g > out_cap and out_cap < dt.capacity:
+        return None
+    dtypes = prog.meta["grouped_dtypes"]
+    nk, nv = prog.nk, len(agg_fields)
+    rows = packed[1:]
+    cols = []
+    for i, (e, f) in enumerate(zip(group_exprs, key_fields)):
+        kv = fragment._unpack_i64(rows[i][:g], dtypes[i])
+        km = fragment._unpack_i64(rows[nk + i][:g],
+                                  dtypes[nk + i]).astype(np.bool_)
+        cols.append(drt.decode_group_key(e, f, kv, km, dt, g))
+    for i, f in enumerate(agg_fields):
+        vv = fragment._unpack_i64(rows[2 * nk + i][:g], dtypes[2 * nk + i])
+        vm = fragment._unpack_i64(rows[2 * nk + nv + i][:g],
+                                  dtypes[2 * nk + nv + i]).astype(np.bool_)
+        dc = dcol.DeviceColumn(vv, vm, f.dtype, None)
+        cols.append(dcol.decode_column(f.name, dc, g))
+    return RecordBatch.from_series(cols)
+
+
+def _ref_table(prog, dt, tok, cap_limit):
+    """One table through the ladder alone, decoded per table: its first
+    block as the window dispatched it, re-run once at the grown bucket if
+    it overflowed; None past ``cap_limit`` (host fallback)."""
+    from daft_tpu.device import fragment
+    if prog.nk == 0:
+        packed = np.asarray(fragment._dispatch_packed(
+            prog, dt, fragment._OUT_CAP0))
+        return _ref_decode_global(prog, packed, tok.agg_fields)
+    plan = fragment.dense_plan(prog, dt, fragment._max_out_cap(prog, dt))
+    packed = np.asarray(
+        fragment._dispatch_packed(prog, dt, plan[1], "dense", dims=plan[0])
+        if tok.strategy == "dense" else
+        fragment._dispatch_packed(prog, dt, fragment._OUT_CAP0, "sort"))
+    args = (tok.group_exprs, tok.key_fields, tok.agg_fields)
+    out = _ref_decode_grouped(prog, packed, dt, *args)
+    if out is not None:
+        return out
+    g = int(packed[0, 0])
+    if g > cap_limit:
+        return None
+    cap = min(dcol.bucket_capacity(max(g, fragment._OUT_CAP0)), cap_limit)
+    return _ref_decode_grouped(
+        prog, np.asarray(fragment._dispatch_packed(prog, dt, cap, "sort")),
+        dt, *args)
+
+
+def _bits(series):
+    """A column as (arrow type, validity, value bits)."""
+    import pyarrow as pa
+    import pyarrow.compute as pc
+    arr = series.to_arrow()
+    if isinstance(arr, pa.ChunkedArray):
+        arr = arr.combine_chunks()
+    valid = pc.is_valid(arr).to_pylist()
+    if pa.types.is_floating(arr.type):
+        vals = np.asarray(pc.fill_null(arr, 0.0)).view(
+            np.uint64 if arr.type == pa.float64() else np.uint32).tolist()
+    else:
+        vals = arr.to_pylist()
+    return arr.type, valid, vals
+
+
+def _keyed(keys, n, seed, null_every=0):
+    rng = np.random.default_rng(seed)
+    out = [keys[i] for i in rng.integers(0, len(keys), n)]
+    if null_every:
+        out[::null_every] = [None] * len(out[::null_every])
+    return out
+
+
+def _window_tables(case):
+    """(tables as pydicts, group keys, aggs, predicate, strategy, the
+    window's runs as table counts, None where the host answers)."""
+    rng = np.random.default_rng(5)
+
+    def vals(n, null_every=0):
+        v = (rng.random(n) * 100).tolist()
+        if null_every:
+            v[::null_every] = [None] * len(v[::null_every])
+        return v
+
+    q1_aggs = [col("qty").sum().alias("sum_qty"),
+               col("price").sum().alias("sum_price"),
+               (col("price") * (1 - col("disc"))).sum().alias("sum_disc"),
+               col("qty").count().alias("n_qty"),
+               col("price").min().alias("min_price"),
+               col("disc").max().alias("max_disc")]
+    if case == "q1_dense_6_tables":
+        # every file holds all of A/N/R and F/O: equal dictionaries,
+        # each table's own object
+        tables = [{"flag": _keyed("ANR", 600, s) + list("ANR"),
+                   "status": _keyed("FO", 600, 10 + s) + list("FOF"),
+                   "qty": vals(603), "price": vals(603),
+                   "disc": vals(603)} for s in range(6)]
+        return tables, ["flag", "status"], q1_aggs, None, "dense", [6]
+    if case == "sort_different_groups_a_table":
+        tables = [{"k": rng.integers(0, ndv, 500).tolist(),
+                   "qty": vals(500), "price": vals(500), "disc": vals(500)}
+                  for ndv in (3, 40, 1, 117, 9)]
+        return tables, ["k"], q1_aggs, None, "sort", [5]
+    if case == "overflow_retried":
+        # tables 1 and 3 outgrow the 128-group bucket: re-run together,
+        # decoded as one batch, each back in its place
+        tables = [{"k": (np.arange(900) % ndv).tolist(),
+                   "qty": vals(900), "price": vals(900), "disc": vals(900)}
+                  for ndv in (5, 300, 7, 200, 2)]
+        return tables, ["k"], q1_aggs, None, "sort", [1, 1, 1, 1, 1]
+    if case == "q6_global":
+        tables = [{"qty": vals(400), "price": vals(400),
+                   "disc": (rng.random(400) * 0.1).tolist()}
+                  for _ in range(5)]
+        aggs = [(col("price") * col("disc")).sum().alias("revenue"),
+                col("qty").count().alias("n")]
+        return tables, [], aggs, col("qty") < 24, "sort", [5]
+    if case == "null_keys_and_null_aggregates":
+        tables = [{"flag": _keyed("ANR", 300, s, null_every=7),
+                   "status": _keyed("FO", 300, 20 + s),
+                   "qty": vals(300, null_every=3),
+                   # all of a table's prices NULL: every group's sum is
+                   "price": [None] * 300 if s == 1 else vals(300),
+                   "disc": vals(300)} for s in range(4)]
+        return tables, ["flag", "status"], q1_aggs, None, "dense", [4]
+    if case == "different_dictionaries":
+        # codes 0..2 mean other strings in each table: tables 1 and 2
+        # share a dictionary, 0 and 3 have their own
+        tables = [{"flag": _keyed(keys, 300, s) + list(keys),
+                   "qty": vals(303), "price": vals(303), "disc": vals(303)}
+                  for s, keys in enumerate(("ABC", "BCD", "BCD", "XYZ"))]
+        return tables, ["flag"], q1_aggs, None, "dense", [4]
+    if case == "failed_table_in_the_middle":
+        # table 2 holds more groups than the link-budgeted ceiling (held
+        # to 128 by the test): the host answers it, the run is split
+        tables = [{"k": (np.arange(900) % ndv).tolist(),
+                   "qty": vals(900), "price": vals(900), "disc": vals(900)}
+                  for ndv in (5, 9, 300, 7, 2)]
+        return tables, ["k"], q1_aggs, None, "sort", [2, None, 2]
+    raise AssertionError(case)
+
+
+@pytest.mark.parametrize("case", [
+    "q1_dense_6_tables", "sort_different_groups_a_table",
+    "overflow_retried", "q6_global", "null_keys_and_null_aggregates",
+    "different_dictionaries", "failed_table_in_the_middle"])
+def test_window_decodes_as_one_batch_bit_identical(monkeypatch, case):
+    """The lane decoder (one unpack, one mask, one Arrow array a lane a
+    WINDOW) against the per-table decoder it replaced: the window's runs,
+    laid end to end, are the per-table batches laid end to end, column
+    by column and bit for bit, in task order."""
+    from daft_tpu.aggs import split_agg_expr
+    from daft_tpu.device import fragment
+    from daft_tpu.recordbatch import RecordBatch
+    data, keys, aggs, pred, strategy, want_runs = _window_tables(case)
+    cap_limit = 1 << 20
+    if case == "failed_table_in_the_middle":
+        cap_limit = fragment._OUT_CAP0
+        monkeypatch.setattr(fragment, "_max_out_cap",
+                            lambda prog, dt: cap_limit)
+    rbs = [RecordBatch.from_pydict(d) for d in data]
+    gexprs = [col(k) for k in keys]
+    specs = [split_agg_expr(a) for a in aggs]
+    prog = fragment.get_fused_agg(
+        gexprs, [s[1].alias(f"__v{i}__") for i, s in enumerate(specs)],
+        tuple(s[0] for s in specs), pred, rbs[0].schema)
+    assert prog is not None
+    out_schema = (rbs[0].filter(pred) if pred is not None else rbs[0]) \
+        .agg(aggs, gexprs).schema
+    tables = [dcol.encode_batch(rb, prog.compiled.needs_cols) for rb in rbs]
+    tok = fragment.submit_fused_agg_tables(
+        prog, tables, rbs[0].schema, gexprs, [col(s[2]) for s in specs],
+        out_schema)
+    assert tok.strategy == strategy and not tok.failed
+    runs = fragment.drain_fused_agg_tables(tok)
+    refs = [_ref_table(prog, dt, tok, cap_limit) for dt in tables]
+
+    assert [r.tables if r.batch is not None else None for r in runs] \
+        == want_runs
+    at = 0
+    for run in runs:
+        ref = refs[at:at + run.tables]
+        at += run.tables
+        if run.batch is None:
+            assert ref == [None]    # the fallback keeps its place
+            continue
+        want = RecordBatch.concat(ref)
+        assert run.batch.column_names() == want.column_names()
+        assert len(run.batch) == len(want)
+        for got_c, want_c in zip(run.batch.columns(), want.columns()):
+            assert got_c.datatype() == want_c.datatype(), got_c.name()
+            assert _bits(got_c) == _bits(want_c), got_c.name()
+    assert at == len(tables)
+
+
+def test_a_host_task_between_device_tables_splits_the_run():
+    """`places`: tables whose tasks are not neighbours (the host answers
+    one between them) never share a run, so the executor can yield in
+    task order."""
+    from daft_tpu.device import fragment
+    from daft_tpu.recordbatch import RecordBatch
+    rb = RecordBatch.from_pydict({"x": list(range(10))})
+    pieces = [(rb, 0, 2), (rb, 2, 5), (rb, 5, 6), None, (rb, 6, 10)]
+    runs = fragment._runs(pieces, [0, 1, 3, 4, 5])
+    assert [(r.tables, None if r.batch is None else r.batch.to_pydict()["x"])
+            for r in runs] == [(2, [0, 1, 2, 3, 4]), (1, [5]), (1, None),
+                               (1, [6, 7, 8, 9])]
+    whole = fragment._runs(pieces[:3] + pieces[4:], [0, 1, 2, 3])
+    assert len(whole) == 1 and whole[0].batch is rb   # no slice, no copy
+
+
+@pytest.mark.parametrize("inflight", ["2", "0"])
+def test_q1_over_16_files_decodes_a_batch_a_window(monkeypatch, tmp_path,
+                                                   inflight):
+    """The normal path: `read_parquet` over 16 files -> Q1. The answer is
+    the host tier's; the 16 packed results are decoded as one batch a
+    window (6 + 6 + 4 tables under the default in-flight window of 2; the
+    synchronous loop's one wide window under 0) and the fragment yields
+    one partition a batch, not one a table."""
+    import os
+
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    from daft_tpu.execution.executor import LocalExecutor
+    for i in range(16):
+        rng = np.random.default_rng(100 + i)
+        n = 500
+        pq.write_table(
+            pa.table({"flag": _keyed("ANR", n, i) + list("ANR"),
+                      "status": _keyed("FO", n, 50 + i) + list("FOF"),
+                      "qty": np.append(rng.random(n) * 50, [1., 2., 3.]),
+                      "price": np.append(rng.random(n) * 1000,
+                                         [1., 2., 3.])}),
+            str(tmp_path / f"part{i:02d}.parquet"))
+
+    def q1():
+        return (daft.read_parquet(f"{tmp_path}/*.parquet")
+                .groupby("flag", "status")
+                .agg(col("qty").sum().alias("sum_qty"),
+                     col("price").mean().alias("avg_price"),
+                     col("qty").count().alias("cnt"))
+                .sort([col("flag"), col("status")]))
+
+    monkeypatch.setenv("DAFT_TPU_DEVICE", "0")
+    host = _run(q1())
+    yielded = []
+    real = LocalExecutor._exec_DeviceFragmentAgg
+
+    def counting(self, node):
+        for mp in real(self, node):
+            yielded.append(len(mp))
+            yield mp
+
+    monkeypatch.setattr(LocalExecutor, "_exec_DeviceFragmentAgg", counting)
+    monkeypatch.setenv("DAFT_TPU_DEVICE", "1")
+    monkeypatch.setenv("DAFT_TPU_DEVICE_FORCE", "1")
+    monkeypatch.setenv("DAFT_TPU_DEVICE_INFLIGHT", inflight)
+    monkeypatch.setenv("DAFT_TPU_TRACE", "1")
+    tracing.reset_for_tests()
+    dev = _run(q1())
+    summary = tracing.finished()[-1]
+    tracing.reset_for_tests()
+
+    assert dev["flag"] == host["flag"] and dev["status"] == host["status"]
+    assert dev["cnt"] == host["cnt"] and len(dev["flag"]) == 6
+    for name in ("sum_qty", "avg_price"):
+        assert dev[name] == pytest.approx(host[name], rel=1e-9)
+    windows = 3 if inflight == "2" \
+        else -(-16 // (max(os.cpu_count() or 4, 4) * 2))
+    assert summary["decode"] == {"tables": 16, "batches": windows}
+    assert summary["tables"]["host"] == 0
+    assert len(yielded) == windows and sum(yielded) == 16 * 6
+    decode = summary["phases"]["device:decode"]
+    assert decode["count"] == windows

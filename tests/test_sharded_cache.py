@@ -42,8 +42,9 @@ def lineitem(tmp_path_factory):
 @contextlib.contextmanager
 def visible_chips(n, budget=None):
     """``n`` chips visible to the scan path, the device tier forced (the
-    files hold 3 750 rows), queries traced, the cache empty before and
-    after."""
+    files hold 3 750 rows), queries traced, and a cache of its own: the
+    process's one keeps an (empty) share for every chip an earlier test
+    of the same worker put a table on, eight in this suite."""
     mp = pytest.MonkeyPatch()
     mp.setenv("DAFT_TPU_MESH_DEVICES", str(n))
     mp.setenv("DAFT_TPU_DEVICE_FORCE", "1")
@@ -54,14 +55,13 @@ def visible_chips(n, budget=None):
     real_put = jax.device_put
     mp.setattr(jax, "device_put", lambda x, device=None, **kw: (
         puts.append(device), real_put(x, device, **kw))[1])
+    mp.setattr(dcache, "_cache", dcache.DeviceColumnCache())
     pmesh.reset_for_tests()
-    dcache.get_cache().clear()
     try:
         yield puts
     finally:
         mp.undo()
         pmesh.reset_for_tests()
-        dcache.get_cache().clear()
 
 
 def _run(root, q):
