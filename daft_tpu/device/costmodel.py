@@ -44,7 +44,13 @@ from .. import tracing as _tracing
 HOST_VECTOR_BPS = 2.0e9     # elementwise eval / filter, per byte touched
 HOST_AGG_BPS = 3.0e8        # hash/grouped aggregation, per byte touched
 HOST_SORT_ROWS_PER_S = 12.0e6   # multi-key argsort, rows/s
-HOST_JOIN_ROWS_PER_S = 25.0e6   # hash join build+probe, rows/s
+HOST_JOIN_ROWS_PER_S = 10.0e6   # sort-merge match of a bucket pair, rows/s
+#                                 (left + right). Read on the chip's host,
+#                                 PR 38: 241 M rows of TPC-H SF10 Q3 / Q10
+#                                 pairs in 22.9 s of ``joins.match_indices``
+#                                 under the pipeline's stage threads (5.9 M
+#                                 rows/s under 0.5 M-row pairs, 10.5 M at
+#                                 2.1 M-row ones)
 HOST_PIL_BPS = 85e6             # per-image PIL resize, input bytes/s
 #                                 (measured: 64x64 RGB -> 32x32, 1 core)
 
@@ -53,7 +59,14 @@ HOST_PIL_BPS = 85e6             # per-image PIL resize, input bytes/s
 DEV_VECTOR_BPS = 8.0e9      # fused elementwise XLA, per byte touched
 DEV_AGG_BPS = 4.0e9         # fused grouped-agg, per byte touched
 DEV_SORT_ROWS_PER_S = 50.0e6    # XLA multi-key sort, rows/s
-DEV_JOIN_ROWS_PER_S = 40.0e6    # sort/searchsorted/expand join, rows/s
+DEV_JOIN_ROWS_PER_S = 2.0e6     # sort/searchsorted/expand join, rows/s
+#                                 (left + right). Read on a TPU v5e, PR 38:
+#                                 the same 80 pairs a pass, 60.3 M rows, took
+#                                 29.5 s of device time as
+#                                 ``join_fused_impl`` (device trace, forced
+#                                 run). Slower than the host's rate: the gate
+#                                 keeps a pair on the host at every size
+#                                 until the kernel changes
 DEV_DISPATCH_S = 2.0e-3     # per-decision executable launch + (amortized)
 #                             shape-bucket compile overhead
 INVEST_MAX_RATIO = 8.0      # max cache-fill cost vs one host pass (see

@@ -111,17 +111,19 @@ def match_indices(l_gids: np.ndarray, r_gids: np.ndarray,
     (``device.kernels.join_fused_kernel`` — one dispatch, one packed
     result transfer) is chosen by the measured link cost model
     (``device.costmodel.join_wins``): the output is row-shaped (one
-    index pair per match), so on a transfer-bound single-chip link the
-    device loses to the host by >10× measured and the model picks numpy;
-    on a local chip (or the CPU mesh in tests) the kernel wins and the
-    model picks it. ``DAFT_TPU_DEVICE_JOIN=1/0`` force-overrides.
+    index pair per match) and the kernel's rate as read on a TPU v5e
+    (``costmodel.DEV_JOIN_ROWS_PER_S``) is under the host's, so the
+    model picks numpy for every bucket pair until the kernel changes.
+    ``DAFT_TPU_DEVICE_JOIN=1/0`` force-overrides. Each pair is tallied on
+    the query's trace by the tier that matched it (``_tally_pair``).
     """
+    from . import tracing
     from .analysis import knobs
     env = knobs.env_raw("DAFT_TPU_DEVICE_JOIN")
     use_device = env == "1"
+    n_l, n_r = len(l_gids), len(r_gids)
     if env is None:
         from .device import costmodel, runtime as drt
-        n_l, n_r = len(l_gids), len(r_gids)
         # output estimate: FK-join shaped — about one match per probe row
         est_out = 2 * 8 * max(n_l, n_r)
         # priced SERIAL on purpose: the join dispatch runs inline on its
@@ -138,13 +140,13 @@ def match_indices(l_gids: np.ndarray, r_gids: np.ndarray,
     if use_device:
         out = _device_match_indices(l_gids, r_gids, l_valid, r_valid)
         if out is not None:
+            _tally_pair("device", n_l, n_r)
             return out
-    from . import tracing
-    n_l = len(l_gids)
+    _tally_pair("host", n_l, n_r)
     # build: the right side's keys, sorted
     with tracing.span("join:build", lane="pipeline",
-                      attrs={"rows": len(r_gids), "side": "right",
-                             "step": "sort"}):
+                      attrs={"rows": n_r, "side": "right", "step": "sort",
+                             "rows_left": n_l, "rows_right": n_r}):
         r_idx = np.flatnonzero(r_valid)
         r_vals = r_gids[r_idx]
         order = np.argsort(r_vals, kind="stable")
@@ -165,6 +167,16 @@ def match_indices(l_gids: np.ndarray, r_gids: np.ndarray,
         ri = r_sorted_idx[np.repeat(starts, counts) + offsets]
         sp.set("pairs", total)
     return li, ri, counts
+
+
+def _tally_pair(tier: str, n_l: int, n_r: int) -> None:
+    """One bucket pair of ``match_indices`` on the query's trace: which
+    tier matched it, its rows, and the largest pair so far
+    (``summary()["joins"]``)."""
+    from . import tracing
+    tracing.tally(f"join_pairs_{tier}")
+    tracing.tally(f"join_rows_{tier}", n_l + n_r)
+    tracing.tally_max("join_max_pair_rows", n_l + n_r)
 
 
 def _take_nullable(s: Series, idx: np.ndarray, valid: np.ndarray) -> Series:
@@ -192,6 +204,7 @@ def _device_match_indices(l_gids, r_gids, l_valid, r_valid):
         return None
     import time as _time
 
+    import jax
     import jax.numpy as jnp
 
     from .device import costmodel, kernels as K, mfu
@@ -216,34 +229,44 @@ def _device_match_indices(l_gids, r_gids, l_valid, r_valid):
         from .analysis import retrace_sanitizer
         # declared trace signature: build/probe capacity classes + the
         # out-capacity bucket; the same signature must re-enter the jit
-        # cache, never re-trace
-        from .device import pipeline as dpipe
+        # cache, never re-trace. The fetch is ``join:device``'s own: a
+        # ``device:fetch`` span here would nest one leaf in another
         with retrace_sanitizer.dispatch_scope("kernels.join_fused",
                                               (c_l, c_r, cap)):
-            return np.asarray(dpipe.fetch_host(K.join_fused_kernel(
+            return np.asarray(jax.device_get(K.join_fused_kernel(
                 jnp.asarray(pad(l_gids.astype(np.int64), c_l)),
                 jnp.asarray(pad(l_valid, c_l)), jnp.asarray(lmask),
                 jnp.asarray(pad(r_gids.astype(np.int64), c_r)),
                 jnp.asarray(pad(r_valid, c_r)), jnp.asarray(rmask),
                 out_capacity=cap)))
 
-    t0 = _time.perf_counter()
-    cap = max(bucket_capacity(max(n_l, n_r, 1)), 1024)
-    packed = dispatch(cap)
-    counts = packed[2, :n_l].astype(np.int64)
-    total = int(counts.sum())
-    dispatches, nbytes = 1, mfu.join_bytes_model(c_l, c_r, cap)
-    if total > cap:  # rare: many-to-many blowup past the FK estimate
-        cap = bucket_capacity(total)
+    from . import tracing
+    with tracing.span("join:device", lane="device",
+                      attrs={"rows": n_l + n_r, "rows_left": n_l,
+                             "rows_right": n_r}) as sp:
+        t0 = _time.perf_counter()
+        cap = max(bucket_capacity(max(n_l, n_r, 1)), 1024)
         packed = dispatch(cap)
-        dispatches += 1
-        nbytes += mfu.join_bytes_model(c_l, c_r, cap)
-    costmodel.ledger_record(
-        "join", rows=n_l + n_r, nbytes=nbytes,
-        seconds=_time.perf_counter() - t0, dispatches=dispatches,
-        strategy="sort")
-    return (packed[0, :total].astype(np.int64),
-            packed[1, :total].astype(np.int64), counts)
+        counts = packed[2, :n_l].astype(np.int64)
+        total = int(counts.sum())
+        dispatches, nbytes = 1, mfu.join_bytes_model(c_l, c_r, cap)
+        if total > cap:  # rare: many-to-many blowup past the FK estimate
+            cap = bucket_capacity(total)
+            packed = dispatch(cap)
+            dispatches += 1
+            nbytes += mfu.join_bytes_model(c_l, c_r, cap)
+        li = packed[0, :total].astype(np.int64)
+        ri = packed[1, :total].astype(np.int64)
+        seconds = _time.perf_counter() - t0
+        sp.set("capacity", cap)
+        sp.set("pairs", total)
+        sp.set("bytes", int(packed.nbytes))
+    # after the leaf closed: the ledger's own ``device:join`` span is the
+    # pair's sibling, not its child
+    costmodel.ledger_record("join", rows=n_l + n_r, nbytes=nbytes,
+                            seconds=seconds, dispatches=dispatches,
+                            strategy="sort")
+    return li, ri, counts
 
 
 def join_recordbatch(left, right, left_on: List[Expression],

@@ -174,6 +174,10 @@ class SpanRecorder:
         with self._lock:
             self._tallies[key] = self._tallies.get(key, 0) + n
 
+    def tally_max(self, key: str, n: int) -> None:
+        with self._lock:
+            self._tallies[key] = max(self._tallies.get(key, 0), n)
+
     def tally_chip(self, chip: int, tables: int = 0, rows: int = 0,
                    resident_bytes: Optional[int] = None) -> None:
         with self._lock:
@@ -226,7 +230,8 @@ class SpanRecorder:
         the :data:`LEAF_SPANS`), ``tables`` (the scan-task tally),
         ``footers`` (Parquet footers planned ``from_store`` or ``read``),
         ``decode`` (the packed results of how many device ``tables`` were
-        decoded into how many record ``batches``) and
+        decoded into how many record ``batches``), ``joins`` (the bucket
+        pairs ``joins.match_indices`` matched: :data:`JOIN_TALLIES`) and
         ``chips`` (the same tally per chip: ``chip``, ``tables``, ``rows``
         and ``resident_bytes``, one entry a chip, in chip order),
         computed once, when the root closed."""
@@ -257,6 +262,8 @@ class SpanRecorder:
             out["footers"] = _footer_counts(tallies)
             out["decode"] = {"tables": tallies.get("decode_tables", 0),
                              "batches": tallies.get("decode_batches", 0)}
+            out["joins"] = {k: tallies.get("join_" + k, 0)
+                            for k in JOIN_TALLIES}
             out["chips"] = chips
         return out
 
@@ -266,13 +273,27 @@ class SpanRecorder:
 #: ``device:put`` and ``device:dispatch`` carry the ``chip`` their planes
 #: or program went to (an index of ``parallel.mesh.scan_devices()``; 0
 #: when one chip is visible), ``device:fetch`` the number of ``chips``
-#: its results lay on (and ``chip`` when that is one)
+#: its results lay on (and ``chip`` when that is one). ``join:device``
+#: is one bucket pair matched by the fused device join
+#: (``joins._device_match_indices``: pad, put, dispatch, fetch and unpack,
+#: with ``rows_left``, ``rows_right``, ``capacity``, ``pairs``, ``bytes``
+#: fetched); a pair matched on the host is ``join:build`` + ``join:probe``
 LEAF_SPANS = frozenset((
     "plan:optimize", "plan:translate", "scan:load", "device:encode",
     "device:put", "device:dispatch", "device:fetch", "device:decode",
-    "agg:host", "join:build", "join:probe", "sort:topn", "expr:eval",
+    "agg:host", "join:build", "join:probe", "join:device", "sort:topn",
+    "expr:eval",
     "exchange:partition", "exchange:gather", "mem:size",
     "result:collect"))
+
+#: ``summary()["joins"]``: the bucket pairs ``joins.match_indices`` matched
+#: with the fused device program (``join:device``) or on the host
+#: (``join:build`` + ``join:probe``), the rows (left + right) of each
+#: tier's pairs, and the rows of the largest pair: the size the join
+#: gate's break-even (``costmodel.join_wins``) is compared with. Tallied
+#: as ``join_<key>`` on the query's root span
+JOIN_TALLIES = ("pairs_device", "pairs_host", "rows_device", "rows_host",
+                "max_pair_rows")
 
 #: where a scan task's table came from, as the device tier's scan path
 #: tallies it (``SpanRecorder.tally`` / :func:`tally`)
@@ -529,6 +550,14 @@ def tally(key: str, n: int = 1) -> None:
     ctx = current()
     if ctx is not None:
         ctx.recorder.tally(key, n)
+
+
+def tally_max(key: str, n: int) -> None:
+    """Keep the largest ``n`` under ``key`` on the current trace's root
+    span (no-op when untraced)."""
+    ctx = current()
+    if ctx is not None:
+        ctx.recorder.tally_max(key, n)
 
 
 def footer_counts() -> Dict[str, int]:

@@ -485,6 +485,10 @@ def test_join_overlap_pricing_admits_more(monkeypatch):
     monkeypatch.setenv("DAFT_TPU_LINK_RTT_MS", "40")
     monkeypatch.setenv("DAFT_TPU_LINK_UP_MBPS", "40")
     monkeypatch.setenv("DAFT_TPU_LINK_DOWN_MBPS", "40")
+    # a fused join faster than the host's: the rates the chip showed
+    # (PR 38: 2e6 against 10e6 rows/s) leave no overlap to discount
+    monkeypatch.setattr(cm, "HOST_JOIN_ROWS_PER_S", 25.0e6)
+    monkeypatch.setattr(cm, "DEV_JOIN_ROWS_PER_S", 40.0e6)
     cm.reset_for_tests()
     try:
         # host ≈ 0.32 s; serial device ≈ 0.49 s (declines); pipelined
