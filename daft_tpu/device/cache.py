@@ -52,18 +52,30 @@ def _budget() -> int:
 
 def task_fingerprint(task) -> Optional[Tuple]:
     """Stable identity of a scan task's *loaded rows*, or None if the task
-    has no cacheable identity (generator source, unstat-able paths)."""
+    has no cacheable identity (generator source, unstat-able paths). Built
+    from the ``(st_size, st_mtime_ns)`` the task carries for its paths;
+    a task that carries none (catalog readers, hand-made tasks) is
+    stat-ed here."""
     if getattr(task, "generator", None) is not None:
         return None
-    try:
-        stats = []
-        for p in task.paths:
-            if not os.path.exists(p):
-                return None  # remote path: no cheap invalidation signal
-            st = os.stat(p)
-            stats.append((p, st.st_size, st.st_mtime_ns))
-    except OSError:
-        return None
+    carried = getattr(task, "identities", None)
+    if carried is not None:
+        # the identities the task was planned under (``io/footers.py``):
+        # the same ``stat`` that chose its footer
+        stats = [(p, *ident) for p, ident in zip(task.paths, carried)]
+    else:
+        from .. import tracing
+        try:
+            stats = []
+            for p in task.paths:
+                tracing.tally("file_stats")
+                if not os.path.exists(p):
+                    return None  # remote path: no cheap invalidation signal
+                tracing.tally("file_stats")
+                st = os.stat(p)
+                stats.append((p, st.st_size, st.st_mtime_ns))
+        except OSError:
+            return None
     pd = task.pushdowns
     filt = pd.filters._key() if getattr(pd, "filters", None) is not None \
         else None

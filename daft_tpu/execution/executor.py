@@ -2020,13 +2020,14 @@ def _task_column_ndv(tasks, name: str):
             if t.file_format != "parquet" or not t.paths:
                 return None
             md_cached = getattr(t, "pq_metadata", None)
-            for path in t.paths:
+            for k, path in enumerate(t.paths):
                 if path in seen:
                     continue
                 seen.add(path)
                 md = md_cached if md_cached is not None \
                     and len(t.paths) == 1 \
-                    else footers.footer(path, t.io_config).metadata
+                    else footers.footer(path, t.io_config,
+                                        t.identity(k)).metadata
                 idx = {md.schema.column(i).name: i
                        for i in range(md.num_columns)}.get(name)
                 if idx is None:

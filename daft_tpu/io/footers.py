@@ -11,11 +11,20 @@ query paid it again for the same unchanged files.
 and a :class:`Footer` digest of it in plain Python values, under the
 identity the HBM column cache (``device/cache.task_fingerprint``) already
 trusts a file's *data* by: ``(path, st_size, st_mtime_ns)``. A hit costs
-one ``os.stat`` and a dictionary lookup; a file rewritten, replaced or
-touched misses and is read anew. Only facts about a *file* are kept:
-every query still builds its own scan tasks and prunes row groups by its
-own filter (no plan or task list is cached). A remote path, or one that
-cannot be ``stat``-ed, is read every time, as before.
+a dictionary lookup; a file rewritten, replaced or touched misses and is
+read anew. Only facts about a *file* are kept: every query still builds
+its own scan tasks and prunes row groups by its own filter (no plan, task
+list or identity is cached). A remote path, or one that cannot be
+``stat``-ed, is read every time, as before.
+
+Where a file is ``stat``-ed: once a query, in :func:`identities`, which
+``GlobScanOperator.to_scan_tasks`` calls with all the scan's paths when it
+makes the scan's tasks (the ``stat``s go out together on the IO pool).
+The identity is handed to :meth:`FooterStore.get` and travels on the
+``ScanTask`` to ``device/cache.task_fingerprint``. ``get`` stats for
+itself only when given none (schema inference at ``read_parquet``, the
+catalog readers). Every such call is tallied on the query's trace as
+``file_stats``, beside ``files_planned`` (``summary()["files"]``).
 """
 
 from __future__ import annotations
@@ -84,18 +93,21 @@ class FooterStore:
         with self._lock:
             self._entries.clear()
 
-    def get(self, path: str, io_config: Any = None) -> Footer:
-        """The footer of ``path`` as the file is now: from the store when
-        its size and mtime are the stored ones, else read (and, for a
+    def get(self, path: str, io_config: Any = None,
+            identity: Optional[Tuple[int, int]] = None) -> Footer:
+        """The footer of the file ``path`` was when ``identity`` (its
+        ``(st_size, st_mtime_ns)``; read here when None) was taken: from
+        the store when that is the stored one, else read (and, for a
         local file, stored and tallied on the current trace). Raises what
         the read raises."""
-        try:
-            st = os.stat(path)
-        except OSError:  # remote, or gone: no identity to keep it under
+        ident = identity
+        if ident is None and "://" not in path:  # handed none: stat here
+            tracing.tally("file_stats")
+            ident, = _stat_each([path])
+        if ident is None:  # remote, or gone: no identity to keep it under
             from .readers import _open_ranged
             return Footer(pq.ParquetFile(
                 _open_ranged(path, io_config)).metadata)
-        ident = (st.st_size, st.st_mtime_ns)
         with self._lock:
             held = self._entries.get(path)
             if held is not None and held[0] == ident:
@@ -124,6 +136,41 @@ def get_store() -> FooterStore:
     return _STORE
 
 
-def footer(path: str, io_config: Any = None) -> Footer:
+def footer(path: str, io_config: Any = None,
+           identity: Optional[Tuple[int, int]] = None) -> Footer:
     """:meth:`FooterStore.get` on the process's store."""
-    return _STORE.get(path, io_config)
+    return _STORE.get(path, io_config, identity)
+
+
+#: the batch of one scan's ``stat``s goes out in at most this many chunks
+_STAT_CHUNKS = 8
+
+
+def _stat_each(paths: List[str]) -> List[Optional[Tuple[int, int]]]:
+    out: List[Optional[Tuple[int, int]]] = []
+    for p in paths:
+        try:
+            st = os.stat(p)
+            out.append((st.st_size, st.st_mtime_ns))
+        except OSError:
+            out.append(None)
+    return out
+
+
+def identities(paths: List[str]) -> List[Optional[Tuple[int, int]]]:
+    """``(st_size, st_mtime_ns)`` of each path as it is now, in order;
+    None for a path that cannot be ``stat``-ed (remote, gone). The local
+    paths are stat-ed together: in a few chunks on the IO pool
+    (``os.stat`` drops the GIL), the last one on the calling thread.
+    Tallied as ``file_stats`` on the current trace."""
+    todo = [p for p in paths if "://" not in p]
+    if not todo:
+        return [None] * len(paths)
+    tracing.tally("file_stats", len(todo))
+    step = -(-len(todo) // _STAT_CHUNKS)
+    chunks = [todo[k:k + step] for k in range(0, len(todo), step)]
+    from .object_io import io_pool
+    futs = [io_pool().submit(_stat_each, c) for c in chunks[:-1]]
+    last = _stat_each(chunks[-1])
+    got = iter([ident for f in futs for ident in f.result()] + last)
+    return [None if "://" in p else next(got) for p in paths]

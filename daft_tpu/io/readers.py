@@ -168,13 +168,17 @@ def _csv_options(options: Dict[str, Any]):
 def make_scan_tasks(path: str, file_format: str, schema: Schema,
                     pushdowns: Pushdowns, options: Dict[str, Any],
                     partition_values: Dict[str, Any],
-                    io_config=None) -> List[ScanTask]:
+                    io_config=None, identity=None) -> List[ScanTask]:
     """Per-file scan tasks, with parquet row-group pruning + split. A local
     file's footer comes from ``footers``' store when the file is the one
-    the store read (one ``stat``); the task list is built anew each call."""
+    the store read, by ``identity`` (its ``(st_size, st_mtime_ns)`` as
+    ``footers.identities`` read it, which the task then carries; given
+    none, the file is stat-ed here and the task carries none); the task
+    list is built anew each call."""
+    carried = None if identity is None else [identity]
     if file_format == "parquet":
         try:
-            footer = footers.footer(path, io_config)
+            footer = footers.footer(path, io_config, identity)
         except Exception:
             footer = None
         if footer is not None:
@@ -186,7 +190,8 @@ def make_scan_tasks(path: str, file_format: str, schema: Schema,
                 size = sum(footer.group_bytes[g] for g in groups)
             task = ScanTask([path], "parquet", schema, pushdowns, nrows, size,
                             [groups] if groups is not None else None,
-                            options, partition_values, io_config=io_config)
+                            options, partition_values, io_config=io_config,
+                            identities=carried)
             # reused by split_scan_tasks, the reader and the NDV gates
             task.pq_metadata = footer.metadata
             return [task]
@@ -196,10 +201,15 @@ def make_scan_tasks(path: str, file_format: str, schema: Schema,
             size = get_io_client(io_config).source_for(path).get_size(path)
         except Exception:
             size = None
+    elif identity is not None:
+        size = identity[0]
     else:
+        from .. import tracing
+        tracing.tally("file_stats", 2)
         size = os.path.getsize(path) if os.path.exists(path) else None
     return [ScanTask([path], file_format, schema, pushdowns, None, size, None,
-                     options, partition_values, io_config=io_config)]
+                     options, partition_values, io_config=io_config,
+                     identities=carried)]
 
 
 def _prune_row_groups(footer: "footers.Footer",

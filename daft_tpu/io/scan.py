@@ -10,6 +10,7 @@ and split-by-rowgroup).
 from __future__ import annotations
 
 import dataclasses
+import fnmatch
 import glob as _glob
 import os
 from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tuple
@@ -58,8 +59,15 @@ class ScanTask:
                  format_options: Optional[Dict[str, Any]] = None,
                  partition_values: Optional[Dict[str, Any]] = None,
                  generator: Optional[Callable[[], Iterator[RecordBatch]]] = None,
-                 io_config: Any = None):
+                 io_config: Any = None,
+                 identities: Optional[List[Tuple[int, int]]] = None):
         self.paths = paths
+        #: ``(st_size, st_mtime_ns)`` of each of ``paths`` as read when
+        #: the task was made (``footers.identities``), or None for a task
+        #: that carries none (a path that could not be stat-ed, a catalog
+        #: reader's task): ``device/cache.task_fingerprint`` then stats
+        #: for itself
+        self.identities = identities
         self.io_config = io_config
         self.file_format = file_format
         self.schema = schema
@@ -76,6 +84,10 @@ class ScanTask:
             keep = [n for n in self.pushdowns.columns if n in self.schema]
             return self.schema.project(keep)
         return self.schema
+
+    def identity(self, i: int) -> Optional[Tuple[int, int]]:
+        """The identity ``paths[i]`` was planned under, or None."""
+        return None if self.identities is None else self.identities[i]
 
     def num_rows(self) -> Optional[int]:
         if self.pushdowns.filters is not None:
@@ -203,10 +215,44 @@ def _empty_batch(schema: Schema, pushdowns: Pushdowns):
     return RecordBatch.empty(schema)
 
 
+def _glob_local_files(pattern: str) -> List[str]:
+    """The files ``pattern`` matches, sorted: what ``sorted(m for m in
+    glob.glob(pattern, recursive=True) if os.path.isfile(m))`` gives,
+    without a ``stat`` a match. Where the last part holds the magic
+    (``<dir>/*.parquet``) its directories are listed with ``os.scandir``
+    and a match's kind comes with its directory entry (``DirEntry`` stats
+    by itself for a symlink, or where the file system gives no kind);
+    a pattern whose last part is plain, or that holds a ``**`` (``glob``
+    treats a leading one apart), is left to ``glob``."""
+    head, tail = os.path.split(pattern)
+    if not _glob.has_magic(tail) or "**" in pattern:
+        return sorted(m for m in _glob.glob(pattern, recursive=True)
+                      if os.path.isfile(m))
+    dirs = _glob.glob(head) if _glob.has_magic(head) else [head]
+    out: List[str] = []
+    for d in dirs:
+        try:
+            with os.scandir(d or os.curdir) as it:
+                entries = {e.name: e for e in it}
+        except OSError:  # not a directory, or gone: matches nothing
+            continue
+        names = entries if tail.startswith(".") \
+            else [n for n in entries if not n.startswith(".")]  # as glob
+        for n in fnmatch.filter(names, tail):
+            try:
+                if entries[n].is_file():
+                    out.append(os.path.join(d, n))
+            except OSError:  # as os.path.isfile: not a file
+                pass
+    return sorted(out)
+
+
 def glob_paths(path_or_paths, io_config=None) -> List[str]:
     """Local / file:// / remote (s3://) glob expansion (fanout-style,
     reference ``object_store_glob.rs``). Directories expand to their
-    files."""
+    files. The listing stats no file (:func:`_glob_local_files`) and
+    takes no identity: a ``DataFrame`` may be built long before it is
+    collected."""
     paths = [path_or_paths] if isinstance(path_or_paths, str) else list(path_or_paths)
     out: List[str] = []
     for p in paths:
@@ -221,8 +267,7 @@ def glob_paths(path_or_paths, io_config=None) -> List[str]:
                 out.append(p)
             continue
         if any(ch in p for ch in "*?[]"):
-            matches = sorted(_glob.glob(p, recursive=True))
-            out.extend(m for m in matches if os.path.isfile(m))
+            out.extend(_glob_local_files(p))
         elif os.path.isdir(p):
             for root, _, files in sorted(os.walk(p)):
                 for f in sorted(files):
@@ -238,10 +283,14 @@ def glob_paths(path_or_paths, io_config=None) -> List[str]:
 class GlobScanOperator(ScanOperator):
     """Scan over globbed files with schema inference from the first file
     (reference: ``glob.rs:28``) plus hive partition-value inference
-    (``hive.rs``). A local Parquet file is ``stat``-ed when the scan is
-    planned (``to_scan_tasks``) and its footer taken from
-    ``footers.FooterStore`` under ``(path, st_size, st_mtime_ns)``, the
-    identity ``device/cache.task_fingerprint`` keeps its columns under."""
+    (``hive.rs``). A local file is ``stat``-ed once a query: when the
+    scan's tasks are made (``to_scan_tasks``, on every ``collect()``), with
+    all the scan's files in one batch (``footers.identities``). That
+    ``(st_size, st_mtime_ns)`` finds a Parquet file's footer in
+    ``footers.FooterStore`` and travels on the ``ScanTask``, where
+    ``device/cache.task_fingerprint`` reads it: the footer a task was
+    planned from and the columns the HBM cache answers with hang on the
+    same ``stat``. Building the operator (the listing) stats no file."""
 
     def __init__(self, paths, file_format: str,
                  schema: Optional[Schema] = None,
@@ -285,8 +334,11 @@ class GlobScanOperator(ScanOperator):
 
     def to_scan_tasks(self, pushdowns: Pushdowns) -> List[ScanTask]:
         from . import read_planner as rp, readers
+        from .. import tracing
         from ..context import get_context
         cfg = get_context().execution_config
+        idents = dict(zip(self._paths, footers.identities(self._paths)))
+        tracing.tally("files_planned", len(self._paths))
 
         def plan_one(p: str) -> List[ScanTask]:
             pv = {}
@@ -297,7 +349,7 @@ class GlobScanOperator(ScanOperator):
                 pv = {k: vals.get(k) for k in self._hive_fields}
             return readers.make_scan_tasks(
                 p, self._format, self._schema, pushdowns, self._options, pv,
-                self._io_config)
+                self._io_config, idents[p])
 
         remote = [p for p in self._paths if "://" in p
                   and not p.startswith("file://")]
@@ -346,7 +398,8 @@ def split_scan_tasks(tasks: List[ScanTask], max_size: int,
         md = getattr(t, "pq_metadata", None)
         if md is None:
             try:
-                md = footers.footer(t.paths[0], t.io_config).metadata
+                md = footers.footer(t.paths[0], t.io_config,
+                                    t.identity(0)).metadata
             except Exception:
                 out.append(t)
                 continue
@@ -360,7 +413,8 @@ def split_scan_tasks(tasks: List[ScanTask], max_size: int,
             if group and gsize + rg.total_byte_size > max_size:
                 out.append(ScanTask(t.paths, "parquet", t.schema, t.pushdowns,
                                     grows, gsize, [group], t.format_options,
-                                    t.partition_values))
+                                    t.partition_values,
+                                    identities=t.identities))
                 group, gsize, grows = [], 0, 0
             group.append(g)
             gsize += rg.total_byte_size
@@ -368,7 +422,8 @@ def split_scan_tasks(tasks: List[ScanTask], max_size: int,
         if group:
             out.append(ScanTask(t.paths, "parquet", t.schema, t.pushdowns,
                                 grows, gsize, [group], t.format_options,
-                                t.partition_values))
+                                t.partition_values,
+                                identities=t.identities))
     return out
 
 
@@ -393,7 +448,10 @@ def merge_scan_tasks(tasks: List[ScanTask], min_size: int, max_size: int,
                            None if (acc._num_rows is None or t._num_rows is None)
                            else acc._num_rows + t._num_rows,
                            acc_size + sz, None, acc.format_options,
-                           acc.partition_values)
+                           acc.partition_values,
+                           identities=None if (acc.identities is None
+                                               or t.identities is None)
+                           else acc.identities + t.identities)
             acc_size += sz
             if acc_size >= min_size:
                 out.append(acc)

@@ -71,6 +71,7 @@ class Optimizer:
     def optimize(self, plan: lp.LogicalPlan) -> lp.LogicalPlan:
         from ..analysis import plan_sanitizer
         sanitize = plan_sanitizer.is_enabled()
+        _forget_scan_tasks(plan)
         for batch in self.batches:
             passes = 1 if batch.strategy == "once" else batch.max_passes
             prev_key = None
@@ -88,6 +89,24 @@ class Optimizer:
                     break
                 prev_key = key
         return plan
+
+
+def _forget_scan_tasks(plan: lp.LogicalPlan) -> None:
+    """Drop what an earlier query left on this plan's ``Source`` nodes: the
+    task list memoised for one query's stats pass, rules and translation
+    (``materialized_tasks``) and the column ranges read off its footers. A
+    ``Source`` the rules do not rebuild is the ``DataFrame``'s own node,
+    which the next query derived from it meets again; its tasks carry the
+    files' identities (``ScanTask.identities``), and an identity must not
+    be older than the query that trusts it. So every query starts with
+    none and stats its files itself."""
+    stack = [plan]
+    while stack:
+        node = stack.pop()
+        if isinstance(node, lp.Source):
+            node.__dict__.pop("materialized_tasks", None)
+            node.__dict__.pop("_ndv_cache", None)
+        stack.extend(node.children)
 
 
 # ---------------------------------------------------------------------------

@@ -230,13 +230,14 @@ def _source_column_range(node: lp.Source, name: str) -> Optional[float]:
                 # split tasks share a file; one footer per path, reusing
                 # the footer the task was planned from when it carries one
                 md_cached = getattr(t, "pq_metadata", None)
-                for p in t.paths:
+                for k, p in enumerate(t.paths):
                     if p in seen_paths:
                         continue
                     seen_paths.add(p)
                     md = md_cached if md_cached is not None \
                         and len(t.paths) == 1 \
-                        else footers.footer(p, t.io_config).metadata
+                        else footers.footer(p, t.io_config,
+                                            t.identity(k)).metadata
                     idx = {md.schema.column(i).name: i
                            for i in range(md.num_columns)}.get(name)
                     if idx is None:

@@ -53,6 +53,10 @@ class NativeRunner(Runner):
                     optimized = builder.optimize()
                     for k, n in tracing.footer_counts().items():
                         sp.set("footers_" + k, n)
+                    files = tracing.file_counts()
+                    if files:
+                        sp.set("files_planned", files["planned"])
+                        sp.set("file_stats", files["stats"])
                 with tracing.span("plan:translate", lane="planner"):
                     pplan = translate(optimized.plan)
                 executor = make_local_executor(cfg)

@@ -229,6 +229,8 @@ class SpanRecorder:
         went: ``phases`` (per span name), ``covered_us`` (the union of
         the :data:`LEAF_SPANS`), ``tables`` (the scan-task tally),
         ``footers`` (Parquet footers planned ``from_store`` or ``read``),
+        ``files`` (the files its scans ``planned`` and the ``stats``, the
+        stat-like system calls, it made on them),
         ``decode`` (the packed results of how many device ``tables`` were
         decoded into how many record ``batches``), ``joins`` (the bucket
         pairs ``joins.match_indices`` matched: :data:`JOIN_TALLIES`) and
@@ -260,6 +262,7 @@ class SpanRecorder:
                  if s["name"] in LEAF_SPANS], lo, hi)
             out["tables"] = {k: tallies.get(k, 0) for k in TABLE_SOURCES}
             out["footers"] = _footer_counts(tallies)
+            out["files"] = _file_counts(tallies)
             out["decode"] = {"tables": tallies.get("decode_tables", 0),
                              "batches": tallies.get("decode_batches", 0)}
             out["joins"] = {k: tallies.get("join_" + k, 0)
@@ -305,6 +308,18 @@ def _footer_counts(tallies: Dict[str, int]) -> Dict[str, int]:
     by ``io.footers``' store, or read from the file (and stored)."""
     return {"from_store": tallies.get("footers_from_store", 0),
             "read": tallies.get("footers_read", 0)}
+
+
+def _file_counts(tallies: Dict[str, int]) -> Dict[str, int]:
+    """The files a trace's glob scans made tasks for (one count a
+    ``to_scan_tasks``) and the stat-like system calls the program made on
+    scan files inside the query: ``io.footers.identities``' batch, and
+    the fallbacks of a task or a footer lookup that was handed no
+    identity (``FooterStore.get``, ``readers.make_scan_tasks``,
+    ``device/cache.task_fingerprint``). One a file is the floor: every
+    query stats every local file it reads."""
+    return {"planned": tallies.get("files_planned", 0),
+            "stats": tallies.get("file_stats", 0)}
 
 
 def _union_us(intervals, lo: Optional[int] = None,
@@ -568,6 +583,16 @@ def footer_counts() -> Dict[str, int]:
         return {}
     with ctx.recorder._lock:
         return _footer_counts(ctx.recorder._tallies)
+
+
+def file_counts() -> Dict[str, int]:
+    """``{"planned", "stats"}`` of the current trace so far (empty when
+    untraced)."""
+    ctx = current()
+    if ctx is None:
+        return {}
+    with ctx.recorder._lock:
+        return _file_counts(ctx.recorder._tallies)
 
 
 def tally_chip(chip: int, tables: int = 0, rows: int = 0,

@@ -313,7 +313,8 @@ def test_the_counts_are_on_the_optimize_span(tmp_path, monkeypatch):
         return add(self, name, *a, **kw)
     monkeypatch.setattr(tracing.SpanRecorder, "add", spy)
     _traced(monkeypatch, df)
-    assert seen == [{"footers_from_store": 1, "footers_read": 2}]
+    assert seen == [{"footers_from_store": 1, "footers_read": 2,
+                     "files_planned": 3, "file_stats": 3}]
 
 
 def test_a_scan_of_no_file_tallies_nothing(monkeypatch):
