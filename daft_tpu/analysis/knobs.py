@@ -110,10 +110,14 @@ _KNOBS: List[Knob] = [
        "`<repo>/.cache/jax` off-CPU) makes them survive restarts",
        config_field="tpu_aot_warmup"),
     _k("DAFT_TPU_FUSION", "str", "auto", "daft_tpu/physical/fusion.py",
-       "device", "whole-query fusion regions (round 21): `auto` lets the "
-       "cost model price each region (`costmodel.fusion_wins`), `1` "
-       "force-admits every planned region, `0` disables the planner pass "
-       "entirely", config_field="tpu_fusion"),
+       "device", "whole-query fusion regions (round 21) and the scan's "
+       "selection over resident columns (`executor._scan_select`): `auto` "
+       "lets the cost model price each region (`costmodel.fusion_wins`) "
+       "and each filtered scan's table (`costmodel.select_wins`), `1` "
+       "force-admits every planned region and every table the selection "
+       "program can take, `0` disables the planner pass entirely and "
+       "keeps every filtered scan with the reader",
+       config_field="tpu_fusion"),
     _k("DAFT_TPU_FUSION_MAX_OPS", "int", 8, "daft_tpu/physical/fusion.py",
        "device", "region-size cap: the planner stops growing a fusion "
        "region past this many fused operators (bounds trace size and "

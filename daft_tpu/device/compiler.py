@@ -331,8 +331,10 @@ def _build(e: Expression, ctx: _Ctx, f32: bool):
                 return np.asarray(out, dtype=np.int32)
             si = ctx.add_scalar(ScalarSpec(src.params[0], spec_fn))
             name = src.params[0]
+            # items x rows, the rows along the lanes: rows x items pads a
+            # two-item list to a vector register's 128 lanes a row
             return lambda env: (
-                (env[0][name][:, None] == env[3][si][None, :]).any(axis=-1),
+                (env[0][name][None, :] == env[3][si][:, None]).any(axis=0),
                 env[1][name])
         c = _build(target, ctx, f32)
         vals = [i.params[0] for i in items]
@@ -340,7 +342,7 @@ def _build(e: Expression, ctx: _Ctx, f32: bool):
 
         def ifn(env):
             v, m = c(env)
-            return (v[:, None] == consts[None, :]).any(axis=-1), m
+            return (v[None, :] == consts[:, None]).any(axis=0), m
         return ifn
     if op == "if_else":
         cp = _build(e.args[0], ctx, f32)

@@ -67,6 +67,36 @@ DEV_JOIN_ROWS_PER_S = 2.0e6     # sort/searchsorted/expand join, rows/s
 #                                 run). Slower than the host's rate: the gate
 #                                 keeps a pair on the host at every size
 #                                 until the kernel changes
+HOST_SELECT_VALUES_PER_S = 150.0e6  # a filtered Parquet scan in the reader
+#                                 (read, decode, filter), values (rows x
+#                                 pruned columns) a second of the scan's
+#                                 wall under the scan pool. Read on the
+#                                 chip's host, PR 41: TPC-H SF10 Q14's
+#                                 lineitem, 4 numeric / date columns x 60 M
+#                                 rows in 1.3-1.8 s of ``scan:load``
+#                                 (136-185 M values/s); Q19's 6 columns, two
+#                                 of them strings, read 62-75 M. The host's
+#                                 better case: the gate errs to the host
+DEV_SELECT_ROWS_PER_S = 300.0e6     # the chain program's part that grows
+#                                 with the table (predicate, the running
+#                                 count of the mask), padded rows a second
+#                                 of device time. Read on a TPU v5e, PR 41:
+#                                 2.6 ms (Q14's date range) to ~15 ms
+#                                 (Q19's string tests) a 4 194 304-row table
+DEV_SELECT_SLOT_S = 0.2e-6          # and its part that grows with the
+#                                 survivors' bucket: the block search and
+#                                 the output columns' gathers a slot. Read,
+#                                 PR 41, at the 262 144 rung of a 4 M-row
+#                                 table: the gathers of Q19's six columns
+#                                 and their validity 43 ms (device trace),
+#                                 the three-level block search 4.4 ms
+#                                 (``chip_proof/compact_bench.py``; the
+#                                 binary search it replaced 44-57 ms)
+SELECT_FETCH_BPS = 0.25e9           # packed survivors back on the host:
+#                                 fetch + decode, bytes a second. Read, PR
+#                                 41: Q19's 168 MB a query in 0.17 s of link
+#                                 (0.98 GB/s down) + 0.5 s of
+#                                 ``device:decode``; Q14's 33.5 MB in 0.10 s
 DEV_DISPATCH_S = 2.0e-3     # per-decision executable launch + (amortized)
 #                             shape-bucket compile overhead
 INVEST_MAX_RATIO = 8.0      # max cache-fill cost vs one host pass (see
@@ -1150,6 +1180,79 @@ def join_wins(n_left: int, n_right: int, bytes_up: float,
     _log("join", dev_s < host_s, host_s, dev_s,
          n_left=n_left, n_right=n_right, bytes_up=bytes_up)
     return dev_s < host_s
+
+
+def select_wins(rows: int, n_cols: int, slots: Optional[int],
+                out_words: int, resident: bool, bytes_up: float = 0.0,
+                cacheable: bool = False) -> bool:
+    """A filtered scan's table that ends in rows: the chain program over
+    its encoded columns (one dispatch, the packed survivors back) against
+    the host's reader (read, decode and filter the ``n_cols`` pruned
+    columns of ``rows`` rows). ``slots`` is the bucket the expected
+    survivors fill, ``out_words`` the packed words a slot; None: no table
+    of this predicate has run and the footer bounds nothing, so the host
+    takes it and the bet is made on what it finds.
+
+    A resident table is priced from read rates alone, so its decision
+    does not move with the probed link. A miss reads the same columns
+    unfiltered, encodes and uploads them (the link's price, as for an
+    aggregate's upload) and is an investment under the same two rules as
+    ``agg_upload_wins``: the resident rerun must beat the host, and the
+    fill may cost at most ``INVEST_MAX_RATIO`` host passes."""
+    f = _forced()
+    if f is not None:
+        return f
+    host_s, fixed_s, slot_s = _select_prices(rows, n_cols, out_words)
+    if slots is None:
+        _log("select", False, host_s, math.inf, rows=rows,
+             resident=resident)
+        return False
+    resident_s = fixed_s + slots * slot_s
+    if resident:
+        _log("select", resident_s < host_s, host_s, resident_s, rows=rows,
+             slots=slots, resident=True)
+        return resident_s < host_s
+    from ..analysis import knobs
+    dev_s = host_s + link_profile().device_seconds(bytes_up, 0.0, 1.0,
+                                                   resident_s)
+    win = cacheable and knobs.env_bool("DAFT_TPU_CACHE_INVEST") \
+        and resident_s < host_s and dev_s < INVEST_MAX_RATIO * host_s
+    _log("select_invest", win, host_s, dev_s, resident_s=resident_s,
+         rows=rows, bytes_up=bytes_up, slots=slots)
+    return win
+
+
+def _select_prices(rows: int, n_cols: int, out_words: int
+                   ) -> Tuple[float, float, float]:
+    """(the reader's seconds for a table; the resident selection's seconds
+    whatever survives: one dispatch and the program's pass over the rows;
+    its seconds a slot of the survivors' bucket: search, gathers, fetch
+    and decode)."""
+    return (rows * max(n_cols, 1) / HOST_SELECT_VALUES_PER_S,
+            DEV_DISPATCH_S + rows / DEV_SELECT_ROWS_PER_S,
+            DEV_SELECT_SLOT_S + 8.0 * out_words / SELECT_FETCH_BPS)
+
+
+def select_max_rows(rows: int, n_cols: int, out_words: int) -> int:
+    """The most survivors of a ``rows``-row table worth fetching: where
+    the resident selection's price meets the host's (the ladder's
+    ceiling; a table past it is re-read on the host)."""
+    host_s, fixed_s, slot_s = _select_prices(rows, n_cols, out_words)
+    return max(int((host_s - fixed_s) / slot_s), 0)
+
+
+def count_select(tier: str, tables: int, rows_in: int,
+                 rows_out: int) -> None:
+    """Tally filtered scan tables that ended in rows, by where their
+    filter ran (``device`` / ``host``), with the rows they held and the
+    rows that survived, on the current query's trace
+    (``summary()["selects"]``)."""
+    _tracing.tally(f"select_tables_{tier}", tables)
+    _tracing.tally("select_rows_in", rows_in)
+    _tracing.tally("select_rows_out", rows_out)
+    if tier == "device":    # what the programs read and the fetch carries
+        _tracing.tally("select_rows_in_device", rows_in)
+        _tracing.tally("select_rows_out_device", rows_out)
 
 
 # ------------------------------------------------------ kernel strategy

@@ -77,6 +77,84 @@ def _unpack_i64(row: np.ndarray, dtype) -> np.ndarray:
     return row.astype(dt)
 
 
+#: a region's outputs share one validity word a row: bit ``i`` is output
+#: ``i``'s, and the word's upper half holds the header in row 0
+_MAX_ROW_OUTPUTS = 31
+
+
+def _is_wide(dtype) -> bool:
+    return np.dtype(dtype).itemsize == 8
+
+
+def _packed_words(logical_dtypes) -> int:
+    """Words a row of :func:`_pack_rows`' block takes for outputs of these
+    logical types, by the device's encoding of each (a type with none
+    counts a whole word: a price, not a layout)."""
+    def wide(dt):
+        try:
+            return _is_wide(dcol.device_np_dtype(dt))
+        except ValueError:
+            return True
+    n_wide = sum(wide(dt) for dt in logical_dtypes)
+    return 1 + n_wide + (len(logical_dtypes) - n_wide + 1) // 2
+
+
+def _pack_rows(vals, valids, live) -> jnp.ndarray:
+    """Row-shaped outputs (``[w]`` each, their validity beside them) as one
+    int64 block ``[words, w]``: word 0 holds every output's validity bit
+    (bit ``i`` output ``i``'s) and, in row 0, ``live`` (the count of rows
+    that are real) in its upper half; then one word an 8-byte output, and
+    one word a PAIR of narrower ones (the first in the lower half). A
+    float32 beside a date costs a row 8 bytes on the link, where a word a
+    value and a word its validity cost 32."""
+    assert len(vals) <= _MAX_ROW_OUTPUTS  # get_fused_region declines more
+    head = jnp.zeros(vals[0].shape, jnp.int64)
+    for i, m in enumerate(valids):
+        head = head | (m.astype(jnp.int64) << i)
+    head = head.at[0].add(live.astype(jnp.int64) << 32)
+    words = [head] + [_pack_i64(v) for v in vals if _is_wide(v.dtype)]
+    narrow = [_pack_i64(v.astype(jnp.int32) if jnp.issubdtype(
+        v.dtype, jnp.signedinteger) else v) & 0xFFFFFFFF
+        for v in vals if not _is_wide(v.dtype)]
+    for lo, hi in zip(narrow[::2], narrow[1::2] + [None]):
+        words.append(lo if hi is None else lo | (hi << 32))
+    return jnp.stack(words)
+
+
+def _packed_live(block: np.ndarray) -> int:
+    """The header of a :func:`_pack_rows` block."""
+    return int(block[0, 0]) >> 32
+
+
+def _unpack_rows(block: np.ndarray, dtypes):
+    """Host-side inverse of :func:`_pack_rows` over the columns given
+    (a fetched block cut to its live rows, or several blocks' live rows
+    side by side): ``[(values, validity)]`` an output, in order."""
+    bits = block[0]
+    out, wide_at, narrow_at = [], 1, 0
+    first_narrow = 1 + sum(_is_wide(dt) for dt in dtypes)
+    for i, dt in enumerate(dtypes):
+        dt = np.dtype(dt)
+        valid = ((bits >> i) & 1).astype(np.bool_)
+        if _is_wide(dt):
+            vals = _unpack_i64(block[wide_at], dt)
+            wide_at += 1
+        else:
+            word = block[first_narrow + narrow_at // 2]
+            half = ((word >> 32) if narrow_at % 2 else word) & 0xFFFFFFFF
+            narrow_at += 1
+            if dt == np.bool_:
+                vals = half != 0
+            elif dt == np.float32:
+                vals = half.astype(np.uint32).view(np.float32)
+            elif np.issubdtype(dt, np.signedinteger):
+                vals = half.astype(np.uint32).view(np.int32).astype(dt)
+            else:
+                vals = half.astype(dt)
+        out.append((vals, valid))
+    return out
+
+
 class FusedAggProgram:
     def __init__(self, packed_fn, run_packed, compiled: compiler.Compiled,
                  nk: int, ops: Tuple[str, ...], has_pred: bool, meta: dict):
@@ -866,10 +944,63 @@ def run_fused_agg_tables(prog: FusedAggProgram, tables, in_schema: Schema,
 #   bucket out_cap), both read from the packed header.
 
 _region_cache: Dict[Tuple, object] = {}
+#: (predicate, the columns it reads) -> the share of rows it last kept
+#: (``FusedRegionProgram.survivors_hint``)
+_survivor_shares: Dict[Tuple, float] = {}
 
 #: join pair-width ceiling: past this the fused join's expand planes cost
 #: more HBM than the morsel itself and the host join is the right tool
 _REGION_MAX_W = 1 << 22
+
+
+#: rows of a block, and blocks of a block of blocks, in the block search of
+#: :func:`_survivor_rows`: a vector register's lanes
+_LANES = 128
+
+
+def _survivor_rows(row_mask: jnp.ndarray, w: int) -> jnp.ndarray:
+    """Stable compaction: the source row of each of the first ``w`` live
+    rows of ``row_mask`` (``[C]`` bool), in source order, as ``[w]`` int32;
+    slot ``k`` holds the first row whose running count of live rows
+    reaches ``k + 1`` (a slot past the last live row holds some valid row
+    index; the caller masks it by the live count).
+
+    On the chip an element gather costs ~20 ns and a gather of a 128-lane
+    row little more, while compares over a ``[w, 128]`` block are nearly
+    free (read on a TPU v5e, PR 41: ``chip_proof/compact_bench.py``). So
+    the search runs over three levels of running counts -- blocks of 128
+    x 128 rows, blocks of 128 rows, rows -- each a dense compare-and-count
+    over one gathered row of 128 counts: 4.4 ms at 4 M rows and 262 144
+    slots, where the 22-step binary search over the flat running count
+    (one element gather a step a slot) took 44 ms and a stable sort of
+    the inverted mask 5.3 ms and 15-20 s of compile a rung. A table that
+    does not split into such blocks, or whose bucket is so wide that the
+    gathered rows would outweigh it, takes the binary search."""
+    C = row_mask.shape[0]
+    t = jnp.arange(1, w + 1, dtype=jnp.int32)       # the rank wanted
+    B = _LANES
+    if C % (B * B) or w * 8 > C:
+        running = jnp.cumsum(row_mask.astype(jnp.int32))
+        return jnp.minimum(jnp.searchsorted(running, t, side="left"),
+                           C - 1).astype(jnp.int32)
+    n2 = C // (B * B)
+    r0 = jnp.cumsum(row_mask.astype(jnp.int32).reshape(n2, B, B), axis=-1)
+    e1 = jnp.cumsum(r0[..., -1], axis=-1)   # ends of the 128-row blocks
+    e2 = jnp.cumsum(e1[..., -1])            # ends of the 128 x 128 blocks
+
+    def count_below(row, rank):
+        return jnp.minimum(jnp.sum(row < rank[:, None], axis=1,
+                                   dtype=jnp.int32), B - 1)
+
+    b2 = jnp.minimum(jnp.sum(e2[None, :] < t[:, None], axis=1,
+                             dtype=jnp.int32), n2 - 1)
+    t1 = t - jnp.take(e2 - e1[..., -1], b2)          # rank in its block
+    row1 = jnp.take(e1, b2, axis=0)                  # [w, 128]
+    b1 = count_below(row1, t1)
+    t0 = t1 - jnp.max(jnp.where(row1 < t1[:, None], row1, 0), axis=1)
+    p0 = count_below(jnp.take(r0.reshape(n2 * B, B), b2 * B + b1, axis=0),
+                     t0)
+    return ((b2 * B + b1) * B + p0).astype(jnp.int32)
 
 
 class FusedRegionProgram:
@@ -890,10 +1021,32 @@ class FusedRegionProgram:
         self.fused_ops = fused_ops
         self.limit = limit
         self.in_np_dtypes = None
+        #: i64 words a row of the packed block takes (:func:`_pack_rows`);
+        #: what the gates price the fetch with
+        self.out_words = _packed_words([f.dtype for f in
+                                        compiled.out_fields[:nout]])
         #: per input-capacity survivor bucket observed on the last drain:
         #: the ladder's learned first rung (benign race: worst case one
         #: extra overflow re-dispatch)
         self.w_hint: Dict[int, int] = {}
+        #: what :attr:`survivors_hint` is kept under: the predicate and
+        #: the columns it reads, whatever the program projects
+        self.share_key: Optional[Tuple] = None
+
+    @property
+    def survivors_hint(self) -> Optional[float]:
+        """Survivors over rows of the tables this program's PREDICATE last
+        ran on, on the device or in the host's reader (the scan
+        selection's gate prices a table with it, the ladder takes its
+        first rung from it); None until one has run. Shared by every
+        program of the same predicate over the same columns: a scan with
+        a projection above it and the bare scan learn from each other."""
+        return _survivor_shares.get(self.share_key)
+
+    @survivors_hint.setter
+    def survivors_hint(self, share: Optional[float]) -> None:
+        if share is not None:
+            _survivor_shares[self.share_key] = share
 
     def donate_fn(self):
         """Donating twin (r12 discipline): one-shot input planes are dead
@@ -933,6 +1086,9 @@ def get_fused_region(exprs, predicate, schema: Schema,
         _region_cache[key] = False
         return None
     n = len(exprs)
+    if n > _MAX_ROW_OUTPUTS:    # one validity bit an output (_pack_rows)
+        _region_cache[key] = False
+        return None
     ns = len(sort_by)
     has_pred = predicate is not None
     desc = tuple(bool(d) for d in descending)
@@ -958,24 +1114,30 @@ def get_fused_region(exprs, predicate, schema: Schema,
                 kernels._sort_codes(skeys, svalids, row_mask, desc, nf), C)
             live = jnp.minimum(live, jnp.asarray(k_lim, jnp.int32))
             outs = outs[:n]
-        else:
-            # stable compaction: live rows to the front in source order
-            perm = lax.sort(((~row_mask).astype(jnp.int8),
-                             jnp.arange(C, dtype=jnp.int32)),
-                            num_keys=1, is_stable=True)[1]
         w = min(out_w, C)
-        idx = perm[:w]
+        if ns:
+            idx = perm[:w]
+        else:
+            idx = _survivor_rows(row_mask, w)
         sel = jnp.arange(w, dtype=jnp.int32) < live
-        flat = [jnp.take(v, idx) for v, _ in outs] \
-            + [jnp.take(m, idx) & sel for _, m in outs]
-        meta["region_dtypes"] = [x.dtype for x in flat]
-        rows = [jnp.zeros((w,), jnp.int64).at[0].set(live.astype(jnp.int64))]
-        rows += [_pack_i64(x) for x in flat]
-        return jnp.stack(rows)
+        vals = [jnp.take(v, idx) for v, _ in outs]
+        meta["region_dtypes"] = [x.dtype for x in vals]
+        return _pack_rows(vals, [jnp.take(m, idx) & sel for _, m in outs],
+                          live)
 
+    # the profile names a program after its function: ``jit_run_select``
+    # (chain) / ``jit_run_topk``, apart from the fused aggregate's
+    # ``jit_run_packed``
+    run_packed.__name__ = run_packed.__qualname__ = \
+        "run_topk" if ns else "run_select"
     prog = FusedRegionProgram(
         shape, jax.jit(run_packed, static_argnames=("out_w",)),
         run_packed, c, n, has_pred, meta, fused_ops=fused_ops, limit=k_lim)
+    # by the columns the predicate reads, not by what else the scan keeps
+    prog.share_key = (predicate._key(), tuple(
+        (nm, repr(schema[nm].dtype))
+        for nm in sorted(set(predicate.column_names())))) \
+        if has_pred else None
     try:
         prog.in_np_dtypes = {nm: dcol.device_np_dtype(schema[nm].dtype)
                              for nm in c.needs_cols}
@@ -1000,6 +1162,13 @@ def region_start_w(prog: FusedRegionProgram, dt: dcol.DeviceTable) -> int:
         # survivor bucket — steady-state selectivity makes it right for
         # the next one, turning the ladder into a one-dispatch path
         return min(hint, dt.capacity)
+    if prog.survivors_hint is not None:
+        # no table of this capacity has drained, but the predicate's
+        # share of survivors is known (the host's reader ran it, or the
+        # footer's min / max bound it): that share's bucket
+        return min(dcol.bucket_capacity(
+            max(int(prog.survivors_hint * dt.row_count), _OUT_CAP0)),
+            dt.capacity)
     return min(dcol.bucket_capacity(
         max(min(dt.capacity, dt.row_count) // 4, _OUT_CAP0)), dt.capacity)
 
@@ -1031,7 +1200,8 @@ def _dispatch_region(prog: FusedRegionProgram, dt: dcol.DeviceTable,
     from ..analysis import retrace_sanitizer
     with tracing.span("device:dispatch", lane="device",
                       attrs={"program": "region", "capacity": dt.capacity,
-                             "strategy": prog.shape}):
+                             "strategy": prog.shape,
+                             "chip": dt.chip or 0}):
         arrays = {n: col.data for n, col in dt.columns.items()}
         valids = {n: col.validity for n, col in dt.columns.items()}
         scalars = runtime._prep_scalars(prog.compiled, dt)
@@ -1039,7 +1209,8 @@ def _dispatch_region(prog: FusedRegionProgram, dt: dcol.DeviceTable,
         with retrace_sanitizer.dispatch_scope(
                 "region.topk" if prog.shape == "topk" else "region.chain",
                 (id(prog), dt.capacity, out_w,
-                 tuple(s.shape for s in scalars))):
+                 tuple(s.shape for s in scalars), dt.chip)):
+            # runs where its arguments lie: on the table's chip
             return fn(arrays, valids, dt.row_mask, scalars, out_w=out_w)
 
 
@@ -1066,53 +1237,240 @@ def submit_region(prog: FusedRegionProgram, batch, exprs, out_schema: Schema
     return tok
 
 
+def _decode_survivors(prog: FusedRegionProgram, exprs, fields, mats,
+                      tables):
+    """Packed chain results of ``tables`` (one ``mats`` entry each, every
+    one holding its survivors: header <= width) -> ONE RecordBatch, the
+    tables' surviving rows in order, and ``ends``: table ``k``'s rows of it
+    are ``ends[k]:ends[k + 1]``. Every lane is unpacked and decoded once
+    over all the tables (as :func:`_decode_lanes` does for partial
+    aggregates); a string column decodes through each table's OWN
+    dictionary, a stretch of equal dictionaries at a time."""
+    from .. import tracing
+    from ..recordbatch import RecordBatch
+    from ..series import Series
+    counts = [_packed_live(m) for m in mats]
+    block = mats[0][:, :counts[0]] if len(mats) == 1 else np.concatenate(
+        [m[:, :g] for m, g in zip(mats, counts)], axis=1)
+    ends = np.cumsum([0] + counts).tolist()
+    cols = []
+    for (e, f), (v, m) in zip(zip(exprs, fields), _unpack_rows(
+            block, prog.meta["region_dtypes"])):
+        coded = f.dtype.is_string() or f.dtype.is_binary()
+        runs = _key_dictionary_runs(runtime._string_out_source(e), tables) \
+            if coded else [(0, len(tables))]
+        parts = [runtime.decode_group_key(
+            e, f, v[ends[a]:ends[b]], m[ends[a]:ends[b]], tables[a],
+            ends[b] - ends[a]) for a, b in runs]
+        cols.append(parts[0] if len(parts) == 1 else Series.concat(parts))
+    tracing.tally("decode_tables", len(mats))
+    tracing.tally("decode_batches")
+    return RecordBatch.from_series(cols), ends
+
+
+def _ledger_region(prog: FusedRegionProgram, rows: int, nbytes: int,
+                   seconds: float, dispatches: int = 1) -> None:
+    from . import costmodel
+    n_ops = max(len(prog.fused_ops), 2)
+    costmodel.ledger_record(
+        "region", rows=rows, nbytes=nbytes, seconds=seconds,
+        dispatches=dispatches, strategy=prog.shape, fused_ops=n_ops,
+        round_trips_saved=n_ops - 1,
+        fusion_serial_seconds=costmodel.fusion_serial_estimate(rows, n_ops))
+
+
 def drain_region(tok: InflightRegion):
     """Blocking drain: one packed fetch → RecordBatch, continuing the
     width ladder when a chain's survivor count outgrew the bucket."""
     import time as _time
 
-    from . import costmodel, pipeline
+    from . import pipeline
     prog = tok.prog
     t_drain0 = _time.perf_counter()
     while True:
         packed = np.asarray(pipeline.fetch_host(tok.packed))
-        live = int(packed[0, 0])
+        live = _packed_live(packed)
         w = packed.shape[1]
         if live <= w:
             from .. import tracing
-            from ..recordbatch import RecordBatch
-            dtypes = prog.meta["region_dtypes"]
-            nout = prog.nout
             with tracing.span("device:decode", lane="device",
-                              attrs={"tables": 1, "groups": live}):
-                rows = packed[1:]
-                cols = []
-                for i, (e, f) in enumerate(zip(tok.exprs, tok.fields)):
-                    v = _unpack_i64(rows[i][:live], dtypes[i])
-                    m = _unpack_i64(rows[nout + i][:live],
-                                    dtypes[nout + i]).astype(np.bool_)
-                    cols.append(runtime.decode_group_key(e, f, v, m,
-                                                         tok.dt, live))
-                out = RecordBatch.from_series(cols)
+                              attrs={"tables": 1, "batches": 1,
+                                     "groups": live}):
+                out, _ = _decode_survivors(prog, tok.exprs, tok.fields,
+                                           [packed], [tok.dt])
             if prog.has_pred and prog.shape != "topk":
                 prog.w_hint[tok.dt.capacity] = min(
                     dcol.bucket_capacity(max(live, _OUT_CAP0)),
                     tok.dt.capacity)
-            n_ops = max(len(prog.fused_ops), 2)
-            secs = tok.submitted_s + (_time.perf_counter() - t_drain0)
-            costmodel.ledger_record(
-                "region", rows=tok.dt.row_count,
-                nbytes=(1 + 2 * nout) * 8 * w, seconds=secs,
-                strategy=prog.shape, fused_ops=n_ops,
-                round_trips_saved=n_ops - 1,
-                fusion_serial_seconds=costmodel.fusion_serial_estimate(
-                    tok.dt.row_count, n_ops))
+            _ledger_region(prog, tok.dt.row_count,
+                           prog.out_words * 8 * w,
+                           tok.submitted_s
+                           + (_time.perf_counter() - t_drain0))
             return out
         if tok.donate:
             tok.dt = tok.reencode()
             tok.donate = False
         tok.out_w = min(dcol.bucket_capacity(live), tok.dt.capacity)
         tok.packed = _dispatch_region(prog, tok.dt, tok.out_w)
+
+
+# A scan's selection over a window of encoded tables (HBM-cache-resident
+# or just uploaded): the chain program once a table, ONE fetch and ONE
+# decode a window -- the fused aggregate's window discipline
+# (``submit_fused_agg_tables`` / ``drain_fused_agg_tables``) with rows,
+# not partial groups, coming back.
+
+class InflightSelect:
+    """A window's chain dispatches (one a DeviceTable) awaiting ONE
+    batched fetch."""
+
+    __slots__ = ("prog", "tables", "places", "exprs", "fields", "max_w",
+                 "packs", "t0", "submitted_s", "failed")
+
+    def __init__(self, prog, tables, places, exprs, fields, max_w):
+        import time as _time
+        self.prog = prog
+        self.tables = tables
+        self.places = list(range(len(tables))) if places is None \
+            else list(places)
+        self.exprs = exprs
+        self.fields = fields
+        #: table -> the widest survivor bucket worth fetching (the gate's
+        #: ceiling); past it the table is the host's
+        self.max_w = max_w
+        self.packs: list = []
+        self.t0 = _time.perf_counter()
+        self.submitted_s = 0.0
+        self.failed = False
+
+
+def submit_select_tables(prog: FusedRegionProgram, tables, exprs,
+                         out_schema: Schema, places=None, max_w=None
+                         ) -> InflightSelect:
+    """Dispatch the chain program over every table of a window at its
+    first rung (:func:`region_start_w`: the learned survivor bucket), no
+    fetch. Never donates: resident tables share their planes with the
+    HBM cache, and an overflow re-dispatches over the same table. A
+    dispatch failure marks the token failed -> the drain hands every
+    table to the host."""
+    import time as _time
+    fields = [out_schema[e.name()] for e in exprs]
+    tok = InflightSelect(prog, tables, places, exprs, fields,
+                         max_w or (lambda dt: dt.capacity))
+    try:
+        tok.packs = [_dispatch_region(
+            prog, dt, min(region_start_w(prog, dt), tok.max_w(dt)))
+            for dt in tables]
+    except Exception as exc:
+        runtime.device_failed("fragment.select_tables.submit", exc)
+        tok.failed = True
+    tok.submitted_s = _time.perf_counter() - tok.t0
+    return tok
+
+
+def _decode_select_window(tok: InflightSelect, idx, mats, pieces,
+                          rerun: bool = False) -> list:
+    """Decode the packed survivors ``mats`` of the tables ``idx`` into one
+    batch and note each table's rows of it in ``pieces`` as ``(batch, lo,
+    hi)``. A table whose survivors outgrew its bucket is tallied
+    (``select_overflows``) and left out: returned as ``(table, grown
+    width)`` to re-run, or, past the ceiling ``tok.max_w``, left None in
+    ``pieces`` (the caller re-reads its task on the host)."""
+    from .. import tracing
+    prog, tables = tok.prog, tok.tables
+    retry, fit = [], []
+    with tracing.span("device:decode", lane="device",
+                      attrs={"tables": len(idx)}) as sp:
+        for i, mat in zip(idx, mats):
+            live, w = _packed_live(mat), mat.shape[1]
+            if live <= w:
+                fit.append((i, mat))
+                continue
+            tracing.tally("select_overflows")
+            grown = min(dcol.bucket_capacity(live), tables[i].capacity)
+            if grown <= tok.max_w(tables[i]):
+                retry.append((i, grown))
+        if not fit:
+            return retry
+        try:
+            batch, ends = _decode_survivors(
+                prog, tok.exprs, tok.fields, [m for _, m in fit],
+                [tables[i] for i, _ in fit])
+        except Exception as exc:
+            runtime.device_failed("fragment.select_tables.decode", exc)
+            return retry
+        rows_in = 0
+        widest: Dict[int, int] = {}
+        for k, (i, _) in enumerate(fit):
+            pieces[i] = (batch, ends[k], ends[k + 1])
+            dt = tables[i]
+            rows_in += dt.row_count
+            widest[dt.capacity] = max(
+                widest.get(dt.capacity, 0),
+                min(dcol.bucket_capacity(
+                    max(ends[k + 1] - ends[k], _OUT_CAP0)), dt.capacity))
+        for cap, w in widest.items():
+            # the next scan's first rung: the widest bucket this window's
+            # tables of that capacity drained at (a re-run only widens it)
+            prog.w_hint[cap] = max(w, prog.w_hint.get(cap, 0)) if rerun \
+                else w
+        note_select(prog, "device", len(fit), rows_in, ends[-1])
+        sp.set("batches", 1)
+        sp.set("groups", len(batch))
+    return retry
+
+
+def note_select(prog: FusedRegionProgram, tier: str, tables: int,
+                rows_in: int, rows_out: int) -> None:
+    """Tally tables this predicate ran over (``costmodel.count_select``)
+    and keep the survivors' share on the program for the gate's next bet
+    and the ladder's first rung."""
+    from . import costmodel
+    costmodel.count_select(tier, tables, rows_in, rows_out)
+    if rows_in > 0:
+        prog.survivors_hint = rows_out / rows_in
+
+
+def drain_select_tables(tok: InflightSelect) -> List[DecodedRun]:
+    """Blocking drain: ALL the window's packed survivors in a single
+    ``device_get``, decoded lane by lane over all its tables at once;
+    tables that outgrew their rung re-dispatch as one batch at the grown
+    bucket and decode as one. Returns the window's :class:`DecodedRun` s
+    in task order; ``batch`` None for a table the device did not answer
+    (failed, or more survivors than the ceiling holds)."""
+    import time as _time
+
+    from . import pipeline
+    prog, tables = tok.prog, tok.tables
+    if not tables:
+        return []
+    failed = [DecodedRun(1, None)] * len(tables)
+    if tok.failed:
+        return failed
+    t_drain0 = _time.perf_counter()
+    try:
+        mats = [np.asarray(m) for m in pipeline.fetch_host(tok.packs)]
+    except Exception as exc:
+        runtime.device_failed("fragment.select_tables.fetch", exc)
+        return failed
+    _ledger_region(prog, sum(dt.row_count for dt in tables),
+                   sum(int(m.nbytes) for m in mats),
+                   tok.submitted_s + (_time.perf_counter() - t_drain0),
+                   len(mats))
+    pieces: list = [None] * len(tables)
+    retry = _decode_select_window(tok, range(len(tables)), mats, pieces)
+    if retry:
+        try:
+            packs2 = [_dispatch_region(prog, tables[i], w)
+                      for i, w in retry]
+            mats2 = [np.asarray(m) for m in pipeline.fetch_host(packs2)]
+        except Exception as exc:
+            runtime.device_failed("fragment.select_tables.retry", exc)
+            mats2 = None
+        if mats2 is not None:
+            _decode_select_window(tok, [i for i, _ in retry], mats2, pieces,
+                                  rerun=True)
+    return _runs(pieces, tok.places)
 
 
 class FusedJoinAggProgram:

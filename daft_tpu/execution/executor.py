@@ -259,6 +259,17 @@ class LocalExecutor:
         if not node.tasks:
             yield MicroPartition.empty(node.schema())
             return
+        selected, prog = self._scan_select(node)
+        if selected is not None:
+            yield from self._morselize(selected)
+            return
+        if node.tasks[0].pushdowns.filters is not None:
+            yield from _tally_host_select(node.tasks,
+                                          self._scan_host(node, rp), prog)
+            return
+        yield from self._scan_host(node, rp)
+
+    def _scan_host(self, node: pp.ScanSource, rp):
         prefetch = rp.scan_prefetch_tasks()
         if prefetch <= 0 or rp.scan_sequential_fallback():
             # pre-fast-path behavior: whole-task loads on the pool (kept
@@ -448,6 +459,15 @@ class LocalExecutor:
 
     # pipelined maps ---------------------------------------------------
     def _exec_Project(self, node: pp.Project):
+        src = node.children[0]
+        if isinstance(src, pp.ScanSource) and src.tasks \
+                and getattr(src, "shared_consumers", 1) <= 1:
+            # a projection directly over a filtered scan rides the scan's
+            # selection program
+            selected, _ = self._scan_select(src, node.exprs, node.schema())
+            if selected is not None:
+                yield from self._morselize(selected)
+                return
         child = self._exec(node.children[0])
         yield from _ordered_parallel(
             child, lambda p: p.eval_expression_list(node.exprs))
@@ -733,19 +753,74 @@ class LocalExecutor:
                                        poll=self._poll_cancel)
 
     def _fragment_scan_tasks(self, node, prog, src, agg_cols, host_agg):
-        """Windowed streaming over scan tasks: resolve each task in the
-        window to an encoded DeviceTable (HBM cache hit, or load+encode+
-        insert) or a host batch, dispatch the window's fused programs, and
-        fetch ALL its packed results in one transfer. The window bounds
-        host RAM and non-cached HBM residency like the morsel pipeline's
-        in-flight limit; fallbacks re-read the pristine task (never decode
-        the lossy device encoding back)."""
+        """The fused aggregate over a scan's tasks, a window at a time
+        (:meth:`_scan_windows`): each window's programs are dispatched
+        together and ALL their packed partials fetched in one transfer."""
+        from ..device import costmodel, fragment, runtime as drt
+        needs = prog.compiled.needs_cols
+        packed_out = fragment.packed_bytes_per_group(
+            prog.nk, len(prog.ops)) * fragment._OUT_CAP0
+
+        def upload_wins(rb, col_bytes, cacheable, n_sharing, pwin):
+            # the packed fetch's round trips amortize over the tasks that
+            # actually SHARE the transfer: committed cache hits + gate
+            # candidates (r4 advisor: dividing by the whole window length
+            # under-charged device tasks in mixed windows where forced-host
+            # tasks never join the fetch). Still optimistic by candidates
+            # the gate itself rejects — the safe direction, since fewer
+            # sharers only makes the gate stricter.
+            return costmodel.agg_upload_wins(
+                col_bytes, packed_out, cacheable=cacheable,
+                round_trips=2.0 / max(1, n_sharing),
+                host_bytes=drt._batch_cols_nbytes(rb, needs),
+                # overlap pricing when the windows really pipeline
+                window=pwin)
+
+        def submit(tables, at):
+            return fragment.submit_fused_agg_tables(
+                prog, tables, src.schema(), node.group_by, agg_cols,
+                node.schema(), at)
+
+        yield from self._scan_windows(
+            src.tasks, needs,
+            upload_wins=upload_wins, submit=submit,
+            drain=fragment.drain_fused_agg_tables,
+            host=lambda rb, i, reread: host_agg(rb),
+            wrap=lambda batch: MicroPartition.from_recordbatch(
+                batch.cast_to_schema(node.schema())))
+
+    def _scan_windows(self, tasks, needs_cols, upload_wins, submit, drain,
+                      host, wrap, wanted=None, pristine=None):
+        """Windowed streaming over scan tasks, shared by every program
+        that runs over a scan's encoded tables (the fused aggregate,
+        :meth:`_fragment_scan_tasks`; the selection, :meth:`_scan_select`):
+        resolve each task of a window to an encoded DeviceTable (HBM cache
+        hit on the chip that holds it, or load + gate + encode + insert)
+        or leave it to the host, hand the window's tables to ``submit``
+        (one program a table, no fetch), and ``drain`` them with ONE
+        transfer a window. The window bounds host RAM and non-cached HBM
+        residency like the morsel pipeline's in-flight limit.
+
+        ``tasks``: what is loaded, encoded and cached under its
+        fingerprint. ``wanted(i, dt)``: may the device take task ``i``'s
+        table (``dt`` its resident table, None on a miss, asked before
+        anything is loaded); default: always. ``upload_wins(rb, col_bytes,
+        cacheable, n_sharing, pwin)``: the consumer's price of a miss.
+        ``submit(tables, at)`` -> token, ``drain(token)`` ->
+        ``fragment.DecodedRun`` s. ``pristine``: the tasks a table the
+        device does not answer is re-read from (never decoded back from
+        the lossy device encoding); default: ``tasks``. ``host(rb, i,
+        reread)``: task ``i``'s result on the host, from ``rb``: its
+        pristine task's rows (``reread``), or the batch loaded from
+        ``tasks[i]`` for the gate, which declined it; ``wrap(batch)``: a
+        decoded run's partition."""
         import itertools
         from .. import tracing
         from ..device import cache as dcache, column as dcol, costmodel
-        from ..device import fragment, runtime as drt
+        from ..device import runtime as drt
 
-        n_tasks = len(src.tasks)
+        n_tasks = len(tasks)
+        pristine = tasks if pristine is None else pristine
         # the chips the tables are spread over; with one, nothing is
         # placed (``chip`` None: the default device, as ever)
         from ..parallel import mesh as pmesh
@@ -780,21 +855,26 @@ class LocalExecutor:
             gate (``costmodel.scan_table_counts``)."""
             i, t = it
             fp = dcache.task_fingerprint(t)
-            if fp is not None:
-                dt = dcache.get_cache().get_table(fp, prog.compiled.needs_cols)
-                if dt is not None:
-                    costmodel.count_scan_table("from_cache", dt.chip,
-                                               dt.row_count)
-                    return ("dev", dt, t)
+            dt = dcache.get_cache().get_table(fp, needs_cols) \
+                if fp is not None else None
+            if wanted is not None and not wanted(i, dt):
+                costmodel.count_scan_table("host")
+                # here, beside the window's other tasks, not one by one
+                # in the drain
+                return ("read", load(pristine[i]), i)
+            if dt is not None:
+                costmodel.count_scan_table("from_cache", dt.chip,
+                                           dt.row_count)
+                return ("dev", dt, i)
             rb = load(t)
             if len(rb) < max(drt._min_rows(), 1):
                 costmodel.count_scan_table("host")
-                return ("host", rb, t)
-            for nm in prog.compiled.needs_cols:
+                return ("host", rb, i)
+            for nm in needs_cols:
                 if rb.get_column(nm).is_pyobject():
                     costmodel.count_scan_table("host")
-                    return ("host", rb, t)
-            return ("cand", rb, t, fp, chip_for(i, fp))
+                    return ("host", rb, i)
+            return ("cand", rb, i, fp, chip_for(i, fp))
 
         def gate(cand, n_sharing):
             """Phase B: measured cost gate. A cacheable upload is an
@@ -804,37 +884,20 @@ class LocalExecutor:
             query and put_table would refuse oversized tables anyway).
             The budget is a chip's, so the test is the fullest chip's:
             its share of the tasks against one budget."""
-            from ..device import fragment as dfrag
-            _, rb, t, fp, chip = cand
-            packed_out = dfrag.packed_bytes_per_group(
-                prog.nk, len(prog.ops)) * dfrag._OUT_CAP0
-            col_bytes = dcol.encoded_nbytes(rb, prog.compiled.needs_cols)
+            _, rb, i, fp, chip = cand
+            col_bytes = dcol.encoded_nbytes(rb, needs_cols)
             fits = col_bytes * -(-max(n_tasks, 1) // n_chips) \
                 <= dcache._budget()
-            # the packed fetch's round trips amortize over the tasks that
-            # actually SHARE the transfer: committed cache hits + gate
-            # candidates (r4 advisor: dividing by the whole window length
-            # under-charged device tasks in mixed windows where forced-host
-            # tasks never join the fetch). Still optimistic by candidates
-            # the gate itself rejects — the safe direction, since fewer
-            # sharers only makes the gate stricter.
-            if not costmodel.agg_upload_wins(
-                    col_bytes, packed_out,
-                    cacheable=fp is not None and fits,
-                    round_trips=2.0 / max(1, n_sharing),
-                    host_bytes=drt._batch_cols_nbytes(
-                        rb, prog.compiled.needs_cols),
-                    # overlap pricing when the windows really pipeline
-                    # (pwin is assigned before any window resolves)
-                    window=pwin):
+            # (pwin is assigned before any window resolves)
+            if not upload_wins(rb, col_bytes, fp is not None and fits,
+                               n_sharing, pwin):
                 costmodel.count_scan_table("host")
-                return ("host", rb, t)
+                return ("host", rb, i)
             try:
-                dt = dcol.encode_batch(rb, prog.compiled.needs_cols,
-                                       chip=chip)
+                dt = dcol.encode_batch(rb, needs_cols, chip=chip)
             except (ValueError, TypeError):
                 costmodel.count_scan_table("host")
-                return ("host", rb, t)
+                return ("host", rb, i)
             if fp is not None and fits:
                 # only cache working sets that FIT the budget: caching a
                 # slice of an oversized scan just LRU-evicts entries other
@@ -842,7 +905,7 @@ class LocalExecutor:
                 # streams through as a one-shot morsel instead
                 dcache.get_cache().put_table(fp, dt)
             costmodel.count_scan_table("encoded", chip, dt.row_count)
-            return ("dev", dt, t)
+            return ("dev", dt, i)
 
         width = max((os.cpu_count() or 4), 4) * 2
         from ..device import pipeline as dpipe
@@ -856,7 +919,7 @@ class LocalExecutor:
             width = max(1, min(width, -(-n_tasks // max(pwin + 1, 1))))
 
         def windows():
-            it = iter(enumerate(src.tasks))
+            it = iter(enumerate(tasks))
             while True:
                 w = list(itertools.islice(it, width))
                 if not w:
@@ -866,7 +929,8 @@ class LocalExecutor:
         def resolve(window_tasks):
             classified = list(_ordered_parallel(iter(window_tasks),
                                                 classify))
-            n_sharing = sum(1 for c in classified if c[0] != "host")
+            n_sharing = sum(1 for c in classified
+                            if c[0] in ("dev", "cand"))
             gated = _ordered_parallel(
                 iter([c for c in classified if c[0] == "cand"]),
                 lambda c: gate(c, n_sharing))
@@ -883,23 +947,22 @@ class LocalExecutor:
             return [resolved[j][1] for j in at], at
 
         def emit(resolved, runs):
-            """The window's partial aggregates in task order: one
-            partition for each run of device tables that decoded together
+            """The window's results in task order: one partition for each
+            run of device tables that decoded together
             (``fragment.DecodedRun``), a host result for every other task."""
             runs, inside = iter(runs), 0
-            for kind, val, t in resolved:
+            for kind, val, i in resolved:
                 if kind != "dev":
-                    yield host_agg(val)
+                    yield host(val, i, kind == "read")
                 elif inside:    # its rows left with its run's batch
                     inside -= 1
                 else:
                     n, batch = next(runs)
                     inside = n - 1
                     if batch is None:  # device failure → pristine host re-read
-                        yield host_agg(load(t))
+                        yield host(load(pristine[i]), i, True)
                     else:
-                        yield MicroPartition.from_recordbatch(
-                            batch.cast_to_schema(node.schema()))
+                        yield wrap(batch)
 
         if pwin <= 0:
             # synchronous window loop, kept verbatim as the chaos /
@@ -908,10 +971,7 @@ class LocalExecutor:
             for w in windows():
                 resolved = resolve(w)
                 tables, at = dev_tables(resolved)
-                runs = fragment.run_fused_agg_tables(
-                    prog, tables, src.schema(), node.group_by, agg_cols,
-                    node.schema(), at)
-                yield from emit(resolved, runs)
+                yield from emit(resolved, drain(submit(tables, at)))
             return
 
         # round 17 async pipeline over windows: window N+1's classify /
@@ -937,9 +997,7 @@ class LocalExecutor:
                 slot = dpipe.acquire_slot(wgate, seq, self.mem, est)
                 try:
                     t1 = _time.perf_counter()
-                    tok = fragment.submit_fused_agg_tables(
-                        prog, tables, src.schema(), node.group_by,
-                        agg_cols, node.schema(), at)
+                    tok = submit(tables, at)
                     sub_s = pre_s + (_time.perf_counter() - t1)
                 except BaseException:
                     dpipe.release_slot(slot)
@@ -952,7 +1010,7 @@ class LocalExecutor:
             resolved, tok = ret.token
             dpipe.note_compute_span(seq, pwin, ret.t_dispatched_us)
             with dpipe.download_span(seq, pwin):
-                runs = fragment.drain_fused_agg_tables(tok)
+                runs = drain(tok)
             # release BEFORE emitting: a device-failure fallback re-reads
             # its task through load()'s own admission, which must not
             # wait on this very slot's bytes (release_slot is idempotent
@@ -964,6 +1022,120 @@ class LocalExecutor:
                                         window=pwin, width=pwin + 1,
                                         poll=self._poll_cancel):
             yield from outs
+
+    def _scan_select(self, src: pp.ScanSource, exprs=None, out_schema=None):
+        """A filtered Parquet scan that ends in rows, its filter (and the
+        projection directly above it, ``exprs``) run as the chain program
+        over the scan's encoded tables: resolved table by table by the
+        resolver the fused aggregate uses (:meth:`_scan_windows`), so a
+        table whose columns lie in the HBM column cache is neither read
+        nor decoded nor filtered on the host. The cache holds the tasks'
+        UNFILTERED columns (``ScanTask.unfiltered``), which every filter
+        over the same files shares. Returns the stream of survivors as
+        ordinary partitions and the program, or None for the stream where
+        the reader is to scan: with the program where it could have run
+        (the reader's scan then teaches it the predicate's share of
+        survivors), with None where the scan is not of that kind.
+
+        ``DAFT_TPU_FUSION``: ``0`` keeps every scan on the host, ``1``
+        sends every table the program can take to the device, ``auto``
+        asks ``costmodel.select_wins`` table by table. A table the device
+        does not answer (priced out, more survivors than the ladder's
+        ceiling holds, a failed dispatch) is read from its pristine task
+        by the reader, filter included."""
+        from ..device import column as dcol, costmodel, fragment
+        from ..device import runtime as drt
+        from ..physical import fusion as pfusion
+        mode = pfusion.fusion_mode(self.cfg)
+        if mode == "0" or not drt.device_enabled():
+            return None, None
+        pd = src.tasks[0].pushdowns
+        if pd.filters is None or pd.limit is not None:
+            return None, None
+        for t in src.tasks:
+            # (a scan's tasks share one Pushdowns object)
+            if t.file_format != "parquet" or t.generator is not None \
+                    or t.partition_values or t.pushdowns is not pd:
+                return None, None
+        in_schema = src.tasks[0].materialized_schema()
+        if exprs is None:
+            exprs = [col(c) for c in src.schema().column_names]
+            out_schema = src.schema()
+        prog = _select_program(exprs, pd.filters, in_schema, out_schema)
+        if prog is None:
+            return None, None
+        needs = prog.compiled.needs_cols
+        words = prog.out_words
+        forced = mode == "1"
+
+        def slots(i, rows):
+            """The bucket the expected survivors fill: by the share this
+            predicate last kept, else the footer's estimate (which then
+            stands as the program's first bet, rung included); None where
+            nothing is known."""
+            if prog.survivors_hint is None:
+                from ..io import readers
+                prog.survivors_hint = readers.footer_selectivity(
+                    src.tasks[i])
+            share = prog.survivors_hint
+            if share is None:
+                return None
+            return min(dcol.bucket_capacity(
+                max(int(share * rows), fragment._OUT_CAP0)),
+                dcol.bucket_capacity(max(rows, 1)))
+
+        def wanted(i, dt, resident=False):
+            rows = dt.row_count if dt is not None \
+                else (src.tasks[i].rows_scanned() or 0)
+            if rows < max(drt._min_rows(), 1):
+                return False
+            if forced:
+                return True
+            if dt is not None or resident:
+                return costmodel.select_wins(rows, len(needs),
+                                             slots(i, rows), words, True)
+            up = sum(np.dtype(d).itemsize + 1
+                     for d in prog.in_np_dtypes.values()) \
+                * dcol.bucket_capacity(rows)
+            # priced as cacheable; a task that is not (no fingerprint, a
+            # scan over the budget) is declined in ``upload_wins``
+            return costmodel.select_wins(rows, len(needs), slots(i, rows),
+                                         words, False, bytes_up=up,
+                                         cacheable=True)
+
+        def max_w(dt):
+            if forced:
+                return dt.capacity
+            most = costmodel.select_max_rows(dt.row_count, len(needs), words)
+            return min(max(1 << max(most.bit_length() - 1, 0),
+                           fragment._OUT_CAP0), dt.capacity)
+
+        def host(rb, i, reread):
+            rows_in = src.tasks[i].rows_scanned()
+            if not reread:  # loaded whole for the gate, which declined it
+                rows_in = len(rb)
+                rb = rb.filter(pd.filters)
+            fragment.note_select(prog, "host", 1,
+                                 len(rb) if rows_in is None else rows_in,
+                                 len(rb))
+            return MicroPartition.from_recordbatch(
+                rb.eval_expression_list(exprs).cast_to_schema(out_schema))
+
+        if not any(wanted(i, None, resident=True)
+                   for i in range(len(src.tasks))):
+            # no table of this scan is worth the device even resident: the
+            # reader's streaming scan, not a window of fallbacks
+            return None, prog
+        return self._scan_windows(
+            [t.unfiltered() for t in src.tasks], needs, wanted=wanted,
+            pristine=src.tasks,
+            upload_wins=lambda rb, nbytes, cacheable, n, pwin:
+                forced or cacheable,
+            submit=lambda tables, at: fragment.submit_select_tables(
+                prog, tables, exprs, out_schema, at, max_w),
+            drain=fragment.drain_select_tables, host=host,
+            wrap=lambda batch: MicroPartition.from_recordbatch(
+                batch.cast_to_schema(out_schema))), prog
 
     # fused regions (round 21 whole-query compilation) -----------------
     def _exec_FusedRegion(self, node: pp.FusedRegion):
@@ -1025,7 +1197,7 @@ class LocalExecutor:
             return costmodel.fusion_wins(
                 node.shape, len(rb),
                 dcol.encoded_nbytes(rb, prog.compiled.needs_cols),
-                (1 + 2 * prog.nout) * 8 * est_w, n_ops,
+                prog.out_words * 8 * est_w, n_ops,
                 host_bytes=drt._batch_cols_nbytes(
                     rb, prog.compiled.needs_cols),
                 window=window)
@@ -2143,6 +2315,59 @@ def _decode_mesh_shards(n: int, live_mask: np.ndarray, cols_spec, schema
         outs.append(MicroPartition.from_recordbatch(
             RecordBatch.from_series(cols).cast_to_schema(schema)))
     return outs
+
+
+def _select_program(exprs, predicate, in_schema, out_schema):
+    """The chain program of a scan's selection, or None where its rows
+    cannot come back as they are: every output a plain value the device
+    holds exactly (a float64 rides float32 on a chip without it: the
+    device's encoding), or a string / binary column passed through with
+    its dictionary; every column the predicate reads exact on the device,
+    so that the filter keeps the rows the host's would."""
+    from ..device import column as dcol, fragment, runtime as drt
+
+    def exact(dtype):
+        return dcol.is_lossless_device_dtype(dtype) and not dtype.is_null()
+
+    try:
+        for e in exprs:
+            f = out_schema[e.name()]
+            if f.dtype.is_string() or f.dtype.is_binary():
+                if drt._string_out_source(e) is None:
+                    return None
+            elif not (exact(f.dtype) or f.dtype.is_floating()):
+                return None
+        for name in predicate.column_names():
+            dtype = in_schema[name].dtype
+            if not (exact(dtype) or dtype.is_string()
+                    or dtype.is_binary()):
+                return None
+    except (KeyError, ValueError):
+        return None
+    prog = fragment.get_fused_region(exprs, predicate, in_schema,
+                                     fused_ops=("filter", "scan"))
+    if prog is None or prog.in_np_dtypes is None:
+        return None
+    return prog
+
+
+def _tally_host_select(tasks, stream, prog=None):
+    """A filtered scan the reader answers: its tables, their rows and the
+    survivors on the query's trace (``summary()["selects"]``), as the
+    device's selection tallies its own; and on ``prog`` (the selection
+    program that could have run) the share that survived, which is the
+    gate's next bet."""
+    from ..device import costmodel
+    rows_in = [t.rows_scanned() for t in tasks]
+    rows_out = 0
+    for p in stream:
+        rows_out += len(p)
+        yield p
+    known = None not in rows_in
+    costmodel.count_select("host", len(tasks),
+                           sum(rows_in) if known else rows_out, rows_out)
+    if prog is not None and known and sum(rows_in):
+        prog.survivors_hint = rows_out / sum(rows_in)
 
 
 def _loaded_batches(task):

@@ -194,6 +194,8 @@ def make_scan_tasks(path: str, file_format: str, schema: Schema,
                             identities=carried)
             # reused by split_scan_tasks, the reader and the NDV gates
             task.pq_metadata = footer.metadata
+            # and the digest, by the selection's bet (footer_selectivity)
+            task.pq_footer = footer
             return [task]
     if _is_remote(path):
         try:
@@ -268,6 +270,63 @@ def _prune_row_groups(footer: "footers.Footer",
         if ok:
             keep.append(g)
     return keep
+
+
+def footer_selectivity(task: ScanTask) -> Optional[float]:
+    """The share of a Parquet task's rows its pushdown filter is expected
+    to keep, from the min / max of the footer it was planned from alone: each ``col <cmp> literal``
+    conjunct over a numeric or date column cuts its row group's range
+    where the literal falls (values taken as uniform between min and max;
+    both ends of a range on one column are taken together, columns as
+    independent). None where no conjunct bounds anything (strings,
+    ``is_in``, computed values): the caller knows nothing yet."""
+    import datetime
+    filters = task.pushdowns.filters
+    if filters is None or task.file_format != "parquet":
+        return None
+    ranges: Dict[str, list] = {}
+    for cname, op, lit in _extract_bounds(filters):
+        if op in ("lt", "le", "gt", "ge") and isinstance(
+                lit, (int, float, datetime.date)) \
+                and not isinstance(lit, bool):
+            ranges.setdefault(cname, []).append((op, lit))
+    if not ranges:
+        return None
+
+    def position(lit, mn, mx) -> float:
+        span, at = mx - mn, lit - mn
+        if isinstance(span, datetime.timedelta):
+            span, at = span.total_seconds(), at.total_seconds()
+        return min(max(at / span, 0.0), 1.0) if span > 0 \
+            else float(lit > mn)
+
+    footer = getattr(task, "pq_footer", None)
+    if footer is None or len(task.paths) != 1:
+        return None     # a merged or hand-made task: nothing planned it
+    groups = task.row_groups[0] if task.row_groups else None
+    kept = total = 0.0
+    try:
+        for g, rows in enumerate(footer.group_rows):
+            if groups is not None and g not in groups:
+                continue
+            share = 1.0
+            for cname, conds in ranges.items():
+                stats = (footer.columns.get(cname) or [None] * (g + 1))[g]
+                if stats is None or not stats[0]:
+                    continue
+                lo, hi = 0.0, 1.0
+                for op, lit in conds:
+                    at = position(lit, stats[1], stats[2])
+                    if op in ("lt", "le"):
+                        hi = min(hi, at)
+                    else:
+                        lo = max(lo, at)
+                share *= max(hi - lo, 0.0)
+            kept += share * rows
+            total += rows
+    except Exception:
+        return None
+    return kept / total if total else None
 
 
 _LIT_TYPES = (int, float, str, bytes)

@@ -79,6 +79,26 @@ class ScanTask:
         self.partition_values = partition_values or {}
         self.generator = generator
 
+    def rows_scanned(self) -> Optional[int]:
+        """The rows of the files' selected row groups, before any filter
+        or limit (the footer's count; None where nothing planned it)."""
+        return self._num_rows
+
+    def unfiltered(self) -> "ScanTask":
+        """This task less its pushdown filter: the same files, row groups
+        and columns, every row of them (what the HBM column cache keeps
+        for a selection that runs on the device)."""
+        twin = ScanTask(
+            self.paths, self.file_format, self.schema,
+            self.pushdowns.with_filters(None), self._num_rows,
+            self._size_bytes, self.row_groups, self.format_options,
+            self.partition_values, self.generator, self.io_config,
+            self.identities)
+        md = getattr(self, "pq_metadata", None)
+        if md is not None:
+            twin.pq_metadata = md
+        return twin
+
     def materialized_schema(self) -> Schema:
         if self.pushdowns.columns is not None:
             keep = [n for n in self.pushdowns.columns if n in self.schema]

@@ -233,7 +233,9 @@ class SpanRecorder:
         stat-like system calls, it made on them),
         ``decode`` (the packed results of how many device ``tables`` were
         decoded into how many record ``batches``), ``joins`` (the bucket
-        pairs ``joins.match_indices`` matched: :data:`JOIN_TALLIES`) and
+        pairs ``joins.match_indices`` matched: :data:`JOIN_TALLIES`),
+        ``selects`` (the filtered scans' tables that ended in rows:
+        :data:`SELECT_TALLIES`) and
         ``chips`` (the same tally per chip: ``chip``, ``tables``, ``rows``
         and ``resident_bytes``, one entry a chip, in chip order),
         computed once, when the root closed."""
@@ -267,6 +269,8 @@ class SpanRecorder:
                              "batches": tallies.get("decode_batches", 0)}
             out["joins"] = {k: tallies.get("join_" + k, 0)
                             for k in JOIN_TALLIES}
+            out["selects"] = {k: tallies.get("select_" + k, 0)
+                              for k in SELECT_TALLIES}
             out["chips"] = chips
         return out
 
@@ -297,6 +301,18 @@ LEAF_SPANS = frozenset((
 #: as ``join_<key>`` on the query's root span
 JOIN_TALLIES = ("pairs_device", "pairs_host", "rows_device", "rows_host",
                 "max_pair_rows")
+
+#: ``summary()["selects"]``: the tables of filtered scans that ended in
+#: rows (a scan under a join, a sort, a projection: not under a fused
+#: aggregate), by where the filter ran: the chain program over the
+#: table's encoded columns (``fragment.drain_select_tables``) or the
+#: reader on the host; the rows those tables held and the rows that
+#: survived, on both tiers alike (and the device's share of both apart:
+#: what its programs read and what its fetches carried); and the tables
+#: whose survivors outgrew a rung of the device's ladder. Tallied as
+#: ``select_<key>`` on the query's root span
+SELECT_TALLIES = ("tables_device", "tables_host", "rows_in", "rows_out",
+                  "rows_in_device", "rows_out_device", "overflows")
 
 #: where a scan task's table came from, as the device tier's scan path
 #: tallies it (``SpanRecorder.tally`` / :func:`tally`)

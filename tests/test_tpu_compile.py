@@ -7,8 +7,10 @@ set of Pallas kernels refused outright; they are gone), so the programs
 TPC-H Q1/Q6/Q3 dispatch are lowered and compiled here: Q1's fused fragment
 at the dense strategy every benchmark cell runs, the sort grouped-agg in
 Q1's key/agg layout (what a non-dictionary key falls to), the fused join,
-the packed-key argsort, Q6's fused scan->filter->agg fragment, and the mesh
-grouped-agg collective on a 4-device ``Mesh`` of the described devices.
+the packed-key argsort, Q6's fused scan->filter->agg fragment, the scan's
+selection in Q19's shape at the star cell's real bucket (no sort in it: 5 s),
+and the mesh grouped-agg collective on a 4-device ``Mesh`` of the described
+devices.
 Capacities are moderate on purpose (16384-row sorts, ~25 s each):
 ``lax.sort`` compile time on this compiler grows with the bucket (ROADMAP
 A8), and the whole file must stay under ~3 minutes in one worker.
@@ -169,7 +171,47 @@ def _fused_q1_dense(S):
                                 dims=(4, 2))
 
 
+def _scan_select_q19(S):
+    """The scan's selection (fragment.get_fused_region's chain program, as
+    ``executor._scan_select`` runs it) in TPC-H Q19's shape, at the
+    benchmark cell's real sizes: two string predicates against runtime
+    scalars of the table's dictionary, six columns out (an int64 key,
+    three floats, two dictionary codes), the count-and-search compaction
+    and the packed block at the 262 144 rung of the 4 194 304 bucket."""
+    import pyarrow as pa
+
+    from daft_tpu import DataType, col
+    from daft_tpu.device import fragment
+    from daft_tpu.schema import Field, Schema
+    f32 = DataType.float32()   # what a float64 rides on the chip
+    schema = Schema([Field("l_partkey", DataType.int64()),
+                     Field("l_quantity", f32),
+                     Field("l_extendedprice", f32),
+                     Field("l_discount", f32),
+                     Field("l_shipinstruct", DataType.string()),
+                     Field("l_shipmode", DataType.string())])
+    pred = ((col("l_shipinstruct") == "DELIVER IN PERSON")
+            & col("l_shipmode").is_in(["AIR", "AIR REG"])
+            & col("l_partkey").not_null())
+    prog = fragment.get_fused_region(
+        [col(c) for c in schema.column_names], pred, schema)
+    assert prog is not None, "Q19's selection must be device-compilable"
+    assert prog.out_words == 5   # header+validity, the key, 5 halves
+    C, w = 4194304, 262144
+    arrays = {n: S((C,), prog.in_np_dtypes[n])
+              for n in prog.compiled.needs_cols}
+    valids = {n: S((C,), jnp.bool_) for n in prog.compiled.needs_cols}
+    scalars = tuple(
+        S(np.shape(v), np.asarray(v).dtype) for v in (
+            spec.fn(pa.array(["AIR", "DELIVER IN PERSON"]))
+            for spec in prog.compiled.scalar_specs))
+    assert len(scalars) == 2
+    return prog.packed_fn.lower(arrays, valids, S((C,), jnp.bool_),
+                                scalars, out_w=w)
+
+
 ONE_CHIP_PROGRAMS = {
+    "scan_select_q19": _scan_select_q19,
     "fused_scan_filter_agg_q1_dense": _fused_q1_dense,
     "sort_grouped_agg_q1_layout": _q1_sort_grouped_agg,
     "join_fused": _join_fused,
