@@ -83,15 +83,20 @@ DEV_SELECT_ROWS_PER_S = 300.0e6     # the chain program's part that grows
 #                                 of device time. Read on a TPU v5e, PR 41:
 #                                 2.6 ms (Q14's date range) to ~15 ms
 #                                 (Q19's string tests) a 4 194 304-row table
-DEV_SELECT_SLOT_S = 0.2e-6          # and its part that grows with the
+DEV_SELECT_SLOT_S = 0.05e-6         # and its part that grows with the
 #                                 survivors' bucket: the block search and
-#                                 the output columns' gathers a slot. Read,
-#                                 PR 41, at the 262 144 rung of a 4 M-row
-#                                 table: the gathers of Q19's six columns
-#                                 and their validity 43 ms (device trace),
-#                                 the three-level block search 4.4 ms
-#                                 (``chip_proof/compact_bench.py``; the
-#                                 binary search it replaced 44-57 ms)
+#                                 the output stage (one row gather a plane
+#                                 and a dense lane pick) a slot. Read on a
+#                                 TPU v5e, PR 42, over a 4 M-row table
+#                                 (``chip_proof/compact_bench.py``): search
+#                                 4.36 ms + Q19's eight planes 7.16 ms at
+#                                 the 262 144 rung (44 ns a slot), 1.19 +
+#                                 1.33 ms at Q14's 65 536 (38 ns); in the
+#                                 star cell's device trace a whole Q19
+#                                 table takes 11.4 ms, predicate included.
+#                                 Rounded up, so the gate errs to the host.
+#                                 PR 41 read 0.2e-6: twelve element
+#                                 gathers a slot, 43 ms of a 46 ms table
 SELECT_FETCH_BPS = 0.25e9           # packed survivors back on the host:
 #                                 fetch + decode, bytes a second. Read, PR
 #                                 41: Q19's 168 MB a query in 0.17 s of link
@@ -1226,8 +1231,8 @@ def _select_prices(rows: int, n_cols: int, out_words: int
                    ) -> Tuple[float, float, float]:
     """(the reader's seconds for a table; the resident selection's seconds
     whatever survives: one dispatch and the program's pass over the rows;
-    its seconds a slot of the survivors' bucket: search, gathers, fetch
-    and decode)."""
+    its seconds a slot of the survivors' bucket: the search, the output
+    stage's row gathers, fetch and decode)."""
     return (rows * max(n_cols, 1) / HOST_SELECT_VALUES_PER_S,
             DEV_DISPATCH_S + rows / DEV_SELECT_ROWS_PER_S,
             DEV_SELECT_SLOT_S + 8.0 * out_words / SELECT_FETCH_BPS)
@@ -1242,15 +1247,17 @@ def select_max_rows(rows: int, n_cols: int, out_words: int) -> int:
 
 
 def count_select(tier: str, tables: int, rows_in: int,
-                 rows_out: int) -> None:
+                 rows_out: int, row_gather: int = 0) -> None:
     """Tally filtered scan tables that ended in rows, by where their
     filter ran (``device`` / ``host``), with the rows they held and the
-    rows that survived, on the current query's trace
+    rows that survived (and how many of the device's tables the program
+    answered by row gathers), on the current query's trace
     (``summary()["selects"]``)."""
     _tracing.tally(f"select_tables_{tier}", tables)
     _tracing.tally("select_rows_in", rows_in)
     _tracing.tally("select_rows_out", rows_out)
     if tier == "device":    # what the programs read and the fetch carries
+        _tracing.tally("select_tables_row_gather", row_gather)
         _tracing.tally("select_rows_in_device", rows_in)
         _tracing.tally("select_rows_out_device", rows_out)
 

@@ -954,8 +954,17 @@ _REGION_MAX_W = 1 << 22
 
 
 #: rows of a block, and blocks of a block of blocks, in the block search of
-#: :func:`_survivor_rows`: a vector register's lanes
+#: :func:`_survivor_rows`, and the rows :func:`_take_rows` moves at once: a
+#: vector register's lanes
 _LANES = 128
+
+
+def _splits_into_blocks(C: int, w: int) -> bool:
+    """May ``w`` slots of a ``C``-row table be served by gathers of
+    128-lane rows: the table splits into blocks of 128 x 128 rows, and the
+    bucket is narrow enough that the gathered ``[w, 128]`` rows do not
+    outweigh the table."""
+    return C % (_LANES * _LANES) == 0 and w * 8 <= C
 
 
 def _survivor_rows(row_mask: jnp.ndarray, w: int) -> jnp.ndarray:
@@ -965,21 +974,24 @@ def _survivor_rows(row_mask: jnp.ndarray, w: int) -> jnp.ndarray:
     reaches ``k + 1`` (a slot past the last live row holds some valid row
     index; the caller masks it by the live count).
 
-    On the chip an element gather costs ~20 ns and a gather of a 128-lane
-    row little more, while compares over a ``[w, 128]`` block are nearly
-    free (read on a TPU v5e, PR 41: ``chip_proof/compact_bench.py``). So
-    the search runs over three levels of running counts -- blocks of 128
-    x 128 rows, blocks of 128 rows, rows -- each a dense compare-and-count
-    over one gathered row of 128 counts: 4.4 ms at 4 M rows and 262 144
-    slots, where the 22-step binary search over the flat running count
-    (one element gather a step a slot) took 44 ms and a stable sort of
-    the inverted mask 5.3 ms and 15-20 s of compile a rung. A table that
-    does not split into such blocks, or whose bucket is so wide that the
-    gathered rows would outweigh it, takes the binary search."""
+    On the chip an element gather costs ~20 ns a slot and a gather of a
+    128-lane row a fraction of that, while compares over a ``[w, 128]``
+    block are nearly free (read on a TPU v5e, PRs 41 and 42:
+    ``chip_proof/compact_bench.py``). So the search runs over three
+    levels of running counts -- blocks of 128 x 128 rows, blocks of 128
+    rows, rows -- each a dense compare-and-count over one gathered row of
+    128 counts: 4.4 ms at 4 M rows and 262 144 slots, where the 22-step
+    binary search over the flat running count (one element gather a step
+    a slot) took 44 ms and a stable sort of the inverted mask 5.3 ms and
+    15-20 s of compile a rung. The output stage (:func:`_take_rows`)
+    brings the survivors' values back the same way, for the same reason.
+    A table that does not split into such blocks, or whose bucket is so
+    wide that the gathered rows would outweigh it
+    (:func:`_splits_into_blocks`), takes the binary search."""
     C = row_mask.shape[0]
     t = jnp.arange(1, w + 1, dtype=jnp.int32)       # the rank wanted
     B = _LANES
-    if C % (B * B) or w * 8 > C:
+    if not _splits_into_blocks(C, w):
         running = jnp.cumsum(row_mask.astype(jnp.int32))
         return jnp.minimum(jnp.searchsorted(running, t, side="left"),
                            C - 1).astype(jnp.int32)
@@ -1001,6 +1013,97 @@ def _survivor_rows(row_mask: jnp.ndarray, w: int) -> jnp.ndarray:
     p0 = count_below(jnp.take(r0.reshape(n2 * B, B), b2 * B + b1, axis=0),
                      t0)
     return ((b2 * B + b1) * B + p0).astype(jnp.int32)
+
+
+#: :func:`gathers_rows`: the row path composes planes over ALL the table's
+#: rows before it gathers, so a bucket under this share of the table
+#: (1 / 1024: top-k at a small k, a filter that keeps next to nothing) is
+#: no dearer by element gathers (read on a TPU v5e, PR 42, 4 M rows and
+#: Q19's outputs, elements / rows: 0.31 / 0.30 ms at 1 024 slots, 0.52 /
+#: 0.30 at 4 096, 1.74 / 0.54 at 16 384)
+_ROW_GATHER_MIN_SHARE = 1024
+
+
+def gathers_rows(C: int, w: int) -> bool:
+    """Does the chain / top-k program of a ``C``-row table at the bucket
+    ``w`` bring its outputs back by gathers of 128-lane rows
+    (:func:`_take_rows`) and not by element gathers: static a (capacity,
+    rung), read from the shapes inside the traced function and by the
+    dispatch's tally alike."""
+    return _splits_into_blocks(C, w) and w * _ROW_GATHER_MIN_SHARE >= C
+
+
+def _bit_planes(v: jnp.ndarray):
+    """``v``'s bits as int32 planes over its rows -- one, or the two halves
+    of an 8-byte type -- and the inverse over planes of any length: the
+    values are moved, never computed on."""
+    dt = v.dtype
+    if dt.itemsize == 8:
+        bits = _pack_i64(v)
+
+        def back(lo, hi):
+            x = (lo.astype(jnp.int64) & 0xFFFFFFFF) \
+                | (hi.astype(jnp.int64) << 32)
+            return x if dt == jnp.int64 else lax.bitcast_convert_type(x, dt)
+        return [bits.astype(jnp.int32), (bits >> 32).astype(jnp.int32)], back
+    if dt == jnp.bool_:
+        return [v.astype(jnp.int32)], lambda p: p != 0
+    if dt.itemsize == 4:
+        if dt == jnp.int32:
+            return [v], lambda p: p
+        return [lax.bitcast_convert_type(v, jnp.int32)], \
+            lambda p: lax.bitcast_convert_type(p, dt)
+    same = jnp.dtype(f"uint{8 * dt.itemsize}")  # 1- and 2-byte types
+    return [lax.bitcast_convert_type(v, same).astype(jnp.int32)], \
+        lambda p: lax.bitcast_convert_type(p.astype(same), dt)
+
+
+def _take_rows(outs, idx: jnp.ndarray, sel: jnp.ndarray):
+    """The output stage of the chain / top-k program by row gathers:
+    ``outs`` (``[(values, validity)]`` over the table's ``C`` rows) at the
+    source rows ``idx`` (``[w]`` int32), as ``([w] values, [w] validity &
+    sel)``, bit for bit what ``jnp.take`` of every plane gives.
+
+    Composed before gathering, densely: every output's validity bit goes
+    into ONE int32 plane over all ``C`` rows (the layout of
+    :func:`_pack_rows`' word 0), so ``n`` validity gathers become one.
+    Then each plane is viewed ``[C / 128, 128]``, the row ``idx // 128`` is
+    gathered (``[w, 128]``) and the lane ``idx % 128`` picked by a compare
+    against an iota and a masked sum (of one value and 127 zeros, in
+    int32: exact). One plane after another (an ``optimization_barrier``
+    ties a plane's indices to the plane before it): the ``[w, 128]`` rows
+    of a plane are dead before the next plane's are gathered, so the
+    program holds one such block, not one a plane (1.08 GB -> 0.16 GB of
+    temporaries at Q19's shape), and the TPU's compiler then keeps every
+    16 MB plane in on-chip memory while it is gathered: 0.57 ms a plane
+    at 262 144 slots where the gather from HBM took 3.7 (device traces of
+    the star cell on a TPU v5e, PR 42). Needs
+    ``C % 128 == 0``; the caller asks :func:`gathers_rows`."""
+    C, w = outs[0][0].shape[0], idx.shape[0]
+    bits = jnp.zeros((C,), jnp.int32)
+    for i, (_, m) in enumerate(outs):
+        bits = bits | (m.astype(jnp.int32) << i)
+    planes, backs = [bits], []
+    for v, _ in outs:
+        ps, back = _bit_planes(v)
+        backs.append((len(ps), back))
+        planes += ps
+    row, lane = idx >> 7, idx & (_LANES - 1)
+    got: list = []
+    for p in planes:
+        if got:
+            row, lane, _ = lax.optimization_barrier((row, lane, got[-1]))
+        hit = lax.broadcasted_iota(jnp.int32, (w, _LANES), 1) \
+            == lane[:, None]
+        got.append(jnp.sum(jnp.where(hit, jnp.take(
+            p.reshape(C // _LANES, _LANES), row, axis=0), 0), axis=1,
+            dtype=jnp.int32))
+    vals, at = [], 1
+    for n, back in backs:
+        vals.append(back(*got[at:at + n]))
+        at += n
+    return vals, [((got[0] >> i) & 1).astype(jnp.bool_) & sel
+                  for i in range(len(outs))]
 
 
 class FusedRegionProgram:
@@ -1066,7 +1169,19 @@ def get_fused_region(exprs, predicate, schema: Schema,
                      fused_ops: Tuple[str, ...] = ()
                      ) -> Optional[FusedRegionProgram]:
     """Compile (or fetch) a chain/topk region program. None → the region
-    does not lower (caller runs the fallback subtree)."""
+    does not lower (caller runs the fallback subtree).
+
+    The program: predicate and projection over the table's planes, the
+    survivors' source rows (the stable compaction, :func:`_survivor_rows`;
+    top-k: the head of the in-program argsort), the output stage that
+    brings each output's value and validity at those rows, and the packed
+    block (:func:`_pack_rows`). The output stage adapts to the static
+    shapes the traced function sees (:func:`gathers_rows`): gathers of
+    128-lane rows (:func:`_take_rows`) where the table splits into blocks
+    and the bucket is neither too wide nor too narrow for them, element
+    gathers everywhere else (an unfiltered chain at ``w == C``, top-k at a
+    small ``k``, odd capacities). Either way the block is the same to the
+    bit."""
     shape = "topk" if sort_by else "chain"
     key = ("region", shape, tuple(e._key() for e in exprs),
            predicate._key() if predicate is not None else None,
@@ -1120,10 +1235,13 @@ def get_fused_region(exprs, predicate, schema: Schema,
         else:
             idx = _survivor_rows(row_mask, w)
         sel = jnp.arange(w, dtype=jnp.int32) < live
-        vals = [jnp.take(v, idx) for v, _ in outs]
+        if gathers_rows(C, w):
+            vals, valids = _take_rows(outs, idx, sel)
+        else:
+            vals = [jnp.take(v, idx) for v, _ in outs]
+            valids = [jnp.take(m, idx) & sel for _, m in outs]
         meta["region_dtypes"] = [x.dtype for x in vals]
-        return _pack_rows(vals, [jnp.take(m, idx) & sel for _, m in outs],
-                          live)
+        return _pack_rows(vals, valids, live)
 
     # the profile names a program after its function: ``jit_run_select``
     # (chain) / ``jit_run_topk``, apart from the fused aggregate's
@@ -1201,6 +1319,9 @@ def _dispatch_region(prog: FusedRegionProgram, dt: dcol.DeviceTable,
     with tracing.span("device:dispatch", lane="device",
                       attrs={"program": "region", "capacity": dt.capacity,
                              "strategy": prog.shape,
+                             "gather": "rows" if gathers_rows(
+                                 dt.capacity, min(out_w, dt.capacity))
+                             else "elements",
                              "chip": dt.chip or 0}):
         arrays = {n: col.data for n, col in dt.columns.items()}
         valids = {n: col.validity for n, col in dt.columns.items()}
@@ -1414,19 +1535,22 @@ def _decode_select_window(tok: InflightSelect, idx, mats, pieces,
             # tables of that capacity drained at (a re-run only widens it)
             prog.w_hint[cap] = max(w, prog.w_hint.get(cap, 0)) if rerun \
                 else w
-        note_select(prog, "device", len(fit), rows_in, ends[-1])
+        note_select(prog, "device", len(fit), rows_in, ends[-1],
+                    row_gather=sum(gathers_rows(tables[i].capacity,
+                                                m.shape[1]) for i, m in fit))
         sp.set("batches", 1)
         sp.set("groups", len(batch))
     return retry
 
 
 def note_select(prog: FusedRegionProgram, tier: str, tables: int,
-                rows_in: int, rows_out: int) -> None:
-    """Tally tables this predicate ran over (``costmodel.count_select``)
-    and keep the survivors' share on the program for the gate's next bet
-    and the ladder's first rung."""
+                rows_in: int, rows_out: int, row_gather: int = 0) -> None:
+    """Tally tables this predicate ran over (``costmodel.count_select``;
+    ``row_gather``: how many of the device's brought their survivors back
+    by row gathers, :func:`gathers_rows`) and keep the survivors' share on
+    the program for the gate's next bet and the ladder's first rung."""
     from . import costmodel
-    costmodel.count_select(tier, tables, rows_in, rows_out)
+    costmodel.count_select(tier, tables, rows_in, rows_out, row_gather)
     if rows_in > 0:
         prog.survivors_hint = rows_out / rows_in
 

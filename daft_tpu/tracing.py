@@ -308,11 +308,15 @@ JOIN_TALLIES = ("pairs_device", "pairs_host", "rows_device", "rows_host",
 #: table's encoded columns (``fragment.drain_select_tables``) or the
 #: reader on the host; the rows those tables held and the rows that
 #: survived, on both tiers alike (and the device's share of both apart:
-#: what its programs read and what its fetches carried); and the tables
-#: whose survivors outgrew a rung of the device's ladder. Tallied as
+#: what its programs read and what its fetches carried); the tables
+#: whose survivors outgrew a rung of the device's ladder; and, of the
+#: device's tables, those whose survivors the program brought back by
+#: gathers of 128-lane rows (``fragment.gathers_rows``: static a
+#: capacity and rung; the rest took element gathers). Tallied as
 #: ``select_<key>`` on the query's root span
 SELECT_TALLIES = ("tables_device", "tables_host", "rows_in", "rows_out",
-                  "rows_in_device", "rows_out_device", "overflows")
+                  "rows_in_device", "rows_out_device", "overflows",
+                  "tables_row_gather")
 
 #: where a scan task's table came from, as the device tier's scan path
 #: tallies it (``SpanRecorder.tally`` / :func:`tally`)

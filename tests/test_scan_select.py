@@ -9,7 +9,10 @@ forced off and left to the gate; (b) the selection alone against pyarrow's
 filtering of the same files; (c) an overflow of the ladder's first rung and
 of its ceiling; (d) the HBM column cache across runs and a rewritten file;
 (e) the fused aggregate over the resolver it now shares with the selection;
-(f) the gate's answers and the footer's estimate of the survivors."""
+(f) the gate's answers and the footer's estimate of the survivors; (g) the
+packed block's layout; (h) the compaction's search; (i) the output stage:
+the survivors brought back by gathers of 128-lane rows against element
+gathers and numpy, bit for bit, and which shapes take which."""
 
 import datetime
 import importlib
@@ -100,6 +103,9 @@ def test_star_queries_against_reference(star, monkeypatch, q, mode):
         assert sel["tables_device"] + sel["tables_host"] == 2 * N_FILES
     if q != "q6":
         assert 0 < sel["rows_out"] < sel["rows_in"]
+    # SF0.01's tables sit in the 4 096 bucket, which does not split into
+    # blocks of 128 x 128 rows: element gathers, whoever filtered
+    assert sel["tables_row_gather"] == 0
 
 
 # ------------------------------------- (b) the selection alone
@@ -224,11 +230,53 @@ def test_first_rung_overflow_redispatches(files, monkeypatch):
     # (the planner may merge small files into one task: a table a task)
     assert sel["overflows"] >= 1
     assert sel["tables_device"] >= 1 and sel["tables_host"] == 0
+    # a quarter of the bucket and then all of it: both too wide for rows
+    assert sel["tables_row_gather"] == 0
     assert failures() == before
     # the rung is learned: the next scan overflows nowhere
     daft_tpu.read_parquet(files).where(
         col("instruct") != "TAKE BACK RETURN").to_pydict()
     assert last_summary()["selects"]["overflows"] == 0
+
+
+def test_row_gathers_engage_where_the_bucket_splits_into_blocks(
+        tmp_path, monkeypatch):
+    """A 12 000-row table (the 16 384 bucket, which splits into blocks of
+    128 x 128 rows) under a date range that keeps ~1.5%: the footer's
+    min / max bound the share, so the first rung is 256 slots and the
+    program brings its survivors back by row gathers, as its dispatch
+    span and the tally say; a filter that keeps half the rows at the same
+    bucket (rung 8 192: too wide) keeps the element gathers."""
+    pattern = write_files(str(tmp_path), n_files=2, rows=12000, seed=7)
+    set_mode(monkeypatch, "forced-on")
+    before = failures()
+    kept = []
+    real = tracing.SpanRecorder.finish
+    monkeypatch.setattr(tracing.SpanRecorder, "finish", lambda self, *a: (
+        real(self, *a), kept.append(self.spans()))[0])
+
+    def gathers():
+        return sorted((s["attrs"]["capacity"], s["attrs"]["gather"])
+                      for s in kept[-1] if s["name"] == "device:dispatch"
+                      and s["attrs"].get("program") == "region")
+
+    pred, keep = PREDICATES["date-range"]
+    want = arrow_answer(pattern, keep)
+    assert 100 < len(want["key"]) < 2 * 256
+    for _ in range(2):      # from the footer's estimate, then as learned
+        assert daft_tpu.read_parquet(pattern).where(pred).to_pydict() == want
+        sel = last_summary()["selects"]
+        assert (sel["tables_device"], sel["tables_row_gather"]) == (2, 2)
+        assert sel["overflows"] == 0
+        assert gathers() == [(16384, "rows")] * 2
+    half = col("ship") >= lit(DAY0 + datetime.timedelta(days=1000))
+    got = daft_tpu.read_parquet(pattern).where(half).to_pydict()
+    assert got == arrow_answer(
+        pattern, f("ship") >= DAY0 + datetime.timedelta(days=1000))
+    sel = last_summary()["selects"]
+    assert (sel["tables_device"], sel["tables_row_gather"]) == (2, 0)
+    assert set(gathers()) == {(16384, "elements")}
+    assert failures() == before
 
 
 def test_ceiling_overflow_rereads_on_the_host(files, monkeypatch):
@@ -353,11 +401,15 @@ def test_gate_prices_a_resident_table_by_its_survivors():
     # Q10's ``l_returnflag = 'R'`` 25% (1 048 576): the reader's
     assert not cm.select_wins(rows, 4, 2_097_152, 4, True)
     assert not cm.select_wins(rows, 4, 1_048_576, 4, True)
-    # a filter that keeps everything; Q10's three months of ``orders``
-    # (3.8% of 0.94 M-row tables); ``part``'s ``not_null`` over 125 k rows
+    # a filter that keeps everything; Q3's half of ``orders`` (0.94 M-row
+    # tables); ``part``'s ``not_null`` over 125 k rows
     assert not cm.select_wins(rows, 4, cap, 4, True)
-    assert not cm.select_wins(937_500, 3, 65_536, 3, True)
-    assert not cm.select_wins(125_000, 4, 131_072, 4, True)
+    assert not cm.select_wins(937_500, 4, 524_288, 4, True)
+    assert not cm.select_wins(125_000, 2, 131_072, 3, True)
+    # Q10's three months of ``orders`` (3.8%: the 65 536 bucket, two keys
+    # and a date out) sit on the break-even: the device's since the slot's
+    # price was read from row gathers (16.8 ms against the reader's 18.75)
+    assert cm.select_wins(937_500, 3, 65_536, 4, True)
     # nothing known of the predicate: the host takes the table
     assert not cm.select_wins(rows, 4, None, 4, True)
     # a resident table's price does not read the link; it is tallied
@@ -463,3 +515,160 @@ def test_survivor_rows_against_numpy(capacity, w, share):
     assert got.shape == (w,) and got.dtype == np.int32
     np.testing.assert_array_equal(got[:len(want)], want)
     assert ((got >= 0) & (got < capacity)).all()
+
+
+# --------------------------------------------------- (i) the output stage
+
+MIXES = {
+    "one-f32": (np.float32,),
+    "q14": (np.int64, np.float32, np.float32, np.int32),
+    "q19": (np.int64, np.float32, np.float32, np.float32, np.int32,
+            np.int32),
+    "31": (np.float32, np.int32, np.int64, np.bool_, np.int8, np.int16,
+           np.uint8, np.uint32, np.float64, np.uint16) * 3 + (np.float32,),
+}
+
+#: id -> (capacity, rung, survivors' share, the outputs, row gathers?)
+OUTPUT_STAGES = {
+    "16k-rung128-1pct": (16384, 128, 0.005, "q19", True),
+    "16k-widest-rows-4pct": (16384, 2048, 0.04, "q14", True),
+    "16k-too-wide": (16384, 4096, 0.2, "one-f32", False),
+    "16k-unfiltered-all": (16384, 16384, 1.0, "q19", False),
+    "128k-rung128-none": (131072, 128, 0.0, "q19", True),
+    "128k-31-outputs-4pct": (131072, 8192, 0.04, "31", True),
+    "128k-overflowing-rung": (131072, 2048, 0.5, "q14", True),
+    "128k-unfiltered-all": (131072, 131072, 1.0, "one-f32", False),
+    "4m-rung128-too-narrow": (4194304, 128, 0.00001, "q19", False),
+    "4m-q14-65536-1pct": (4194304, 65536, 0.0128, "q14", True),
+    "4m-q19-262144-4pct": (4194304, 262144, 0.0357, "q19", True),
+    "4m-one-262144-1pct": (4194304, 262144, 0.01, "one-f32", True),
+    "no-128-row-blocks": (5000, 128, 0.01, "q19", False),
+    "no-128x128-blocks": (4096, 128, 0.01, "q19", False),
+}
+
+
+def _planes(rng, dtypes, capacity):
+    """Values over the whole bucket (negative zeros, NaNs, the integer
+    types' extremes among them) and their validity; every third column
+    holds nulls, the rest none."""
+    vals, valids = [], []
+    for k, dt in enumerate(dtypes):
+        dt = np.dtype(dt)
+        if dt == np.bool_:
+            v = rng.random(capacity) < 0.5
+        elif dt.kind == "f":
+            v = rng.normal(0, 1e6, capacity).astype(dt)
+            v[::7] = -0.0
+            v[3::1001] = np.nan
+        else:
+            info = np.iinfo(dt)
+            v = rng.integers(info.min, info.max, capacity, dtype=dt,
+                             endpoint=True)
+        vals.append(v)
+        valids.append(rng.random(capacity) < 0.8 if k % 3 == 0
+                      else np.ones(capacity, np.bool_))
+    return vals, valids
+
+
+@pytest.mark.parametrize("case", list(OUTPUT_STAGES))
+def test_output_stage_rows_against_elements_and_numpy(case):
+    """``fragment._take_rows`` (row gathers and a dense lane pick) and the
+    element gathers it stands in for give the same packed block, bit for
+    bit, dead slots included; its live rows are numpy's; and
+    ``fragment.gathers_rows`` sends each shape where it belongs."""
+    import jax
+    import jax.numpy as jnp
+    capacity, w, share, mix, rows = OUTPUT_STAGES[case]
+    assert fragment.gathers_rows(capacity, w) is rows
+    rng = np.random.default_rng(capacity + w)
+    mask = rng.random(capacity) < share
+    if share < 1.0:
+        mask[capacity - capacity // 10:] = False    # the bucket's padding
+    want = np.nonzero(mask)[0]
+    vals, valids = _planes(rng, MIXES[mix], capacity)
+
+    def by_elements(outs, idx, sel):
+        return ([jnp.take(v, idx) for v, _ in outs],
+                [jnp.take(m, idx) & sel for _, m in outs])
+
+    def block(take):
+        def run(vals, valids, mask):
+            idx = fragment._survivor_rows(mask, w)
+            live = jnp.sum(mask).astype(jnp.int32)
+            sel = jnp.arange(w, dtype=jnp.int32) < live
+            return fragment._pack_rows(
+                *take(list(zip(vals, valids)), idx, sel), live)
+        return np.asarray(jax.jit(run)(
+            [jnp.asarray(v) for v in vals],
+            [jnp.asarray(m) for m in valids], jnp.asarray(mask)))
+
+    elements = block(by_elements)
+    assert fragment._packed_live(elements) == len(want)
+    if capacity % 128 == 0:     # the row path's one need of a shape
+        got = block(fragment._take_rows)
+        assert got.dtype == elements.dtype and got.shape == elements.shape
+        np.testing.assert_array_equal(got, elements)
+    live = min(len(want), w)
+    for (v, ok), src, m in zip(
+            fragment._unpack_rows(elements[:, :live],
+                                  [x.dtype for x in vals]), vals, valids):
+        assert v.dtype == src.dtype
+        np.testing.assert_array_equal(v.view(np.uint8),
+                                      src[want[:live]].view(np.uint8))
+        np.testing.assert_array_equal(ok, m[want[:live]])
+
+
+@pytest.mark.parametrize("capacity,w,rows", [(16384, 1024, True),
+                                             (131072, 8192, True),
+                                             (16384, 4096, False),
+                                             (131072, 64, False)])
+def test_the_program_takes_the_path_its_shape_asks_for(monkeypatch, capacity,
+                                                       w, rows):
+    """The chain program as ``executor._scan_select`` builds it, run at a
+    shape ``gathers_rows`` sends to the rows and at one it does not,
+    against the same program traced with element gathers only: the block
+    that leaves the device is the same to the bit."""
+    import jax.numpy as jnp
+    from daft_tpu import DataType
+    from daft_tpu.schema import Field, Schema
+    schema = Schema([Field("k", DataType.int64()),
+                     Field("x", DataType.float32()),
+                     Field("d", DataType.int32())])
+    rng = np.random.default_rng(w)
+    arrays = {"k": rng.integers(-2**62, 2**62, capacity),
+              "x": rng.normal(0, 1e4, capacity).astype(np.float32),
+              "d": rng.integers(0, 100000, capacity).astype(np.int32)}
+    valids = {n: rng.random(capacity) < 0.9 for n in arrays}
+    mask = np.arange(capacity) < capacity - capacity // 8
+    cut = 50000 * w // capacity     # the survivors half fill the bucket
+
+    def run():
+        fragment._region_cache.clear()
+        prog = fragment.get_fused_region(
+            [col(c) for c in schema.column_names], col("d") < lit(cut),
+            schema)
+        return np.asarray(prog.packed_fn(
+            {n: jnp.asarray(a) for n, a in arrays.items()},
+            {n: jnp.asarray(m) for n, m in valids.items()},
+            jnp.asarray(mask), (), out_w=w))
+
+    assert fragment.gathers_rows(capacity, w) is rows
+    calls = []
+    real = fragment._take_rows
+    monkeypatch.setattr(fragment, "_take_rows",
+                        lambda *a: calls.append(1) or real(*a))
+    got = run()
+    assert bool(calls) is rows
+    monkeypatch.setattr(fragment, "gathers_rows", lambda C, w: False)
+    del calls[:]
+    elements = run()
+    assert not calls
+    fragment._region_cache.clear()
+    np.testing.assert_array_equal(got, elements)
+    keep = mask & valids["d"] & (arrays["d"] < cut)
+    assert 0 < fragment._packed_live(got) == keep.sum() <= w
+    (k, _), (x, _), (d, _) = fragment._unpack_rows(
+        got[:, :keep.sum()], [np.int64, np.float32, np.int32])
+    np.testing.assert_array_equal(k, arrays["k"][keep])
+    np.testing.assert_array_equal(x, arrays["x"][keep])
+    np.testing.assert_array_equal(d, arrays["d"][keep])

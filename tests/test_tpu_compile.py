@@ -8,7 +8,8 @@ TPC-H Q1/Q6/Q3 dispatch are lowered and compiled here: Q1's fused fragment
 at the dense strategy every benchmark cell runs, the sort grouped-agg in
 Q1's key/agg layout (what a non-dictionary key falls to), the fused join,
 the packed-key argsort, Q6's fused scan->filter->agg fragment, the scan's
-selection in Q19's shape at the star cell's real bucket (no sort in it: 5 s),
+selection in Q19's and Q14's shapes at the star cell's real buckets (no sort
+in it: 5 s each; the survivors come back by row gathers there),
 and the mesh grouped-agg collective on a 4-device ``Mesh`` of the described
 devices.
 Capacities are moderate on purpose (16384-row sorts, ~25 s each):
@@ -171,47 +172,84 @@ def _fused_q1_dense(S):
                                 dims=(4, 2))
 
 
-def _scan_select_q19(S):
+def _scan_select(S, columns, pred, strings, words, C, w):
     """The scan's selection (fragment.get_fused_region's chain program, as
-    ``executor._scan_select`` runs it) in TPC-H Q19's shape, at the
-    benchmark cell's real sizes: two string predicates against runtime
-    scalars of the table's dictionary, six columns out (an int64 key,
-    three floats, two dictionary codes), the count-and-search compaction
-    and the packed block at the 262 144 rung of the 4 194 304 bucket."""
+    ``executor._scan_select`` runs it) over ``columns`` at the ``w`` rung
+    of the ``C`` bucket; string tests ride runtime scalars of the table's
+    dictionary (``strings``: one dictionary for every scalar)."""
     import pyarrow as pa
 
-    from daft_tpu import DataType, col
+    from daft_tpu import col
     from daft_tpu.device import fragment
     from daft_tpu.schema import Field, Schema
-    f32 = DataType.float32()   # what a float64 rides on the chip
-    schema = Schema([Field("l_partkey", DataType.int64()),
-                     Field("l_quantity", f32),
-                     Field("l_extendedprice", f32),
-                     Field("l_discount", f32),
-                     Field("l_shipinstruct", DataType.string()),
-                     Field("l_shipmode", DataType.string())])
-    pred = ((col("l_shipinstruct") == "DELIVER IN PERSON")
-            & col("l_shipmode").is_in(["AIR", "AIR REG"])
-            & col("l_partkey").not_null())
+    schema = Schema([Field(n, dt) for n, dt in columns])
     prog = fragment.get_fused_region(
         [col(c) for c in schema.column_names], pred, schema)
-    assert prog is not None, "Q19's selection must be device-compilable"
-    assert prog.out_words == 5   # header+validity, the key, 5 halves
-    C, w = 4194304, 262144
+    assert prog is not None, "the selection must be device-compilable"
+    assert prog.out_words == words
+    # the survivors come back by gathers of 128-lane rows at these shapes
+    assert fragment.gathers_rows(C, w)
     arrays = {n: S((C,), prog.in_np_dtypes[n])
               for n in prog.compiled.needs_cols}
     valids = {n: S((C,), jnp.bool_) for n in prog.compiled.needs_cols}
     scalars = tuple(
         S(np.shape(v), np.asarray(v).dtype) for v in (
-            spec.fn(pa.array(["AIR", "DELIVER IN PERSON"]))
+            spec.fn(pa.array(strings))
             for spec in prog.compiled.scalar_specs))
-    assert len(scalars) == 2
     return prog.packed_fn.lower(arrays, valids, S((C,), jnp.bool_),
                                 scalars, out_w=w)
 
 
+def _scan_select_q19(S):
+    """TPC-H Q19's shape at the benchmark cell's real sizes: two string
+    predicates against runtime scalars of the table's dictionary, six
+    columns out (an int64 key, three floats, two dictionary codes), the
+    count-and-search compaction, the output stage's row gathers (eight
+    int32 planes: the validity bits, the key's halves, five values) and
+    the packed block at the 262 144 rung of the 4 194 304 bucket."""
+    from daft_tpu import DataType, col
+    f32 = DataType.float32()   # what a float64 rides on the chip
+    lowered = _scan_select(
+        S, [("l_partkey", DataType.int64()), ("l_quantity", f32),
+            ("l_extendedprice", f32), ("l_discount", f32),
+            ("l_shipinstruct", DataType.string()),
+            ("l_shipmode", DataType.string())],
+        ((col("l_shipinstruct") == "DELIVER IN PERSON")
+         & col("l_shipmode").is_in(["AIR", "AIR REG"])
+         & col("l_partkey").not_null()),
+        ["AIR", "DELIVER IN PERSON"],
+        5,   # header+validity, the key, 5 halves
+        4194304, 262144)
+    assert len(lowered.args_info[0][3]) == 2    # the two scalars
+    return lowered
+
+
+def _scan_select_q14(S):
+    """TPC-H Q14's shape: a date range, four columns out (an int64 key,
+    two floats, the date) at the 65 536 rung of the 4 194 304 bucket."""
+    import datetime
+
+    from daft_tpu import DataType, col, lit
+    f32 = DataType.float32()
+    return _scan_select(
+        S, [("l_partkey", DataType.int64()), ("l_extendedprice", f32),
+            ("l_discount", f32), ("l_shipdate", DataType.date())],
+        ((col("l_shipdate") >= lit(datetime.date(1995, 9, 1)))
+         & (col("l_shipdate") < lit(datetime.date(1995, 10, 1)))),
+        [], 4, 4194304, 65536)
+
+
+#: gathers of 128-lane rows / of single elements from a plane of the whole
+#: table, in the compiled selection: the search's two row gathers and one
+#: a plane of the output stage (the validity bits, a 64-bit key's halves,
+#: a value each), and no element gather but the search's 256-entry lookup
+SELECT_GATHERS = {"scan_select_q19": (2 + 8, 1),
+                  "scan_select_q14": (2 + 6, 1)}
+
+
 ONE_CHIP_PROGRAMS = {
     "scan_select_q19": _scan_select_q19,
+    "scan_select_q14": _scan_select_q14,
     "fused_scan_filter_agg_q1_dense": _fused_q1_dense,
     "sort_grouped_agg_q1_layout": _q1_sort_grouped_agg,
     "join_fused": _join_fused,
@@ -227,6 +265,12 @@ def test_program_compiles_for_one_v5e_chip(name, one_chip):
 
     compiled = ONE_CHIP_PROGRAMS[name](S).compile()
     assert compiled.memory_analysis() is not None
+    if name in SELECT_GATHERS:
+        import re
+        sizes = re.findall(r" gather\(.*slice_sizes=\{([0-9,]+)\}",
+                           compiled.as_text())
+        assert (sizes.count("1,128"), sizes.count("1")) \
+            == SELECT_GATHERS[name], sizes
 
 
 def test_sharded_grouped_agg_compiles_for_four_chip_mesh(topo):
