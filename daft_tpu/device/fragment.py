@@ -338,10 +338,12 @@ def _dispatch_packed(prog: FusedAggProgram, dt: dcol.DeviceTable,
         # fragment.packed / fragment.donate) — everything the jit cache
         # key may depend on; a second trace for the SAME key is the
         # retrace tax and a sanitizer budget violation
+        site = "fragment.donate" if donate else "fragment.packed"
         with retrace_sanitizer.dispatch_scope(
-                "fragment.donate" if donate else "fragment.packed",
+                site,
                 (id(prog), dt.capacity, out_cap, strategy, dims,
-                 tuple(s.shape for s in scalars), dt.chip)):
+                 tuple(s.shape for s in scalars), dt.chip)), \
+                tracing.launch(site, dt.chip or 0):
             # runs where its arguments lie: on the table's chip
             return fn(arrays, valids, dt.row_mask, scalars,
                       out_cap=out_cap, strategy=strategy, dims=dims)
@@ -1327,10 +1329,12 @@ def _dispatch_region(prog: FusedRegionProgram, dt: dcol.DeviceTable,
         valids = {n: col.validity for n, col in dt.columns.items()}
         scalars = runtime._prep_scalars(prog.compiled, dt)
         fn = prog.donate_fn() if donate else prog.packed_fn
+        site = "region.topk" if prog.shape == "topk" else "region.chain"
         with retrace_sanitizer.dispatch_scope(
-                "region.topk" if prog.shape == "topk" else "region.chain",
+                site,
                 (id(prog), dt.capacity, out_w,
-                 tuple(s.shape for s in scalars), dt.chip)):
+                 tuple(s.shape for s in scalars), dt.chip)), \
+                tracing.launch(site, dt.chip or 0):
             # runs where its arguments lie: on the table's chip
             return fn(arrays, valids, dt.row_mask, scalars, out_w=out_w)
 
@@ -1806,7 +1810,8 @@ def _dispatch_join_agg(prog: FusedJoinAggProgram, dt: dcol.DeviceTable,
                 "region.join_agg",
                 (id(prog), dt.capacity, build.dt.capacity, W, out_cap,
                  tuple(s.shape for s in p_scalars),
-                 tuple(s.shape for s in post_scalars))):
+                 tuple(s.shape for s in post_scalars))), \
+                tracing.launch("region.join_agg", dt.chip or 0):
             return prog.packed_fn(
                 p_arrays, p_valids, dt.row_mask, p_scalars, b_arrays,
                 b_valids, build.sorted_key, build.perm, build.live_count,

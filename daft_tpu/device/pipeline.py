@@ -230,24 +230,33 @@ def acquire_slot(gate: WindowGate, seq: int, mem=None,
     exempt, deadlock-free), then memory admission for the slot's
     host+HBM footprint.  The returned Slot OWNS both; every caller must
     :func:`release_slot` it on all paths or hand it off whole (the
-    ``device-slot-leak`` Contract row proves this statically)."""
-    gate.acquire(seq)
-    if mem is not None and nbytes > 0:
-        # gate doubles as the cancel signal: a torn-down pipeline must
-        # not leave a worker waiting forever on admission it will never
-        # get (the consumer that would release bytes is gone)
-        # daft-lint: allow(memory-admission-leak) -- the admitted bytes
-        # transfer into the returned Slot by design (acquire-on-submit,
-        # release-on-drain); the device-slot-leak contract proves every
-        # acquire_slot caller releases or hands the Slot off whole
-        if not mem.try_acquire(
-                nbytes, deadline=time.monotonic() + _ADMIT_DEADLINE_S,
-                cancel=gate):
-            if gate.is_set():
-                gate.slot_released()
-                raise PipelineAborted()
-            _count("admission_timeouts")
-            nbytes = 0
+    ``device-slot-leak`` Contract row proves this statically).  Traced,
+    the two waits together are one ``wait:window`` span (``seq``, and
+    ``admitted``: False where the memory admission timed out)."""
+    from .. import tracing
+    with tracing.wait("wait:window") as sp:
+        sp.set("seq", seq)
+        sp.set("admitted", True)
+        gate.acquire(seq)
+        if mem is not None and nbytes > 0:
+            # gate doubles as the cancel signal: a torn-down pipeline
+            # must not leave a worker waiting forever on admission it
+            # will never get (the consumer that would release bytes is
+            # gone)
+            # daft-lint: allow(memory-admission-leak) -- the admitted
+            # bytes transfer into the returned Slot by design
+            # (acquire-on-submit, release-on-drain); the device-slot-leak
+            # contract proves every acquire_slot caller releases or hands
+            # the Slot off whole
+            if not mem.try_acquire(
+                    nbytes, deadline=time.monotonic() + _ADMIT_DEADLINE_S,
+                    cancel=gate):
+                if gate.is_set():
+                    gate.slot_released()
+                    raise PipelineAborted()
+                _count("admission_timeouts")
+                nbytes = 0
+                sp.set("admitted", False)
     return Slot(gate, mem, nbytes if mem is not None else 0, seq)
 
 
@@ -324,10 +333,13 @@ def run_pipelined(items: Iterator, submit: Callable, drain: Callable, *,
     and releases every undrained slot — the admission-leak and
     cancellation tests pin this."""
     from .. import observability as obs
+    from .. import tracing
 
     gate = WindowGate(window)
     pool = _pipe_pool()
-    pending = collections.deque()  # (future, seq)
+    # (future, seq, what the submit carried: traced, a stamp that says
+    # when the worker was done)
+    pending = collections.deque()
     it = iter(items)
     seq_next = [0]
     # ACTIVE wall only: time the driver spends working (or waiting on
@@ -358,9 +370,10 @@ def run_pipelined(items: Iterator, submit: Callable, drain: Callable, *,
             return False
         seq = seq_next[0]
         seq_next[0] += 1
-        fut = pool.submit(obs.run_attributed, obs.current_attribution(),
-                          submit, item, seq, gate)
-        pending.append((fut, seq))
+        stamp = obs.submit_attribution("devpipe")
+        fut = pool.submit(obs.run_attributed, stamp, submit, item, seq,
+                          gate)
+        pending.append((fut, seq, stamp))
         return True
 
     def _fill() -> None:
@@ -371,9 +384,15 @@ def run_pipelined(items: Iterator, submit: Callable, drain: Callable, *,
     try:
         _fill()
         while pending:
-            fut, seq = pending.popleft()
+            fut, seq, stamp = pending.popleft()
             try:
-                ret = fut.result()
+                # the consumer blocked on the head of the window
+                with tracing.wait("wait:result") as sp:
+                    sp.set("seq", seq)
+                    try:
+                        ret = fut.result()
+                    finally:
+                        sp.handed(getattr(stamp, "done_ns", 0))
             except PipelineAborted:
                 gate.note_drained(seq)
                 continue
@@ -400,7 +419,7 @@ def run_pipelined(items: Iterator, submit: Callable, drain: Callable, *,
     finally:
         active_s[0] += time.perf_counter() - t_resume
         gate.abort()
-        for fut, seq in pending:
+        for fut, seq, _ in pending:
             if fut.cancel():
                 continue
             try:
@@ -448,7 +467,7 @@ def note_compute_span(seq: int, window: int, t_dispatched_us: int) -> None:
     if ctx is None or not t_dispatched_us:
         return
     rec = ctx.recorder
-    now = tracing._now_us()
+    now = rec.now_us()
     rec.add("device:inflight", rec.unique_span_id(f"devpipe.comp.{seq}"),
             ctx.span_id, t_dispatched_us,
             max(now - t_dispatched_us, 0),
@@ -467,7 +486,8 @@ def download_span(seq: int, window: int):
 
 def now_us() -> int:
     from .. import tracing
-    return tracing._now_us() if tracing.current() is not None else 0
+    ctx = tracing.current()
+    return ctx.recorder.now_us() if ctx is not None else 0
 
 
 # ------------------------------------------- device-resident hand-off

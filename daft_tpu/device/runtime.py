@@ -23,6 +23,7 @@ import pyarrow as pa
 import jax
 import jax.numpy as jnp
 
+from .. import tracing
 from ..datatype import DataType
 from ..expressions.expressions import Expression
 from ..schema import Schema
@@ -254,7 +255,8 @@ def _run_compiled(c: compiler.Compiled, batch, exprs: List[Expression]):
     # shapes — never per raw row count
     with retrace_sanitizer.dispatch_scope(
             "compiler.projection",
-            (id(c), dt.capacity, tuple(s.shape for s in scalars))):
+            (id(c), dt.capacity, tuple(s.shape for s in scalars))), \
+            tracing.launch("compiler.projection", dt.chip or 0):
         outs = c.fn(arrays, valids, dt.row_mask, scalars)
     return dt, outs
 
@@ -375,12 +377,14 @@ def try_argsort(key_series: List[Series], descending: List[bool],
     t0 = _time.perf_counter()
     desc = tuple(bool(d) for d in descending)
     nf = tuple(bool(x) for x in nulls_first)
+    mask = jnp.asarray(mask)  # ahead of the launch span: a copy, no launch
     with retrace_sanitizer.dispatch_scope(
             "kernels.argsort",
-            (tuple(str(c.data.dtype) for c in cols), cap, desc, nf)):
+            (tuple(str(c.data.dtype) for c in cols), cap, desc, nf)), \
+            tracing.launch("kernels.argsort"):
         perm = kernels.argsort_kernel(
             tuple(c.data for c in cols), tuple(c.validity for c in cols),
-            jnp.asarray(mask), desc, nf)
+            mask, desc, nf)
     out = np.asarray(jax.device_get(perm))[:n].astype(np.int64)
     costmodel.ledger_record(
         "argsort", rows=n,
@@ -468,7 +472,8 @@ def try_agg(batch, to_agg: List[Expression], group_by: List[Expression]):
         with retrace_sanitizer.dispatch_scope(
                 "kernels.grouped_agg",
                 ("global", ops, tuple(str(v.dtype) for v in vals),
-                 dt.capacity)):
+                 dt.capacity)), \
+                tracing.launch("kernels.grouped_agg", dt.chip or 0):
             results = kernels.global_agg_kernel(tuple(vals), tuple(valids),
                                                 dt.row_mask, ops)
         # ONE batched transfer for all scalar results (round 17: the
@@ -497,7 +502,8 @@ def try_agg(batch, to_agg: List[Expression], group_by: List[Expression]):
     kdtypes = tuple(str(v.dtype) for v, _ in keys_b)
     vdtypes = tuple(str(v.dtype) for v, _ in vals_b)
     with retrace_sanitizer.dispatch_scope(
-            "kernels.grouped_agg", (ops, kdtypes, vdtypes, dt.capacity)):
+            "kernels.grouped_agg", (ops, kdtypes, vdtypes, dt.capacity)), \
+            tracing.launch("kernels.grouped_agg", dt.chip or 0):
         out_keys, out_kvalids, out_vals, out_valids, gcount = \
             kernels.grouped_agg_kernel(*karg)
     costmodel.log_strategy_decision("groupby_strategy", "sort",

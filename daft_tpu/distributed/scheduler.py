@@ -456,7 +456,8 @@ class StageRunner:
             with cf.ThreadPoolExecutor(
                     max_workers=len(work),
                     thread_name_prefix="daft-tpu-meshgrp") as pool:
-                futs = [pool.submit(tracing.run_attached, tctx,
+                futs = [pool.submit(tracing.run_attached,
+                                    tracing.submitted(tctx, "meshgrp"),
                                     self._run_one_mesh_group, stage, b,
                                     gi, g, gtasks)
                         for gi, g, gtasks in work]

@@ -226,7 +226,8 @@ class _ParallelFetch:
             self._pool = cf.ThreadPoolExecutor(
                 max_workers=k, thread_name_prefix="daft-tpu-fetch")
             self._futs = [
-                self._pool.submit(tracing.run_attached, tctx,
+                self._pool.submit(tracing.run_attached,
+                                  tracing.submitted(tctx, "fetch"),
                                   fetch_partition, address, shuffle_id,
                                   spec.partition, fault_key=self._key(j))
                 for j, (address, shuffle_id) in enumerate(spec.sources)]

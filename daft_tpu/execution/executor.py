@@ -84,7 +84,8 @@ def _ordered_parallel(inputs: Iterator, fn: Callable,
             # pool worker: shared-plane counters bumped inside fn must
             # credit the query this morsel belongs to
             pending.append(pool.submit(
-                obs.run_attributed, obs.current_attribution(), fn, x))
+                obs.run_attributed, obs.submit_attribution("exec"),
+                fn, x))
         if not pending:
             return
         yield pending.pop(0).result()
@@ -393,7 +394,7 @@ class LocalExecutor:
                 return False
             st = _Stream()
             from .. import observability as obs
-            pool.submit(obs.run_attributed, obs.current_attribution(),
+            pool.submit(obs.run_attributed, obs.submit_attribution("scan"),
                         produce, t, st, submitted[0])
             submitted[0] += 1
             inflight.append(st)

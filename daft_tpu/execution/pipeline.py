@@ -42,8 +42,10 @@ from __future__ import annotations
 import os
 import queue
 import threading
+import time
 from typing import Callable, Iterator, List, Optional
 
+from .. import tracing
 from ..micropartition import MicroPartition
 from ..physical import plan as pp
 from .executor import LocalExecutor
@@ -110,13 +112,31 @@ class PipelineContext:
 _DONE = object()
 
 
+class _Stamped:
+    """An item put by a traced thread, with the instant of the put
+    (``time.perf_counter_ns()``): what the taker's hand-off latency is
+    counted from."""
+
+    __slots__ = ("item", "t_ns")
+
+    def __init__(self, item):
+        self.item = item
+        self.t_ns = time.perf_counter_ns()
+
+
 class Channel:
     """Bounded channel with producer-refcounted close.
 
     ``producers`` producers must each call :meth:`close`; when the last
     one does, ``consumers`` DONE markers are enqueued so every consumer's
     iteration terminates. Blocked puts/gets poll the context's cancel
-    event (there is no way to interrupt a raw ``queue`` wait)."""
+    event (there is no way to interrupt a raw ``queue`` wait).
+
+    Traced, a put on a full queue and a take from an empty one are
+    ``wait:channel`` spans (``side``: ``put`` / ``get``), and every take
+    tallies one hand-off: the time from the later of (item put, taker
+    began to wait) to the taker running. Untraced the queue holds the
+    very objects it was given."""
 
     def __init__(self, ctx: PipelineContext, capacity: int = 4,
                  producers: int = 1, consumers: int = 1):
@@ -127,6 +147,18 @@ class Channel:
         self._lock = threading.Lock()
 
     def put(self, item) -> None:
+        if tracing.current() is None:
+            self._put(item)
+            return
+        item = _Stamped(item)
+        try:
+            self._q.put_nowait(item)
+        except queue.Full:
+            self._put(item)
+            tracing.note_wait("wait:channel", item.t_ns,
+                              time.perf_counter_ns(), {"side": "put"})
+
+    def _put(self, item) -> None:
         while True:
             if self.ctx.cancelled.is_set():
                 raise PipelineCancelled()
@@ -147,14 +179,47 @@ class Channel:
             except PipelineCancelled:
                 return
 
-    def __iter__(self) -> Iterator:
+    def _get(self):
         while True:
             if self.ctx.cancelled.is_set():
                 raise PipelineCancelled()
             try:
-                item = self._q.get(timeout=_POLL_S)
+                return self._q.get(timeout=_POLL_S)
             except queue.Empty:
                 continue
+
+    def _get_traced(self, tctx):
+        """A take by a traced thread: the wait on an empty queue as a
+        span, the hand-off tallied, the item unwrapped."""
+        rec = tctx.recorder
+        try:
+            item = self._q.get_nowait()
+            t_wait = 0
+        except queue.Empty:
+            t_wait = time.perf_counter_ns()
+            item = self._get()
+        if type(item) is not _Stamped:      # put by an untraced thread
+            return item
+        if not t_wait:
+            # it lay in the queue when its taker came: handed over at once
+            rec.handoff(0)
+            return item.item
+        now = time.perf_counter_ns()
+        tail_us = (now - max(item.t_ns, t_wait)) // 1000
+        rec.handoff(tail_us)
+        rec.add_wait("wait:channel", tctx.span_id, t_wait, now,
+                     {"side": "get", "tail_us": tail_us})
+        return item.item
+
+    def __iter__(self) -> Iterator:
+        while True:
+            tctx = tracing.current()
+            if tctx is not None:
+                item = self._get_traced(tctx)
+            else:
+                item = self._get()
+                if type(item) is _Stamped:  # a traced put, an untraced take
+                    item = item.item
             if item is _DONE:
                 return
             yield item

@@ -117,7 +117,7 @@ class SpillWriterGroup:
                     raise self._err
             self._pending_bytes += nbytes
             self._inflight += 1
-        attribution = obs.current_attribution()
+        attribution = obs.submit_attribution("spill-write")
 
         def run():
             try:
@@ -193,7 +193,8 @@ def prefetch_ordered(thunks: Iterator[Callable[[], object]],
                     done = True
                     break
                 pending.append(pool.submit(
-                    obs.run_attributed, obs.current_attribution(), t))
+                    obs.run_attributed,
+                    obs.submit_attribution("spill-read"), t))
             if not pending:
                 return
             yield pending.pop(0).result()

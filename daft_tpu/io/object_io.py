@@ -74,6 +74,7 @@ def parallel_get_ranges(source: "ObjectSource", path: str,
     err: List[BaseException] = []
 
     from .. import observability as obs
+    from .. import tracing
     attr_ctx = obs.current_attribution()
 
     def submit():
@@ -83,7 +84,8 @@ def parallel_get_ranges(source: "ObjectSource", path: str,
             return
         # IO-pool workers inherit the submitting query's stats
         # attribution so per-query io counters stay scoped
-        pending[pool.submit(obs.run_attributed, attr_ctx,
+        pending[pool.submit(obs.run_attributed,
+                            tracing.submitted(attr_ctx, "io"),
                             source.get, path, r, stats)] = i
 
     for _ in range(min(par, len(ranges))):

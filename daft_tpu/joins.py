@@ -231,14 +231,15 @@ def _device_match_indices(l_gids, r_gids, l_valid, r_valid):
         # out-capacity bucket; the same signature must re-enter the jit
         # cache, never re-trace. The fetch is ``join:device``'s own: a
         # ``device:fetch`` span here would nest one leaf in another
-        with retrace_sanitizer.dispatch_scope("kernels.join_fused",
-                                              (c_l, c_r, cap)):
-            return np.asarray(jax.device_get(K.join_fused_kernel(
-                jnp.asarray(pad(l_gids.astype(np.int64), c_l)),
+        args = (jnp.asarray(pad(l_gids.astype(np.int64), c_l)),
                 jnp.asarray(pad(l_valid, c_l)), jnp.asarray(lmask),
                 jnp.asarray(pad(r_gids.astype(np.int64), c_r)),
-                jnp.asarray(pad(r_valid, c_r)), jnp.asarray(rmask),
-                out_capacity=cap)))
+                jnp.asarray(pad(r_valid, c_r)), jnp.asarray(rmask))
+        with retrace_sanitizer.dispatch_scope("kernels.join_fused",
+                                              (c_l, c_r, cap)), \
+                tracing.launch("kernels.join_fused"):
+            packed = K.join_fused_kernel(*args, out_capacity=cap)
+        return np.asarray(jax.device_get(packed))
 
     from . import tracing
     with tracing.span("join:device", lane="device",

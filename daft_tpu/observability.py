@@ -114,11 +114,26 @@ def query_scope():
             finalize_query(ctx)
 
 
+def submit_attribution(pool: str) -> Optional["RuntimeStatsContext"]:
+    """:func:`current_attribution` as a pool-submit site passes it to
+    :func:`run_attributed`: when the thread is traced, stamped with the
+    pool's name and the instant (``tracing.submitted``), so the worker
+    records the submit's time in the pool's queue as ``wait:pool``."""
+    from . import tracing
+    return tracing.submitted(getattr(_attr_tl, "ctx", None), pool)
+
+
 def run_attributed(ctx, fn, *args, **kwargs):
     """Run ``fn`` with ``ctx`` attributed — the shape pool-submit sites
-    use to carry the submitting thread's attribution onto the worker."""
-    with attributed(ctx):
-        return fn(*args, **kwargs)
+    use to carry the submitting thread's attribution onto the worker
+    (``ctx`` from :func:`current_attribution` or
+    :func:`submit_attribution`)."""
+    from . import tracing
+    with attributed(tracing.started(ctx)):
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            tracing.done(ctx)
 
 
 def bump_plane(plane: str, key: str, n: float = 1) -> None:

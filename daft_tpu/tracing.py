@@ -32,8 +32,24 @@ Design contracts:
 - **one clock** — while a profile is being taken every live span is
   also a ``jax.profiler.TraceAnnotation`` named ``daft:<span>`` on the
   same thread, so the profile holds the program's spans on its host
-  lines, beside the device lines they explain. Both stamp wall-clock
-  time.
+  lines, beside the device lines they explain. A span's ``ts_us`` is
+  wall-clock time (the trace's start on ``time.time()`` plus what
+  ``time.perf_counter_ns()`` has counted since); its ``dur_us`` comes
+  from ``perf_counter_ns`` alone, so a host that steps its clock moves
+  no duration.
+- **work apart from wait** — a live leaf span (and the launch:
+  :data:`CPU_SPANS`) also reads its thread's CPU clock
+  (``time.thread_time_ns()``) and carries ``cpu_us``:
+  ``dur_us - cpu_us`` is how long the thread stood still inside it, and
+  :data:`COMPUTE_SPANS` names the spans for which that is a wait for the
+  GIL or a lock (``phases[name]["timed_us"] - ["cpu_us"]``). The jitted
+  call is a span of its own (``dispatch:launch``, :func:`launch`). The
+  places where work waits in a queue are ``wait:*`` spans
+  (``wait:window``, ``wait:result``, ``wait:pool``, ``wait:channel``;
+  :func:`wait`, :func:`note_wait`), a taker's latency after an item was
+  handed to it is tallied (``summary()["handoffs"]``), and
+  ``summary()["holes"]`` lays those spans over the part of the query's
+  wall that no leaf span covers.
 - **deterministic under chaos** — span ids are minted by hashing the
   planner's stable identities (``Stage.task_key`` fault keys, operator
   names, attempt numbers), never RNG, so a seeded
@@ -84,6 +100,28 @@ def _now_us() -> int:
     return int(time.time() * 1e6)
 
 
+#: a wait shorter than this is counted (``summary()["waits_short"]``),
+#: not stored: a join pass hands thousands of morsels from stage to stage
+#: and most takers wait a few microseconds for theirs, while a query's
+#: buffer holds ``DAFT_TPU_TRACE_MAX_SPANS`` spans
+WAIT_FLOOR_US = 200
+
+
+def _replayed() -> bool:
+    """A chaos replay (``DAFT_TPU_CHAOS_SERIALIZE=1`` or an active fault
+    plan: ``device.pipeline.sequential_fallback``'s two conditions): its
+    span ids have to come out bit-identical, and which waits pass the
+    floor hangs on the clock, so every wait is counted and none stored."""
+    from .analysis import knobs
+    if knobs.env_bool("DAFT_TPU_CHAOS_SERIALIZE"):
+        return True
+    try:
+        from .distributed.resilience import active_fault_plan
+        return active_fault_plan() is not None
+    except Exception:
+        return False
+
+
 # ----------------------------------------------------------- recorder
 
 
@@ -100,6 +138,8 @@ class SpanRecorder:
         #: ``jax.profiler.TraceAnnotation`` (decided once, at the start)
         self.bridge = bridge
         self.max_spans = max(int(max_spans), 1)
+        #: decided once, at the start, as ``bridge`` is
+        self.wait_floor_us = sys.maxsize if _replayed() else WAIT_FLOOR_US
         self._lock = threading.Lock()
         self._spans: List[dict] = []
         self.dropped = 0
@@ -107,9 +147,12 @@ class SpanRecorder:
         self.clock_offsets_us: Dict[str, int] = {}
         self.root_id = span_id_from("query")
         self._root_t0 = _now_us()
-        #: the same instant on ``time.perf_counter()``: a reader in this
-        #: process places the trace among its own timings with no offset
-        self._root_perf_s = time.perf_counter()
+        #: the same instant on ``time.perf_counter_ns()``, the clock every
+        #: duration of this trace is taken from (:meth:`wall_us`); as
+        #: ``time.perf_counter()`` seconds a reader in this process
+        #: places the trace among its own timings with no offset
+        self._root_perf_ns = time.perf_counter_ns()
+        self._root_perf_s = self._root_perf_ns / 1e9
         self._root_dur = 0
         #: counts kept on the root span (``tally``): where each scan
         #: task's table came from
@@ -136,14 +179,32 @@ class SpanRecorder:
     def unique_span_id(self, key: str) -> str:
         return span_id_from(self.unique_key(key))
 
+    # -- clock ---------------------------------------------------------
+    def wall_us(self, perf_ns: int) -> int:
+        """The wall-clock microsecond of a ``time.perf_counter_ns()``
+        reading: the trace's start plus what the monotonic clock counted
+        since, so spans of one trace keep their order and their lengths
+        whatever the host does to its clock meanwhile."""
+        return self._root_t0 + (perf_ns - self._root_perf_ns) // 1000
+
+    def now_us(self) -> int:
+        return self.wall_us(time.perf_counter_ns())
+
     # -- recording ----------------------------------------------------
     def add(self, name: str, span_id: str, parent_id: Optional[str],
             ts_us: int, dur_us: int, attrs: Optional[dict] = None,
-            lane: str = "driver", status: str = "ok") -> None:
+            lane: str = "driver", status: str = "ok",
+            cpu_us: Optional[int] = None) -> None:
+        """``cpu_us``: the CPU time of the thread that lived the span
+        (live spans of :data:`CPU_SPANS` only; any other, a span added
+        with explicit timestamps, or one shipped back from another
+        process, has none: absent, not 0)."""
         span = {"name": name, "span_id": span_id,
                 "parent_id": parent_id or self.root_id,
                 "ts_us": int(ts_us), "dur_us": max(int(dur_us), 0),
                 "lane": lane}
+        if cpu_us is not None:
+            span["cpu_us"] = max(int(cpu_us), 0)
         if attrs:
             span["attrs"] = attrs
         if status != "ok":
@@ -178,6 +239,37 @@ class SpanRecorder:
         with self._lock:
             self._tallies[key] = max(self._tallies.get(key, 0), n)
 
+    def add_wait(self, name: str, parent_id: Optional[str], t0_ns: int,
+                 t1_ns: int, attrs: Optional[dict] = None) -> None:
+        """A wait whose length is known only afterwards, between two
+        ``time.perf_counter_ns()`` readings: a ``wait:*`` span with
+        explicit timestamps (so not in a profile), or, under
+        :data:`WAIT_FLOOR_US`, a count."""
+        dur_us = max(t1_ns - t0_ns, 0) // 1000
+        if dur_us < self.wait_floor_us:
+            self.wait_short(dur_us)
+            return
+        self.add(name, self.unique_span_id(name), parent_id,
+                 self.wall_us(t0_ns), dur_us, attrs=attrs, lane="wait")
+
+    def wait_short(self, dur_us: int) -> None:
+        with self._lock:
+            t = self._tallies
+            t["waits_short"] = t.get("waits_short", 0) + 1
+            t["waits_short_us"] = t.get("waits_short_us", 0) + dur_us
+
+    def handoff(self, latency_us: int) -> None:
+        """One item taken from a channel, or one submit started on a
+        pool: how long after it was ready (and its taker waiting) the
+        taker ran."""
+        latency_us = max(int(latency_us), 0)
+        with self._lock:
+            t = self._tallies
+            t["handoffs"] = t.get("handoffs", 0) + 1
+            t["handoff_us"] = t.get("handoff_us", 0) + latency_us
+            t["handoff_max_us"] = max(t.get("handoff_max_us", 0),
+                                      latency_us)
+
     def tally_chip(self, chip: int, tables: int = 0, rows: int = 0,
                    resident_bytes: Optional[int] = None) -> None:
         with self._lock:
@@ -200,7 +292,7 @@ class SpanRecorder:
             tallies = dict(self._tallies)
         if status is not None:
             self.status = status
-        self._root_dur = max(_now_us() - self._root_t0, 0)
+        self._root_dur = max(self.now_us() - self._root_t0, 0)
         self.add("query", self.root_id, None, self._root_t0,
                  self._root_dur, attrs=tallies or None, lane="driver",
                  status=self.status)
@@ -235,10 +327,16 @@ class SpanRecorder:
         decoded into how many record ``batches``), ``joins`` (the bucket
         pairs ``joins.match_indices`` matched: :data:`JOIN_TALLIES`),
         ``selects`` (the filtered scans' tables that ended in rows:
-        :data:`SELECT_TALLIES`) and
+        :data:`SELECT_TALLIES`),
         ``chips`` (the same tally per chip: ``chip``, ``tables``, ``rows``
         and ``resident_bytes``, one entry a chip, in chip order),
-        computed once, when the root closed."""
+        ``handoffs`` (``count`` items taken from a channel or submits
+        started on a pool, the ``us`` their takers took to run once the
+        item was there and they were waiting, and the longest,
+        ``max_us``), ``waits_short`` (the ``count`` and ``us`` of waits
+        under :data:`WAIT_FLOOR_US`, which no span holds) and ``holes``
+        (:func:`_holes`: the wall no leaf span covers, by the name of
+        what lay over it), computed once, when the root closed."""
         return self._summary or self._summarize()
 
     def _summarize(self) -> dict:
@@ -259,9 +357,15 @@ class SpanRecorder:
             out["t0_perf_s"] = self._root_perf_s
             out["wall_us"] = self._root_dur
             out["phases"] = _phases(spans, self.root_id)
-            out["covered_us"] = _union_us(
-                [(s["ts_us"], s["ts_us"] + s["dur_us"]) for s in spans
-                 if s["name"] in LEAF_SPANS], lo, hi)
+            leaves = _merged([_interval(s) for s in spans
+                              if s["name"] in LEAF_SPANS], lo, hi)
+            out["covered_us"] = _length(leaves)
+            out["holes"] = _holes(spans, leaves, lo, hi)
+            out["handoffs"] = {"count": tallies.get("handoffs", 0),
+                               "us": tallies.get("handoff_us", 0),
+                               "max_us": tallies.get("handoff_max_us", 0)}
+            out["waits_short"] = {"count": tallies.get("waits_short", 0),
+                                  "us": tallies.get("waits_short_us", 0)}
             out["tables"] = {k: tallies.get(k, 0) for k in TABLE_SOURCES}
             out["footers"] = _footer_counts(tallies)
             out["files"] = _file_counts(tallies)
@@ -292,6 +396,42 @@ LEAF_SPANS = frozenset((
     "expr:eval",
     "exchange:partition", "exchange:gather", "mem:size",
     "result:collect"))
+
+#: the spans whose body is computation in the calling thread, so that a
+#: thread off the CPU inside one (``dur_us - cpu_us``) is a thread that
+#: waits for the GIL or for a lock. (Where Arrow hands part of a kernel to
+#: its own pool the caller waits too: read a span's share with nothing
+#: beside it before the number under load is believed.) The other live
+#: leaves wait for something else: ``device:fetch`` for the chip and the
+#: link, ``scan:load`` for the file system and Arrow's pool,
+#: ``plan:optimize`` for the IO pool's batch of ``stat``s, ``device:put``
+#: for the copy to the chip, ``join:device`` for its own fetch
+COMPUTE_SPANS = frozenset((
+    "expr:eval", "exchange:partition", "exchange:gather", "mem:size",
+    "join:build", "join:probe", "agg:host", "sort:topn", "device:decode",
+    "device:encode", "plan:translate", "device:dispatch"))
+
+#: the live spans that read their thread's CPU clock: the leaves and the
+#: launch, which are what a metric reads. Not every live span: on the
+#: machines with the chips one ``time.thread_time_ns()`` costs 6.2 us (a
+#: trap into the sandbox's kernel; 0.6 us elsewhere) and steps by 10 ms,
+#: and a resident pass holds ~170 stage, submit, drain and wait spans whose
+#: CPU time nobody asks for (``chip_proof/clock_cost.py``; PERF.md §6,
+#: PR 43)
+CPU_SPANS = LEAF_SPANS | {"dispatch:launch"}
+
+#: the spans that say what was going on while no leaf span ran
+#: (``summary()["holes"]["unnamed_us"]`` is what lies under none of them).
+#: A taker that waits for its producer (``wait:channel``, ``wait:result``)
+#: says nothing of what the producer is about, and some thread waits so
+#: all through a query: such a span names a hole only over its tail, the
+#: hand-off (``tail_us``: the item was there, the taker waiting, and
+#: not yet running). ``pipeline:stage`` and the root span a thread's whole
+#: life and name nothing
+HOLE_SPANS = frozenset((
+    "device:submit", "device:drain", "device:inflight", "scan:prefetch",
+    "dispatch:launch", "wait:pool", "wait:window"))
+_LIFELONG_SPANS = frozenset(("pipeline:stage", "query"))
 
 #: ``summary()["joins"]``: the bucket pairs ``joins.match_indices`` matched
 #: with the fused device program (``join:device``) or on the host
@@ -342,30 +482,105 @@ def _file_counts(tallies: Dict[str, int]) -> Dict[str, int]:
             "stats": tallies.get("file_stats", 0)}
 
 
-def _union_us(intervals, lo: Optional[int] = None,
-              hi: Optional[int] = None) -> int:
-    """Length of the union of ``(start, end)`` intervals, clipped to
-    ``[lo, hi]`` when given: spans of one name on several threads
-    overlap, and a wall must not count the overlap twice."""
-    total = 0
-    at = None
+def _interval(span: dict) -> Tuple[int, int]:
+    return span["ts_us"], span["ts_us"] + span["dur_us"]
+
+
+def _merged(intervals, lo: Optional[int] = None,
+            hi: Optional[int] = None) -> List[Tuple[int, int]]:
+    """The union of ``(start, end)`` intervals, clipped to ``[lo, hi]``
+    when given, as disjoint intervals in order."""
+    out: List[Tuple[int, int]] = []
     for s, e in sorted(intervals):
         if lo is not None:
             s = max(s, lo)
         if hi is not None:
             e = min(e, hi)
-        if at is None or s > at:
-            at = s
-        if e > at:
-            total += e - at
-            at = e
+        if e <= s:
+            continue
+        if out and s <= out[-1][1]:
+            if e > out[-1][1]:
+                out[-1] = (out[-1][0], e)
+        else:
+            out.append((s, e))
+    return out
+
+
+def _length(merged: List[Tuple[int, int]]) -> int:
+    return sum(e - s for s, e in merged)
+
+
+def _union_us(intervals, lo: Optional[int] = None,
+              hi: Optional[int] = None) -> int:
+    """Length of the union of ``(start, end)`` intervals, clipped to
+    ``[lo, hi]`` when given: spans of one name on several threads
+    overlap, and a wall must not count the overlap twice."""
+    return _length(_merged(intervals, lo, hi))
+
+
+def _overlap_us(a: List[Tuple[int, int]], b: List[Tuple[int, int]]) -> int:
+    """Length of the intersection of two lists of disjoint intervals in
+    order (:func:`_merged`)."""
+    total = i = j = 0
+    while i < len(a) and j < len(b):
+        lo, hi = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
+        if hi > lo:
+            total += hi - lo
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
     return total
+
+
+def _holes(spans: List[dict], leaves: List[Tuple[int, int]], lo: int,
+           hi: int) -> dict:
+    """The query's wall that no leaf span covers (``us``: ``wall_us -
+    covered_us``), with every other span laid over it: ``by`` gives, per
+    span name, how much of the holes the union of that name's spans
+    overlaps, and under ``handoff`` the same for the hand-off tails of
+    the waits that carry one (names may add to more than ``us``: threads
+    run abreast); ``unnamed_us`` is the part under none of
+    :data:`HOLE_SPANS` and no hand-off, each microsecond counted once."""
+    holes: List[Tuple[int, int]] = []
+    at = lo
+    for s, e in leaves:
+        if s > at:
+            holes.append((at, s))
+        at = e
+    if hi > at:
+        holes.append((at, hi))
+    by_name: Dict[str, list] = {}
+    for s in spans:
+        name = s["name"]
+        if name not in LEAF_SPANS and name not in _LIFELONG_SPANS:
+            by_name.setdefault(name, []).append(_interval(s))
+            tail = (s.get("attrs") or {}).get("tail_us")
+            if tail:
+                end = s["ts_us"] + s["dur_us"]
+                by_name.setdefault("handoff", []).append(
+                    (end - min(tail, s["dur_us"]), end))
+    by = {}
+    naming = []
+    for name, group in by_name.items():
+        us = _overlap_us(holes, _merged(group, lo, hi))
+        if us:
+            by[name] = us
+        if name in HOLE_SPANS or name == "handoff":
+            naming.extend(group)
+    total = _length(holes)
+    return {"us": total, "by": by,
+            "unnamed_us": total - _overlap_us(holes,
+                                              _merged(naming, lo, hi))}
 
 
 def _phases(spans: List[dict], root_id: str) -> Dict[str, dict]:
     """Per span name: how many, the union of their intervals
     (``wall_us``, never more than the query's wall), their plain sum
-    (``sum_us``), and the ``bytes`` and ``rows`` attributes summed."""
+    (``sum_us``), the ``bytes`` and ``rows`` attributes summed, and the
+    thread-CPU time of the spans that carry one (``cpu_us``) beside the
+    ``dur_us`` of those same spans (``timed_us``): ``timed_us - cpu_us``
+    is how long the threads stood still inside spans of that name."""
     by_name: Dict[str, list] = {}
     for s in spans:
         if s["span_id"] != root_id:
@@ -373,11 +588,13 @@ def _phases(spans: List[dict], root_id: str) -> Dict[str, dict]:
     out = {}
     for name, group in by_name.items():
         attrs = [s.get("attrs") or {} for s in group]
+        timed = [s for s in group if "cpu_us" in s]
         out[name] = {
             "count": len(group),
-            "wall_us": _union_us([(s["ts_us"], s["ts_us"] + s["dur_us"])
-                                  for s in group]),
+            "wall_us": _union_us([_interval(s) for s in group]),
             "sum_us": sum(s["dur_us"] for s in group),
+            "cpu_us": sum(s["cpu_us"] for s in timed),
+            "timed_us": sum(s["dur_us"] for s in timed),
             "bytes": sum(int(a.get("bytes") or 0) for a in attrs),
             "rows": sum(int(a.get("rows", a.get("rows_in")) or 0)
                         for a in attrs)}
@@ -445,9 +662,60 @@ def attach(ctx: Optional[SpanContext]):
 
 def run_attached(ctx: Optional[SpanContext], fn, *args, **kwargs):
     """Run ``fn`` under ``ctx`` — the shape pool-submit sites use to
-    carry the submitting thread's span context onto a worker thread."""
-    with attach(ctx):
-        return fn(*args, **kwargs)
+    carry the submitting thread's span context onto a worker thread
+    (``ctx`` as is, or stamped by :func:`submitted`)."""
+    with attach(started(ctx)):
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            done(ctx)
+
+
+class _Submitted:
+    """A context on its way to a pool worker, stamped where the traced
+    submitting thread captured it; ``done_ns``: when the worker was done
+    with the submit (0 until then), for whoever waits for its result."""
+
+    __slots__ = ("ctx", "trace", "pool", "t_ns", "done_ns")
+
+    def __init__(self, ctx, trace: SpanContext, pool: str):
+        self.ctx = ctx
+        self.trace = trace
+        self.pool = pool
+        self.done_ns = 0
+        self.t_ns = time.perf_counter_ns()
+
+
+def submitted(ctx, pool: str):
+    """``ctx`` (a span context, or ``observability``'s attribution) as a
+    pool-submit site hands it to ``run_attached`` / ``run_attributed``:
+    itself when the submitting thread is untraced, else stamped with the
+    pool's name and the instant, so that the worker can say how long the
+    submit stood in the pool's queue (:func:`started`)."""
+    trace = current()
+    if trace is None:
+        return ctx
+    return _Submitted(ctx, trace, pool)
+
+
+def started(ctx):
+    """On the worker, before anything else: the context a submit site
+    passed, unwrapped; for a stamped one the time from submit to now is
+    a ``wait:pool`` span (``pool``: its name) and one hand-off."""
+    if type(ctx) is not _Submitted:
+        return ctx
+    now = time.perf_counter_ns()
+    rec = ctx.trace.recorder
+    rec.add_wait("wait:pool", ctx.trace.span_id, ctx.t_ns, now,
+                 {"pool": ctx.pool})
+    rec.handoff((now - ctx.t_ns) // 1000)
+    return ctx.ctx
+
+
+def done(ctx) -> None:
+    """On the worker, last of all: a stamped context learns when."""
+    if type(ctx) is _Submitted:
+        ctx.done_ns = time.perf_counter_ns()
 
 
 # ---------------------------------------------------------- live spans
@@ -463,6 +731,9 @@ class _NoopSpan:
         return False
 
     def set(self, key, value):
+        pass
+
+    def handed(self, ready_ns):
         pass
 
 
@@ -517,7 +788,7 @@ def _close_annotation(ann, attrs: Optional[dict], exc=(None, None, None)):
 
 class _LiveSpan:
     __slots__ = ("_ctx", "_name", "_key", "_attrs", "_lane", "_t0",
-                 "_id", "_prev", "_ann")
+                 "_cpu0", "_id", "_prev", "_ann")
 
     def __init__(self, ctx: SpanContext, name: str, key: Optional[str],
                  attrs: Optional[dict], lane: str):
@@ -536,20 +807,59 @@ class _LiveSpan:
         rec = self._ctx.recorder
         self._id = rec.unique_span_id(self._key)
         self._ann = _annotate(self._name) if rec.bridge else None
-        self._t0 = _now_us()
         self._prev = _set_current(SpanContext(rec, self._id))
+        # the CPU clock (:data:`CPU_SPANS` only) is read inside the wall
+        # clock's interval, at both ends: cpu_us <= dur_us but for the
+        # clocks' grain
+        self._t0 = time.perf_counter_ns()
+        self._cpu0 = time.thread_time_ns() \
+            if self._name in CPU_SPANS else None
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dur = _now_us() - self._t0
+        cpu = None if self._cpu0 is None else \
+            (time.thread_time_ns() - self._cpu0) // 1000
+        dur = time.perf_counter_ns() - self._t0
         _set_current(self._prev)
         if self._ann is not None:
             _close_annotation(self._ann, self._attrs, (exc_type, exc, tb))
-        self._ctx.recorder.add(
-            self._name, self._id, self._ctx.span_id, self._t0, dur,
-            attrs=self._attrs, lane=self._lane,
-            status="error" if exc_type is not None else "ok")
+        self._record(dur // 1000, cpu,
+                     "error" if exc_type is not None else "ok")
         return False
+
+    def _record(self, dur_us: int, cpu_us: Optional[int],
+                status: str) -> None:
+        rec = self._ctx.recorder
+        rec.add(self._name, self._id, self._ctx.span_id,
+                rec.wall_us(self._t0), dur_us, attrs=self._attrs,
+                lane=self._lane, status=status, cpu_us=cpu_us)
+
+
+class _LiveWait(_LiveSpan):
+    """A wait that is lived through (so it rides the ``daft:`` bridge
+    into a profile); under :data:`WAIT_FLOOR_US` it is counted, not
+    stored, as :meth:`SpanRecorder.add_wait` does."""
+
+    __slots__ = ()
+
+    def handed(self, ready_ns: int) -> None:
+        """What was waited for is here, and was ready at ``ready_ns``
+        (``time.perf_counter_ns()``; 0: not known, nothing is counted):
+        one hand-off, of the time since the later of that and the wait's
+        start, which is also the span's ``tail_us``."""
+        if not ready_ns:
+            return
+        us = (time.perf_counter_ns() - max(ready_ns, self._t0)) // 1000
+        self._ctx.recorder.handoff(us)
+        self.set("tail_us", us)
+
+    def _record(self, dur_us: int, cpu_us: Optional[int],
+                status: str) -> None:
+        rec = self._ctx.recorder
+        if dur_us < rec.wait_floor_us and status == "ok":
+            rec.wait_short(dur_us)
+        else:
+            super()._record(dur_us, cpu_us, status)
 
 
 def span(name: str, key: Optional[str] = None,
@@ -561,6 +871,39 @@ def span(name: str, key: Optional[str] = None,
     if ctx is None:
         return _NOOP
     return _LiveSpan(ctx, name, key, attrs, lane)
+
+
+def launch(program: str, chip: int = 0):
+    """``dispatch:launch``: the call of a jitted function and nothing
+    else, opened exactly where ``retrace_sanitizer.dispatch_scope``
+    brackets it (``program`` is the sanitizer's site id). No leaf: it
+    nests in its ``device:dispatch`` / ``join:device``, or stands alone
+    at ``device/runtime.py``'s sites. Its ``cpu_us`` is the client's
+    launch work in the calling thread; the rest of its wall the thread
+    spent off the CPU inside the call."""
+    ctx = current()
+    if ctx is None:
+        return _NOOP
+    return _LiveSpan(ctx, "dispatch:launch", None,
+                     {"program": program, "chip": chip}, "device")
+
+
+def wait(name: str):
+    """A live ``wait:*`` span around a blocking call (``wait:window``,
+    ``wait:result``); the no-op singleton when the thread is untraced."""
+    ctx = current()
+    if ctx is None:
+        return _NOOP
+    return _LiveWait(ctx, name, None, None, "wait")
+
+
+def note_wait(name: str, t0_ns: int, t1_ns: int,
+              attrs: Optional[dict] = None) -> None:
+    """A ``wait:*`` span between two ``time.perf_counter_ns()`` readings,
+    recorded once the wait is over (no-op when untraced)."""
+    ctx = current()
+    if ctx is not None:
+        ctx.recorder.add_wait(name, ctx.span_id, t0_ns, t1_ns, attrs)
 
 
 def event(name: str, key: Optional[str] = None,
@@ -576,7 +919,7 @@ def event(name: str, key: Optional[str] = None,
     if ann is not None:
         _close_annotation(ann, attrs)
     rec.add(name, rec.unique_span_id(key or name),
-            parent_id or ctx.span_id, _now_us(), 0, attrs=attrs,
+            parent_id or ctx.span_id, rec.now_us(), 0, attrs=attrs,
             lane=lane)
 
 
@@ -760,6 +1103,15 @@ _LANE_PRIORITY = ("driver", "serving", "planner", "pipeline", "scan",
                   "device", "dev:upload", "dev:compute", "dev:download")
 
 
+def _export_attrs(span: dict) -> dict:
+    """A span's attributes as the exports carry them: its own, and
+    ``cpu_us`` where it has one."""
+    attrs = dict(span.get("attrs") or {})
+    if "cpu_us" in span:
+        attrs["cpu_us"] = span["cpu_us"]
+    return attrs
+
+
 def chrome_trace_events(rec: SpanRecorder) -> List[dict]:
     """Perfetto-loadable event list: one ``X`` (complete) event per
     span on a per-lane tid, plus ``M`` thread-name metadata events.
@@ -780,8 +1132,7 @@ def chrome_trace_events(rec: SpanRecorder) -> List[dict]:
         for lane, tid in sorted(lanes.items(), key=lambda kv: kv[1])]
     for s in spans:
         args = {"span_id": s["span_id"], "parent_id": s["parent_id"]}
-        if s.get("attrs"):
-            args.update({k: v for k, v in s["attrs"].items()})
+        args.update(_export_attrs(s))
         if s.get("status", "ok") != "ok":
             args["status"] = s["status"]
         events.append({"name": s["name"], "ph": "X",
@@ -876,7 +1227,7 @@ def otlp_spans_payload(rec: SpanRecorder) -> dict:
         }
         if s["parent_id"] != s["span_id"]:
             out["parentSpanId"] = s["parent_id"]
-        for k, v in (s.get("attrs") or {}).items():
+        for k, v in _export_attrs(s).items():
             if isinstance(v, bool):
                 val = {"boolValue": v}
             elif isinstance(v, int):

@@ -799,3 +799,92 @@ def test_q1_over_16_files_decodes_a_batch_a_window(monkeypatch, tmp_path,
     assert len(yielded) == windows and sum(yielded) == 16 * 6
     decode = summary["phases"]["device:decode"]
     assert decode["count"] == windows
+
+
+# ------------------------------------------ waits as spans (PR 43)
+
+def _traced():
+    rec = tracing.SpanRecorder("d" * 32)
+    return rec, tracing.SpanContext(rec, rec.root_id)
+
+
+def test_a_window_gate_held_shut_is_a_wait_window_span():
+    """A slot beyond the window waits at the gate until the consumer
+    drains the head: at least the 50 ms the head is held here."""
+    import threading
+    import time
+    rec, ctx = _traced()
+    gate = dpipe.WindowGate(1)
+    head = dpipe.acquire_slot(gate, 0)       # untraced: the window is full
+    got = []
+
+    def second():
+        with tracing.attach(ctx):
+            got.append(dpipe.acquire_slot(gate, 1,
+                                          MemoryManager(budget=1 << 20), 64))
+
+    t = threading.Thread(target=second)
+    t.start()
+    time.sleep(0.05)
+    dpipe.release_slot(head)
+    gate.note_drained(0)
+    t.join(5)
+    assert got and got[0].nbytes == 64
+    (w,) = [s for s in rec.spans() if s["name"] == "wait:window"]
+    assert w["dur_us"] >= 50_000 and w["lane"] == "wait"
+    assert w["attrs"] == {"seq": 1, "admitted": True}
+    assert "cpu_us" not in w        # a wait reads no CPU clock (CPU_SPANS)
+    dpipe.release_slot(got[0])
+    # the head's own, untraced, left nothing; a wait under the floor is
+    # counted, not stored
+    with tracing.attach(ctx):
+        dpipe.release_slot(dpipe.acquire_slot(gate, 2))
+    assert len([s for s in rec.spans() if s["name"] == "wait:window"]) == 1
+    assert rec._tallies["waits_short"] == 1
+
+
+def test_the_consumers_wait_on_the_head_is_a_wait_result_span():
+    """``run_pipelined``'s consumer blocks in ``fut.result()`` while the
+    pool runs the head submit (30 ms here); the hand-off is from the
+    worker's last act to the consumer running."""
+    import time
+    rec, ctx = _traced()
+
+    def submit(item, seq, gate):
+        time.sleep(0.03)
+        return item                       # host-routed: holds no slot
+
+    with tracing.attach(ctx):
+        out = list(dpipe.run_pipelined(range(3), submit,
+                                       lambda ret, seq: ret, window=2))
+    assert out == [0, 1, 2]
+    waits = [s for s in rec.spans() if s["name"] == "wait:result"]
+    assert waits and waits[0]["attrs"]["seq"] == 0
+    assert waits[0]["dur_us"] >= 25_000
+    # the tail is what came after the worker was done, not its 30 ms
+    assert 0 <= waits[0]["attrs"]["tail_us"] <= waits[0]["dur_us"] - 20_000
+    assert [s["attrs"]["pool"] for s in rec.spans()
+            if s["name"] == "wait:pool"] in ([], ["devpipe"] * 1,
+                                             ["devpipe"] * 2,
+                                             ["devpipe"] * 3)
+    rec.finish()
+    # three submits started on the pool + three results handed over
+    assert rec.summary()["handoffs"]["count"] == 6
+
+
+def test_untraced_pipeline_makes_no_stamp_and_no_span(monkeypatch):
+    import time
+    monkeypatch.setattr(time, "thread_time_ns", lambda: 1 / 0)
+    tracing.reset_for_tests()
+    seen = []
+
+    def submit(item, seq, gate):
+        seen.append(obs.current_attribution())
+        slot = dpipe.acquire_slot(gate, seq)
+        return dpipe.InflightItem(slot, item)
+
+    assert tracing.current() is None
+    out = list(dpipe.run_pipelined(range(4), submit,
+                                   lambda ret, seq: ret.token, window=2))
+    assert out == [0, 1, 2, 3] and seen == [None] * 4
+    assert tracing.finished() == []

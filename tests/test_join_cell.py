@@ -128,7 +128,12 @@ def test_device_join_span_is_a_leaf_and_holds_its_fetch(runs):
     _, summary, spans, _ = runs["q3", "forced-on"]
     device = [s for s in spans if s["name"] == "join:device"]
     ids = {s["span_id"] for s in device}
-    assert ids and not [s for s in spans if s.get("parent_id") in ids]
+    # no leaf nests in it (its fetch is its own); the launch of its one
+    # program does, and is no leaf
+    inside = [s for s in spans if s.get("parent_id") in ids]
+    assert ids and {s["name"] for s in inside} == {"dispatch:launch"}
+    assert len(inside) >= len(device)
+    assert {s["attrs"]["program"] for s in inside} == {"kernels.join_fused"}
     p = summary["phases"]["join:device"]
     assert p["count"] == len(device) and p["bytes"] > 0
     assert p["rows"] == summary["joins"]["rows_device"]

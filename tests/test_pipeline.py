@@ -491,3 +491,119 @@ def test_interp_executor_parity(case, shapes_df):
             "ORDER BY s", t=df),
     }
     _interp_and_push(builds[case])
+
+
+# ------------------------------------------ waits on a channel (PR 43)
+
+def _traced_channel(capacity=4):
+    from daft_tpu import tracing
+    from daft_tpu.execution.pipeline import Channel, PipelineContext
+    rec = tracing.SpanRecorder("c" * 32)
+    return (rec, tracing.SpanContext(rec, rec.root_id),
+            Channel(PipelineContext(), capacity=capacity))
+
+
+def test_a_consumer_that_waits_for_its_item_tallies_a_handoff():
+    from daft_tpu import tracing
+    rec, ctx, ch = _traced_channel()
+    item = object()
+
+    def produce():
+        with tracing.attach(ctx):
+            time.sleep(0.05)      # the taker is waiting by now: 30 ms at least
+            ch.put(item)
+            ch.close()
+
+    t = threading.Thread(target=produce)
+    with tracing.attach(ctx):
+        t.start()
+        got = list(ch)
+    t.join(5)
+    assert got == [item] and got[0] is item     # unwrapped again
+    waits = [s for s in rec.spans() if s["name"] == "wait:channel"]
+    assert waits[0]["attrs"]["side"] == "get"
+    assert waits[0]["dur_us"] >= 30_000 and "cpu_us" not in waits[0]
+    # the item came after the taker began to wait: the hand-off is the
+    # sliver from the put to the taker running, not the 30 ms before it
+    assert 0 <= waits[0]["attrs"]["tail_us"] <= waits[0]["dur_us"] - 29_000
+    rec.finish()
+    h = rec.summary()["handoffs"]
+    assert h["count"] == 2                      # the item and the end marker
+    assert waits[0]["attrs"]["tail_us"] <= h["max_us"] <= h["us"]
+
+
+def test_a_put_on_a_full_channel_is_a_wait_span():
+    from daft_tpu import tracing
+    rec, ctx, ch = _traced_channel(capacity=1)
+    got = []
+
+    def consume():
+        with tracing.attach(ctx):
+            time.sleep(0.03)
+            got.extend(ch)
+
+    t = threading.Thread(target=consume)
+    with tracing.attach(ctx):
+        ch.put("a")             # fits: no wait
+        t.start()
+        ch.put("b")             # the queue is full until the consumer comes
+        ch.close()
+    t.join(5)
+    assert got == ["a", "b"]
+    puts = [s for s in rec.spans() if s["name"] == "wait:channel"
+            and s["attrs"]["side"] == "put"]
+    assert len(puts) >= 1 and puts[0]["dur_us"] >= 25_000
+    assert "tail_us" not in puts[0]["attrs"]
+    rec.finish()
+    s = rec.summary()
+    # "a" lay in the queue when its taker came: handed over at once
+    assert s["handoffs"]["count"] == 3
+    assert s["holes"]["by"]["wait:channel"] > 0
+
+
+def test_untraced_a_channel_hands_over_the_very_object(monkeypatch):
+    from daft_tpu import tracing
+    from daft_tpu.execution.pipeline import Channel, PipelineContext
+    monkeypatch.setattr(time, "thread_time_ns", lambda: 1 / 0)
+    monkeypatch.setattr(time, "perf_counter_ns", lambda: 1 / 0)
+    assert tracing.current() is None
+    ch = Channel(PipelineContext(), capacity=2)
+    item = object()
+    ch.put(item)
+    assert ch._q.queue[0] is item               # no stamp, no wrapper
+    ch.close()
+    assert [x is item for x in ch] == [True]
+
+
+@pytest.mark.parametrize("query", ["agg", "join"])
+def test_a_traced_query_names_its_holes(many_files, monkeypatch, query):
+    pattern, n = many_files
+
+    def build():
+        df = dt.read_parquet(pattern)
+        if query == "agg":
+            return df.where(col("v") > 10).groupby("g").agg(
+                col("v").sum().alias("s"))
+        small = dt.from_pydict({"g": list(range(7)),
+                                "w": [float(i) for i in range(7)]})
+        return df.join(small, on="g").groupby("g").agg(
+            (col("v") * col("w")).sum().alias("s"))
+
+    out, spans = _traced_spans(monkeypatch, build)
+    assert len(out["g"]) == 7
+    from daft_tpu import tracing
+    s = tracing.finished()[-1]
+    assert s["dropped"] == 0
+    assert s["handoffs"]["count"] > 0
+    assert s["handoffs"]["us"] >= s["handoffs"]["max_us"]
+    holes = s["holes"]
+    assert holes["us"] == s["wall_us"] - s["covered_us"]
+    assert 0 <= holes["unnamed_us"] <= holes["us"]
+    assert all(0 < v <= holes["us"] for v in holes["by"].values())
+    for sp in spans:
+        if "cpu_us" in sp:
+            assert sp["cpu_us"] <= sp["dur_us"] + 50, sp
+        if sp["name"].startswith("wait:"):
+            assert sp["dur_us"] >= tracing.WAIT_FLOOR_US
+    # every live span of the host operators says how much of it was work
+    assert any("cpu_us" in sp for sp in spans if sp["name"] == "scan:load")

@@ -973,3 +973,359 @@ def test_cache_counts_and_table_tally(cold_then_warm, phase, source):
     assert {c["chip"]: c["resident_bytes"] for c in chips
             if c["resident_bytes"]} == \
         {k: v["bytes"] for k, v in got["chips"].items() if v["bytes"]}
+
+
+# ------------------------------------------ work apart from wait (PR 43)
+
+def _recorder(**kw):
+    rec = tracing.SpanRecorder("w" * 32, **kw)
+    return rec, tracing.SpanContext(rec, rec.root_id)
+
+
+def _busy(seconds):
+    end = time.perf_counter() + seconds
+    x = 0
+    while time.perf_counter() < end:
+        x += 1
+    return x
+
+
+@pytest.mark.parametrize("body,lo,hi", [
+    (lambda: _busy(0.08), 0.8, 1.0),
+    (lambda: time.sleep(0.08), 0.0, 0.1),
+], ids=["busy-loop", "sleep"])
+def test_live_span_reads_thread_cpu_time(body, lo, hi):
+    # a busy loop on a loaded host may lose its core: the best of a few
+    for _ in range(5):
+        rec, ctx = _recorder()
+        with tracing.attach(ctx):
+            with tracing.span("expr:eval"):
+                body()
+        (s,) = rec.spans()
+        assert s["dur_us"] >= 80_000
+        assert s["cpu_us"] <= s["dur_us"] + 50
+        if lo * s["dur_us"] <= s["cpu_us"] <= hi * s["dur_us"] + 50:
+            return
+    raise AssertionError(f"cpu_us {s['cpu_us']} of dur_us {s['dur_us']}")
+
+
+def test_explicit_and_remote_spans_carry_no_cpu_time():
+    rec, ctx = _recorder()
+    with tracing.attach(ctx):
+        with tracing.span("agg:host"):
+            _busy(0.01)
+        tracing.note_wait("wait:channel", 0, 5_000_000, {"side": "get"})
+    rec.add("agg:host", "e" * 16, None, rec.now_us(), 700)
+    rec.add_remote([{"name": "agg:host", "span_id": "r" * 16,
+                     "ts_us": rec.now_us(), "dur_us": 900, "cpu_us": 900}],
+                   offset_us=0, worker="w0")
+    spans = {s["span_id"]: s for s in rec.spans()}
+    assert "cpu_us" not in spans["e" * 16]
+    assert "cpu_us" not in spans["r" * 16]          # absent, not 0
+    live = [s for s in spans.values() if "cpu_us" in s]
+    assert [s["name"] for s in live] == ["agg:host"]
+    rec.finish()
+    phase = rec.summary()["phases"]["agg:host"]
+    # timed_us is the duration of the spans that carry a CPU time only
+    assert phase["count"] == 3
+    assert phase["timed_us"] == live[0]["dur_us"]
+    assert phase["cpu_us"] == live[0]["cpu_us"]
+    assert phase["sum_us"] == live[0]["dur_us"] + 700 + 900
+    wait = rec.summary()["phases"]["wait:channel"]
+    assert (wait["timed_us"], wait["cpu_us"], wait["sum_us"]) == (0, 0, 5000)
+
+
+def test_only_the_leaves_and_the_launch_read_the_cpu_clock(monkeypatch):
+    """A read of the thread-CPU clock costs ~6 us on the machines with
+    the chips, so the stage, submit, drain and wait spans, whose CPU time
+    no metric reads, do not pay it (``tracing.CPU_SPANS``)."""
+    reads = []
+    real = time.thread_time_ns
+    monkeypatch.setattr(time, "thread_time_ns",
+                        lambda: reads.append(1) or real())
+    rec, ctx = _recorder()
+    with tracing.attach(ctx):
+        for name in ("pipeline:stage", "device:submit", "device:drain",
+                     "serve:run"):
+            with tracing.span(name):
+                pass
+        with tracing.wait("wait:window"):
+            time.sleep(0.001)
+        assert reads == []
+        with tracing.span("device:dispatch"):
+            with tracing.launch("fragment.packed"):
+                pass
+    assert len(reads) == 4
+    assert tracing.CPU_SPANS == tracing.LEAF_SPANS | {"dispatch:launch"}
+    assert {s["name"] for s in rec.spans() if "cpu_us" in s} == \
+        {"device:dispatch", "dispatch:launch"}
+
+
+def test_durations_come_from_the_monotonic_clock(monkeypatch):
+    """A host that steps its wall clock mid-span moves no duration."""
+    rec, ctx = _recorder()
+    real = time.time
+    with tracing.attach(ctx):
+        with tracing.span("expr:eval"):
+            monkeypatch.setattr(time, "time", lambda: real() - 3600.0)
+            time.sleep(0.01)
+    monkeypatch.setattr(time, "time", real)
+    rec.finish()
+    (s, root) = rec.spans()
+    assert 10_000 <= s["dur_us"] < 1_000_000
+    assert 0 <= s["ts_us"] - root["ts_us"] < 1_000_000
+    assert root["dur_us"] < 1_000_000
+
+
+def _fixed_recorder():
+    """Spans at hand-picked microseconds after the root's start."""
+    rec, _ = _recorder()
+    t0 = rec._root_t0
+    for i, (name, at, dur, attrs) in enumerate([
+            ("plan:optimize", 0, 100, None),
+            ("device:dispatch", 300, 100, None),
+            ("device:fetch", 600, 200, None),
+            ("device:submit", 250, 200, None),         # hole 250-300, 400-450
+            ("wait:pool", 120, 100, {"pool": "devpipe"}),   # hole 120-220
+            ("wait:result", 100, 480, {"tail_us": 30}),  # its tail: 550-580
+            ("wait:channel", 0, 1000, {"side": "get", "tail_us": 50}),
+            ("pipeline:stage", 0, 1000, None),
+            ("op:Sort", 0, 900, None)]):
+        rec.add(name, f"{i:016x}", None, t0 + at, dur, attrs=attrs)
+    return rec
+
+
+def _finish_at(rec, wall_us):
+    rec._root_perf_ns = time.perf_counter_ns() - wall_us * 1000
+    rec.finish()
+    return rec.summary()
+
+
+def test_holes_are_named_by_what_lay_over_them():
+    s = _finish_at(_fixed_recorder(), 1000)
+    assert 1000 <= s["wall_us"] < 1100
+    extra = s["wall_us"] - 1000          # the root closed a little later
+    # leaves cover 0-100, 300-400, 600-800
+    assert s["covered_us"] == 400
+    holes = s["holes"]
+    assert holes["us"] == 600 + extra == s["wall_us"] - s["covered_us"]
+    by = holes["by"]
+    assert by["device:submit"] == 100
+    assert by["wait:pool"] == 100
+    assert by["wait:result"] == 480 - 100    # 100-300, 400-580
+    assert by["wait:channel"] == 600         # all of them, and says nothing
+    assert by["handoff"] == 30 + 50          # 550-580 and 950-1000
+    assert by["op:Sort"] == 500
+    assert "pipeline:stage" not in by and "query" not in by
+    assert "plan:optimize" not in by
+    # named: 120-220 (pool), 250-300 + 400-450 (submit), the two tails
+    assert holes["unnamed_us"] == holes["us"] - (100 + 100 + 30 + 50)
+
+
+def test_launch_is_no_leaf():
+    """``covered_us`` of a fixed recorder is the same to the microsecond
+    with ``dispatch:launch`` spans laid into and beside its leaves."""
+    before = _finish_at(_fixed_recorder(), 1000)
+    rec = _fixed_recorder()
+    t0 = rec._root_t0
+    rec.add("dispatch:launch", "a" * 16, None, t0 + 320, 60)   # nested
+    rec.add("dispatch:launch", "b" * 16, None, t0 + 820, 100)  # alone
+    after = _finish_at(rec, 1000)
+    assert after["covered_us"] == before["covered_us"] == 400
+    assert "dispatch:launch" not in tracing.LEAF_SPANS
+    assert not [n for n in tracing.LEAF_SPANS if n.startswith("wait:")]
+    assert after["holes"]["by"]["dispatch:launch"] == 100
+    assert after["holes"]["unnamed_us"] == \
+        before["holes"]["unnamed_us"] - 100 + \
+        (after["wall_us"] - before["wall_us"])
+    assert tracing.COMPUTE_SPANS < tracing.LEAF_SPANS
+
+
+def _launches(run):
+    run()
+    spans = obs.last_query_stats().trace_ctx.recorder.spans()
+    by_id = {s["span_id"]: s for s in spans}
+    found = set()
+    for s in spans:
+        if s["name"] == "dispatch:launch":
+            parent = by_id[s["parent_id"]]
+            assert "cpu_us" in s and s["lane"] == "device"
+            if parent["name"] != "query":
+                assert parent["ts_us"] <= s["ts_us"]
+                assert s["ts_us"] + s["dur_us"] <= \
+                    parent["ts_us"] + parent["dur_us"]
+            found.add((s["attrs"]["program"], parent["name"]))
+    return found
+
+
+def _fusion_queries():
+    import numpy as np
+    rng = np.random.default_rng(7)
+    n = 4000
+    df = daft.from_pydict({
+        "a": rng.integers(0, 100, n).astype(np.int64),
+        "b": rng.normal(size=n),
+        "k": rng.integers(0, 50, n).astype(np.int64)})
+    build = daft.from_pydict({
+        "k2": np.arange(0, 40, dtype=np.int64), "w": rng.normal(size=40),
+        "g": (np.arange(40, dtype=np.int64) % 5)})
+    return {
+        "fragment.packed": lambda: df.groupby("k").agg(
+            col("b").sum().alias("s")).to_pydict(),
+        "region.chain": lambda: df.where(col("a") > 30).select(
+            (col("b") * 2.0).alias("b2"), col("a")).to_pydict(),
+        "region.topk": lambda: df.where(col("a") > 10).select(
+            col("a"), col("b")).sort(col("b"), desc=True).limit(9)
+        .to_pydict(),
+        "region.join_agg": lambda: df.where(col("a") > 20).join(
+            build, left_on=col("k"), right_on=col("k2"), how="inner")
+        .groupby(col("g")).agg((col("b") * col("w")).sum().alias("rev"))
+        .to_pydict(),
+        "kernels.argsort": lambda: df.sort(col("b")).to_pydict(),
+    }
+
+
+@pytest.mark.parametrize("site,parent,fusion", [
+    ("fragment.packed", "device:dispatch", "0"),
+    ("region.chain", "device:dispatch", "1"),
+    ("region.topk", "device:dispatch", "1"),
+    ("region.join_agg", "device:dispatch", "1"),
+    # device/runtime.py's four sites open no dispatch leaf of their own:
+    # the launch stands under whatever called it
+    ("compiler.projection", "expr:eval", "0"),
+    ("kernels.argsort", "sort:topn", "0"),
+    ("kernels.grouped_agg", "pipeline:stage", "0"),
+])
+def test_launch_span_sits_where_the_jitted_call_is(monkeypatch, site,
+                                                   parent, fusion):
+    monkeypatch.setenv("DAFT_TPU_TRACE", "1")
+    monkeypatch.setenv("DAFT_TPU_DEVICE", "1")
+    monkeypatch.setenv("DAFT_TPU_DEVICE_FORCE", "1")
+    monkeypatch.setenv("DAFT_TPU_FUSION", fusion)
+    queries = _fusion_queries()
+    run = queries.get(site) or queries["fragment.packed"]
+    assert (site, parent) in _launches(run)
+
+
+def test_launch_span_nests_in_the_device_join(monkeypatch):
+    import numpy as np
+    from daft_tpu.joins import _device_match_indices
+    monkeypatch.setenv("DAFT_TPU_DEVICE", "1")
+    rng = np.random.default_rng(3)
+    rec, ctx = _recorder()
+    with tracing.attach(ctx):
+        out = _device_match_indices(
+            rng.integers(0, 50, 400), rng.integers(0, 50, 150),
+            np.ones(400, bool), np.ones(150, bool))
+    assert out is not None
+    spans = {s["name"]: s for s in rec.spans()}
+    launch, join = spans["dispatch:launch"], spans["join:device"]
+    assert launch["parent_id"] == join["span_id"]
+    assert launch["attrs"]["program"] == "kernels.join_fused"
+    assert join["ts_us"] <= launch["ts_us"] and \
+        launch["dur_us"] <= join["dur_us"]
+
+
+def test_short_waits_are_counted_not_stored():
+    rec, ctx = _recorder(max_spans=64)
+    t = time.perf_counter_ns()
+    with tracing.attach(ctx):
+        for i in range(10_000):
+            tracing.note_wait("wait:channel", t, t + 20_000)   # 20 us
+        for _ in range(3):
+            with tracing.wait("wait:result"):
+                pass
+        tracing.note_wait("wait:channel", t,
+                          t + tracing.WAIT_FLOOR_US * 1000, {"side": "put"})
+    rec.finish()
+    s = rec.summary()
+    assert s["dropped"] == 0 == rec.dropped
+    assert s["waits_short"]["count"] == 10_003
+    assert s["waits_short"]["us"] >= 10_000 * 20
+    assert s["phases"]["wait:channel"]["count"] == 1   # the one at the floor
+    assert "wait:result" not in s["phases"]
+    assert s["spans"] == 2
+
+
+def test_a_chaos_replay_counts_its_waits_and_stores_none(monkeypatch):
+    monkeypatch.setenv("DAFT_TPU_CHAOS_SERIALIZE", "1")
+    rec, ctx = _recorder()
+    with tracing.attach(ctx):
+        tracing.note_wait("wait:pool", 0, 50_000_000)
+    assert rec.spans() == []
+    assert rec.summary()["spans"] == 0
+    assert rec._tallies["waits_short"] == 1
+
+
+def test_wait_pool_from_a_pool_of_one_busy_worker():
+    import concurrent.futures as cf
+    rec, ctx = _recorder()
+    release = threading.Event()
+    with cf.ThreadPoolExecutor(max_workers=1) as pool:
+        with tracing.attach(ctx):
+            first = pool.submit(tracing.run_attached,
+                                tracing.submitted(ctx, "one"),
+                                release.wait, 5)
+            second = pool.submit(tracing.run_attached,
+                                 tracing.submitted(ctx, "one"),
+                                 tracing.current)
+        time.sleep(0.06)
+        release.set()
+        assert first.result() is True
+        # the worker ran under the submitter's context, unwrapped
+        assert second.result().recorder is rec
+    # the second submit stood in the queue behind the first (whose own
+    # wait, a thread's start, is stored only if it passed the floor)
+    waits = sorted((s for s in rec.spans() if s["name"] == "wait:pool"),
+                   key=lambda s: s["dur_us"])
+    assert 1 <= len(waits) <= 2 and waits[-1]["dur_us"] >= 60_000
+    assert waits[-1]["attrs"] == {"pool": "one"}
+    assert "cpu_us" not in waits[-1]
+    rec.finish()
+    h = rec.summary()["handoffs"]
+    assert h["count"] == 2 and h["max_us"] >= 60_000 and h["us"] >= h["max_us"]
+    # the same through observability's shape, untraced: the context goes
+    # through as it is
+    assert obs.submit_attribution("one") is obs.current_attribution()
+    assert tracing.submitted(None, "one") is None
+
+
+def test_exports_carry_cpu_time():
+    rec, ctx = _recorder()
+    with tracing.attach(ctx):
+        with tracing.span("expr:eval", attrs={"rows": 3}):
+            pass
+    rec.add("device:inflight", "f" * 16, None, rec.now_us(), 5)
+    events = {e["name"]: e for e in tracing.chrome_trace_events(rec)
+              if e["ph"] == "X"}
+    assert events["expr:eval"]["args"]["rows"] == 3
+    assert "cpu_us" in events["expr:eval"]["args"]
+    assert "cpu_us" not in events["device:inflight"]["args"]
+    otlp = tracing.otlp_spans_payload(rec)
+    spans = {s["name"]: {a["key"] for a in s["attributes"]}
+             for s in otlp["resourceSpans"][0]["scopeSpans"][0]["spans"]}
+    assert "cpu_us" in spans["expr:eval"]
+    assert "cpu_us" not in spans["device:inflight"]
+
+
+def test_untraced_nothing_reads_the_cpu_clock(monkeypatch):
+    """Off means off: no thread-CPU read, no stamp, no wrapper."""
+    def boom():
+        raise AssertionError("thread_time_ns read on an untraced thread")
+    monkeypatch.setattr(time, "thread_time_ns", boom)
+    assert tracing.current() is None
+    assert tracing.span("expr:eval") is tracing._NOOP
+    assert tracing.launch("fragment.packed") is tracing._NOOP
+    assert tracing.wait("wait:window") is tracing._NOOP
+    tracing.note_wait("wait:channel", 0, 10 ** 9)
+    marker = object()
+    assert tracing.submitted(marker, "p") is marker
+    assert tracing.started(marker) is marker
+    assert obs.run_attributed(None, lambda x: x, marker) is marker
+    assert tracing.run_attached(None, lambda x: x, marker) is marker
+    out = (daft.from_pydict({"x": list(range(100)),
+                             "g": [i % 3 for i in range(100)]})
+           .where(col("x") > 10).groupby("g").agg(col("x").sum())
+           .to_pydict())
+    assert len(out["g"]) == 3
+    assert tracing.finished() == []
