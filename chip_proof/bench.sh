@@ -17,7 +17,7 @@ while read -r label side wl seed trace rest; do
   for w in $rest; do case "$w" in *=*) envs="$envs $w";; *) args="$args $w";; esac; done
   t0=$(date +%s)
   left=$((BUDGET - (t0 - START)))
-  if [ $left -lt 150 ]; then echo "== $label SKIPPED: $left s left"; continue; fi
+  if [ $left -lt ${MIN_LEFT:-150} ]; then echo "== $label SKIPPED: $left s left"; continue; fi
   [ $left -gt 1500 ] && left=1500
   (cd chip_proof/$side && env $envs timeout -k 10 $left python3 ../cell.py $label --workload $wl --seed $seed --seconds 40 --trace $trace $args) > chiprun_out/$label.out 2> chiprun_out/$label.err
   rc=$?

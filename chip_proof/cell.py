@@ -35,8 +35,16 @@ def main():
             try:
                 sys.path.insert(0, "/root/repo/chip_proof")
                 import launch_split
-                rec["launch_split"] = launch_split.split(path)
+                import plane_split
                 rec["xplane_MB"] = os.path.getsize(path) / 1e6
+                rec["plane_split"] = plane_split.split(path)
+                rec["launch_split"] = launch_split.split(path)
+                if rec["xplane_MB"] < 200:
+                    import gzip
+                    import shutil
+                    with open(path, "rb") as src, gzip.open(os.path.join(
+                            out_dir, label + ".xplane.pb.gz"), "wb") as dst:
+                        shutil.copyfileobj(src, dst)
             except BaseException as e:
                 rec["launch_split_error"] = repr(e)
         ts = real_reduce(path, *a, **kw)
@@ -79,7 +87,7 @@ def main():
         rec["dropped_max"] = max((s.get("dropped", 0) for s in fin), default=None)
         rec["spans_max"] = max((s.get("spans", 0) for s in fin), default=None)
         for s in fin[-12:]:
-            keep.append({k: s.get(k) for k in ("wall_us", "covered_us", "tables", "selects", "joins", "decode", "chips", "t0_perf_s",
+            keep.append({k: s.get(k) for k in ("wall_us", "covered_us", "tables", "selects", "agg_launches", "joins", "decode", "chips", "t0_perf_s",
                                                "spans", "dropped", "holes", "handoffs", "waits_short")}
                         | {"phases": {n: {k: p.get(k) for k in ("count", "wall_us", "sum_us", "bytes", "cpu_us", "timed_us")}
                                       for n, p in (s.get("phases") or {}).items()}})

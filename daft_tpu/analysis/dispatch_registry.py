@@ -87,6 +87,12 @@ SITES: Tuple[DispatchSite, ...] = (
        "(program, capacity class, out_cap bucket, strategy, "
        "scalar-plane shapes, the table's chip)",
        "donating twin of fragment.packed; same signature contract"),
+    _s("fragment.round", "daft_tpu/device/fragment.py",
+       ("round_fn",),
+       "(program, capacity class, out_cap bucket, strategy, dims, "
+       "scalar-plane shapes, the chips of the round)",
+       "SPMD twin of fragment.packed over the data mesh, a table a chip: "
+       "one trace per (schema, size-class, strategy, chips)"),
     _s("region.chain", "daft_tpu/device/fragment.py",
        ("get_fused_region",),
        "(program, capacity class, out-width bucket, scalar-plane shapes)",

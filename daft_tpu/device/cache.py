@@ -120,6 +120,16 @@ class DeviceColumnCache:
         # these): whole-table lookups served / not, bytes put
         self._hits = self._misses = 0
         self._put_bytes = 0
+        #: the assembled inputs of the fused aggregate's round launches
+        #: (``fragment._dispatch_round``), kept beside the tables they
+        #: are made of: ids of the member planes -> (the planes, what
+        #: was built over them). An entry holds its planes, so no id is
+        #: reused while it lives; a global array holds its shards'
+        #: buffers, so every entry goes when ANY plane leaves the cache
+        #: (eviction, a table put again or on another chip, ``clear``):
+        #: a round is cheap to assemble again, and HBM a dropped table
+        #: held is not kept
+        self._rounds: Dict[Tuple, Tuple] = {}
 
     # ------------------------------------------------------------------
     def stats(self) -> Dict:
@@ -133,7 +143,8 @@ class DeviceColumnCache:
             out = {key: sum(c[key] for c in chips.values())
                    for key in ("entries", "bytes", "evicted_bytes")}
             out.update(hits=self._hits, misses=self._misses,
-                       put_bytes=self._put_bytes, chips=chips)
+                       put_bytes=self._put_bytes, chips=chips,
+                       rounds=len(self._rounds))
             return out
 
     def clear(self) -> None:
@@ -143,6 +154,7 @@ class DeviceColumnCache:
                 c.cols.clear()
                 c.masks.clear()
                 c.bytes = 0
+            self._rounds.clear()
 
     def _find_locked(self, fp: Tuple):
         """(the chip's share that holds this task, its mask entry), or
@@ -182,6 +194,23 @@ class DeviceColumnCache:
                 {n: e.col for n, e in zip(cols, entries)}, row_mask, rows,
                 cap, resident=True, chip=chip)
 
+    def round_inputs(self, planes: Tuple, build):
+        """``build()`` over ``planes`` (the planes of a round's resident
+        tables, each the cache's own, in a fixed order), assembled once
+        and kept until a plane leaves the cache. Two threads that miss
+        together both build; the later entry stands. A table evicted
+        between its lookup and its launch leaves an entry behind that
+        the next eviction drops."""
+        key = tuple(map(id, planes))
+        with self._lock:
+            hit = self._rounds.get(key)
+        if hit is not None:
+            return hit[1], True
+        built = build()
+        with self._lock:
+            self._rounds[key] = (planes, built)
+        return built, False
+
     def put_table(self, fp: Tuple, dt: dcol.DeviceTable) -> None:
         from .. import tracing
         add = 0
@@ -203,6 +232,10 @@ class DeviceColumnCache:
             dt.resident = True
             with self._lock:
                 for k, other in self._chips.items():
+                    if fp in other.masks:
+                        # a table put again: its mask plane is replaced,
+                        # its columns may be, wherever it lay
+                        self._rounds.clear()
                     if k != at:     # one table, one chip
                         other.drop(fp)
                 c = self._chips.setdefault(at, _Chip())
@@ -224,6 +257,7 @@ class DeviceColumnCache:
             _, e = c.cols.popitem(last=False)
             c.bytes -= e.nbytes
             c.evicted_bytes += e.nbytes
+            self._rounds.clear()
         live_fps = {k[0] for k in c.cols}
         for fp in [f for f in c.masks if f not in live_fps]:
             del c.masks[fp]

@@ -174,6 +174,37 @@ class FusedAggProgram:
         #: passthrough (dictionary-coded plane) — dense-strategy
         #: eligibility; None otherwise
         self.key_sources = None
+        #: (mesh, out_cap, strategy, dims) -> the round's program
+        self._round_fns: Dict[Tuple, object] = {}
+
+    def round_fn(self, mesh, out_cap: int, strategy: str,
+                 dims: Tuple[int, ...]):
+        """The SPMD twin of :attr:`packed_fn` over the 1-D ``data`` mesh:
+        every shard runs ``run_packed`` on its own chip's planes (no
+        collective: each chip's partials stay apart for the host's
+        float64 merge) and the output is the chips' packed blocks one
+        after another along axis 0. Its inputs are global arrays whose
+        shards are a round's tables, one a chip (:func:`_dispatch_round`);
+        a runtime scalar comes with a leading axis of one a chip. The
+        statics are closed over, so one executable a (mesh, out_cap,
+        strategy, dims), as ``packed_fn`` has one a chip; the jitted
+        function is still called ``run_packed``, which is the name the
+        device trace shows its module under."""
+        key = (mesh, out_cap, strategy, dims)
+        fn = self._round_fns.get(key)
+        if fn is None:
+            from jax.sharding import PartitionSpec as P
+            base = self._run_packed
+
+            def run_packed(arrays, valids, row_mask, scalars):
+                return base(arrays, valids, row_mask,
+                            tuple(s[0] for s in scalars), out_cap=out_cap,
+                            strategy=strategy, dims=dims)
+
+            fn = self._round_fns[key] = jax.jit(jax.shard_map(
+                run_packed, mesh=mesh, in_specs=P("data"),
+                out_specs=P("data"), check_vma=False))
+        return fn
 
     def donate_fn(self):
         """The donating twin executable (round 12 megakernel discipline):
@@ -349,6 +380,118 @@ def _dispatch_packed(prog: FusedAggProgram, dt: dcol.DeviceTable,
                       out_cap=out_cap, strategy=strategy, dims=dims)
 
 
+def _round_key(dt: dcol.DeviceTable, how, scalars) -> Tuple:
+    """What the packed program is traced for over ``dt`` at ``how`` =
+    ``(out_cap, strategy, dims)`` with the runtime ``scalars``, but the
+    table's chip: the tables of a round must agree in it to be the
+    shards of one program."""
+    return (dt.capacity, how,
+            tuple((n, c.data.dtype, c.data.shape, c.validity.dtype)
+                  for n, c in dt.columns.items()),
+            dt.row_mask.dtype,
+            tuple((x.shape, x.dtype) for x in scalars))
+
+
+def _launches(prog: "FusedAggProgram", tables, hows):
+    """The window's launches where its tables lie on several chips, each
+    the places in ``tables`` it answers, and every table's runtime
+    scalars on the host (``runtime._scalar_planes``, made once here): the
+    tables are taken chip by chip in task order and the j-th of every
+    chip form a *round*, ONE launch of the SPMD program
+    (:func:`_dispatch_round`) when it has a table on every chip of
+    ``mesh.scan_devices()`` and they agree in :func:`_round_key`; the
+    tables of a ragged round are launched one by one. None where one
+    chip is visible (``DeviceTable.chip`` None) or the window's tables
+    lie on one chip: a launch a table, as ever."""
+    from ..parallel import mesh as pmesh
+    n_chips = len(pmesh.scan_devices())
+    if n_chips < 2:
+        return None
+    by_chip: List[List[int]] = [[] for _ in range(n_chips)]
+    for i, dt in enumerate(tables):
+        if dt.chip is None or not 0 <= dt.chip < n_chips:
+            return None
+        by_chip[dt.chip].append(i)
+    if sum(1 for on in by_chip if on) < 2:
+        return None
+    scalars = [runtime._scalar_planes(prog.compiled, dt) for dt in tables]
+    out: List[Tuple[int, ...]] = []
+    for j in range(max(len(on) for on in by_chip)):
+        members = tuple(on[j] for on in by_chip if j < len(on))
+        if len(members) == n_chips and len(
+                {_round_key(tables[i], hows[i], scalars[i])
+                 for i in members}) == 1:
+            out.append(members)
+        else:
+            out.extend((i,) for i in members)
+    return out, scalars
+
+
+def _dispatch_round(prog: FusedAggProgram, dts, scalars, out_cap: int,
+                    strategy: str, dims: Tuple[int, ...] = ()):
+    """ONE launch for a round: ``dts`` (a table a chip, in chip order,
+    agreeing in :func:`_round_key`, each with its runtime ``scalars`` on
+    the host) as the shards of the SPMD program
+    ``prog.round_fn``. Every input is a global array over the ``data``
+    mesh made of the planes the tables hold, where they lie: no copy, no
+    device operation (for resident tables assembled once and kept on the
+    HBM cache beside them, ``DeviceColumnCache.round_inputs``: the span
+    says ``assembled`` 1 or 0). The output is the chips' packed blocks
+    along axis 0, shard ``k`` what :func:`_dispatch_packed` gives for
+    ``dts[k]``."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from .. import tracing
+    from ..analysis import retrace_sanitizer
+    from ..parallel import mesh as pmesh
+    from . import cache as dcache
+    n = len(dts)
+    with tracing.span("device:dispatch", lane="device",
+                      attrs={"program": "fused_agg",
+                             "capacity": dts[0].capacity,
+                             "strategy": strategy,
+                             "tables": n, "chips": n}) as sp:
+        mesh = pmesh.get_mesh()
+        sharding = NamedSharding(mesh, P("data"))
+
+        def whole(planes):
+            shape = planes[0].shape
+            return jax.make_array_from_single_device_arrays(
+                (n * shape[0],) + shape[1:], sharding, planes)
+
+        def assemble():
+            return ({nm: whole([dt.columns[nm].data for dt in dts])
+                     for nm in dts[0].columns},
+                    {nm: whole([dt.columns[nm].validity for dt in dts])
+                     for nm in dts[0].columns},
+                    whole([dt.row_mask for dt in dts]))
+
+        if all(dt.resident for dt in dts):
+            # the cache's own planes: their global arrays are kept beside
+            # them (15 of them are a third of a round's dispatch at sf100)
+            (arrays, valids, row_mask), kept = \
+                dcache.get_cache().round_inputs(
+                    tuple(p for dt in dts for c in dt.columns.values()
+                          for p in (c.data, c.validity))
+                    + tuple(dt.row_mask for dt in dts), assemble)
+            sp.set("assembled", 0 if kept else 1)
+        else:
+            arrays, valids, row_mask = assemble()
+            sp.set("assembled", 1)
+        # a scalar of each table's own dictionary, the chips' side by side
+        scalars = tuple(jax.device_put(np.stack(per_chip), sharding)
+                        for per_chip in zip(*scalars))
+        fn = prog.round_fn(mesh, out_cap, strategy, dims)
+        # dispatch_registry: fragment.round, fragment.packed's signature
+        # with the chips of the round for the table's chip
+        with retrace_sanitizer.dispatch_scope(
+                "fragment.round",
+                (id(prog), dts[0].capacity, out_cap, strategy, dims,
+                 tuple(s.shape for s in scalars), n)), \
+                tracing.launch("fragment.round", None, tables=n, chips=n):
+            return fn(arrays, valids, row_mask, scalars)
+
+
 #: dense-strategy slot ceiling: K = prod(dim+1) static slots per dispatch;
 #: past this the slot planes outgrow the group blocks they stand in for
 #: and sort territory begins anyway
@@ -519,29 +662,35 @@ def _max_out_cap(prog: FusedAggProgram, dt: dcol.DeviceTable) -> int:
 
 def _ledger_grouped(prog: FusedAggProgram, rows: int, cap: int,
                     out_cap: int, seconds: float, dispatches: int,
-                    strategy: str) -> None:
+                    strategy: str, tables: Optional[int] = None) -> None:
     """Per-dispatch MFU accounting for the fused grouped-agg family; the
-    byte model follows the strategy the dispatch actually ran."""
+    byte model follows the strategy the dispatch actually ran. The work
+    is modeled a table (``tables``; default: one a dispatch), the
+    dispatches are the launches: a round is one over several tables."""
     from . import costmodel, mfu
     model = mfu.dense_agg_models if strategy == "dense" \
         else mfu.grouped_agg_models
     flops, nbytes = model(cap, out_cap, max(prog.nk, 1), len(prog.ops))
+    tables = dispatches if tables is None else tables
     costmodel.ledger_record("grouped_agg", rows=rows,
-                            nbytes=dispatches * nbytes,
-                            flops=dispatches * flops, seconds=seconds,
+                            nbytes=tables * nbytes,
+                            flops=tables * flops, seconds=seconds,
                             dispatches=dispatches, strategy=strategy)
 
 
 def _ledger_global(prog: FusedAggProgram, rows: int, cap: int,
-                   seconds: float, dispatches: int) -> None:
+                   seconds: float, dispatches: int,
+                   tables: Optional[int] = None) -> None:
     """Ledger record for the fused SCALAR-agg fragment (no group keys —
     TPC-H Q6's shape): one streaming read of each value plane plus the
-    row mask. Until PR 23 this site dispatched without a record, so a
-    query made only of it showed no kernel family at all."""
+    row mask (a table; ``dispatches`` are the launches, as in
+    :func:`_ledger_grouped`). Until PR 23 this site dispatched without a
+    record, so a query made only of it showed no kernel family at all."""
     from . import costmodel
+    tables = dispatches if tables is None else tables
     costmodel.ledger_record(
         "global_agg", rows=rows,
-        nbytes=dispatches * (len(prog.ops) + 1) * cap * 4,
+        nbytes=tables * (len(prog.ops) + 1) * cap * 4,
         seconds=seconds, dispatches=dispatches)
 
 
@@ -698,11 +847,12 @@ class DecodedRun(NamedTuple):
 
 class InflightFusedAggBatch:
     """A window's worth of in-flight fused-agg dispatches (one per
-    DeviceTable) awaiting ONE batched pytree fetch."""
+    DeviceTable, or one per round of tables that lie one on each chip)
+    awaiting ONE batched pytree fetch."""
 
     __slots__ = ("prog", "tables", "places", "in_schema", "group_exprs",
                  "agg_exprs", "out_schema", "key_fields", "agg_fields",
-                 "strategy", "packs", "t0", "submitted_s", "failed")
+                 "strategy", "packs", "cuts", "t0", "submitted_s", "failed")
 
     def __init__(self, prog, tables, places, in_schema, group_exprs,
                  agg_exprs, out_schema):
@@ -721,6 +871,10 @@ class InflightFusedAggBatch:
         self.agg_fields = [out_schema[e.name()] for e in agg_exprs]
         self.strategy = "sort"
         self.packs: list = []
+        #: the tables each of ``packs`` answers, as places in ``tables``
+        #: (several: a round, their blocks along axis 0); None: ``packs``
+        #: is one a table, in order
+        self.cuts: Optional[List[Tuple[int, ...]]] = None
         self.t0 = _time.perf_counter()
         self.submitted_s = 0.0   # dispatch wall (see InflightFusedAgg)
         self.failed = False
@@ -731,8 +885,10 @@ def submit_fused_agg_tables(prog: FusedAggProgram, tables,
                             out_schema: Schema, places=None
                             ) -> InflightFusedAggBatch:
     """Async submit half of :func:`run_fused_agg_tables`: dispatch every
-    table's fused program (no fetch).  Dispatch failures mark the token
-    failed → the drain falls back per-table."""
+    table's fused program (no fetch; :func:`_dispatch_window`: a launch a
+    table, or a launch a round where the tables lie on several chips).
+    Dispatch failures mark the token failed → the drain falls back
+    per-table."""
     import time as _time
     tok = InflightFusedAggBatch(prog, tables, places, in_schema,
                                 group_exprs, agg_exprs, out_schema)
@@ -750,25 +906,79 @@ def submit_fused_agg_tables(prog: FusedAggProgram, tables,
     if all(p is not None for p in plans):
         tok.strategy = "dense"
         try:
-            tok.packs = [
-                _dispatch_packed(prog, dt, p[1], "dense", dims=p[0])
-                for dt, p in zip(tables, plans)]
+            tok.packs, tok.cuts = _dispatch_window(
+                prog, tables, [(p[1], "dense", p[0]) for p in plans])
             tok.submitted_s = _time.perf_counter() - tok.t0
             return tok
         except Exception as exc:
             # resource exhaustion only (anything else propagates): fall
             # through to the sort batch path, counted
             runtime.device_failed("fragment.fused_agg_tables.dense", exc)
-            tok.packs = []
+            tok.packs, tok.cuts = [], None
     tok.strategy = "sort"
     try:
-        tok.packs = [_dispatch_packed(prog, dt, _OUT_CAP0, "sort")
-                     for dt in tables]
+        tok.packs, tok.cuts = _dispatch_window(
+            prog, tables, [(_OUT_CAP0, "sort", ())] * len(tables))
     except Exception as exc:
         runtime.device_failed("fragment.fused_agg_tables.submit", exc)
         tok.failed = True
     tok.submitted_s = _time.perf_counter() - tok.t0
     return tok
+
+
+def _dispatch_window(prog: FusedAggProgram, tables, hows):
+    """Launch the packed program over a window's ``tables``, table ``i``
+    at ``hows[i]`` = ``(out_cap, strategy, dims)``: one launch a table,
+    or, where the tables lie on several chips, one a round
+    (:func:`_launches`). Returns the launches' outputs and, with rounds,
+    the tables each answers (``InflightFusedAggBatch.cuts``). A round
+    whose launch fails (resource exhaustion, counted) is launched table
+    by table."""
+    from .. import tracing
+    rounds = _launches(prog, tables, hows)
+    if rounds is None:
+        tracing.tally("agg_tables_single", len(tables))
+        return [_dispatch_packed(prog, dt, cap, strategy, dims=dims)
+                for dt, (cap, strategy, dims) in zip(tables, hows)], None
+    launches, scalars = rounds
+    packs, cuts = [], []
+    for members in launches:
+        cap, strategy, dims = hows[members[0]]
+        if len(members) > 1:
+            try:
+                packs.append(_dispatch_round(
+                    prog, [tables[i] for i in members],
+                    [scalars[i] for i in members], cap, strategy, dims))
+                cuts.append(members)
+                tracing.tally("agg_tables_round", len(members))
+                continue
+            except Exception as exc:
+                runtime.device_failed("fragment.fused_agg_tables.round",
+                                      exc)
+        for i in members:
+            cap, strategy, dims = hows[i]
+            packs.append(_dispatch_packed(prog, tables[i], cap, strategy,
+                                          dims=dims))
+            cuts.append((i,))
+        tracing.tally("agg_tables_single", len(members))
+    return packs, cuts
+
+
+def _cut_rounds(fetched, cuts, n_tables: int) -> List[np.ndarray]:
+    """A window's fetched launch outputs as one packed result a table,
+    in the tables' order: a round's output holds its tables' blocks one
+    after another along axis 0."""
+    if cuts is None:
+        return [np.asarray(m) for m in fetched]
+    mats: list = [None] * n_tables
+    for m, members in zip(fetched, cuts):
+        m = np.asarray(m)
+        if len(members) == 1:
+            mats[members[0]] = m
+        else:
+            for i, block in zip(members, np.split(m, len(members))):
+                mats[i] = block
+    return mats
 
 
 def _decode_window(tok: InflightFusedAggBatch, idx, mats, pieces) -> list:
@@ -838,7 +1048,8 @@ def _runs(pieces, places) -> List[DecodedRun]:
 def drain_fused_agg_tables(tok: InflightFusedAggBatch) -> List[DecodedRun]:
     """Blocking drain half: ALL packed results come back in a single
     pytree ``device_get`` (one batched transfer for the whole window —
-    per-task gets would serialize one round trip each), then decode
+    per-task gets would serialize one round trip each; a round's output
+    is cut into its tables' blocks, :func:`_cut_rounds`), then decode
     lane by lane over all the window's tables at once
     (:func:`_decode_lanes`); overflowed tables re-dispatch as one batch
     and decode as one. Returns the window's :class:`DecodedRun` s in
@@ -857,7 +1068,8 @@ def drain_fused_agg_tables(tok: InflightFusedAggBatch) -> List[DecodedRun]:
     strategy = tok.strategy
     t_drain0 = _time.perf_counter()
     try:
-        stacked = [np.asarray(m) for m in pipeline.fetch_host(tok.packs)]
+        stacked = _cut_rounds(pipeline.fetch_host(tok.packs), tok.cuts,
+                              len(tables))
     except Exception as exc:
         runtime.device_failed("fragment.fused_agg_tables.fetch", exc)
         return failed
@@ -867,19 +1079,19 @@ def drain_fused_agg_tables(tok: InflightFusedAggBatch) -> List[DecodedRun]:
         costmodel.log_strategy_decision(
             "groupby_strategy", strategy,
             rows=sum(dt.row_count for dt in tables), out_cap=_OUT_CAP0,
-            tables=len(tok.packs))
+            tables=len(tables))
         # submit wall + fetch wall, excluding any in-window queue wait
         # between them (see InflightFusedAgg.submitted_s)
         _ledger_grouped(prog, sum(dt.row_count for dt in tables),
                         max(dt.capacity for dt in tables), _OUT_CAP0,
                         tok.submitted_s
                         + (_time.perf_counter() - t_drain0),
-                        len(tok.packs), strategy)
+                        len(tok.packs), strategy, tables=len(tables))
     else:
         _ledger_global(prog, sum(dt.row_count for dt in tables),
                        max(dt.capacity for dt in tables),
                        tok.submitted_s + (_time.perf_counter() - t_drain0),
-                       len(tok.packs))
+                       len(tok.packs), tables=len(tables))
     pieces: list = [None] * len(tables)
     # overflowed tables are re-dispatched as ONE batch, not per table
     # (each serial round trip pays the link RTT)

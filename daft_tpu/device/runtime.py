@@ -213,16 +213,22 @@ def _string_out_source(e: Expression) -> Optional[str]:
     return inner.params[0] if inner.op == "col" else None
 
 
-def _prep_scalars(c: compiler.Compiled, dt: dcol.DeviceTable):
-    scalars = []
+def _scalar_planes(c: compiler.Compiled, dt: dcol.DeviceTable) -> list:
+    """The program's runtime scalars for ``dt``, on the host: each a
+    function of one column's own dictionary."""
+    planes = []
     for spec in c.scalar_specs:
         d = dt.columns[spec.col].dictionary
         if d is None:
             d = pa.array([], type=pa.large_string())
-        # beside the table's planes, so a dispatch on another chip
-        # moves nothing from the default one
-        scalars.append(dcol.put_plane(spec.fn(d), dt.chip))
-    return tuple(scalars)
+        planes.append(spec.fn(d))
+    return planes
+
+
+def _prep_scalars(c: compiler.Compiled, dt: dcol.DeviceTable):
+    # beside the table's planes, so a dispatch on another chip moves
+    # nothing from the default one
+    return tuple(dcol.put_plane(x, dt.chip) for x in _scalar_planes(c, dt))
 
 
 def encode_for(c: compiler.Compiled, batch):

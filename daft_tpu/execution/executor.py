@@ -918,6 +918,10 @@ class LocalExecutor:
             # to fill the in-flight ladder without multiplying the
             # per-window fetch round-trips an RTT-bound query pays
             width = max(1, min(width, -(-n_tasks // max(pwin + 1, 1))))
+        # over several chips a window is launched round by round (a table
+        # of every chip, ``fragment.submit_fused_agg_tables``): a width
+        # that is a multiple of the chips leaves no round ragged
+        width = -(-width // n_chips) * n_chips
 
         def windows():
             it = iter(enumerate(tasks))

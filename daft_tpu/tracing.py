@@ -327,7 +327,9 @@ class SpanRecorder:
         decoded into how many record ``batches``), ``joins`` (the bucket
         pairs ``joins.match_indices`` matched: :data:`JOIN_TALLIES`),
         ``selects`` (the filtered scans' tables that ended in rows:
-        :data:`SELECT_TALLIES`),
+        :data:`SELECT_TALLIES`), ``agg_launches`` (the fused aggregate's
+        window tables by the launch that answered them:
+        :data:`AGG_LAUNCH_TALLIES`),
         ``chips`` (the same tally per chip: ``chip``, ``tables``, ``rows``
         and ``resident_bytes``, one entry a chip, in chip order),
         ``handoffs`` (``count`` items taken from a channel or submits
@@ -375,6 +377,8 @@ class SpanRecorder:
                             for k in JOIN_TALLIES}
             out["selects"] = {k: tallies.get("select_" + k, 0)
                               for k in SELECT_TALLIES}
+            out["agg_launches"] = {k: tallies.get("agg_" + k, 0)
+                                   for k in AGG_LAUNCH_TALLIES}
             out["chips"] = chips
         return out
 
@@ -383,7 +387,9 @@ class SpanRecorder:
 #: query's wall is covered by their union (``summary()["covered_us"]``).
 #: ``device:put`` and ``device:dispatch`` carry the ``chip`` their planes
 #: or program went to (an index of ``parallel.mesh.scan_devices()``; 0
-#: when one chip is visible), ``device:fetch`` the number of ``chips``
+#: when one chip is visible; a round launch of the fused aggregate, one
+#: program over a table on every chip, carries ``tables`` and ``chips``
+#: in its place), ``device:fetch`` the number of ``chips``
 #: its results lay on (and ``chip`` when that is one). ``join:device``
 #: is one bucket pair matched by the fused device join
 #: (``joins._device_match_indices``: pad, put, dispatch, fetch and unpack,
@@ -457,6 +463,14 @@ JOIN_TALLIES = ("pairs_device", "pairs_host", "rows_device", "rows_host",
 SELECT_TALLIES = ("tables_device", "tables_host", "rows_in", "rows_out",
                   "rows_in_device", "rows_out_device", "overflows",
                   "tables_row_gather")
+
+#: ``summary()["agg_launches"]``: the tables of the fused aggregate's
+#: windows (``fragment.submit_fused_agg_tables``) by the launch that
+#: answered them: a round launch, ONE call of the SPMD program over a
+#: table on every chip, or a launch of their own (one chip visible, a
+#: ragged round, a round whose launch failed). Tallied as ``agg_<key>`` on
+#: the query's root span
+AGG_LAUNCH_TALLIES = ("tables_round", "tables_single")
 
 #: where a scan task's table came from, as the device tier's scan path
 #: tallies it (``SpanRecorder.tally`` / :func:`tally`)
@@ -873,10 +887,12 @@ def span(name: str, key: Optional[str] = None,
     return _LiveSpan(ctx, name, key, attrs, lane)
 
 
-def launch(program: str, chip: int = 0):
+def launch(program: str, chip: Optional[int] = 0, **attrs):
     """``dispatch:launch``: the call of a jitted function and nothing
     else, opened exactly where ``retrace_sanitizer.dispatch_scope``
-    brackets it (``program`` is the sanitizer's site id). No leaf: it
+    brackets it (``program`` is the sanitizer's site id; a round launch,
+    ``fragment.round``, carries its ``tables`` and ``chips`` and no
+    ``chip``). No leaf: it
     nests in its ``device:dispatch`` / ``join:device``, or stands alone
     at ``device/runtime.py``'s sites. Its ``cpu_us`` is the client's
     launch work in the calling thread; the rest of its wall the thread
@@ -884,8 +900,10 @@ def launch(program: str, chip: int = 0):
     ctx = current()
     if ctx is None:
         return _NOOP
-    return _LiveSpan(ctx, "dispatch:launch", None,
-                     {"program": program, "chip": chip}, "device")
+    if chip is not None:
+        attrs["chip"] = chip
+    attrs["program"] = program
+    return _LiveSpan(ctx, "dispatch:launch", None, attrs, "device")
 
 
 def wait(name: str):

@@ -740,9 +740,10 @@ def test_q1_over_16_files_decodes_a_batch_a_window(monkeypatch, tmp_path,
                                                    inflight):
     """The normal path: `read_parquet` over 16 files -> Q1. The answer is
     the host tier's; the 16 packed results are decoded as one batch a
-    window (6 + 6 + 4 tables under the default in-flight window of 2; the
-    synchronous loop's one wide window under 0) and the fragment yields
-    one partition a batch, not one a table."""
+    window (6 + 6 + 4 tables under the default in-flight window of 2 on
+    one chip, 8 + 8 over the suite's eight; the synchronous loop's one
+    wide window under 0) and the fragment yields one partition a batch,
+    not one a table."""
     import os
 
     import pyarrow as pa
@@ -792,8 +793,15 @@ def test_q1_over_16_files_decodes_a_batch_a_window(monkeypatch, tmp_path,
     assert dev["cnt"] == host["cnt"] and len(dev["flag"]) == 6
     for name in ("sum_qty", "avg_price"):
         assert dev[name] == pytest.approx(host[name], rel=1e-9)
-    windows = 3 if inflight == "2" \
-        else -(-16 // (max(os.cpu_count() or 4, 4) * 2))
+    # the executor's window: cores x 2 wide, cut so that the in-flight
+    # window + 1 windows cover the scan, then rounded up to a multiple of
+    # the chips the tables are spread over (PR 44: no ragged round)
+    from daft_tpu.parallel import mesh as pmesh
+    chips = max(len(pmesh.scan_devices()), 1)
+    width = max(os.cpu_count() or 4, 4) * 2
+    if inflight == "2":
+        width = min(width, -(-16 // 3))
+    windows = -(-16 // (-(-width // chips) * chips))
     assert summary["decode"] == {"tables": 16, "batches": windows}
     assert summary["tables"]["host"] == 0
     assert len(yielded) == windows and sum(yielded) == 16 * 6

@@ -272,12 +272,16 @@ def test_the_spans_carry_the_chip(lineitem):
     with visible_chips(4):
         _run(lineitem, "q1")
         spans = obs.last_query_stats().trace_ctx.recorder.spans()
-    by_chip = {}
-    for s in spans:
-        if s["name"] == "device:dispatch" and \
-                (s.get("attrs") or {}).get("strategy") != "plan":
-            by_chip[s["attrs"]["chip"]] = by_chip.get(s["attrs"]["chip"], 0) + 1
-    assert by_chip == {k: FILES // 4 for k in range(4)}
+    # a launch a round of four tables, one a chip (PR 44): the span of a
+    # round carries its tables and chips, not one chip
+    rounds = [s["attrs"] for s in spans if s["name"] == "device:dispatch"
+              and s["attrs"].get("strategy") != "plan"]
+    assert [(a["tables"], a["chips"], "chip" in a) for a in rounds] == \
+        [(4, 4, False)] * (FILES // 4)
+    launches = [s["attrs"] for s in spans if s["name"] == "dispatch:launch"
+                and s["attrs"]["program"].startswith("fragment.")]
+    assert [(a["program"], a["tables"], a["chips"]) for a in launches] == \
+        [("fragment.round", 4, 4)] * (FILES // 4)
     puts = [s["attrs"]["chip"] for s in spans if s["name"] == "device:put"]
     assert set(puts) == {0, 1, 2, 3}
     fetches = [s["attrs"] for s in spans if s["name"] == "device:fetch"]

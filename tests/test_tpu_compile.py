@@ -98,7 +98,23 @@ def _packed_argsort(S):
         descending=(True, False), nulls_first=(False, False))
 
 
-def _fused_scan_filter_agg(S):
+def _lower_packed(S, C, prog, how):
+    """``prog.packed_fn`` lowered over ``C``-row planes at ``how`` =
+    ``(out_cap, strategy, dims)``."""
+    out_cap, strategy, dims = how
+    return prog.packed_fn.lower(*_packed_inputs(S, C, prog), out_cap=out_cap,
+                                strategy=strategy, dims=dims)
+
+
+def _packed_inputs(S, rows, prog):
+    arrays = {n: S((rows,), prog.in_np_dtypes[n])
+              for n in prog.compiled.needs_cols}
+    valids = {n: S((rows,), jnp.bool_) for n in prog.compiled.needs_cols}
+    assert not prog.compiled.scalar_specs
+    return arrays, valids, S((rows,), jnp.bool_), ()
+
+
+def _q6_program():
     """One fused scan->filter->project->agg fragment (fragment.get_fused_agg):
     TPC-H Q6's shape — predicate over date/float columns, a product, one
     global sum — as the single jit program the executor dispatches."""
@@ -117,17 +133,15 @@ def _fused_scan_filter_agg(S):
     child = [(col("l_extendedprice") * col("l_discount")).alias("__v0__")]
     prog = fragment.get_fused_agg([], child, ("sum",), pred, schema)
     assert prog is not None, "Q6-shaped fragment must be device-compilable"
+    return prog, (fragment._OUT_CAP0, "sort", ())
+
+
+def _fused_scan_filter_agg(S):
     C = 524288   # chip_smoke's lineitem bucket (SF1 in 16 parts)
-    arrays = {n: S((C,), prog.in_np_dtypes[n])
-              for n in prog.compiled.needs_cols}
-    valids = {n: S((C,), jnp.bool_) for n in prog.compiled.needs_cols}
-    assert not prog.compiled.scalar_specs
-    return prog.packed_fn.lower(arrays, valids, S((C,), jnp.bool_), (),
-                                out_cap=fragment._OUT_CAP0, strategy="sort",
-                                dims=())
+    return _lower_packed(S, C, *_q6_program())
 
 
-def _fused_q1_dense(S):
+def _q1_program():
     """TPC-H Q1's fused scan->filter->project->agg fragment at
     ``strategy="dense"``: the program all five benchmark cells dispatch,
     once a lineitem file (``agg_hbm_pct`` is its device time). Two string
@@ -162,14 +176,12 @@ def _fused_q1_dense(S):
         col("l_shipdate") <= lit(datetime.date(1998, 9, 2)), schema)
     assert prog is not None, "Q1-shaped fragment must be device-compilable"
     assert prog.key_sources == ("l_returnflag", "l_linestatus")
+    return prog, (fragment._OUT_CAP0, "dense", (4, 2))
+
+
+def _fused_q1_dense(S):
     C = 524288   # the bucket of _fused_scan_filter_agg: SF1 in 16 parts
-    arrays = {n: S((C,), prog.in_np_dtypes[n])
-              for n in prog.compiled.needs_cols}
-    valids = {n: S((C,), jnp.bool_) for n in prog.compiled.needs_cols}
-    assert not prog.compiled.scalar_specs
-    return prog.packed_fn.lower(arrays, valids, S((C,), jnp.bool_), (),
-                                out_cap=fragment._OUT_CAP0, strategy="dense",
-                                dims=(4, 2))
+    return _lower_packed(S, C, *_q1_program())
 
 
 def _scan_select(S, columns, pred, strings, words, C, w):
@@ -296,3 +308,33 @@ def test_sharded_grouped_agg_compiles_for_four_chip_mesh(topo):
                                   S(jnp.float32), S(jnp.float32), b, b, b)
     text = lowered.compile().as_text()
     assert "all-to-all" in text
+
+
+ROUND_PROGRAMS = {"q1_dense": _q1_program, "q6": _q6_program}
+
+
+@pytest.mark.parametrize("name", sorted(ROUND_PROGRAMS))
+def test_round_program_holds_no_collective_on_four_chips(name, topo):
+    """The fused aggregate's round launch (``FusedAggProgram.round_fn``:
+    ``run_packed`` under ``shard_map`` over the four described devices,
+    a table a chip at the resident cells' 4 194 304-row bucket): every
+    shard reduces its own planes and the chips' partials stay apart for
+    the host's float64 merge, so the compiled module holds no collective;
+    and it keeps the name the device trace is read by."""
+    assert len(topo.devices) == 4
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    sh = NamedSharding(mesh, P("data"))
+    prog, how = ROUND_PROGRAMS[name]()
+    C = 4194304
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    text = prog.round_fn(mesh, *how).lower(
+        *_packed_inputs(S, 4 * C, prog)).compile().as_text()
+    head = text.splitlines()[0]
+    assert head.startswith("HloModule jit_run_packed,"), head
+    assert "num_partitions=4" in head
+    for op in ("all-reduce", "all-gather", "collective-permute",
+               "all-to-all", "reduce-scatter", "collective-broadcast"):
+        assert op not in text, op
