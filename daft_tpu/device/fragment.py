@@ -350,6 +350,16 @@ def submit_fused_agg(prog: FusedAggProgram, batch, group_exprs, agg_exprs,
         reencode=lambda: dcol.encode_batch(batch, prog.compiled.needs_cols))
 
 
+def _how_attrs(strategy: str, dims: Tuple[int, ...]) -> dict:
+    """What a launch's span and its strategy decision say of the
+    reduction: the ``strategy`` and, for a dense one, the ``inner`` loop
+    its slot count takes (``kernels.dense_inner_loop``: ``masked`` /
+    ``matmul``), known from ``dims`` before the launch."""
+    if strategy != "dense":
+        return {"strategy": strategy}
+    return {"strategy": strategy, "inner": kernels.dense_inner_loop(dims)}
+
+
 def _dispatch_packed(prog: FusedAggProgram, dt: dcol.DeviceTable,
                      out_cap: int, strategy: str = "sort",
                      donate: bool = False, dims: Tuple[int, ...] = ()):
@@ -359,7 +369,7 @@ def _dispatch_packed(prog: FusedAggProgram, dt: dcol.DeviceTable,
     with tracing.span("device:dispatch", lane="device",
                       attrs={"program": "fused_agg",
                              "capacity": dt.capacity,
-                             "strategy": strategy,
+                             **_how_attrs(strategy, dims),
                              "chip": dt.chip or 0}):
         arrays = {n: col.data for n, col in dt.columns.items()}
         valids = {n: col.validity for n, col in dt.columns.items()}
@@ -449,7 +459,7 @@ def _dispatch_round(prog: FusedAggProgram, dts, scalars, out_cap: int,
     with tracing.span("device:dispatch", lane="device",
                       attrs={"program": "fused_agg",
                              "capacity": dts[0].capacity,
-                             "strategy": strategy,
+                             **_how_attrs(strategy, dims),
                              "tables": n, "chips": n}) as sp:
         mesh = pmesh.get_mesh()
         sharding = NamedSharding(mesh, P("data"))
@@ -531,10 +541,7 @@ def dense_plan(prog: FusedAggProgram, dt: dcol.DeviceTable,
     dims = dense_dims(prog, dt)
     if dims is None:
         return None
-    K = 1
-    for d in dims:
-        K *= d + 1
-    out_cap = dcol.bucket_capacity(max(K, _OUT_CAP0))
+    out_cap = dcol.bucket_capacity(max(kernels.dense_slots(dims), _OUT_CAP0))
     if out_cap > cap_limit:
         return None
     return dims, out_cap
@@ -736,8 +743,8 @@ def _ladder_dispatch(tok: InflightFusedAgg) -> None:
                                   tok.strategy, tok.donate, tok.dims)
     tok.dispatches += 1
     costmodel.log_strategy_decision(
-        "groupby_strategy", tok.strategy, rows=tok.dt.row_count,
-        out_cap=tok.out_cap)
+        "groupby_strategy", rows=tok.dt.row_count, out_cap=tok.out_cap,
+        **_how_attrs(tok.strategy, tok.dims))
 
 
 def submit_fused_agg_table(prog: FusedAggProgram, dt: dcol.DeviceTable,
@@ -852,7 +859,8 @@ class InflightFusedAggBatch:
 
     __slots__ = ("prog", "tables", "places", "in_schema", "group_exprs",
                  "agg_exprs", "out_schema", "key_fields", "agg_fields",
-                 "strategy", "packs", "cuts", "t0", "submitted_s", "failed")
+                 "strategy", "inner", "packs", "cuts", "t0", "submitted_s",
+                 "failed")
 
     def __init__(self, prog, tables, places, in_schema, group_exprs,
                  agg_exprs, out_schema):
@@ -870,6 +878,9 @@ class InflightFusedAggBatch:
         self.key_fields = [e.to_field(in_schema) for e in group_exprs]
         self.agg_fields = [out_schema[e.name()] for e in agg_exprs]
         self.strategy = "sort"
+        #: a dense window's inner loop(s), ``kernels.dense_inner_loop`` of
+        #: its tables' ``dims`` (``masked+matmul`` where they differ)
+        self.inner: Optional[str] = None
         self.packs: list = []
         #: the tables each of ``packs`` answers, as places in ``tables``
         #: (several: a round, their blocks along axis 0); None: ``packs``
@@ -905,6 +916,8 @@ def submit_fused_agg_tables(prog: FusedAggProgram, tables,
                  for dt in tables]
     if all(p is not None for p in plans):
         tok.strategy = "dense"
+        tok.inner = "+".join(sorted(
+            {kernels.dense_inner_loop(dims) for dims, _ in plans}))
         try:
             tok.packs, tok.cuts = _dispatch_window(
                 prog, tables, [(p[1], "dense", p[0]) for p in plans])
@@ -915,7 +928,7 @@ def submit_fused_agg_tables(prog: FusedAggProgram, tables,
             # through to the sort batch path, counted
             runtime.device_failed("fragment.fused_agg_tables.dense", exc)
             tok.packs, tok.cuts = [], None
-    tok.strategy = "sort"
+    tok.strategy, tok.inner = "sort", None
     try:
         tok.packs, tok.cuts = _dispatch_window(
             prog, tables, [(_OUT_CAP0, "sort", ())] * len(tables))
@@ -1079,7 +1092,8 @@ def drain_fused_agg_tables(tok: InflightFusedAggBatch) -> List[DecodedRun]:
         costmodel.log_strategy_decision(
             "groupby_strategy", strategy,
             rows=sum(dt.row_count for dt in tables), out_cap=_OUT_CAP0,
-            tables=len(tables))
+            tables=len(tables),
+            **({"inner": tok.inner} if tok.inner else {}))
         # submit wall + fetch wall, excluding any in-window queue wait
         # between them (see InflightFusedAgg.submitted_s)
         _ledger_grouped(prog, sum(dt.row_count for dt in tables),

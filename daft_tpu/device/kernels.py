@@ -492,6 +492,41 @@ def grouped_agg_block_impl(keys, key_valids, vals, val_valids, row_mask,
 # ---------------------------------------------------------------------------
 # dense direct-indexed grouped aggregation (dictionary-coded keys)
 
+#: slots at and under which the dense aggregate's additive reductions are
+#: masked sums a slot and a plane (``"masked"``), over which they are ONE
+#: one-hot matmul over the stacked planes (``"matmul"``). The masked form
+#: is the vector unit's: ncols x K selects and adds a row, and ncols x K
+#: reductions in the HLO (165 at TPC-H Q1: 11 planes, 15 slots), so it
+#: grows with K. The stack is HBM's: every plane written, copied into a
+#: sublane-padded ``[ncols, C]`` operand and read back, ~250 B a row
+#: whatever K. Both grow with the plane count alike, so K alone decides.
+#: Read on a TPU v5e inside Q1's fused program at C = 4 194 304 (PR 47,
+#: ``chip_proof/dense_inner.py``; ms a table, masked / matmul): K = 15
+#: 0.80 / 1.63, 27 1.26 / 1.64, 32 1.50 / 1.64, 33 1.51 / 1.73, 45 2.25 /
+#: 1.78: they cross near 38, and the TPU compile at the bound takes 6.5 s
+#: against 2–3. (The stack was chosen on a CPU, where a scatter is a
+#: serial loop and a GEMM is multithreaded; at Q1's 15 slots it rode
+#: nothing on the chip: 0.79 ms of a 1.48 ms table copied planes.)
+DENSE_MASKED_MAX_SLOTS = 32
+
+
+def dense_slots(dims: Tuple[int, ...]) -> int:
+    """K = prod(d + 1): a dense dispatch's static slot count (slot ``d``
+    of a key holds its nulls)."""
+    K = 1
+    for d in dims:
+        K *= d + 1
+    return K
+
+
+def dense_inner_loop(dims: Tuple[int, ...]) -> str:
+    """The inner loop ``grouped_agg_dense_impl`` runs at ``dims``:
+    ``"masked"`` or ``"matmul"`` (:data:`DENSE_MASKED_MAX_SLOTS`). Known
+    on the host before the launch, so a dispatch span can say which."""
+    return "masked" if dense_slots(dims) <= DENSE_MASKED_MAX_SLOTS \
+        else "matmul"
+
+
 def grouped_agg_dense_impl(keys, key_valids, vals, val_valids, row_mask,
                            ops: Tuple[str, ...], out_cap: int,
                            dims: Tuple[int, ...]):
@@ -502,10 +537,12 @@ def grouped_agg_dense_impl(keys, key_valids, vals, val_valids, row_mask,
     a row's group id is pure arithmetic over its codes: a mixed-radix
     number over the per-key slot widths ``dims`` (each dictionary size
     rounded up to a power of two so the static-arg space stays bounded;
-    slot ``d`` of a key holds its nulls). Aggregation is then ONE O(C)
-    scatter pass per reduced plane over ``K = prod(d+1)`` slots — the
-    radix sort + inverse-permutation sort of the sort strategy (≥4
-    streaming passes over the packed row planes) disappears entirely.
+    slot ``d`` of a key holds its nulls). Aggregation is then per-slot
+    sums over ``K = prod(d+1)`` slots (:func:`dense_inner_loop`: masked
+    sums with few slots, a one-hot matmul with many; integer sums and
+    min / max / ``any_value`` by scatter) — the radix sort +
+    inverse-permutation sort of the sort strategy (≥4 streaming passes
+    over the packed row planes) disappears entirely.
 
     Strides are most-significant-first over the keys with nulls at each
     key's top slot, so occupied slots enumerate groups in ascending key
@@ -517,9 +554,7 @@ def grouped_agg_dense_impl(keys, key_valids, vals, val_valids, row_mask,
     :func:`grouped_agg_block_impl`.
     """
     C = row_mask.shape[0]
-    K = 1
-    for d in dims:
-        K *= d + 1
+    K = dense_slots(dims)
     if K > out_cap:
         raise ValueError("dense dispatch requires K <= out_cap")
     # mixed-radix group id per ORIGINAL row (no gathers, no sort)
@@ -531,25 +566,17 @@ def grouped_agg_dense_impl(keys, key_valids, vals, val_valids, row_mask,
             gid = gid * (d + 1) + comp
         seg = jnp.where(row_mask, gid, out_cap).astype(jnp.int32)
 
-    # ONE [C, K] one-hot shared by every additive reduction below: the
-    # per-slot sums become a single stacked matmul instead of a scatter
-    # per plane. XLA CPU lowers scatter to a serial per-row update loop
-    # (the q1 profile showed it dominating the whole dispatch), while a
-    # [C, K]·[K] GEMM is multithreaded there and rides the MXU on TPU.
-    # K is the tiny static slot count (dictionary product), NOT out_cap,
-    # so the materialized one-hot stays ~C·K·8 bytes.
-    with jax.named_scope("dense/onehot-matmul"):
-        acc_dt = jnp.float64 if any(
-            v.dtype == jnp.float64 for v in vals) else jnp.float32
-        oh = jax.nn.one_hot(jnp.where(row_mask, gid, K), K, dtype=acc_dt)
+    # Every additive reduction below (slot occupancy, contribution counts,
+    # float sums, squared sums) is one row of ``R``, [ncols, K]: plane i
+    # summed into slot k, by the inner loop the slot count asks for.
+    acc_dt = jnp.float64 if any(
+        v.dtype == jnp.float64 for v in vals) else jnp.float32
 
     def slot_pad(x):
         """[K] slot vector → [out_cap] (slots past K are empty)."""
         return jnp.zeros((out_cap,), x.dtype).at[:K].set(x)
 
-    # pass 1 — collect every additive plane (slot occupancy, contrib
-    # counts, float sums, squared sums) into ONE [ncols, C] matrix for a
-    # single GEMM against the shared one-hot. Integer sums keep the
+    # pass 1 — collect every DISTINCT additive plane. Integer sums keep the
     # exact int64 scatter, and min/max/any/bool reductions scatter too
     # (no additive form).
     with jax.named_scope("dense/pack"):
@@ -558,7 +585,7 @@ def grouped_agg_dense_impl(keys, key_valids, vals, val_valids, row_mask,
 
         def want(i, tag, x, src):
             # queries reuse planes (q1 sums l_quantity three ways over one
-            # validity mask) — identical sources collapse to one matrix row
+            # validity mask) — identical sources collapse to one row of R
             shared = (tag,) + src
             ix = col_ix.get(shared)
             if ix is None:
@@ -578,12 +605,35 @@ def grouped_agg_dense_impl(keys, key_valids, vals, val_valids, row_mask,
                 if op in ("var", "stddev"):
                     xa = x.astype(acc_dt)
                     want(i, "s2", xa * xa, (id(v), id(vv)))
-        # stack along axis 0 (each column lands contiguously) and contract
-        # the row axis directly — the axis=1/transpose formulation pays an
-        # extra interleaving copy of the whole matrix
-        M = jnp.stack(mm_cols, axis=0)
     with jax.named_scope("dense/onehot-matmul"):
-        R = jnp.matmul(M, oh, precision=lax.Precision.HIGHEST)  # [ncols, K]
+        if dense_inner_loop(dims) == "masked":
+            # a masked sum a slot and a plane: what makes a plane fuses
+            # into what reduces it, so no plane is written out only to be
+            # read back. f32 summed in f32 (in a tree: another order than
+            # the matmul's), counts exact below 2^24 rows
+            zero = jnp.zeros((), acc_dt)
+            hits = [seg == k for k in range(K)]   # a masked row hits none
+            sums = [jnp.sum(jnp.where(h, x, zero))
+                    for x in mm_cols for h in hits]
+            # the K x ncols scalars are laid into R by ONE chain of selects
+            # over its grid (one fusion on the TPU), not stacked a plane:
+            # either way a scalar costs ~0.36 us to fetch there, but the
+            # chain leaves the compiler a better plan for the C-wide
+            # fusions (PERF.md §6, PR 47: 803 against 868 us a table)
+            grid = (len(mm_cols), K)
+            at = lax.broadcasted_iota(jnp.int32, grid, 0) * K \
+                + lax.broadcasted_iota(jnp.int32, grid, 1)
+            R = jnp.zeros_like(at, acc_dt)
+            for n, total in enumerate(sums):
+                R = jnp.where(at == n, total, R)
+        else:
+            # a masked row's seg is out_cap >= K: an all-zero one-hot row.
+            # The planes are stacked along axis 0 (each lands contiguously)
+            # and the row axis contracted directly — the axis=1/transpose
+            # formulation pays an extra interleaving copy of the matrix
+            oh = jax.nn.one_hot(seg, K, dtype=acc_dt)
+            M = jnp.stack(mm_cols, axis=0)
+            R = jnp.matmul(M, oh, precision=lax.Precision.HIGHEST)
 
     with jax.named_scope("dense/reduce"):
         occ = R[col_ix[(-1, "occ")]]

@@ -109,12 +109,26 @@ def grouped_agg_models(cap: int, out_cap: int, n_keys: int,
 
 def dense_agg_models(cap: int, out_cap: int, n_keys: int, n_vals: int,
                      val_bytes: int = 4):
-    """(flops, bytes) of one DENSE direct-indexed grouped-agg dispatch:
-    one pass over each key-code plane (the mixed-radix group id is pure
-    arithmetic), one scatter pass per reduced plane (values + the count
-    plane), and the [out_cap] slot planes. No sort — the lighter byte
-    model of the two strategies, which is exactly why the dispatch sites
-    prefer it whenever the dictionaries fit."""
+    """(flops, bytes) of one DENSE direct-indexed grouped-agg dispatch,
+    as the LEAST either inner loop (``kernels.dense_inner_loop``) moves:
+    one read of each key-code plane (the mixed-radix group id is pure
+    arithmetic), of the row mask, and of each reduced plane with its
+    validity (values + the count plane), and the [out_cap] slot planes.
+    No sort — the lighter byte model of the two strategies, which is why
+    the dispatch sites prefer it whenever the dictionaries fit.
+
+    What the loops really move (TPU v5e, Q1 at 4 194 304 rows, PR 47):
+    ``masked`` (K x planes masked sums, at and under
+    ``kernels.DENSE_MASKED_MAX_SLOTS`` slots) reads the inputs once or
+    twice and writes a few shared planes — about this model; its cost is
+    the vector unit's, planes x K selects and adds a row, which the zero
+    flops here leave out. ``matmul`` (over the bound) writes every
+    distinct additive plane, copies them into one ``[planes, cap]`` stack
+    (sublane-padded to a multiple of 8 rows) and reads that back: ~250 B
+    a row at Q1's 11 planes, between three and four times this model. The
+    numbers stay the model's: the
+    ledger's ``grouped_agg`` bytes calibrate ``DEV_AGG_BPS`` (where
+    ``DAFT_TPU_CALIBRATION`` is on), so they are a gate's input."""
     row_bytes = cap * (n_keys * 4 + 1 + (n_vals + 1) * (val_bytes + 1))
     slot_bytes = out_cap * (n_vals + 2) * 8
     return 0.0, int(row_bytes + slot_bytes)
@@ -133,8 +147,12 @@ def _timed_iters(jitted, args, iters: int = _ITERS) -> float:
 
 def measure_grouped_agg(n: int = 1 << 20, groups: int = 256,
                         n_vals: int = 2) -> Dict:
-    """MFU of the one-hot-matmul grouped aggregation (the TPC-H Q1 shape:
-    few groups, several reduced value planes)."""
+    """MFU of the SORT strategy's grouped aggregation
+    (``grouped_agg_block_impl``: packed-key sort, then a one-hot matmul
+    over the sorted segments) at few groups and several reduced value
+    planes. Not TPC-H Q1's program: dictionary-coded keys take the dense
+    strategy, whose few slots are summed by masked sums and never touch
+    the MXU (``kernels.dense_inner_loop``)."""
     rng = np.random.default_rng(0)
     keys = jnp.asarray(rng.integers(0, groups, n).astype(np.int64))
     valid = jnp.ones(n, dtype=bool)

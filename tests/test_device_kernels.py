@@ -543,6 +543,174 @@ def test_dense_refuses_a_bucket_smaller_than_its_slots():
                                  ("sum",), 16, (4, 4))
 
 
+# The dense strategy's two inner loops (``K.dense_inner_loop``: masked sums
+# a slot and a plane at and under ``K.DENSE_MASKED_MAX_SLOTS`` slots, the
+# stacked one-hot matmul over it) against numpy in float64. A case's data
+# is reduced once at slot widths that keep K under the bound and once at
+# wider ones that put it over: the codes are the same, so the groups, their
+# order and every aggregate must be too.
+
+def _over_the_bound(dims):
+    """``dims`` with the first key's width grown until K passes the
+    bound (the least such width: the one-hot stays small on the CPU)."""
+    return (max(dims[0], _BOUND // K.dense_slots(dims[1:])),) + dims[1:]
+
+
+def _ref_dense(keys, kvalids, vals, vvalids, mask, ops):
+    """[(key tuple, value tuple)] in the kernel's group order (ascending
+    keys, most significant first, NULLs last), float64 / exact ints."""
+    keys, kvalids, vals, vvalids = (
+        [np.asarray(x) for x in xs] for xs in (keys, kvalids, vals, vvalids))
+    mask = np.asarray(mask)
+    rows = {}
+    for r in np.flatnonzero(mask):
+        key = tuple(int(k[r]) if kv[r] else None
+                    for k, kv in zip(keys, kvalids))
+        rows.setdefault(key, []).append(r)
+    out = []
+    for key in sorted(rows, key=lambda t: tuple(
+            (x is None, x or 0) for x in t)):
+        got = []
+        for v, vv, op in zip(vals, vvalids, ops):
+            x = v[[r for r in rows[key] if vv[r]]]
+            if op == "count":
+                got.append(len(x))
+            elif len(x) == 0:
+                got.append(None)
+            elif op == "sum":
+                got.append(int(x.astype(np.int64).sum()) if x.dtype.kind
+                           in "bi" else float(x.astype(np.float64).sum()))
+            elif op == "any_value":
+                got.append(x[0].item())
+            else:
+                fn = {"mean": np.mean, "var": np.var, "stddev": np.std,
+                      "min": np.min, "max": np.max}[op]
+                got.append(fn(x.astype(np.float64) if op in
+                              ("mean", "var", "stddev") else x).item())
+        out.append((key, tuple(got)))
+    return out
+
+
+def _q1_layout(rng, C):
+    """Two code keys (3 flags, 2 statuses), seven f32 planes that share
+    ONE validity plane (the distinct-plane collapse), sum / mean /
+    count."""
+    keys = tuple(jnp.asarray(rng.integers(0, d, C).astype(np.int32))
+                 for d in (3, 2))
+    ones = jnp.ones(C, bool)
+    vv = jnp.asarray(rng.random(C) > 0.01)
+    vals = tuple(jnp.asarray(rng.uniform(0, s, C).astype(np.float32))
+                 for s in (50, 1e5, 1e5, 1e5, 0.1, 50, 1e5))
+    ops = ("sum", "sum", "sum", "sum", "mean", "mean", "count")
+    return (keys, (ones, ones), vals, (vv,) * 7,
+            jnp.asarray(np.arange(C) < C - C // 9), ops), (4, 2)
+
+
+def _nulls(rng, C):
+    """NULLs in both keys and in the values; a row mask with holes."""
+    keys = tuple(jnp.asarray(rng.integers(0, d, C).astype(np.int32))
+                 for d in (4, 2))
+    kvalids = tuple(jnp.asarray(rng.random(C) > 0.2) for _ in keys)
+    vals = (jnp.asarray(rng.uniform(-9, 9, C).astype(np.float32)),) * 2
+    vvalids = tuple(jnp.asarray(rng.random(C) > 0.3) for _ in vals)
+    return (keys, kvalids, vals, vvalids, jnp.asarray(rng.random(C) > 0.4),
+            ("sum", "count")), (4, 2)
+
+
+def _emptied_slot(rng, C):
+    """The row mask takes every row of code 1 away: its slot stays
+    empty and the groups after it move up."""
+    k = rng.integers(0, 4, C).astype(np.int32)
+    ones = jnp.ones(C, bool)
+    v = jnp.asarray(rng.uniform(0, 9, C).astype(np.float32))
+    return ((jnp.asarray(k),), (ones,), (v, v), (ones, ones),
+            jnp.asarray(k != 1), ("sum", "mean")), (4,)
+
+
+def _empty_table(rng, C):
+    (keys, kv, vals, vv, _, ops), dims = _nulls(rng, C)
+    return (keys, kv, vals, vv, jnp.zeros(C, bool), ops), dims
+
+
+def _moments(rng, C):
+    """var / stddev / mean over float64 planes (the f64 accumulator)."""
+    keys = (jnp.asarray(rng.integers(0, 5, C).astype(np.int32)),)
+    v = jnp.asarray(rng.normal(3.0, 2.0, C))
+    vv = jnp.asarray(rng.random(C) > 0.1)
+    return (keys, (jnp.asarray(rng.random(C) > 0.1),), (v, v, v),
+            (vv, vv, vv), jnp.ones(C, bool),
+            ("var", "stddev", "mean")), (8,)
+
+
+def _exact_kinds(rng, C):
+    """What never rides the additive planes: integer and bool sums (the
+    exact int64 scatter), min / max, ``any_value``; beside a float sum."""
+    keys = (jnp.asarray(rng.integers(0, 3, C).astype(np.int32)),)
+    i = jnp.asarray(rng.integers(-2**40, 2**40, C))
+    b = jnp.asarray(rng.random(C) > 0.5)
+    f = jnp.asarray(rng.uniform(-5, 5, C).astype(np.float32))
+    vvalids = tuple(jnp.asarray(rng.random(C) > 0.2) for _ in range(6))
+    return (keys, (jnp.ones(C, bool),), (i, b, f, f, f, i), vvalids,
+            jnp.asarray(rng.random(C) > 0.1),
+            ("sum", "sum", "min", "max", "sum", "any_value")), (4,)
+
+
+def _one_key_at(width):
+    def build(rng, C):
+        """One key whose codes fill ``width`` slots (none for K = 1)."""
+        ones = jnp.ones(C, bool)
+        keys = () if width is None else (
+            jnp.asarray((np.arange(C) % width).astype(np.int32)),)
+        v = jnp.asarray(rng.uniform(0, 9, C).astype(np.float32))
+        return (keys, (ones,) * len(keys), (v, v), (ones, ones),
+                jnp.asarray(rng.random(C) > 0.1), ("sum", "count")), \
+            (() if width is None else (width,))
+    return build
+
+
+_BOUND = K.DENSE_MASKED_MAX_SLOTS
+_INNER_CASES = [
+    (name, build, C, wide, inner)
+    for name, build, C in [("q1_layout_524288", _q1_layout, 524288),
+                           ("nulls", _nulls, 512),
+                           ("emptied_slot", _emptied_slot, 256),
+                           ("empty_table", _empty_table, 64),
+                           ("var_stddev_f64", _moments, 2048),
+                           ("int_bool_min_max_any", _exact_kinds, 1024)]
+    for wide, inner in [(False, "masked"), (True, "matmul")]
+] + [("K_is_1", _one_key_at(None), 256, False, "masked"),
+     ("K_at_the_bound", _one_key_at(_BOUND - 1), 1024, False, "masked"),
+     ("K_one_past_the_bound", _one_key_at(_BOUND), 1024, False, "matmul")]
+
+
+@pytest.mark.parametrize("name,build,C,wide,inner", _INNER_CASES,
+                         ids=[f"{c[0]}-{c[4]}" for c in _INNER_CASES])
+def test_dense_inner_loops_match_float64_reference(name, build, C, wide,
+                                                   inner):
+    """Both inner loops give numpy's groups in numpy's order: counts and
+    integer sums bit-equal, min / max / ``any_value`` equal, float sums
+    within 1e-5 relative (f32 summed in f32 over up to 524 288 rows)."""
+    args, dims = build(np.random.default_rng(len(name) * 1000 + C), C)
+    if wide:
+        dims = _over_the_bound(dims)
+    assert K.dense_inner_loop(dims) == inner
+    assert (K.dense_slots(dims) <= _BOUND) == (inner == "masked")
+    out_cap = dcol.bucket_capacity(max(K.dense_slots(dims), 128))
+    fn = jax.jit(K.grouped_agg_dense_impl,
+                 static_argnames=("ops", "out_cap", "dims"))
+    got = _agg_rows(fn(*args[:5], ops=args[5], out_cap=out_cap, dims=dims))
+    want = _ref_dense(*args)
+    assert [k for k, _ in got] == [k for k, _ in want]
+    for (key, g), (_, w) in zip(got, want):
+        for x, y, op in zip(g, w, args[5]):
+            if y is None or isinstance(y, (int, bool)) \
+                    or op in ("min", "max", "any_value"):
+                assert x == y and type(x) is type(y), (key, op, x, y)
+            else:
+                assert x == pytest.approx(y, rel=1e-5, abs=1e-9), \
+                    (key, op, x, y)
+
+
 def test_sort_agg_overflow_signals_and_redispatch_recovers():
     """More groups than ``out_cap``: the returned group count exceeds the
     bucket (the overflow contract — the caller re-dispatches at a grown

@@ -191,6 +191,36 @@ def test_a_window_over_four_chips_is_a_launch_a_round(monkeypatch, case):
         + [("fragment.packed", None, None)] * retried
 
 
+@pytest.mark.parametrize("case,inner", [("q1_dense", "masked"),
+                                        ("sort_groups", None)])
+@pytest.mark.parametrize("launch", ["round", "table"])
+def test_a_dense_launch_says_which_inner_loop_it_takes(
+        monkeypatch, tmp_path, case, inner, launch):
+    """The dense aggregate's inner loop (``kernels.dense_inner_loop`` of
+    the table's ``dims``: Q1's six slots are summed ``masked``) is on the
+    ``device:dispatch`` span of every dense launch, a round's and a
+    table's, and on the window's strategy decision; a sort launch says
+    nothing of it."""
+    import json
+    log = tmp_path / "decisions.jsonl"
+    with visible_chips(CHIPS) as mp:
+        mp.setenv("DAFT_TPU_DISPATCH_LOG", str(log))
+        prog, tables, args, strategy = _encoded(case, [0, 1, 2, 3])
+        log.write_text("")   # what making the case decided is not asked
+        if launch == "table":
+            mp.setattr(fragment, "_launches", lambda prog, tables, hows: None)
+        _, n, _, tok, spans = _answer(prog, tables, args)
+    assert (tok.strategy, tok.inner) == (strategy, inner)
+    assert n == (1 if launch == "round" else CHIPS)
+    launched = [s["attrs"] for s in spans if s["name"] == "device:dispatch"
+                and s["attrs"].get("strategy") != "plan"]
+    assert [(a["strategy"], a.get("inner")) for a in launched] \
+        == [(strategy, inner)] * n
+    decided = [json.loads(ln) for ln in log.read_text().splitlines()]
+    assert [(d["strategy"], d.get("inner")) for d in decided
+            if d["kind"] == "groupby_strategy"] == [(strategy, inner)]
+
+
 @pytest.mark.parametrize("case,chips,cuts", [
     # chip 3 is a table short: its last round is ragged
     ("q1_dense", [0, 1, 2, 3, 0, 1, 2],

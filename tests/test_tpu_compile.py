@@ -184,6 +184,23 @@ def _fused_q1_dense(S):
     return _lower_packed(S, C, *_q1_program())
 
 
+#: the resident cells' bucket: an SF10 / SF100 lineitem file's 3.75 M rows
+RESIDENT_C = 4194304
+
+
+def _fused_q1_dense_resident(S):
+    return _lower_packed(S, RESIDENT_C, *_q1_program())
+
+
+def _wide_concatenates(text, C):
+    """The ``concatenate`` instructions of ``text`` (StableHLO or HLO)
+    that hold a ``C``-wide operand or result."""
+    import re
+    return [ln.strip() for ln in text.splitlines()
+            if re.search(r"\bconcatenate\b", ln)
+            and re.search(rf"[\[<x,]{C}[\]x>,]", ln)]
+
+
 def _scan_select(S, columns, pred, strings, words, C, w):
     """The scan's selection (fragment.get_fused_region's chain program, as
     ``executor._scan_select`` runs it) over ``columns`` at the ``w`` rung
@@ -263,6 +280,7 @@ ONE_CHIP_PROGRAMS = {
     "scan_select_q19": _scan_select_q19,
     "scan_select_q14": _scan_select_q14,
     "fused_scan_filter_agg_q1_dense": _fused_q1_dense,
+    "fused_scan_filter_agg_q1_dense_resident": _fused_q1_dense_resident,
     "sort_grouped_agg_q1_layout": _q1_sort_grouped_agg,
     "join_fused": _join_fused,
     "packed_key_argsort": _packed_argsort,
@@ -283,6 +301,31 @@ def test_program_compiles_for_one_v5e_chip(name, one_chip):
                            compiled.as_text())
         assert (sizes.count("1,128"), sizes.count("1")) \
             == SELECT_GATHERS[name], sizes
+    if name == "fused_scan_filter_agg_q1_dense_resident":
+        # 15 slots are summed by masked sums a slot and a plane: nothing
+        # stacks the additive planes (a 268 MB ``f32[11, C]`` copy of a
+        # 352.6 MB temporary until PR 47) and no plane is written out only
+        # to be read back
+        text = compiled.as_text()
+        assert _wide_concatenates(text[text.index("ENTRY"):],
+                                  RESIDENT_C) == []
+        assert compiled.memory_analysis().temp_size_in_bytes < 100e6
+
+
+@pytest.mark.parametrize("dims,inner", [((4, 2), "masked"),
+                                        ((16, 4), "matmul")])
+def test_q1_stacks_its_planes_only_over_the_slot_bound(dims, inner):
+    """Q1's ``run_packed`` as traced (StableHLO, the CPU backend: no
+    topology): at and under ``kernels.DENSE_MASKED_MAX_SLOTS`` slots no
+    ``concatenate`` holds a C-wide operand; over it the one stack of the
+    additive planes is there."""
+    C = 524288
+    prog, (out_cap, strategy, _) = _q1_program()
+    assert kernels.dense_inner_loop(dims) == inner
+    text = _lower_packed(jax.ShapeDtypeStruct, C, prog,
+                         (out_cap, strategy, dims)).as_text()
+    wide = _wide_concatenates(text, C)
+    assert len(wide) == (0 if inner == "masked" else 1), wide
 
 
 def test_sharded_grouped_agg_compiles_for_four_chip_mesh(topo):
