@@ -87,7 +87,7 @@ def main():
         rec["dropped_max"] = max((s.get("dropped", 0) for s in fin), default=None)
         rec["spans_max"] = max((s.get("spans", 0) for s in fin), default=None)
         for s in fin[-12:]:
-            keep.append({k: s.get(k) for k in ("wall_us", "covered_us", "tables", "selects", "agg_launches", "joins", "decode", "chips", "t0_perf_s",
+            keep.append({k: s.get(k) for k in ("wall_us", "covered_us", "tables", "selects", "agg_launches", "joins", "plan", "decode", "chips", "t0_perf_s",
                                                "spans", "dropped", "holes", "handoffs", "waits_short")}
                         | {"phases": {n: {k: p.get(k) for k in ("count", "wall_us", "sum_us", "bytes", "cpu_us", "timed_us")}
                                       for n, p in (s.get("phases") or {}).items()}})

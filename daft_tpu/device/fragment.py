@@ -2142,6 +2142,11 @@ def drain_join_agg(tok: InflightJoinAgg):
                 dc = dcol.DeviceColumn(vv, vm, f.dtype, None)
                 cols.append(dcol.decode_column(f.name, dc, g))
             out = RecordBatch.from_series(cols)
+        # the region matched this probe morsel against its build side
+        # inside the program: ``joins.match_indices`` never saw the pair
+        from .. import joins
+        joins.tally_pair("device", tok.dt.row_count,
+                         tok.build.dt.row_count, total)
         n_ops = max(len(prog.fused_ops), 3)
         secs = tok.submitted_s + (_time.perf_counter() - t_drain0)
         costmodel.ledger_record(

@@ -140,9 +140,8 @@ def match_indices(l_gids: np.ndarray, r_gids: np.ndarray,
     if use_device:
         out = _device_match_indices(l_gids, r_gids, l_valid, r_valid)
         if out is not None:
-            _tally_pair("device", n_l, n_r)
+            tally_pair("device", n_l, n_r, len(out[0]))
             return out
-    _tally_pair("host", n_l, n_r)
     # build: the right side's keys, sorted
     with tracing.span("join:build", lane="pipeline",
                       attrs={"rows": n_r, "side": "right", "step": "sort",
@@ -166,16 +165,22 @@ def match_indices(l_gids: np.ndarray, r_gids: np.ndarray,
         offsets = np.arange(total) - np.repeat(cum, counts)
         ri = r_sorted_idx[np.repeat(starts, counts) + offsets]
         sp.set("pairs", total)
+    tally_pair("host", n_l, n_r, total)
     return li, ri, counts
 
 
-def _tally_pair(tier: str, n_l: int, n_r: int) -> None:
-    """One bucket pair of ``match_indices`` on the query's trace: which
-    tier matched it, its rows, and the largest pair so far
-    (``summary()["joins"]``)."""
+def tally_pair(tier: str, n_l: int, n_r: int, pairs: int) -> None:
+    """One matched bucket pair on the query's trace: which tier matched
+    it, its rows, the rows of its smaller side, the index pairs it gave
+    (its output rows) and the largest pair so far
+    (``summary()["joins"]``). ``match_indices`` tallies here, and so does
+    a join matched without passing it (``fragment.drain_join_agg``: a
+    probe morsel against the fused region's build side)."""
     from . import tracing
     tracing.tally(f"join_pairs_{tier}")
     tracing.tally(f"join_rows_{tier}", n_l + n_r)
+    tracing.tally("join_rows_small", min(n_l, n_r))
+    tracing.tally("join_rows_out", pairs)
     tracing.tally_max("join_max_pair_rows", n_l + n_r)
 
 

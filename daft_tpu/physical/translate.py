@@ -57,6 +57,28 @@ def translate(plan: lp.LogicalPlan) -> pp.PhysicalPlan:
             _tl.memo = {}
 
 
+def repeated_scans(plan: pp.PhysicalPlan) -> int:
+    """The scans of a physical plan that read a file an earlier scan of
+    the same plan reads (a scan shared by several consumers is one
+    scan): what a common-subplan rule or a wider column set would save.
+    ``summary()["plan"]["repeated_scans"]``."""
+    seen: set = set()
+    visited: set = set()
+    repeated = 0
+    stack = [plan]
+    while stack:
+        node = stack.pop()
+        if id(node) in visited:
+            continue
+        visited.add(id(node))
+        if isinstance(node, pp.ScanSource):
+            paths = {p for t in node.tasks for p in t.paths}
+            repeated += bool(paths & seen)
+            seen |= paths
+        stack.extend(reversed(node.children))
+    return repeated
+
+
 def _nondeterministic(node: lp.LogicalPlan) -> bool:
     """True when the subtree's output is not a pure function of its
     inputs — e.g. an unseeded Sample. Such subtrees must never merge:

@@ -15,7 +15,7 @@ from typing import Iterator, List, Optional, Tuple
 
 from ..execution.executor import LocalExecutor
 from ..micropartition import MicroPartition
-from ..physical.translate import translate
+from ..physical.translate import repeated_scans, translate
 from .runner import Runner
 
 
@@ -59,6 +59,9 @@ class NativeRunner(Runner):
                         sp.set("file_stats", files["stats"])
                 with tracing.span("plan:translate", lane="planner"):
                     pplan = translate(optimized.plan)
+                    if tracing.current() is not None:
+                        tracing.tally("plan_repeated_scans",
+                                      repeated_scans(pplan))
                 executor = make_local_executor(cfg)
                 it = executor.run(pplan)
         except BaseException:

@@ -323,9 +323,12 @@ class SpanRecorder:
         ``footers`` (Parquet footers planned ``from_store`` or ``read``),
         ``files`` (the files its scans ``planned`` and the ``stats``, the
         stat-like system calls, it made on them),
+        ``plan`` (what the physical plan asks for, counted once where it
+        is built: :data:`PLAN_TALLIES`),
         ``decode`` (the packed results of how many device ``tables`` were
         decoded into how many record ``batches``), ``joins`` (the bucket
-        pairs ``joins.match_indices`` matched: :data:`JOIN_TALLIES`),
+        pairs the query's joins matched, their rows in, the rows of their
+        smaller sides and their rows out: :data:`JOIN_TALLIES`),
         ``selects`` (the filtered scans' tables that ended in rows:
         :data:`SELECT_TALLIES`), ``agg_launches`` (the fused aggregate's
         window tables by the launch that answered them:
@@ -371,6 +374,8 @@ class SpanRecorder:
             out["tables"] = {k: tallies.get(k, 0) for k in TABLE_SOURCES}
             out["footers"] = _footer_counts(tallies)
             out["files"] = _file_counts(tallies)
+            out["plan"] = {k: tallies.get("plan_" + k, 0)
+                           for k in PLAN_TALLIES}
             out["decode"] = {"tables": tallies.get("decode_tables", 0),
                              "batches": tallies.get("decode_batches", 0)}
             out["joins"] = {k: tallies.get("join_" + k, 0)
@@ -441,12 +446,28 @@ _LIFELONG_SPANS = frozenset(("pipeline:stage", "query"))
 
 #: ``summary()["joins"]``: the bucket pairs ``joins.match_indices`` matched
 #: with the fused device program (``join:device``) or on the host
-#: (``join:build`` + ``join:probe``), the rows (left + right) of each
-#: tier's pairs, and the rows of the largest pair: the size the join
-#: gate's break-even (``costmodel.join_wins``) is compared with. Tallied
-#: as ``join_<key>`` on the query's root span
+#: (``join:build`` + ``join:probe``), and the probe morsels a ``join_agg``
+#: region matched against its build side inside its own program (device
+#: pairs too: ``fragment.drain_join_agg``); the rows (left + right) of
+#: each tier's pairs; the rows of the largest pair: the size the join
+#: gate's break-even (``costmodel.join_wins``) is compared with; and, over
+#: both tiers, ``rows_small`` (the rows of each pair's smaller side: a
+#: side matched against many morsels, a broadcast build side, counts once
+#: a pair) and ``rows_out`` (the index pairs matched: an inner join's
+#: output rows; the unmatched rows an outer join adds are not in it).
+#: ``rows_out`` over rows in is what a join kept of what it was handed.
+#: Tallied as ``join_<key>`` on the query's root span (``joins.tally_pair``)
 JOIN_TALLIES = ("pairs_device", "pairs_host", "rows_device", "rows_host",
-                "max_pair_rows")
+                "max_pair_rows", "rows_small", "rows_out")
+
+#: ``summary()["plan"]``: ``repeated_scans``, the scans of the physical
+#: plan that read a file an earlier scan of the same plan reads
+#: (``physical.translate.repeated_scans``: Q17's two joins of ``part`` to
+#: ``lineitem`` need other columns, so ``translate`` cannot share them and
+#: both tables are read twice: 2). Tallied as ``plan_<key>`` on the
+#: query's root span, once a query, where the physical plan is built
+#: (``runners/native_runner.py``)
+PLAN_TALLIES = ("repeated_scans",)
 
 #: ``summary()["selects"]``: the tables of filtered scans that ended in
 #: rows (a scan under a join, a sort, a projection: not under a fused

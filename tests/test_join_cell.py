@@ -110,6 +110,13 @@ def test_the_tally_adds_up_to_the_calls(runs, q, mode):
     assert j["max_pair_rows"] == max(
         s["attrs"]["rows_left"] + s["attrs"]["rows_right"]
         for s in device + host)
+    assert j["rows_small"] == sum(
+        min(s["attrs"]["rows_left"], s["attrs"]["rows_right"])
+        for s in device + host)
+    assert 0 < 2 * j["rows_small"] <= j["rows_device"] + j["rows_host"]
+    matched = device + [s for s in spans if s["name"] == "join:probe"
+                        and s["attrs"]["step"] == "match"]
+    assert j["rows_out"] == sum(s["attrs"]["pairs"] for s in matched) > 0
     if mode == "forced-on":
         assert j["pairs_host"] == 0 and j["rows_host"] == 0
         assert all({"capacity", "pairs", "bytes"} <= set(s["attrs"])
@@ -153,7 +160,9 @@ def test_device_join_span_counts_as_covered():
     assert s["covered_us"] == s["phases"]["join:device"]["wall_us"] >= 9_000
     assert s["joins"] == {"pairs_device": 0, "pairs_host": 0,
                           "rows_device": 0, "rows_host": 0,
-                          "max_pair_rows": 7}
+                          "max_pair_rows": 7, "rows_small": 0,
+                          "rows_out": 0}
+    assert s["plan"] == {"repeated_scans": 0}
     tracing.reset_for_tests()
 
 
